@@ -1,0 +1,211 @@
+"""The main path's programs, compiled for a described v5e with no chip
+attached (the only file in the repository that describes a chip).
+
+The TPU's compiler is installed here and compiles for a topology that is
+described, not attached: what it refuses — a misaligned slice, too much
+fast memory, a kernel that cannot be partitioned, a program that does
+not fit 16 GB — it refuses before any chip time is spent. Nothing runs,
+so this says nothing about results or times; ``chip_smoke.py`` does.
+
+Only one process may load the TPU's library, so the topology is
+described inside a module-scoped fixture (never at import time, not
+``autouse``, not in ``conftest.py``) and every compile happens in the
+test's own process, with the persistent compilation cache off around it
+(an entry written without a chip cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+ROWS = 1 << 17
+K = 104                 # size_bound(compression=100)
+CHUNK = 16384           # example.yaml store_chunk
+COMPRESSION = 100.0
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    from veneur_tpu.parallel.mesh import fleet_mesh
+
+    m = fleet_mesh(topo.devices, hosts=2)
+    assert dict(m.shape) == {"series": 2, "hosts": 2}
+    return m
+
+
+@pytest.fixture(scope="module")
+def kernel_admitted():
+    """``pallas_ok`` asks ``jax.default_backend()``, which is the CPU
+    here: steer it in the test, as the ops would answer on the chip."""
+    from veneur_tpu.ops import tdigest_pallas
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(tdigest_pallas, "pallas_ok",
+                      lambda a: a.ndim == 2 and a.dtype == jnp.float32)
+        yield
+
+
+@pytest.fixture(scope="module")
+def one_chip_flush(one_chip, kernel_admitted):
+    """``_flush_digests`` compiled for one described chip (two tests
+    read it; it compiles once)."""
+    from veneur_tpu.core.store import _flush_digests
+
+    digest, temp = (_on(t, one_chip) for t in _digest_state(ROWS))
+    rows = _f32((ROWS,), one_chip)
+    return _flush_digests.lower(
+        digest, temp, rows, rows, _f32((4,), one_chip), COMPRESSION,
+        True).compile()
+
+
+def _on(tree, sharding):
+    """Shapes of ``tree`` placed by ``sharding`` (one sharding, or a
+    matching tree of them)."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        sharding = jax.tree.map(lambda _: sharding, tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sharding)
+
+
+def _f32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _i32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _digest_state(rows):
+    from veneur_tpu.ops import tdigest as td
+
+    digest = jax.eval_shape(lambda: td.init((rows,), COMPRESSION, K))
+    temp = jax.eval_shape(lambda: td.init_temp(rows, K, COMPRESSION))
+    return digest, temp
+
+
+def _nbytes(tree):
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("sort_b", [False, True])
+def test_drain_quantile_kernel(one_chip, sort_b):
+    from veneur_tpu.ops.tdigest_pallas import _drain_quantile_pallas
+
+    plane = _f32((ROWS, K), one_chip)
+    compiled = _drain_quantile_pallas.lower(
+        plane, plane, plane, plane, _f32((ROWS,), one_chip),
+        _f32((ROWS,), one_chip), _f32((4,), one_chip),
+        compression=COMPRESSION, out_size=K, sort_b=sort_b).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compress_presorted_kernel(one_chip):
+    from veneur_tpu.ops.tdigest_pallas import _compress_presorted_pallas
+
+    plane = _f32((ROWS, K), one_chip)
+    compiled = _compress_presorted_pallas.lower(
+        plane, plane, plane, plane, compression=COMPRESSION,
+        out_size=K).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flush_and_ingest_programs_hold_the_kernel(one_chip,
+                                                   one_chip_flush,
+                                                   kernel_admitted):
+    from veneur_tpu.core.store import _ingest_samples
+
+    digest, temp = (_on(t, one_chip) for t in _digest_state(ROWS))
+    flush = one_chip_flush
+    assert "tpu_custom_call" in flush.as_text()
+    ingest = _ingest_samples.lower(
+        digest, temp, _i32((CHUNK,), one_chip), _f32((CHUNK,), one_chip),
+        _f32((CHUNK,), one_chip), COMPRESSION, True).compile()
+    assert "tpu_custom_call" in ingest.as_text()
+    # one generation's planes, the flush's scratch and its outputs fit
+    # the chip's 16 GB many times over at this size
+    mem = flush.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < (16 << 30) // 8
+
+
+def test_hll_insert_and_estimate(one_chip):
+    """The set group's two programs at p=14: the register scatter-max
+    of an ingest chunk and the batched estimate of a flush."""
+    from veneur_tpu.core.store import _estimate_all, _ingest_hashes
+    from veneur_tpu.ops import hll
+
+    regs = jax.ShapeDtypeStruct((4096, hll.num_registers(14)), jnp.int8,
+                                sharding=one_chip)
+    u32 = jax.ShapeDtypeStruct((CHUNK,), jnp.uint32, sharding=one_chip)
+    _ingest_hashes.lower(regs, _i32((CHUNK,), one_chip), u32,
+                         u32).compile()
+    _estimate_all.lower(regs).compile()
+
+
+def test_mesh_programs_shard_state_over_series(one_chip_flush, mesh,
+                                               kernel_admitted):
+    """The four-chip global: the flush and the routed import compile
+    with the kernel inside ``shard_map``, and each device is handed its
+    series block of the state, not a replica of all of it."""
+    from veneur_tpu.core.mesh_store import (_digest_specs,
+                                            _mesh_flush_digests,
+                                            _mesh_import_routed)
+
+    temp_spec, dig_spec, _sk, s = _digest_specs()
+    named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    digest, temp = _digest_state(ROWS)
+    state_bytes = _nbytes(digest) + _nbytes(temp)
+    m_digest = _on(digest, jax.tree.map(named, dig_spec))
+    m_temp = _on(temp, jax.tree.map(named, temp_spec))
+    m_rows = _f32((ROWS,), named(s))
+
+    flush = _mesh_flush_digests.lower(
+        m_digest, m_temp, m_rows, m_rows, _f32((4,), named(P())), mesh,
+        COMPRESSION).compile()
+    assert "tpu_custom_call" in flush.as_text()
+    per_device = flush.memory_analysis().argument_size_in_bytes
+    assert (per_device
+            < one_chip_flush.memory_analysis().argument_size_in_bytes)
+    assert per_device <= 0.55 * state_bytes + (1 << 20)
+
+    shards = mesh.shape["series"]
+    st = named(P("series", None))
+    stack_f = _f32((shards, CHUNK), st)
+    stack_i = _i32((shards, CHUNK), st)
+    imp = _mesh_import_routed.lower(
+        m_temp, m_digest, m_rows, m_rows, stack_i, stack_f, stack_f,
+        stack_i, stack_f, stack_f, mesh, COMPRESSION, K).compile()
+    assert "tpu_custom_call" in imp.as_text()
+    assert (imp.memory_analysis().argument_size_in_bytes
+            <= 0.55 * state_bytes + (8 << 20))

@@ -67,11 +67,13 @@ def _fill(store, rng, n_hist=24, hot_every=3):
 class TestShardRouter:
     def test_deterministic_and_ring_aligned(self):
         """The router IS the proxy ring rule: same CRC32 ring, members
-        named shard-<i>, same ``name + type + joined_tags`` key."""
+        named shard-<i>, same ``name + type + joined_tags`` key (with
+        the router's own count of virtual points a member)."""
         from veneur_tpu.proxy.consistent import ConsistentRing
 
         router = ShardRouter(4)
-        ring = ConsistentRing([f"shard-{i}" for i in range(4)])
+        ring = ConsistentRing([f"shard-{i}" for i in range(4)],
+                              replicas=router.replicas)
         for i in range(200):
             name, jt = f"api.latency.{i}", "env:prod,az:b"
             want = int(ring.get(name + "timer" + jt).split("-")[1])
@@ -84,9 +86,18 @@ class TestShardRouter:
         hits = np.zeros(4, np.int64)
         for i in range(2000):
             hits[router.shard_for(f"svc.metric.{i}", "histogram", "")] += 1
-        # consistent hashing with 20 replicas/member: rough balance
         assert hits.min() > 0
-        assert hits.max() / hits.mean() < 2.0
+        assert hits.max() / hits.mean() < 1.5
+
+    @pytest.mark.parametrize("shards", [2, 4, 8])
+    def test_balance_at_small_shard_counts(self, shards):
+        """A four-chip host is two series-shards: the ring must not
+        hand one of them most of the series."""
+        router = ShardRouter(shards)
+        hits = np.zeros(shards, np.int64)
+        for i in range(20000):
+            hits[router.shard_for(f"smoke.h.{i}", "histogram", "")] += 1
+        assert hits.max() / hits.mean() < 1.3, hits
 
     def test_single_shard_short_circuit(self):
         assert ShardRouter(1).shard_for("x", "counter", "") == 0

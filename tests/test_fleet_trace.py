@@ -407,6 +407,19 @@ def local_global():
 class TestEndToEndStitch:
     def test_single_trace_id_stitches_all_hops(self, local_global):
         g, gsink, lo, lsink = local_global
+        # a warm-up interval through every hop first: the first
+        # local flush, forward, import and global flush compile and
+        # import their code between hops, and that wall-clock is
+        # set-up, not a gap in the traced interval's hop coverage
+        lo.handle_metric_packet(b"fleet.warm:3|c|#veneurglobalonly")
+        lo.handle_metric_packet(b"local.only:1|c")
+        lo.flush()
+        lsink.get_flush()
+        _wait(lambda: g.obs_hops.snapshot()["pending"] >= 1,
+              msg="warm-up import hop")
+        g.flush()
+        gsink.get_flush()
+
         for i in range(5):
             lo.handle_metric_packet(
                 f"fleet.c{i}:3|c|#veneurglobalonly".encode())

@@ -1,10 +1,10 @@
 """Hardware smoke subset (@pytest.mark.tpu): the accuracy oracles that
 normally run on the virtual CPU mesh, executed on the REAL accelerator.
 
-bench.py runs this file with ``VENEUR_TPU_TESTS=1`` in the bench
-environment and records the result in the bench JSON, closing the gap
-between "tests green on CPU" and "correct on hardware" (VERDICT round-3
-weak #5). Accuracy bounds match the reference's own test envelopes
+Run it with ``VENEUR_TPU_TESTS=1`` in a process that may hold the chip.
+It checks the kernels alone; ``chip_smoke.py`` at the repo root is the
+proof that the served path (``veneur_tpu.cli.server``, UDP in, sink
+out) is right on the chip, with one process per chip. Accuracy bounds match the reference's own test envelopes
 (t-digest eps=.02 over 100k uniform samples, histo_test.go:11-25; HLL
 ~2% at precision 14)."""
 

@@ -6,15 +6,38 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 import signal
 import sys
 import threading
+from typing import Optional
 
 from veneur_tpu.cli import upgrade
 from veneur_tpu.config import read_config
 from veneur_tpu.server import Server
 
 log = logging.getLogger("veneur")
+
+# <checkout>/.jax_cache: a fixed path, because the path is part of the
+# cache key's reach — a directory that moves (a temporary name, a pid,
+# a time) never hits
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def place_compile_cache() -> Optional[str]:
+    """Give JAX's persistent compilation cache a home, so a restart
+    does not pay every first compile again. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set in code (returns None); otherwise the fixed
+    in-checkout path is used and returned."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main(argv=None) -> int:
@@ -36,6 +59,7 @@ def main(argv=None) -> int:
         level=logging.DEBUG if config.debug else logging.INFO,
         format="%(asctime)s %(levelname)s %(name)s %(message)s")
 
+    place_compile_cache()
     server = Server(config)
 
     done = threading.Event()

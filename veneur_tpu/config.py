@@ -132,7 +132,12 @@ class Config:
     hll_precision: int = 14
     # staging-chunk length for device scatters
     store_chunk: int = 16384
-    # initial dense-series capacity per scope-class (grows by doubling)
+    # initial dense-series capacity per scope-class (grows by doubling;
+    # every capacity is a new shape of the ingest and flush programs, so
+    # a deployment that knows its cardinality fixes it here). Digest
+    # groups place their device state on first write; set and
+    # heavy-hitter groups start at no more than 4096 rows whatever this
+    # says (a set row is 16 KiB of registers)
     store_initial_capacity: int = 4096
     # histogram/timer digest backing store: "dense" (one [S,K] plane per
     # group, default), "slab" (flat per-slab planes, the multi-million-

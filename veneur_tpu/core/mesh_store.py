@@ -44,7 +44,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from veneur_tpu.core.store import (IMPORT_DRAIN_BATCH, _GROW_FACTOR,
@@ -57,7 +57,7 @@ from veneur_tpu.obs import recorder as obs_rec
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.ops import tdigest as td_ops
 from veneur_tpu.parallel import collectives
-from veneur_tpu.parallel.mesh import HOSTS_AXIS, SERIES_AXIS, shard_map
+from veneur_tpu.parallel.mesh import HOSTS_AXIS, SERIES_AXIS
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -411,26 +411,28 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         old_block = self.capacity // self.shards
         self.capacity *= _GROW_FACTOR
         sh, ob = self.shards, old_block
-        self.temp = td_ops.TempCentroids(
-            sum_w=_blocked_pad(self.temp.sum_w, sh, ob),
-            sum_wm=_blocked_pad(self.temp.sum_wm, sh, ob),
-            seg_w=_blocked_pad(self.temp.seg_w, sh, ob),
-            seg_wm=_blocked_pad(self.temp.seg_wm, sh, ob),
-            count=_blocked_pad(self.temp.count, sh, ob),
-            vsum=_blocked_pad(self.temp.vsum, sh, ob),
-            vmin=_blocked_pad(self.temp.vmin, sh, ob, fill=np.inf),
-            vmax=_blocked_pad(self.temp.vmax, sh, ob, fill=-np.inf),
-            recip=_blocked_pad(self.temp.recip, sh, ob),
-        )
-        self.digest = td_ops.TDigest(
-            mean=_blocked_pad(self.digest.mean, sh, ob, fill=np.inf),
-            weight=_blocked_pad(self.digest.weight, sh, ob),
-            min=_blocked_pad(self.digest.min, sh, ob, fill=np.inf),
-            max=_blocked_pad(self.digest.max, sh, ob, fill=-np.inf),
-        )
-        self.dmin = _blocked_pad(self.dmin, sh, ob, fill=np.inf)
-        self.dmax = _blocked_pad(self.dmax, sh, ob, fill=-np.inf)
-        self._place()
+        # nothing placed yet: the first touch allocates at the new size
+        if "temp" in self.__dict__:
+            self.temp = td_ops.TempCentroids(
+                sum_w=_blocked_pad(self.temp.sum_w, sh, ob),
+                sum_wm=_blocked_pad(self.temp.sum_wm, sh, ob),
+                seg_w=_blocked_pad(self.temp.seg_w, sh, ob),
+                seg_wm=_blocked_pad(self.temp.seg_wm, sh, ob),
+                count=_blocked_pad(self.temp.count, sh, ob),
+                vsum=_blocked_pad(self.temp.vsum, sh, ob),
+                vmin=_blocked_pad(self.temp.vmin, sh, ob, fill=np.inf),
+                vmax=_blocked_pad(self.temp.vmax, sh, ob, fill=-np.inf),
+                recip=_blocked_pad(self.temp.recip, sh, ob),
+            )
+            self.digest = td_ops.TDigest(
+                mean=_blocked_pad(self.digest.mean, sh, ob, fill=np.inf),
+                weight=_blocked_pad(self.digest.weight, sh, ob),
+                min=_blocked_pad(self.digest.min, sh, ob, fill=np.inf),
+                max=_blocked_pad(self.digest.max, sh, ob, fill=-np.inf),
+            )
+            self.dmin = _blocked_pad(self.dmin, sh, ob, fill=np.inf)
+            self.dmax = _blocked_pad(self.dmax, sh, ob, fill=-np.inf)
+            self._place()
         if self.placement is not None:
             self.placement.grow()
         # re-point staging padding at the new out-of-range row id

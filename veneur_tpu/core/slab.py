@@ -883,6 +883,12 @@ class SlabDigestGroup(OverloadLimited):
 
     # -- flush ------------------------------------------------------------
 
+    def _kernel_plane(self):
+        """The digest plane as the flush ops see it: whatever the
+        storage dtype, a slab is widened to f32 [slab_rows, K] before
+        the drain, and that is what ``pallas_ok`` judges."""
+        return jax.ShapeDtypeStruct((self.slab_rows, self.k), jnp.float32)
+
     def _reset_device(self):
         nslabs = len(self.digests)
         self.digests = [
@@ -945,7 +951,8 @@ class SlabDigestGroup(OverloadLimited):
         out = run_compute_ladder(
             self._compute,
             lambda use_pallas: self._flush_fetch(
-                n, percentiles, want_digests, want_stats, use_pallas))
+                n, percentiles, want_digests, want_stats, use_pallas),
+            self._kernel_plane())
         return self._flush_commit(out)
 
     def flush_begin(self, percentiles: List[float], want_digests=True,
@@ -968,7 +975,8 @@ class SlabDigestGroup(OverloadLimited):
             lambda use_pallas: self._flush_dispatch(
                 n, percentiles, want_digests, want_stats, use_pallas),
             lambda st, use_pallas: self._flush_collect(
-                st, n, percentiles, want_digests))
+                st, n, percentiles, want_digests),
+            self._kernel_plane())
         return lambda: self._flush_commit(fin())
 
     def _flush_empty(self):

@@ -1088,7 +1088,10 @@ class TieredDigestGroup(OverloadLimited):
         out = run_compute_ladder(
             self._compute,
             lambda use_pallas: self._flush_fetch(
-                n, percentiles, want_digests, want_stats, use_pallas))
+                n, percentiles, want_digests, want_stats, use_pallas),
+            # both tiers hand the ops f32 [rows, K] planes (the pool is
+            # dequantized first), so one plane stands for the group
+            jax.ShapeDtypeStruct((self.slab_rows, self.pk), jnp.float32))
         self._end_interval(n)
         interner, self.interner = self.interner, Interner()
         self._device_dirty = False

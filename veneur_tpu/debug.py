@@ -131,6 +131,37 @@ def _group_depths(store) -> Dict[str, Dict[str, int]]:
     return out
 
 
+def device_section(store=None) -> dict:
+    """The device in the program's own words: what JAX reports, and
+    where the histogram digest planes actually live (``.devices()`` of
+    the arrays, not ``jax.devices()`` alone — code that has only seen
+    one chip may leave everything on the first)."""
+    import jax
+
+    devs = jax.devices()
+    out = {"platform": devs[0].platform,
+           "device_kind": devs[0].device_kind,
+           "count": len(devs)}
+    # read through __dict__: a debug read must not be the first touch
+    # that allocates a lazily-placed group's device state
+    group = getattr(store, "histograms", None)
+    digest = getattr(group, "__dict__", {}).get("digest")
+    if digest is not None:
+        held = sorted(digest.mean.devices(), key=lambda d: d.id)
+        out["digest_planes"] = {
+            "devices": [str(d) for d in held],
+            "platform": held[0].platform,
+            "shape": list(digest.mean.shape),
+            "dtype": str(digest.mean.dtype)}
+    # the CPU backend reports no memory statistics
+    peaks = [stats["peak_bytes_in_use"]
+             for stats in (d.memory_stats() for d in devs)
+             if stats and "peak_bytes_in_use" in stats]
+    if peaks:
+        out["peak_bytes_in_use"] = max(peaks)
+    return out
+
+
 def collect_vars(server) -> dict:
     """Store/lane/queue depth snapshot (expvar's role). Every field is
     best-effort: a debug endpoint must never take down the server."""
@@ -146,6 +177,10 @@ def collect_vars(server) -> dict:
             }
     except Exception as e:  # pragma: no cover - diagnostic only
         out["store_error"] = repr(e)
+    try:
+        out["device"] = device_section(getattr(server, "store", None))
+    except Exception as e:  # pragma: no cover - diagnostic only
+        out["device_error"] = repr(e)
     for counter in ("packet_errors", "packet_drops", "spans_dropped"):
         # packet_errors/spans_dropped are read-side sums over sharded
         # per-thread cells + per-lane tallies (veneur_tpu/ingest/):

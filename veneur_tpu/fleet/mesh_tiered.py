@@ -41,7 +41,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from veneur_tpu.core.locking import requires_lock
@@ -59,7 +59,7 @@ from veneur_tpu.obs import kernels as obs_kernels
 from veneur_tpu.obs import recorder as obs_rec
 from veneur_tpu.ops import tdigest as td_ops
 from veneur_tpu.ops.tdigest_pallas import _next_pow2
-from veneur_tpu.parallel.mesh import SERIES_AXIS, shard_map
+from veneur_tpu.parallel.mesh import SERIES_AXIS
 
 
 def _pool_spec() -> PoolSlab:

@@ -33,10 +33,8 @@ import time
 from contextlib import contextmanager
 from typing import Dict, Optional, Tuple
 
-try:  # pragma: no cover - jax is present everywhere we run
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover
-    _TraceAnnotation = None
+import jax
+from jax.profiler import TraceAnnotation
 
 # profiler scope names carry this prefix in xprof captures
 SCOPE_PREFIX = "veneur."
@@ -50,6 +48,27 @@ _xprof_lock = threading.Lock()
 # GIL across the read-modify-write (single bytecode effects are close
 # enough for telemetry; dispatches are chunk-scale, not packet-scale).
 _dispatches: Dict[str, int] = {}
+
+# what compiling cost this process, in JAX's own accounting: seconds
+# inside the backend compile (a persistent-cache hit counts the load it
+# paid instead), programs compiled, and persistent-cache hits. A
+# bring-up reads these to tell set-up from steady state.
+_compile = {"seconds": 0.0, "programs": 0, "cache_hits": 0}
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        _compile["seconds"] += seconds
+        _compile["programs"] += 1
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _compile["cache_hits"] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 # ---------------------------------------------------------------------------
 # the scope coverage map — drift-checked against the lint inventory
@@ -146,15 +165,12 @@ PROGRAM_SCOPES: Dict[str, Tuple[str, Optional[Tuple[str, str]]]] = {
 
 @contextmanager
 def scope(name: str):
-    """One named dispatch region: counts the dispatch and, when the
-    profiler is importable, labels the region in xprof captures. Cheap
+    """One named dispatch region: counts the dispatch and labels the
+    region in xprof captures. Cheap
     enough for the per-chunk drain paths (a dict bump + one context
     object); NOT for per-packet paths."""
     _dispatches[name] = _dispatches.get(name, 0) + 1
-    if _TraceAnnotation is None:  # pragma: no cover - jax always present
-        yield
-        return
-    with _TraceAnnotation(SCOPE_PREFIX + name):
+    with TraceAnnotation(SCOPE_PREFIX + name):
         yield
 
 
@@ -193,9 +209,15 @@ def compiles_total() -> int:
 
 def snapshot() -> dict:
     """The /debug/vars "kernels" section: dispatches per scope plus
-    compiled-variant counts per inventory program."""
+    compiled-variant counts per inventory program and the process's
+    compile bill."""
+    from veneur_tpu.ops import tdigest_pallas
+
     return {"dispatches": dispatch_snapshot(),
-            "compiled_variants": compile_snapshot()}
+            "compiled_variants": compile_snapshot(),
+            "kernel_traces": dict(tdigest_pallas.TRACED),
+            "compile": dict(_compile, seconds=round(_compile["seconds"],
+                                                    3))}
 
 
 def capture_xprof(seconds: float, base_dir: Optional[str] = None) -> tuple:

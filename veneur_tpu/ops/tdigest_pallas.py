@@ -278,6 +278,14 @@ def _drain_kernel(ma_ref, wa_ref, mb_ref, wb_ref, mn_ref, mx_ref, qs_ref,
                                      qs_ref[...], kout, nq)
 
 
+# kernel -> times its body was traced for the backend (not interpret
+# mode). These programs are only ever called from inside other jitted
+# programs, where their own executable cache stays empty; a trace is
+# the evidence that the Mosaic kernel went into a compiled program
+# (obs/kernels.py reports it next to the compiled-variant counts).
+TRACED = {"_drain_quantile_pallas": 0, "_compress_presorted_pallas": 0}
+
+
 @functools.partial(jax.jit,
                    static_argnames=("compression", "out_size", "interpret",
                                     "sort_b"))
@@ -289,6 +297,7 @@ def _drain_quantile_pallas(mean_a, weight_a, mean_b, weight_b, mn, mx, qs,
     in which case the kernel sorts them in VMEM. mn/mx are the final
     per-row extrema [S]; qs is [P]. Rows are processed in <= 1M-row slabs
     to respect Mosaic's 32-bit operand addressing."""
+    TRACED["_drain_quantile_pallas"] += not interpret
     s = mean_a.shape[0]
     if s > _MAX_SLAB_ROWS:
         outs = [
@@ -376,6 +385,7 @@ def _compress_presorted_pallas(mean_a, weight_a, mean_b, weight_b,
                                compression: float, out_size: int,
                                interpret: bool = False,
                                sort_b: bool = False):
+    TRACED["_compress_presorted_pallas"] += not interpret
     s = mean_a.shape[0]
     if s > _MAX_SLAB_ROWS:
         outs = [
@@ -437,12 +447,11 @@ def _compress_presorted_slab(mean_a, weight_a, mean_b, weight_b,
 
 
 def pallas_ok(mean_a: jax.Array) -> bool:
-    """The kernel applies to [S, K] f32 batches on a real TPU backend."""
-    try:
-        on_tpu = jax.default_backend() == "tpu" or any(
-            d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:  # pragma: no cover - backend init failure
-        return False
+    """The kernel applies to [S, K] f32 batches on a real TPU backend.
+    A backend that cannot initialise raises here: that is an error, not
+    a reason to take the XLA path quietly."""
+    on_tpu = jax.default_backend() == "tpu" or any(
+        d.platform == "tpu" for d in jax.devices())
     return (on_tpu and mean_a.ndim == 2
             and mean_a.dtype == jnp.float32)
 

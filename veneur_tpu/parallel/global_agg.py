@@ -25,11 +25,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# version-compat shard_map wrapper (check_vma/check_rep rename)
-from veneur_tpu.parallel.mesh import shard_map
 
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.ops import tdigest as td_ops

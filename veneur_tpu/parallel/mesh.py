@@ -10,31 +10,12 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-import inspect
-
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 HOSTS_AXIS = "hosts"
 SERIES_AXIS = "series"
-
-try:  # JAX >= 0.4.35 exports shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-# replication-check kwarg rename across JAX versions: new builds take
-# ``check_vma``, 0.4.x takes ``check_rep``; translate so the mesh call
-# sites work on both (keeps the multi-device lane runnable everywhere)
-_SM_CHECK_KW = ("check_vma"
-                if "check_vma" in inspect.signature(_shard_map).parameters
-                else "check_rep")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SM_CHECK_KW: check_vma})
 
 
 def _largest_pow2_divisor(n: int, cap: int) -> int:

@@ -58,6 +58,10 @@ class ComputeBreaker:
         self.fallback_total = 0   # group flushes completed on the jnp rung
         self.requeued_total = 0   # rung 3: generations re-merged, late
         self.lost_total = 0       # every rung failed; checkpoint bounds it
+        # the program the last digest flush really ran: "pallas" only
+        # when the fused kernel was admitted into it (core/store.py
+        # _note_rung), else "xla"; None before the first flush
+        self.last_rung: Optional[str] = None
 
     def probe(self, kernel: str = KERNEL_TDIGEST) -> bool:
         """May this flush attempt the Pallas rung right now? Consumes the
@@ -106,7 +110,8 @@ class ComputeBreaker:
         return {"kernels": {name: gauge for name, gauge in self.states()},
                 "fallback_total": self.fallback_total,
                 "requeued_total": self.requeued_total,
-                "lost_total": self.lost_total}
+                "lost_total": self.lost_total,
+                "last_rung": self.last_rung}
 
 
 def from_config(cfg, clock: Callable[[], float] = time.monotonic

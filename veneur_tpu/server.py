@@ -736,6 +736,13 @@ class Server:
         # with import semantics and is re-persisted from the merged
         # state; malformed/stale files discard without ever failing
         # startup (persist/checkpoint.py)
+        # the device in the program's own words: a silent CPU fallback
+        # must be readable from the first lines of the log
+        from veneur_tpu.debug import device_section
+
+        dev = device_section()
+        log.info("device: platform=%s device_kind=%s count=%d",
+                 dev["platform"], dev["device_kind"], dev["count"])
         self._started_wall = time.time()
         if self.checkpointer is not None:
             self.checkpointer.restore()
