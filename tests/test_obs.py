@@ -478,3 +478,411 @@ class TestKernelScopes:
         # the thread hit the held lock and returned 409 (one capture
         # at a time), never a double start_trace
         assert results and results[0][0] == 409
+
+
+# ---------------------------------------------------------------------------
+# the opened stages: merger, store.dispatch, the sink's serializer; the
+# capture without the Python tracer; threads by name
+# ---------------------------------------------------------------------------
+
+
+class _BodyLog:
+    """Datadog post stub: 202 for everything, keeps the bodies."""
+
+    def __init__(self):
+        self.bodies = []
+
+    def __call__(self, url, payload, compress=True, method="POST",
+                 precompressed=False, out_info=None):
+        self.bodies.append(payload)
+        return 202
+
+
+@pytest.fixture()
+def lane_server():
+    """A server with the UDP ingest lanes (and so the merger thread) up
+    and the Datadog sink streaming chunks into a stub."""
+    from veneur_tpu.config import Config
+    from veneur_tpu.native import egress
+    from veneur_tpu.resilience import RetryPolicy
+    from veneur_tpu.server import Server
+    from veneur_tpu.sinks import ChannelMetricSink
+    from veneur_tpu.sinks.datadog import DatadogMetricSink
+
+    if not egress.available():
+        pytest.skip("no native toolchain")
+    post = _BodyLog()
+    dd = DatadogMetricSink(hostname="h0", tags=[], dd_hostname="http://dd",
+                           api_key="k", post=post, interval=10,
+                           flush_max_per_body=25000,
+                           retry_policy=RetryPolicy(max_attempts=1))
+    dd.set_flush_deadline(None)
+    cfg = Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                 num_readers=2, interval="86400s",
+                 http_address="127.0.0.1:0", percentiles=[0.5, 0.99],
+                 obs_timeline_intervals=8, store_initial_capacity=64,
+                 store_chunk=128, flush_pipeline_depth=2,
+                 flush_streaming=True)
+    chan = ChannelMetricSink()
+    srv = Server(cfg, metric_sinks=[dd, chan])
+    srv.start()
+    yield srv, chan, post
+    srv.shutdown()
+
+
+def send_and_merge(srv, lines, sock=None, timeout=10.0):
+    """Send one datagram a line at the lanes (from one socket, so that
+    SO_REUSEPORT hands them all to one lane) and wait until the merger
+    has folded every one into the store."""
+    import socket
+
+    fleet = srv.ingest_fleet
+    want = fleet.totals()["merged"] + len(lines)
+    own = sock is None
+    sock = sock or socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    try:
+        for line in lines:
+            sock.sendto(line, srv.statsd_addrs[0])
+    finally:
+        if own:
+            sock.close()
+    deadline = time.monotonic() + timeout
+    while fleet.totals()["merged"] < want:
+        assert time.monotonic() < deadline, fleet.totals()
+        time.sleep(0.01)
+
+
+def stages_of(entry):
+    return {s["name"]: s for s in entry["stages"]}
+
+
+class TestMergerStages:
+    LINES = ([b"m.h%d:%d.5|h" % (i, i) for i in range(40)]
+             + [b"m.c%d:1|c" % i for i in range(25)])
+
+    def test_four_stages_off_path_and_parts_inside_the_whole(
+            self, lane_server):
+        srv, chan, _post = lane_server
+        # two intervals: the interners restart at each flush, so every
+        # series is first-sight again in the second
+        for _ in range(2):
+            send_and_merge(srv, self.LINES)
+            srv.flush()
+            chan.get_flush()
+            st = stages_of(srv.obs_timeline.entries()[-1])
+            parts = ("ingest.merge.lock_wait", "ingest.merge.remap",
+                     "ingest.merge.stage")
+            for name in ("ingest.merge",) + parts:
+                assert st[name]["off_path"] is True, name
+                assert st[name]["start_ns"] == 0, name
+            merge = st["ingest.merge"]
+            assert merge["rows_interned"] == len(self.LINES)
+            assert merge["chunks"] >= 1
+            assert st["ingest.merge.remap"]["duration_ns"] > 0
+            assert st["ingest.merge.stage"]["duration_ns"] > 0
+            assert 0 < sum(st[p]["duration_ns"] for p in parts) \
+                <= merge["duration_ns"]
+
+    def test_known_rows_are_not_interned_again(self, lane_server):
+        import socket
+
+        srv, chan, _post = lane_server
+        # one socket, so one lane: a lane row counts once an interval
+        # (a second lane that carried the series would count it too)
+        with socket.socket(socket.AF_INET, socket.SOCK_DGRAM) as sock:
+            send_and_merge(srv, self.LINES, sock)
+            send_and_merge(srv, self.LINES[:10], sock)   # all known
+        srv.flush()
+        chan.get_flush()
+        merge = stages_of(srv.obs_timeline.entries()[-1])["ingest.merge"]
+        assert merge["rows_interned"] == len(self.LINES)
+
+    def test_tracing_off_reads_no_clock_and_publishes_nothing(self):
+        from veneur_tpu.core.store import MetricStore
+        from veneur_tpu.ingest import IngestFleet
+        from veneur_tpu.protocol.addr import resolve_addr
+
+        store = MetricStore(initial_capacity=32, chunk=128)
+        fleet = IngestFleet(store, resolve_addr("udp://127.0.0.1:0"), 1,
+                            1 << 16, 4096, chunk_records=128,
+                            use_native=False, trace_stages=False)
+        try:
+            lane = fleet.lanes[0]
+            lane._stage_python([b"a:1|c", b"h:3|ms"])
+            lane._seal()
+            assert fleet.merge_sealed() == 2
+            assert fleet.merge_ns is None
+            assert fleet.take_ingest_stages() is None
+        finally:
+            for lane in fleet.lanes:
+                lane.sock.close()
+
+
+class TestDispatchAndFetchParts:
+    @pytest.mark.parametrize("parent,child", [
+        ("store.dispatch.histograms", "store.dispatch.histograms.drain"),
+        ("store.dispatch.self_timers", "store.dispatch.self_timers.drain"),
+        ("store.dispatch.sets", "store.dispatch.sets.drain"),
+        ("store.dispatch.topk", "store.dispatch.topk.drain"),
+        ("store.histograms.fetch", "store.histograms.fetch.wait"),
+        ("store.self_timers.fetch", "store.self_timers.fetch.wait"),
+        ("store.sets.fetch", "store.sets.fetch.wait"),
+    ])
+    def test_child_lands_inside_its_parent(self, obs_server, parent, child):
+        srv, sink = obs_server
+        for _ in range(2):   # self_timers has rows from the second on
+            for pkt in (b"to:3.5|h", b"tc:1|c", b"tu:u1|s"):
+                srv.handle_metric_packet(pkt)
+            srv.flush()
+            sink.get_flush()
+        entry = srv.obs_timeline.entries()[-1]
+        st = stages_of(entry)
+        p, c = st[parent], st[child]
+        assert p["start_ns"] <= c["start_ns"]
+        assert c["start_ns"] + c["duration_ns"] \
+            <= p["start_ns"] + p["duration_ns"]
+        assert c["duration_ns"] <= p["duration_ns"]
+
+        def find(nodes, name):
+            for n in nodes:
+                if n["name"] == name:
+                    return n
+                hit = find(n["children"], name)
+                if hit is not None:
+                    return hit
+            return None
+
+        node = find(entry["tree"], parent)
+        assert child in {n["name"] for n in node["children"]}
+
+    def test_fetch_keeps_its_lane_and_the_parts_stay_out_of_the_lanes(
+            self, obs_server):
+        """annotate_overlap classifies by leaf: ``fetch`` is still the
+        fetch lane, ``fetch.wait`` and ``drain`` are in none (they would
+        be counted twice, or into a lane that is not theirs)."""
+        srv, sink = obs_server
+        srv.handle_metric_packet(b"to:3.5|h")
+        srv.flush()
+        sink.get_flush()
+        entry = srv.obs_timeline.entries()[-1]
+        fetches = sum(s["duration_ns"] for s in entry["stages"]
+                      if s["name"].endswith(".fetch"))
+        assert entry["lanes"]["fetch"] == fetches
+
+
+class TestSerializerSplit:
+    GOLDEN = (b'{"series":[{"metric":"svc.lat.max","points":[[1000,1.5]],'
+              b'"tags":["env:prod","route:r1"],"type":"gauge","host":"h0",'
+              b'"interval":10}]}')
+
+    def _bodies(self, **kw):
+        import zlib
+
+        from veneur_tpu.core.columnar import build_arenas
+        from veneur_tpu.native import egress
+
+        if not egress.available():
+            pytest.skip("no native toolchain")
+        bodies = egress.dd_series_bodies(
+            build_arenas(["svc.lat"]), build_arenas(["env:prod,route:r1"]),
+            [b".max"], np.array([0], np.uint32), np.array([0], np.uint8),
+            np.array([1.5], np.float64), np.array([0], np.uint8),
+            timestamp=1000, interval=10, default_host="h0", **kw)
+        return bodies, [zlib.decompress(b) if kw.get("compress_level", 1)
+                        else b for b in bodies]
+
+    def test_bodies_are_what_they_were_and_the_timing_adds_up(self):
+        plain, plain_text = self._bodies()
+        timing = {}
+        timed, timed_text = self._bodies(timing=timing)
+        assert timed == plain                 # byte for byte, deflated
+        assert timed_text == [self.GOLDEN]
+        assert timing["encode_ns"] > 0 and timing["deflate_ns"] > 0
+        first = dict(timing)
+        self._bodies(timing=timing)           # a second block adds on
+        assert timing["encode_ns"] > first["encode_ns"]
+        assert timing["deflate_ns"] > first["deflate_ns"]
+
+    def test_uncompressed_bodies_spend_nothing_in_deflate(self):
+        timing = {}
+        _, text = self._bodies(timing=timing, compress_level=0)
+        assert text == [self.GOLDEN]
+        assert timing["deflate_ns"] == 0 and timing["encode_ns"] > 0
+
+    def test_chunk_stages_lie_inside_serialize(self, lane_server):
+        srv, chan, post = lane_server
+        send_and_merge(srv, TestMergerStages.LINES)
+        srv.flush()
+        chan.get_flush()
+        assert post.bodies
+        stages = srv.obs_timeline.entries()[-1]["stages"]
+        by_chunk = {}
+        for s in stages:
+            if s["name"].startswith("post.datadog.serialize"):
+                by_chunk.setdefault(s["chunk"], {})[s["name"]] = s
+        assert by_chunk
+        for chunk, st in by_chunk.items():
+            whole = st["post.datadog.serialize"]
+            enc = st["post.datadog.serialize.encode"]
+            dfl = st["post.datadog.serialize.deflate"]
+            assert enc["duration_ns"] > 0 and dfl["duration_ns"] > 0, chunk
+            assert enc["start_ns"] == dfl["start_ns"] == whole["start_ns"]
+            assert enc["duration_ns"] + dfl["duration_ns"] \
+                <= whole["duration_ns"], chunk
+
+
+class TestCaptureAndThreads:
+    def test_host_scope_is_not_a_dispatch(self):
+        before = obs_kernels.dispatch_snapshot()
+        with obs_kernels.host_scope("test.host"):
+            pass
+        assert obs_kernels.dispatch_snapshot() == before
+        assert "test.host" not in before
+        host = {"merge", "swap", "fetch"}
+        assert not host & {s for s, _ in obs_kernels.PROGRAM_SCOPES.values()}
+
+    def test_capture_holds_the_host_scopes_and_no_python_tracer(
+            self, lane_server, tmp_path):
+        import glob
+
+        from jax.profiler import ProfileData
+
+        srv, chan, _post = lane_server
+        result = []
+        t = threading.Thread(target=lambda: result.append(
+            obs_kernels.capture_xprof(2.0, base_dir=str(tmp_path))))
+        t.start()
+        # drive merges and flushes until the capture ends: some of them
+        # fall inside it wherever its start and stop land
+        while t.is_alive():
+            send_and_merge(srv, TestMergerStages.LINES[:8])
+            srv.flush()
+            chan.get_flush()
+            time.sleep(0.1)
+        t.join(timeout=60)
+        assert not t.is_alive()
+        status, body, _ctype = result[0]
+        assert status == 200, body
+        names, lines = set(), set()
+        for path in glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                              recursive=True):
+            for plane in ProfileData.from_file(path).planes:
+                for line in plane.lines:
+                    lines.add(line.name)
+                    names.update(ev.name for ev in line.events)
+        for scope in ("veneur.merge", "veneur.fetch", "veneur.swap",
+                      "veneur.serialize.histograms",
+                      "veneur.post.datadog.serialize",
+                      "veneur.post.datadog.post",
+                      "veneur.flush.digest.dense"):
+            assert scope in names, (scope, sorted(
+                n for n in names if n.startswith("veneur.")))
+        # the Python tracer writes every call as "$file:line function"
+        assert not [n for n in names if n.startswith("$")]
+        # a thread named when the server turned ready has its name in
+        # the trace too
+        assert "ingest-merger" in lines
+
+    def test_obs_threads_by_name_with_cpu_that_does_not_fall(
+            self, lane_server):
+        srv, chan, _post = lane_server
+        _, body, _ = get(srv.ops_server.port, "/debug/vars")
+        first = json.loads(body)["obs"]["threads"]
+        for name in ("ingest-merger", "flush-ticker", "ingest-lane-0",
+                     "ingest-lane-1", "MainThread"):
+            assert first[name]["cpu_s"] >= 0.0, (name, sorted(first))
+        send_and_merge(srv, TestMergerStages.LINES)
+        srv.flush()
+        chan.get_flush()
+        _, body, _ = get(srv.ops_server.port, "/debug/vars")
+        second = json.loads(body)["obs"]["threads"]
+        for name in ("ingest-merger", "flush-ticker", "ingest-lane-0"):
+            assert second[name]["cpu_s"] >= first[name]["cpu_s"], name
+        merger = srv.ingest_fleet._merger
+        with open(f"/proc/self/task/{merger.native_id}/comm") as f:
+            assert f.read().strip() == "ingest-merger"
+
+    def test_threads_that_share_a_name_are_numbered(self):
+        from veneur_tpu import debug
+
+        stop = threading.Event()
+        twins = [threading.Thread(target=stop.wait, name="obs-twin")
+                 for _ in range(3)]
+        for t in twins:
+            t.start()
+        try:
+            got = debug.thread_cpu()
+        finally:
+            stop.set()
+            for t in twins:
+                t.join(timeout=10)
+        assert {"obs-twin", "obs-twin#2", "obs-twin#3"} <= set(got)
+        assert "MainThread" in got
+        with open("/proc/self/comm") as f:       # the process keeps its name
+            assert f.read().strip() != "MainThread"
+
+
+class TestStageSpanMirror:
+    """The stage tree's SSF mirror is a burst into the server's own span
+    channel, whose fill is an overload pressure source: it is held well
+    under the low watermark however many stages an interval has."""
+
+    def _server(self):
+        from veneur_tpu.config import Config
+        from veneur_tpu.server import Server
+        from veneur_tpu.sinks import ChannelMetricSink
+
+        cfg = Config(statsd_listen_addresses=[], interval="86400s",
+                     store_initial_capacity=32, store_chunk=128)
+        # NOT started: no span worker drains the channel
+        return Server(cfg, metric_sinks=[ChannelMetricSink()])
+
+    @staticmethod
+    def _entry(n):
+        stages = [{"name": "store", "start_ns": 0, "duration_ns": 9}]
+        for i in range(n - 1):
+            name = "store.g%d" % i if i % 3 == 0 else \
+                "store.g%d.part%d" % (i - i % 3, i % 3)
+            stages.append({"name": name, "start_ns": i + 1,
+                           "duration_ns": 1})
+        return {"wall_start": 1000.0, "stages": stages}
+
+    @pytest.mark.parametrize("stages,queued", [(20, 0), (120, 0), (120, 30),
+                                               (120, 90)])
+    def test_burst_stays_under_half_the_low_watermark(self, stages, queued):
+        import queue
+
+        from veneur_tpu import flusher
+        from veneur_tpu.trace import Trace
+
+        srv = self._server()
+        for _ in range(queued):           # other traffic already waiting
+            srv.span_chan.put_nowait(object())
+        cap = int(srv.span_chan.maxsize * srv.overload.low
+                  * flusher.STAGE_SPAN_SHARE)
+        entry = self._entry(stages)
+        root = Trace.start_trace("veneur.flush")
+        flusher._record_stage_spans(srv, root, entry)
+        sent = []
+        while True:
+            try:
+                item = srv.span_chan.get_nowait()
+            except queue.Empty:
+                break
+            if hasattr(item, "name"):
+                sent.append(item)
+        want = min(stages, max(0, cap - queued))
+        assert len(sent) == want
+        assert queued + len(sent) <= max(cap, queued)
+        assert entry.get("stage_spans_skipped", 0) == stages - want
+        # shallowest first: no span went while a shallower stage stayed
+        depth = [s.name.count(".") for s in sent]
+        left = [st["name"].count(".") + 2 for st in entry["stages"]
+                if "veneur.flush." + st["name"] not in
+                {s.name for s in sent}]
+        assert not sent or not left or max(depth) <= min(left)
+        # and every span that went hangs off its parent's span or the root
+        by_name = {s.name: s for s in sent}
+        for s in sent:
+            parent = by_name.get(s.name.rsplit(".", 1)[0])
+            assert s.parent_id == (parent.id if parent else root.span_id)

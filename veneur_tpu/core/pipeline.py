@@ -39,6 +39,7 @@ import threading
 import time
 from typing import List, NamedTuple, Optional
 
+from veneur_tpu.obs import kernels as obs_kernels
 from veneur_tpu.obs import recorder as obs_rec
 
 log = logging.getLogger("veneur.pipeline")
@@ -99,7 +100,8 @@ class SerializerLane:
                 t0 = time.monotonic_ns()
                 try:
                     if self._err is None:
-                        emit(result)
+                        with obs_kernels.host_scope(f"serialize.{name}"):
+                            emit(result)
                 except BaseException as e:  # re-raised at close
                     self._err = e
                     log.exception("flush emission for %s failed", name)

@@ -617,8 +617,8 @@ class SlabDigestBank:
                 jax.block_until_ready(t.sum_w)
 
 
-from veneur_tpu.core.store import OverloadLimited  # noqa: E402  (cycle-safe:
-# store imports nothing from slab at module top level)
+# cycle-safe: store imports nothing from slab at module top level
+from veneur_tpu.core.store import OverloadLimited, fetch_stage  # noqa: E402
 from veneur_tpu.overload import F32_ABS_MAX, MIN_SAMPLE_RATE  # noqa: E402
 
 
@@ -963,7 +963,8 @@ class SlabDigestGroup(OverloadLimited):
         runs the windowed fetch loop — fetching slab j while slab
         j+window executes — then commits. The compute ladder retries
         inside ``finish`` (:func:`begin_compute_ladder` semantics)."""
-        self._drain_staging()
+        with obs_rec.maybe_stage("drain"):
+            self._drain_staging()
         n = len(self.interner)
         if n == 0:
             res = self._flush_empty()
@@ -1107,7 +1108,7 @@ class SlabDigestGroup(OverloadLimited):
                 continue
             need, pk_refs, refs = ref
             st["refs"][j] = None  # drop the fetched slab's refs promptly
-            with obs_rec.maybe_stage("fetch"):
+            with fetch_stage((pk_refs, refs)):
                 if st["packed"]:
                     c_h, pm_h, pw_h = _fetch_packed(*pk_refs, need)
                     pk_counts.append(c_h)

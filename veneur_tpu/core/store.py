@@ -47,6 +47,8 @@ from __future__ import annotations
 import logging
 import math
 import threading
+import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -377,6 +379,19 @@ def begin_compute_ladder(compute, dispatch, collect, plane):
         return out
 
     return finish
+
+
+@contextmanager
+def fetch_stage(refs):
+    """One group's ``fetch`` stage (and ``veneur.fetch`` host scope in a
+    profiler capture) around the caller's ``jax.device_get``, opened by
+    ``fetch.wait``: the wait for the device to have produced ``refs``,
+    which the ``device_get`` would otherwise absorb unseen. What is
+    left of ``fetch`` after it is the transfer itself."""
+    with obs_rec.maybe_stage("fetch"), obs_kernels.host_scope("fetch"):
+        with obs_rec.maybe_stage("wait"):
+            jax.block_until_ready(refs)
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -1025,7 +1040,8 @@ class DigestGroup(OverloadLimited):
         ladder retries inside ``finish`` per group
         (:func:`begin_compute_ladder`), and a double failure raises
         with the group state intact for the store's re-merge rung."""
-        self._drain_staging()
+        with obs_rec.maybe_stage("drain"):
+            self._drain_staging()
         n = len(self.interner)
         if n == 0:
             res = self._flush_empty()
@@ -1086,13 +1102,20 @@ class DigestGroup(OverloadLimited):
         from veneur_tpu.core.slab import _select_stats
 
         sel = _select_stats(want_stats)
-        qs = jnp.asarray(list(percentiles) + [0.5], jnp.float32)
-        # compute = async program dispatch (plus any synchronous
-        # compile); fetch = the blocking device->host transfer, which
-        # also absorbs the device execution it waits on. The split is
-        # what the flush timeline shows per group.
+        # compute = the program's dispatch (plus any synchronous
+        # compile and the quantiles' host->device put); it returns at
+        # once. The dispatch PHASE does block, though, one group later:
+        # on the v5e (PERF.md, PR 29) the next group that has samples
+        # staged (self_timers always has) waits in its drain
+        # (flush_begin) for as long as the device runs the program
+        # enqueued here, 0.193 of the 0.204 s store.dispatch: a fresh
+        # twin's first touch allocates its planes there, and that host
+        # -> device put queues behind the running program (the CPU
+        # backend does the same). So fetch, opened by fetch.wait, finds
+        # the results ready (fetch.wait 0.03-0.08 ms on the chip).
         with obs_rec.maybe_stage("compute"), \
                 obs_kernels.scope("flush.digest.dense"):
+            qs = jnp.asarray(list(percentiles) + [0.5], jnp.float32)
             digest, pcts, count, vsum, vmin, vmax, recip = self._run_flush(
                 qs, use_pallas)
             planes = ()
@@ -1120,7 +1143,7 @@ class DigestGroup(OverloadLimited):
 
         sel, packed, packed_refs, refs = pending
         out = {}
-        with obs_rec.maybe_stage("fetch"):
+        with fetch_stage((packed_refs, refs)):
             if packed:
                 (out["packed_counts"], out["packed_means"],
                  out["packed_weights"]) = _fetch_packed(*packed_refs, n)
@@ -1422,7 +1445,8 @@ class SetGroup(OverloadLimited):
         touch them — the snapshot_begin pattern), and the returned
         ``finish()`` runs the blocking fetch; a later group's device
         execution overlaps it."""
-        self._drain_staging()
+        with obs_rec.maybe_stage("drain"):
+            self._drain_staging()
         n = len(self.interner)
         interner, self.interner = self.interner, Interner()
         if n == 0:
@@ -1447,7 +1471,7 @@ class SetGroup(OverloadLimited):
             self._init_staging()
 
         def finish():
-            with obs_rec.maybe_stage("fetch"):
+            with fetch_stage((est_ref, reg_ref)):
                 estimates = (np.asarray(jax.device_get(est_ref))
                              if want_estimates else None)
                 registers = (np.asarray(jax.device_get(reg_ref), np.uint8)
@@ -1727,7 +1751,8 @@ class HeavyHitterGroup(OverloadLimited):
         dispatch now, the group resets immediately, and ``finish()``
         runs the blocking fetch plus the host-side member/emission
         assembly later."""
-        self._drain_samples()
+        with obs_rec.maybe_stage("drain"):
+            self._drain_samples()
         n = len(self.interner)
         interner, self.interner = self.interner, Interner()
         if n == 0 and not self._device_dirty:
@@ -1748,7 +1773,7 @@ class HeavyHitterGroup(OverloadLimited):
             out = []
             fwd = None
             if n:
-                with obs_rec.maybe_stage("fetch"):
+                with fetch_stage(refs):
                     hi, lo, ct = jax.device_get(refs)
                 # one pass builds both the emission rows and (when
                 # asked) the per-row forwardable candidate lists
@@ -1767,7 +1792,7 @@ class HeavyHitterGroup(OverloadLimited):
                             keys.append(pair)
                             mems.append(member)
                 if want_forward:
-                    with obs_rec.maybe_stage("fetch"):
+                    with fetch_stage(table_ref):
                         table = np.asarray(jax.device_get(table_ref))
                     series = [
                         (key.name, interner.tags[row]) + by_row[row]
@@ -2235,8 +2260,12 @@ class MetricStore:
         # the dedicated self-telemetry group (veneur_tpu/obs/): the
         # server's own stage durations, always a small dense DigestGroup
         # regardless of digest_storage — bounded cardinality (one row
-        # per instrumented stage), local-only, never forwarded
-        self.self_timers = DigestGroup(min(64, initial_capacity), chunk,
+        # per instrumented stage), local-only, never forwarded. 128
+        # rows hold the stage vocabulary with room: a streamed flush
+        # that uses two digest groups has 71 stage names (PR 29), and
+        # a group that outgrows its rows doubles them, which compiles
+        # its programs anew at the next flush
+        self.self_timers = DigestGroup(min(128, initial_capacity), chunk,
                                        compression)
         if mesh is not None:
             from veneur_tpu.core.mesh_store import MeshHeavyHitterGroup
@@ -2798,7 +2827,9 @@ class MetricStore:
         self._ingest_drain = drain
 
     @acquires_lock("store")
-    def import_lane_chunk(self, chunk, resolver) -> List[bytes]:
+    def import_lane_chunk(self, chunk, resolver,
+                          timing: Optional[Dict[str, int]] = None
+                          ) -> List[bytes]:
         """Merge one sealed ingest-lane chunk under ONE store-lock hold
         — the group-boundary half of the reader-lane design
         (veneur_tpu/ingest/lanes.py): readers stage lock-free against
@@ -2814,18 +2845,32 @@ class MetricStore:
         scrubbed and in Go semantics (contribs truncated, weights as
         f32 reciprocals) — the same bits process_batch would stage.
 
+        ``timing`` (the merger's ``IngestFleet.merge_ns``, or None with
+        stage tracing off) gains the chunk's ns waiting for the lock
+        (``lock_wait``), remapping every kind's lane rows (``remap``;
+        ``rows_interned`` counts the first-sight rows it interned) and
+        staging them (``stage``): four clock reads a chunk, which is
+        why the kinds are remapped first and staged after.
+
         Returns the chunk's raw event/service-check lines for the
         caller to route through the Python parser OUTSIDE the lock."""
+        t0 = time.monotonic_ns() if timing is not None else 0
         with self._lock:
+            t1 = time.monotonic_ns() if timing is not None else 0
             if resolver.epoch != self.flush_epoch:
                 resolver.remap = [None] * len(resolver.remap)
                 resolver.epoch = self.flush_epoch
             for kind, new in chunk.new_entries.items():
                 resolver.entries[kind].extend(new)
+            interned = 0
+            staged = []
             for kind, span in chunk.spans.items():
-                rows = span[0]
-                remap = self._lane_remap(kind, resolver, rows)
-                grp_rows = remap[rows]
+                remap, first_sight = self._lane_remap(kind, resolver,
+                                                      span[0])
+                interned += first_sight
+                staged.append((kind, span, remap[span[0]]))
+            t2 = time.monotonic_ns() if timing is not None else 0
+            for kind, span, grp_rows in staged:
                 group = self._group_for_kind(kind)
                 group.ensure_capacity(int(grp_rows.max()))
                 if kind in (_K_COUNTER, _K_GLOBAL_COUNTER):
@@ -2841,10 +2886,15 @@ class MetricStore:
                     group.sample_many(grp_rows.astype(np.int32), span[1],
                                       span[2])
             self.processed += chunk.records
+            if timing is not None:
+                timing["lock_wait"] += t1 - t0
+                timing["remap"] += t2 - t1
+                timing["stage"] += time.monotonic_ns() - t2
+                timing["rows_interned"] += interned
         return chunk.raws
 
     @requires_lock("store")
-    def _lane_remap(self, kind: int, resolver, rows) -> np.ndarray:
+    def _lane_remap(self, kind: int, resolver, rows) -> tuple:
         """Lane-row -> store-row array for one kind, resolved LAZILY
         per referenced row (-1 = unresolved): only rows the incoming
         chunk actually carries re-intern after a flush-epoch bump, so
@@ -2854,7 +2904,8 @@ class MetricStore:
         the lane's lifetime registry. Interning goes through
         _intern_native, so the tag-length cap and the overload
         spill/freeze semantics apply to lane-merged series exactly as
-        to every other ingest path."""
+        to every other ingest path. Returns the array and how many
+        rows this call interned."""
         entries = resolver.entries[kind]
         remap = resolver.remap[kind]
         if remap is None or len(remap) < len(entries):
@@ -2869,7 +2920,7 @@ class MetricStore:
             for r in todo:
                 name_b, tags_b = entries[int(r)]
                 remap[r] = self._intern_native(t, sc, name_b, tags_b)[2]
-        return remap
+        return remap, len(todo)
 
     @acquires_lock("store")
     def import_topk(self, table: np.ndarray, series: List[tuple]):
@@ -3199,7 +3250,8 @@ class MetricStore:
         # it serializes overlapping flush() calls (only the flusher and
         # shutdown ever contend) while ingest proceeds on _lock
         with self._flush_gate:  # lint: ok(lock-across-blocking) the gate's entire job is to hold across the multi-second retired drain; ingest never waits on it (it proceeds on _lock)
-            with obs_rec.maybe_stage("swap"):
+            with obs_rec.maybe_stage("swap"), \
+                    obs_kernels.host_scope("swap"):
                 with self._lock:
                     gen = self._swap_generation()
             return self._flush_generation(

@@ -1065,7 +1065,8 @@ class TieredDigestGroup(OverloadLimited):
         ``finish()`` — the tiered group overlaps at the STORE level
         (other groups serialize/POST while this one computes and
         fetches); its internal per-slab fetch loop stays one phase."""
-        self._drain_staging()  # lint: ok(unlocked-call) two-phase flush slot still runs on the RETIRED generation this thread exclusively owns
+        with obs_rec.maybe_stage("drain"):
+            self._drain_staging()  # lint: ok(unlocked-call) two-phase flush slot still runs on the RETIRED generation this thread exclusively owns
         n = len(self.interner)
         return lambda: self._flush_tiers(n, percentiles, want_digests,
                                          want_stats)

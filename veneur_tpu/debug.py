@@ -13,7 +13,8 @@ be profiled in place. The Python equivalents here:
                                     output is collapsed-stack lines, flame-
                                     graph-ready, hottest stack first
     GET /debug/vars                 JSON of store/lane/queue depths and
-                                    ingest counters (expvar's role)
+                                    ingest counters (expvar's role), and
+                                    each Python thread's CPU seconds
     GET /debug/flush-timeline       last-N flush intervals as stage
                                     trees (veneur_tpu/obs/; server only)
     GET /debug/xprof?seconds=N      on-demand jax.profiler capture —
@@ -32,6 +33,7 @@ Mounted on both the server's OpsServer and the proxy's mux.
 from __future__ import annotations
 
 import json
+import os
 import sys
 import threading
 import time
@@ -159,6 +161,50 @@ def device_section(store=None) -> dict:
              if stats and "peak_bytes_in_use" in stats]
     if peaks:
         out["peak_bytes_in_use"] = max(peaks)
+    return out
+
+
+def name_threads() -> None:
+    """Give every live Python thread its ``threading`` name in the
+    kernel too (``/proc/self/task/<tid>/comm``, 15 bytes; a process may
+    write its own threads'), so ``top -H`` and ``/proc/<pid>/task/*/stat``
+    show ``ingest-merger`` where CPython 3.12 leaves ``python``. Done
+    from outside the threads because not every one passes through a
+    wrapper of ours. The main thread keeps the process's name. Linux
+    only; anywhere else, and for a thread that ended meanwhile, the
+    write fails and is skipped."""
+    main = threading.main_thread()
+    for t in threading.enumerate():
+        if t is main or t.native_id is None:
+            continue
+        try:
+            with open(f"/proc/self/task/{t.native_id}/comm", "w") as f:
+                f.write(t.name[:15])
+        except OSError:
+            continue
+
+
+def thread_cpu() -> Dict[str, dict]:
+    """``{thread name: {"cpu_s": user + system seconds}}`` for the live
+    Python threads, read from ``/proc/self/task/<tid>/stat`` now (so it
+    costs nothing between requests); threads that share a name follow
+    the first as ``name#2``, ``name#3`` in the order they were created.
+    Also (re)names the threads, which catches those started since the
+    server turned ready."""
+    name_threads()
+    tick = os.sysconf("SC_CLK_TCK")
+    out: Dict[str, dict] = {}
+    seen: Counter = Counter()
+    for t in sorted(threading.enumerate(), key=lambda t: t.native_id or 0):
+        try:
+            with open(f"/proc/self/task/{t.native_id}/stat") as f:
+                fields = f.read().rsplit(")", 1)[1].split()
+        except OSError:
+            continue
+        seen[t.name] += 1
+        name = t.name if seen[t.name] == 1 else f"{t.name}#{seen[t.name]}"
+        # fields 14 and 15 of the line: utime and stime, in ticks
+        out[name] = {"cpu_s": (int(fields[11]) + int(fields[12])) / tick}
     return out
 
 
@@ -290,7 +336,8 @@ def collect_vars(server) -> dict:
         if hasattr(server, "obs_timeline"):
             from veneur_tpu.obs import kernels
 
-            section = {"kernels": kernels.snapshot()}
+            section = {"kernels": kernels.snapshot(),
+                       "threads": thread_cpu()}
             timeline = server.obs_timeline
             if timeline is not None:
                 section["timeline"] = timeline.snapshot()

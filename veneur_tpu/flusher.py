@@ -15,6 +15,7 @@ import threading
 import time
 from typing import TYPE_CHECKING
 
+from veneur_tpu.overload import DEFAULT_LOW_WATERMARK
 from veneur_tpu.sinks.base import filter_acceptable
 
 if TYPE_CHECKING:
@@ -123,6 +124,17 @@ def _publish_interval(server, span, rec, timeline):
             rec.record_abs(f"ingest.{stage}", rec.t0_ns,
                            rec.t0_ns + ingest_stages[stage],
                            off_path=True)
+        # the merger thread's own busy time over the same stretch, and
+        # inside it the lock wait, the remap + interning and the
+        # staging calls (core/store.py import_lane_chunk)
+        merger = ingest_stages["merger"]
+        rec.record_abs("ingest.merge", rec.t0_ns,
+                       rec.t0_ns + merger["merge"], off_path=True,
+                       chunks=merger["chunks"],
+                       rows_interned=merger["rows_interned"])
+        for stage in ("lock_wait", "remap", "stage"):
+            rec.record_abs(f"ingest.merge.{stage}", rec.t0_ns,
+                           rec.t0_ns + merger[stage], off_path=True)
     entry = rec.finish()
     if hops:
         tids = sorted({h["trace_id"] for h in hops if h.get("trace_id")})
@@ -230,6 +242,8 @@ def _drain_ingest_stages(server):
             for k in ("recv", "decode", "stage", "seal", "iters",
                       "lanes"):
                 total[k] += stages[k]
+            for k, v in stages["merger"].items():
+                total["merger"][k] += v
     return total
 
 
@@ -260,18 +274,44 @@ def _drain_ingest_latencies(server) -> list:
     return out
 
 
+# the share of the low overload watermark's worth of the span channel
+# that one interval's stage spans may fill (_record_stage_spans)
+STAGE_SPAN_SHARE = 0.5
+
+
 def _record_stage_spans(server, root, entry):
     """Mirror the interval's stage tree as child SSF spans: one span
     per stage, parented on its dotted-path parent's span (top-level
     stages hang off the flush root), start/end mapped onto the root's
     wall clock. Same nonblocking client as the root — a full span
-    channel drops them."""
+    channel drops them.
+
+    The mirror is a burst into the server's own span channel, whose
+    fill is an overload pressure source (overload.py): a span for each
+    of 70 stages read as pressure 0.70 of the default 100-slot channel
+    and froze first-sight series for a tick (PERF.md, PR 29). So the
+    burst is held to ``STAGE_SPAN_SHARE`` of the low watermark's worth
+    of the channel, less what is queued: the shallowest stages go
+    first (so a stage that goes has its ancestors with it), and
+    ``stage_spans_skipped`` on the entry says how many stayed in the
+    timeline only."""
     cl = getattr(server, "trace_client", None)
     if cl is None:
         return
+    stages = entry["stages"]
+    chan = getattr(server, "span_chan", None)
+    if chan is not None and chan.maxsize > 0:
+        low = getattr(getattr(server, "overload", None), "low",
+                      DEFAULT_LOW_WATERMARK)
+        room = int(chan.maxsize * low * STAGE_SPAN_SHARE) - chan.qsize()
+        if len(stages) > room:
+            by_depth = sorted(stages, key=lambda s: s["name"].count("."))
+            keep = {id(s) for s in by_depth[:max(0, room)]}
+            entry["stage_spans_skipped"] = len(stages) - len(keep)
+            stages = [s for s in stages if id(s) in keep]
     wall0 = entry["wall_start"]
     by_path = {}
-    for stage in entry["stages"]:
+    for stage in stages:
         path = stage["name"]
         parent = by_path.get(path.rsplit(".", 1)[0]) \
             if "." in path else None

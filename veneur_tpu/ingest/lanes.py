@@ -40,6 +40,7 @@ from veneur_tpu.core.store import (_K_COUNTER, _K_GAUGE, _K_GLOBAL_COUNTER,
                                    _scrub_float_batch)
 from veneur_tpu.ingest.counters import LaneLedger
 from veneur_tpu.ingest.recvmmsg import BatchReceiver
+from veneur_tpu.obs import kernels as obs_kernels
 from veneur_tpu.overload import (F32_ABS_MAX, LEVEL_SHED_PACKETS,
                                  MIN_SAMPLE_RATE)
 from veneur_tpu.samplers.parser import GLOBAL_ONLY, LOCAL_ONLY
@@ -763,6 +764,16 @@ class IngestFleet:
         # per-lane stage-tracing watermarks (take_ingest_stages diffs
         # the lanes' cumulative single-writer counters per interval)
         self._stage_reported: Dict[tuple, int] = {}
+        # the merger's own busy time, cumulative like the lanes'
+        # stage_ns and diffed by the same reader: ns inside
+        # _merge_chunk (``merge``) and, filled by import_lane_chunk,
+        # the wait for the store lock, the lane-row remap with its
+        # first-sight interning, and the staging calls; plus chunks
+        # merged and rows interned. Written under _merge_lock only;
+        # None when stage tracing is off (no clock is read then).
+        self.merge_ns: Optional[Dict[str, int]] = dict.fromkeys(
+            ("merge", "lock_wait", "remap", "stage", "chunks",
+             "rows_interned"), 0) if trace_stages else None
         self.unrouted_raws: list = []  # only without a raw_handler (tests)
         intern_limit = (intern_limit
                         or getattr(store, "max_series", 0) or (1 << 20))
@@ -831,14 +842,23 @@ class IngestFleet:
             # rows restart at 0 under a new gen, so the old registry
             # must never remap them
             res = self._resolvers[chunk.lane_id] = LaneResolver(chunk.gen)
-        raws = self._store.import_lane_chunk(chunk, res)
+        timing = self.merge_ns
+        t0 = time.monotonic_ns() if timing is not None else 0
+        with obs_kernels.host_scope("merge"):
+            raws = self._store.import_lane_chunk(chunk, res, timing)
         if chunk.records and chunk.ingest_wall_ns:
             # caller (merge_sealed) holds _merge_lock — the same hold
             # take_oldest_ingest_ns resets under
             if (self._oldest_ingest_ns is None
                     or chunk.ingest_wall_ns < self._oldest_ingest_ns):
                 self._oldest_ingest_ns = chunk.ingest_wall_ns
-        latency = time.monotonic_ns() - chunk.sealed_ns
+        now = time.monotonic_ns()
+        if timing is not None:
+            # raw routing below is the Python parser's time, not the
+            # merger's fold: the busy span ends where the latency does
+            timing["merge"] += now - t0
+            timing["chunks"] += 1
+        latency = now - chunk.sealed_ns
         if latency >= 0:
             self._merge_latencies.append(latency)
             self.merge_latency_count += 1
@@ -937,8 +957,10 @@ class IngestFleet:
         over every lane since the last call (recv includes socket
         wait, so the sums are lane-seconds of wall clock, up to
         ``lanes`` x the interval). None when stage tracing is off or
-        nothing accrued. Single reader (the flusher); lane counters
-        are single-writer ints, read GIL-atomically."""
+        nothing accrued. ``merger`` holds the merger thread's own
+        counters over the same stretch (``merge_ns``'s keys). Single
+        reader (the flusher); lane and merger counters are
+        single-writer ints, read GIL-atomically."""
         out = {"recv": 0, "decode": 0, "stage": 0, "seal": 0}
         iters = 0
         traced = False
@@ -959,6 +981,10 @@ class IngestFleet:
             return None
         out["iters"] = iters
         out["lanes"] = len(self.lanes)
+        out["merger"] = merged = {}
+        for key, cur in self.merge_ns.items():  # traced: never None
+            merged[key] = cur - self._stage_reported.get(("merger", key), 0)
+            self._stage_reported[("merger", key)] = cur
         return out
 
     def merge_latency_snapshot(self) -> dict:

@@ -18,9 +18,14 @@ module surfaces the same inventory LIVE:
   count (``PjitFunction._cache_size``), turning the lint pass's
   "bounded static args" proof into an observable number: a variant
   count that grows interval over interval is a recompile leak.
+- :func:`host_scope` labels the leaves where the HOST works between
+  device operations (the merger's chunk, the generation swap, a
+  group's fetch, the serializer lane, a sink's serialize and POST), so
+  a capture's idle gaps can be put down to what the host did in them.
 - :func:`capture_xprof` runs a bounded ``jax.profiler``
   start/stop_trace capture for ``GET /debug/xprof?seconds=N`` —
-  one at a time, clamped, like ``/debug/profile``.
+  one at a time, clamped, like ``/debug/profile`` — without the
+  Python tracer, so it can run over a whole interval under load.
 """
 
 from __future__ import annotations
@@ -40,6 +45,15 @@ from jax.profiler import TraceAnnotation
 SCOPE_PREFIX = "veneur."
 
 MAX_XPROF_SECONDS = 30.0
+
+# /debug/xprof's host tracer level: TraceMe level 1 is where
+# TraceAnnotation (the veneur.* scopes) records, and the device's
+# ``XLA Modules`` / ``XLA Ops`` lines come from the device tracer, which
+# the level does not gate; 0 drops the scopes, 2 (jax's default) adds
+# the runtime's own per-call events. The Python tracer is off: it hooks
+# every Python call of every thread, which at a merger's rate of calls
+# is what made a capture under load shed (PERF.md, PR 28 / PR 29).
+XPROF_HOST_TRACER_LEVEL = 1
 
 # one capture at a time (mirrors debug._profile_lock for /debug/profile)
 _xprof_lock = threading.Lock()
@@ -174,6 +188,16 @@ def scope(name: str):
         yield
 
 
+def host_scope(name: str) -> TraceAnnotation:
+    """One named stretch of HOST work between device operations, for
+    the profiler's trace only: not a dispatch (not counted, not in
+    PROGRAM_SCOPES). Put it on leaves, never inside another ``veneur.*``
+    scope — a trace's idle gap is named by the scope that overlaps it
+    most, so an enclosing scope would swallow its children. Inactive
+    (no capture running) it costs one atomic load."""
+    return TraceAnnotation(SCOPE_PREFIX + name)
+
+
 def dispatch_snapshot() -> Dict[str, int]:
     return dict(_dispatches)
 
@@ -235,8 +259,11 @@ def capture_xprof(seconds: float, base_dir: Optional[str] = None) -> tuple:
         import jax
 
         trace_dir = tempfile.mkdtemp(prefix="veneur-xprof-", dir=base_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = XPROF_HOST_TRACER_LEVEL
         t0 = time.perf_counter()
-        jax.profiler.start_trace(trace_dir)
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
         try:
             time.sleep(seconds)
         finally:

@@ -738,7 +738,7 @@ class Server:
         # startup (persist/checkpoint.py)
         # the device in the program's own words: a silent CPU fallback
         # must be readable from the first lines of the log
-        from veneur_tpu.debug import device_section
+        from veneur_tpu.debug import device_section, name_threads
 
         dev = device_section()
         log.info("device: platform=%s device_kind=%s count=%d",
@@ -879,6 +879,7 @@ class Server:
                      self.checkpointer.path, self.checkpointer.interval_s)
         log.info("veneur server started (role=%s, interval=%.1fs)",
                  "local" if self.is_local() else "global", self.interval)
+        name_threads()  # top -H shows ingest-merger, not python
 
     def _flush_loop(self):
         """Interval ticker, optionally aligned to wall-clock interval

@@ -219,6 +219,7 @@ class DatadogMetricSink(MetricSink):
         (``chunk_rows_dropped``), so memory stays bounded and a long
         outage degrades by counted drop."""
         from veneur_tpu import obs
+        from veneur_tpu.obs.kernels import host_scope
 
         # normally a no-op: the stream worker already reposted for this
         # interval before any chunk flowed (core/pipeline.py); kept for
@@ -231,19 +232,30 @@ class DatadogMetricSink(MetricSink):
         t0_ns = time.monotonic_ns()
         t_marshal = time.perf_counter()
         bodies = []
-        for blk in chunk.blocks:
-            blk_bodies = self._serialize_block(blk, chunk.timestamp)
-            bodies.extend(zip(blk_bodies,
-                              _body_rows(len(blk), self.flush_max_per_body,
-                                         len(blk_bodies))))
+        # ns the native serializer spent encoding JSON and in deflate,
+        # summed over the chunk's blocks (native/egress.py)
+        native_ns = {"encode_ns": 0, "deflate_ns": 0}
+        with host_scope(f"post.{self.name}.serialize"):
+            for blk in chunk.blocks:
+                blk_bodies = self._serialize_block(blk, chunk.timestamp,
+                                                   native_ns)
+                bodies.extend(zip(blk_bodies,
+                                  _body_rows(len(blk),
+                                             self.flush_max_per_body,
+                                             len(blk_bodies))))
         t_marshal = time.perf_counter() - t_marshal
         if rec is not None:
             rec.record_abs(f"post.{self.name}.serialize", t0_ns,
                            time.monotonic_ns(), chunk=chunk.seq)
+            for part in ("encode", "deflate"):
+                rec.record_abs(f"post.{self.name}.serialize.{part}", t0_ns,
+                               t0_ns + native_ns[part + "_ns"],
+                               chunk=chunk.seq)
         t0_ns = time.monotonic_ns()
         t_post = time.perf_counter()
-        for body, nrows in bodies:
-            self._post_chunk_body(body, nrows)
+        with host_scope(f"post.{self.name}.post"):
+            for body, nrows in bodies:
+                self._post_chunk_body(body, nrows)
         t_post = time.perf_counter() - t_post
         if rec is not None:
             rec.record_abs(f"post.{self.name}.post", t0_ns,
@@ -263,11 +275,13 @@ class DatadogMetricSink(MetricSink):
             self.chunks_flushed += 1
         self.metrics_flushed += chunk.rows
 
-    def _serialize_block(self, blk, timestamp: int) -> List[bytes]:
+    def _serialize_block(self, blk, timestamp: int,
+                         timing: Optional[dict] = None) -> List[bytes]:
         """One emission block → deflated series bodies: the
         counter-to-rate finalization (datadog.go:295-297) + the native
         serializer call, shared by the batch and streamed paths so the
-        wire format can never diverge between them."""
+        wire format can never diverge between them. ``timing`` is
+        ``dd_series_bodies``'s: where the native call's time went."""
         from veneur_tpu.core.columnar import TYPE_COUNTER
         from veneur_tpu.native import egress
 
@@ -282,7 +296,7 @@ class DatadogMetricSink(MetricSink):
             default_host=self.hostname,
             common_tags_json=self._common_tags_json(),
             max_per_body=self.flush_max_per_body,
-            compress_level=self.compress_level)
+            compress_level=self.compress_level, timing=timing)
 
     def _post_chunk_body(self, body: bytes, nrows: int,
                          requeued: bool = False) -> bool:
