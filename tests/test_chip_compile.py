@@ -83,8 +83,8 @@ def one_chip_flush(one_chip, kernel_admitted):
     digest, temp = (_on(t, one_chip) for t in _digest_state(ROWS))
     rows = _f32((ROWS,), one_chip)
     return _flush_digests.lower(
-        digest, temp, rows, rows, _f32((4,), one_chip), COMPRESSION,
-        True).compile()
+        digest, temp, rows, rows, _f32((4,), one_chip),
+        _i32((), one_chip), COMPRESSION, True).compile()
 
 
 def _on(tree, sharding):
@@ -157,6 +157,33 @@ def test_flush_and_ingest_programs_hold_the_kernel(one_chip,
     mem = flush.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < (16 << 30) // 8
+
+
+def test_flush_loop_updates_the_planes_in_place(one_chip_flush):
+    """The live-row-bounded flush for the chip: one loop whose body
+    holds the kernel, works on a slab and writes it back into the
+    carried planes (the output planes alias the donated digest's; a
+    copy of a whole plane a trip would eat the gain)."""
+    import re
+
+    from veneur_tpu.ops.tdigest_pallas import _FLUSH_SLAB_ROWS
+
+    text = one_chip_flush.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    body = re.search(r"body=%?([\w.\-]+)", text).group(1)
+    start = text.index("%" + body + " (")  # the body's definition
+    body_text = text[start:text.index("\n}", start)]
+    assert "tpu_custom_call" in body_text
+    assert f"f32[{_FLUSH_SLAB_ROWS},{K}]" in body_text
+    whole = f"f32[{ROWS},{K}]"
+    for line in body_text.splitlines():
+        if re.search(r"= \S+ (copy|transpose)\(", line):
+            assert whole not in line.split("=")[1].split("(")[0], line
+    updates = [ln for ln in body_text.splitlines()
+               if " dynamic-update-slice(" in ln and whole in ln]
+    assert len(updates) == 2  # digest mean and weight
+    planes = 2 * ROWS * K * 4
+    assert one_chip_flush.memory_analysis().alias_size_in_bytes >= planes
 
 
 def test_hll_insert_and_estimate(one_chip):

@@ -240,6 +240,26 @@ class TestServerTimeline:
         child_names = {c["name"] for c in store["children"]}
         assert "store.histograms" in child_names
 
+    def test_flush_rows_live_and_run(self, obs_server):
+        """The dense flush program says what it worked on: each dense
+        group's compute stage carries rows_live and rows_run, and the
+        entry their sums (what benchmark metric flush.rows_run reads)."""
+        from veneur_tpu.ops import tdigest as td_ops
+
+        srv, sink = obs_server
+        self.flush(srv, sink)
+        e = srv.obs_timeline.entries()[-1]
+        compute = {s["name"]: s for s in e["stages"] if "rows_run" in s}
+        histo = compute["store.dispatch.histograms.compute"]
+        assert histo["rows_live"] == 1
+        assert histo["rows_run"] == td_ops.flush_rows_run(
+            srv.store.histograms.capacity, 1)
+        assert all(name.startswith("store.dispatch.")
+                   and name.endswith(".compute") for name in compute)
+        assert e["digest_flush_rows"] == {
+            "live": sum(s["rows_live"] for s in compute.values()),
+            "run": sum(s["rows_run"] for s in compute.values())}
+
     def test_flush_timeline_endpoint_schema_and_bound(self, obs_server):
         srv, sink = obs_server
         for _ in range(6):  # ring holds 4 (obs_timeline_intervals)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from veneur_tpu.ops import tdigest as td
 from veneur_tpu.ops import tdigest_pallas as tp
+from tests.test_tdigest import assert_bounded_matches_full, flush_state
 
 C = 100.0
 K = td.size_bound(C)
@@ -206,3 +207,42 @@ class TestInKernelSort:
                                interpret=True, sort_b=True)
         np.testing.assert_allclose(np.asarray(o1[2]), np.asarray(o2[2]),
                                    rtol=1e-5, atol=1e-5)
+
+
+class TestLiveRowBoundKernel:
+    """tdigest.drain_and_quantile with a row count, on the kernel rung
+    (interpret mode): each slab is the same kernel over the same
+    128-row blocks, so rows [:n] are the full-width program's bit for
+    bit and rows past the last slab are not touched."""
+
+    SLAB = 128
+    ROWS = 512
+
+    @pytest.fixture(autouse=True)
+    def kernel_rung(self, monkeypatch):
+        import functools
+
+        monkeypatch.setattr(tp, "_FLUSH_SLAB_ROWS", self.SLAB)
+        monkeypatch.setattr(tp, "pallas_ok", lambda a: True)
+        monkeypatch.setattr(tp, "drain_quantile", functools.partial(
+            tp.drain_quantile, interpret=True))
+
+    @pytest.fixture(scope="class")
+    def state(self):
+        return flush_state(self.ROWS, seed=5, samples=12000)
+
+    @pytest.mark.parametrize("n", [1, 127, 128, 129, 2 * 128 + 5, 512])
+    def test_rows_equal_full_width(self, state, n):
+        run = td.flush_rows_run(self.ROWS, n)
+        assert run == -(-n // 128) * 128
+        assert_bounded_matches_full(state, n, run)
+
+    def test_kernel_is_in_the_loop(self, state):
+        import jax
+
+        shapes = jax.eval_shape(lambda: state)
+        text = str(jax.make_jaxpr(
+            lambda *a: td.drain_and_quantile(
+                *a, jnp.asarray([0.5], jnp.float32), C,
+                n=np.int32(3)))(*shapes))
+        assert "while" in text and "pallas_call" in text

@@ -103,10 +103,10 @@ class TestLadderIsolation:
         orig = DigestGroup._run_flush
         g = s.timers  # `|ms` samples; retires at the swap
 
-        def failing(qs, use_pallas=True):
+        def failing(qs, use_pallas, n):
             if use_pallas:
                 raise RuntimeError("injected pallas dispatch failure")
-            return orig(g, qs, use_pallas)
+            return orig(g, qs, use_pallas, n)
 
         g._run_flush = failing
         final, _fwd, ms = s.flush([0.5], AGGS, is_local=False, now=7,
@@ -123,7 +123,7 @@ class TestLadderIsolation:
         fill(s)
         g = s.timers
 
-        def always_failing(qs, use_pallas=True):
+        def always_failing(qs, use_pallas, n):
             raise RuntimeError("injected kernel failure, both rungs")
 
         g._run_flush = always_failing

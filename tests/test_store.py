@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from veneur_tpu.core import MetricStore
+from veneur_tpu.ops import tdigest_pallas
 from veneur_tpu.samplers import (
     Aggregate,
     HistogramAggregates,
@@ -380,3 +381,93 @@ class TestSwapOnFlush:
         # every sample landed in exactly one interval: no loss, no dupes
         assert totals["total"] == sent[0] / 2
         assert totals["h.count"] == sent[0] / 2
+
+
+class TestFlushLiveRows:
+    """The dense flush program is told the interval's interned rows:
+    it works on the slabs that hold them (ops/tdigest.py
+    drain_and_quantile) and every answer is the full-width program's."""
+
+    SLAB = tdigest_pallas._FLUSH_SLAB_ROWS
+    CAPACITY = 4 * SLAB
+
+    @staticmethod
+    def _group(n, capacity=CAPACITY, seed=0):
+        from veneur_tpu.core.store import DigestGroup
+
+        g = DigestGroup(capacity=capacity, chunk=4096)
+        rows = np.asarray(
+            [g._row(MetricKey(name=f"h{i}", type="histogram",
+                              joined_tags=""), []) for i in range(n)],
+            np.int32)
+        rng = np.random.default_rng(seed)
+        for _ in range(2):  # a sample a row, then a few rows many
+            g.sample_many(rows, rng.lognormal(0, 1, n).astype(np.float32),
+                          np.ones(n, np.float32))
+            rows = rows[rng.integers(0, n, 3 * n)]
+            n = len(rows)
+        return g
+
+    @pytest.mark.parametrize(
+        "n", [1, SLAB - 1, SLAB, SLAB + 1, 2 * SLAB + 5, CAPACITY])
+    def test_flush_equals_the_full_width_program(self, n):
+        import jax.numpy as jnp
+
+        from veneur_tpu.core import store as store_mod
+
+        g = self._group(n)
+        g._drain_staging()
+        state = [jnp.copy(x) for x in
+                 (*g.digest, *g.temp, g.dmin, g.dmax)]
+        digest = type(g.digest)(*state[:4])
+        temp = type(g.temp)(*state[4:-2])
+        qs = jnp.asarray([0.5, 0.99, 0.5], jnp.float32)
+        full, pcts, *stats = store_mod._flush_digests(
+            digest, temp, state[-2], state[-1], qs, None, g.compression,
+            False)
+        _interner, out = g.flush([0.5, 0.99])
+        want = {"digest_mean": full.mean, "digest_weight": full.weight,
+                "digest_min": full.min, "digest_max": full.max,
+                "percentiles": pcts[:, :-1], "median": pcts[:, -1],
+                **dict(zip(("count", "sum", "min", "max", "recip"),
+                           stats))}
+        for key, ref in want.items():
+            np.testing.assert_array_equal(out[key], np.asarray(ref)[:n],
+                                          err_msg=key)
+
+    def test_no_rows_takes_the_empty_path(self, monkeypatch):
+        from veneur_tpu.core import store as store_mod
+
+        def never(*args):
+            raise AssertionError("an empty group ran the flush program")
+
+        monkeypatch.setattr(store_mod, "_flush_digests", never)
+        assert self._group(0).flush([0.5])[1] == {}
+        assert self._group(0).flush_begin([0.5])()[1] == {}
+
+    def test_one_compiled_variant_for_every_count(self):
+        from veneur_tpu.obs import kernels as obs_kernels
+
+        program = "veneur_tpu/core/store.py::_flush_digests"
+        capacity = 3 * self.SLAB  # a shape no other test flushes
+        before = obs_kernels.compile_snapshot()[program]
+        for n in (3, self.SLAB + 9, capacity):
+            _interner, out = self._group(n, capacity).flush([0.5])
+            assert len(out["count"]) == n
+        assert obs_kernels.compile_snapshot()[program] == before + 1
+
+    @pytest.mark.parametrize("capacity,n,run", [
+        (64, 5, 64), (SLAB, 5, SLAB), (CAPACITY, 5, SLAB),
+        (CAPACITY, SLAB + 1, 2 * SLAB), (CAPACITY, CAPACITY, CAPACITY)])
+    def test_rows_run_on_the_stage(self, capacity, n, run):
+        """A group of at most one slab is the straight-line program
+        over all its rows; a larger one runs the live rows' slabs."""
+        from veneur_tpu.obs import recorder as obs_rec
+
+        g = self._group(n, capacity)
+        rec = obs_rec.StageRecorder()
+        with obs_rec.activate(rec), rec.stage("store"):
+            g.flush_begin([0.5])()
+        stage = next(s for s in rec.finish()["stages"]
+                     if s["name"].endswith("compute"))
+        assert (stage["rows_live"], stage["rows_run"]) == (n, run)

@@ -215,3 +215,119 @@ class TestBatched:
         np.testing.assert_allclose(np.asarray(td.quantile(b, probes)),
                                    np.asarray(td.quantile(a, probes)), rtol=5e-2)
         np.testing.assert_allclose(float(b.count()[0]), float(a.count()[0]), rtol=1e-5)
+
+
+C = 100.0
+K = td.size_bound(C)
+
+
+def flush_state(rows, seed=0, samples=6000):
+    """A group's planes at flush time: a digest from an earlier drain,
+    fresh bins on top, imported extrema. Every row is live; a flush
+    told ``n`` must leave rows past its last slab exactly like this."""
+    rng = np.random.default_rng(seed)
+    temp = td.init_temp(rows, K, C)
+    digest = td.init((rows,), C, K)
+    ones = jnp.ones(samples, jnp.float32)
+    for drain in (True, False):
+        r = jnp.asarray(rng.integers(0, rows, samples).astype(np.int32))
+        v = jnp.asarray(rng.lognormal(0, 1, samples).astype(np.float32))
+        temp = td.ingest_chunk(temp, r, v, ones, C)
+        if drain:
+            digest = td.drain_temp(digest, temp, C, use_pallas=False)
+            temp = td.init_temp(rows, K, C)
+    dmin = jnp.asarray(rng.normal(0, 1, rows).astype(np.float32))
+    return digest, temp, dmin, dmin + 5.0
+
+
+FLUSH_QS = jnp.asarray([0.5, 0.75, 0.99, 0.5], jnp.float32)
+
+
+def _flush_both(state, n, **kw):
+    """(bounded by ``n``, full width): each (mean, weight, min, max,
+    pcts) as NumPy."""
+    out = []
+    for count in (np.int32(n), None):
+        drained, pcts = jax.jit(
+            lambda *a, c=count: td.drain_and_quantile(
+                *a, FLUSH_QS, C, n=c, **kw))(*state)
+        out.append([np.asarray(x) for x in tuple(drained) + (pcts,)])
+    return out
+
+
+def assert_bounded_matches_full(state, n, run, **kw):
+    """Rows [:run] are the full-width program's bit for bit (so rows
+    [:n] are), the rest are the input's (percentiles 0)."""
+    got, full = _flush_both(state, n, **kw)
+    assert run >= n
+    for g, f in zip(got, full):
+        np.testing.assert_array_equal(g[:run], f[:run])
+    digest = state[0]
+    for g, was in zip(got[:4], digest):
+        np.testing.assert_array_equal(g[run:], np.asarray(was)[run:])
+    assert not got[4][run:].any()
+
+
+class TestLiveRowBound:
+    """drain_and_quantile with a row count (the XLA rung): the flush's
+    work follows the live rows, its answers do not change."""
+
+    SLAB = 64
+
+    @pytest.fixture(autouse=True)
+    def small_slab(self, monkeypatch):
+        from veneur_tpu.ops import tdigest_pallas as tp
+
+        monkeypatch.setattr(tp, "_FLUSH_SLAB_ROWS", self.SLAB)
+
+    @pytest.mark.parametrize("n", [1, 63, 64, 65, 2 * 64 + 5, 256])
+    def test_rows_equal_full_width(self, n):
+        state = flush_state(256)
+        run = td.flush_rows_run(256, n)
+        assert run == -(-n // 64) * 64
+        assert_bounded_matches_full(state, n, run, use_pallas=False)
+
+    @pytest.mark.parametrize("n", [1, 128, 129, 200])
+    def test_capacity_no_multiple_of_the_slab(self, n):
+        """200 rows end in a slab clamped back over rows 136-191: what
+        the trip before drained there is not drained twice."""
+        state = flush_state(200, seed=1)
+        run = td.flush_rows_run(200, n)
+        assert run == min(-(-n // 64) * 64, 200)
+        assert_bounded_matches_full(state, n, run, use_pallas=False)
+
+    def test_no_rows_runs_no_slab(self):
+        state = flush_state(256, seed=2)
+        assert td.flush_rows_run(256, 0) == 0
+        assert_bounded_matches_full(state, 0, 0, use_pallas=False)
+
+    @pytest.mark.parametrize("rows,looped", [(32, False), (64, False),
+                                             (65, True), (256, True)])
+    def test_one_slab_or_less_is_straight_line(self, rows, looped):
+        shapes = jax.eval_shape(lambda: flush_state(rows, samples=8))
+        jaxpr = jax.make_jaxpr(
+            lambda *a: td.drain_and_quantile(
+                *a, FLUSH_QS, C, use_pallas=False, n=np.int32(3)))(*shapes)
+        assert ("while" in str(jaxpr)) is looped
+        assert td.flush_rows_run(rows, 3) == (64 if looped else rows)
+
+    def test_no_count_is_the_straight_line_program(self):
+        shapes = jax.eval_shape(lambda: flush_state(256, samples=8))
+        jaxpr = jax.make_jaxpr(
+            lambda *a: td.drain_and_quantile(
+                *a, FLUSH_QS, C, use_pallas=False))(*shapes)
+        assert "while" not in str(jaxpr)
+
+    def test_one_trace_for_every_count(self):
+        traces = []
+
+        def flush(*a):
+            traces.append(1)
+            return td.drain_and_quantile(*a[:4], FLUSH_QS, C,
+                                         use_pallas=False, n=a[4])
+
+        fn = jax.jit(flush)
+        state = flush_state(256, seed=3)
+        for n in (1, 70, 256):
+            fn(*state, np.int32(n))
+        assert len(traces) == 1

@@ -479,10 +479,11 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
                     jnp.asarray(mn_st), jnp.asarray(mx_st), self.mesh,
                     self.compression, self.k)
 
-    def _run_flush(self, qs, use_pallas: bool = True):
+    def _run_flush(self, qs, use_pallas: bool, n: int):
         # the sharded programs compile once per mesh; the compute
         # ladder's retry re-runs the same program here (the mesh path
-        # has no separate kernel variant to fall back to)
+        # has no separate kernel variant to fall back to). Rows are
+        # placed by shard, not as a prefix: no live-row bound here
         return _mesh_flush_digests(self.digest, self.temp, self.dmin,
                                    self.dmax,
                                    jnp.asarray(qs, jnp.float32),
@@ -506,7 +507,7 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         with obs_rec.maybe_stage("compute"), \
                 obs_kernels.scope("flush.digest.mesh"):
             digest, pcts, count, vsum, vmin, vmax, recip = \
-                self._run_flush(qs, use_pallas)
+                self._run_flush(qs, use_pallas, n)
             planes = ()
             if want_digests:
                 planes = (digest.mean[rows], digest.weight[rows],

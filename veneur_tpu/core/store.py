@@ -593,17 +593,21 @@ def _ingest_centroids(digest: td_ops.TDigest, temp: td_ops.TempCentroids,
     return digest, temp, dmin, dmax
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6))
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(6, 7))
 def _flush_digests(digest: td_ops.TDigest, temp: td_ops.TempCentroids,
-                   dmin, dmax, qs, compression, use_pallas=True):
+                   dmin, dmax, qs, n, compression, use_pallas=True):
     """The per-interval flush program: one compress + one batched quantile
     gather for the whole group (the Histo.Flush hot loop of
-    samplers.go:511-636 over all series at once). ``use_pallas=False``
-    is the compute breaker's fallback rung: the same math compiled
-    without the fused kernel (resilience/compute.py)."""
+    samplers.go:511-636 over all series at once). ``n`` is the
+    interval's interned rows as a traced int32 scalar: the program
+    works on the slabs that hold rows ``[:n]`` and leaves the rest of
+    the reserved rows alone (td_ops.drain_and_quantile), one compiled
+    variant whatever ``n``. ``use_pallas=False`` is the compute
+    breaker's fallback rung: the same math compiled without the fused
+    kernel (resilience/compute.py)."""
     drained, pcts = td_ops.drain_and_quantile(digest, temp, dmin, dmax, qs,
                                               compression,
-                                              use_pallas=use_pallas)
+                                              use_pallas=use_pallas, n=n)
     return (drained, pcts, temp.count, temp.vsum, temp.vmin, temp.vmax,
             temp.recip)
 
@@ -992,12 +996,16 @@ class DigestGroup(OverloadLimited):
         self._drain_samples()
         self._drain_imports()
 
-    def _run_flush(self, qs, use_pallas: bool = True):
-        """Execute the jitted flush program (override point for the
-        mesh-sharded store; ``use_pallas=False`` is the compute
-        breaker's fallback rung — same math, no fused kernel)."""
+    def _run_flush(self, qs, use_pallas: bool, n: int):
+        """Execute the jitted flush program over the ``n`` live rows
+        (override point for the mesh-sharded store; ``use_pallas=False``
+        is the compute breaker's fallback rung — same math, no fused
+        kernel). ``n`` goes in as a device scalar, so every interval
+        runs the one compiled variant."""
+        obs_rec.note(rows_live=n,
+                     rows_run=td_ops.flush_rows_run(self.capacity, n))
         return _flush_digests(self.digest, self.temp, self.dmin, self.dmax,
-                              qs, self.compression, use_pallas)
+                              qs, np.int32(n), self.compression, use_pallas)
 
     def flush(self, percentiles: List[float], want_digests=True,
               want_stats=None):
@@ -1105,19 +1113,22 @@ class DigestGroup(OverloadLimited):
         # compute = the program's dispatch (plus any synchronous
         # compile and the quantiles' host->device put); it returns at
         # once. The dispatch PHASE does block, though, one group later:
-        # on the v5e (PERF.md, PR 29) the next group that has samples
+        # on the v5e (PERF.md, PR 30) the next group that has samples
         # staged (self_timers always has) waits in its drain
-        # (flush_begin) for as long as the device runs the program
-        # enqueued here, 0.193 of the 0.204 s store.dispatch: a fresh
+        # (flush_begin) for as long as the device still runs what is
+        # queued: the interval's last ingest dispatch (0.041 s) and the
+        # program enqueued here (0.3 ms for 320 live rows, 0.027 s for
+        # 205,280; 0.154 s before it was bound to the live rows), 0.043
+        # and 0.070 of a 0.051 and 0.078 s store.dispatch. A fresh
         # twin's first touch allocates its planes there, and that host
         # -> device put queues behind the running program (the CPU
         # backend does the same). So fetch, opened by fetch.wait, finds
-        # the results ready (fetch.wait 0.03-0.08 ms on the chip).
+        # the results ready (fetch.wait 0.1-0.9 ms on the chip).
         with obs_rec.maybe_stage("compute"), \
                 obs_kernels.scope("flush.digest.dense"):
             qs = jnp.asarray(list(percentiles) + [0.5], jnp.float32)
             digest, pcts, count, vsum, vmin, vmax, recip = self._run_flush(
-                qs, use_pallas)
+                qs, use_pallas, n)
             planes = ()
             packed_refs = None
             if packed:

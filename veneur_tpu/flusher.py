@@ -146,6 +146,13 @@ def _publish_interval(server, span, rec, timeline):
             "count": len(latencies),
             "max_ns": int(max(latencies)),
             "avg_ns": int(sum(latencies) / len(latencies))}
+    # what the dense flush programs worked on against what was live
+    # (core/store.py DigestGroup._run_flush notes both on its stage)
+    ran = [s for s in entry["stages"] if "rows_run" in s]
+    if ran:
+        entry["digest_flush_rows"] = {
+            "live": sum(s["rows_live"] for s in ran),
+            "run": sum(s["rows_run"] for s in ran)}
     # freshness: the oldest ingest-era stamp this interval aggregated —
     # own lanes and received hops, both taken AT the swap boundary in
     # _flush_once (a post-swap arrival ages the next interval)

@@ -904,13 +904,78 @@ def drain_temp(state: TDigest, temp: TempCentroids,
 def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
                        qs: jax.Array,
                        compression: float = DEFAULT_COMPRESSION,
-                       use_pallas: bool = True):
+                       use_pallas: bool = True, n=None):
     """The whole per-interval digest flush as one op: drain the temp bins
     into the digests, fold in the imported extrema (dmin/dmax), and return
     (drained digests, per-series percentiles). On TPU this is a single
     fused Pallas program; elsewhere — or with ``use_pallas=False``, the
     compute breaker's fallback rung — it composes drain_temp +
-    quantile."""
+    quantile.
+
+    ``n`` (a traced int32 scalar) says that only rows ``[:n]`` are live —
+    the interner hands rows out as a dense prefix. The same pipeline
+    then runs slab by slab (``tdigest_pallas._FLUSH_SLAB_ROWS`` rows) in
+    a loop whose trip count is ``ceil(n / slab)``: one compiled program
+    for every ``n``, its work bounded by the interval's series and not
+    by the rows reserved. Rows past the last slab run keep their input
+    values (their percentiles read 0); nothing reads them. A batch of
+    at most one slab, or no ``n`` (the mesh's shard-routed rows are not
+    a prefix), is the straight-line program."""
+    from veneur_tpu.ops import tdigest_pallas
+
+    slab = tdigest_pallas._FLUSH_SLAB_ROWS
+    qs = jnp.asarray(qs, state.mean.dtype)
+    if n is None or state.mean.shape[0] <= slab:
+        return _drain_and_quantile_rows(state, temp, dmin, dmax, qs,
+                                        compression, use_pallas)
+    rows = state.mean.shape[0]
+    # the slab's inputs: temp's anchors and scalar stats are not read
+    planes = (state.mean, state.weight, state.min, state.max)
+    reads = (temp.sum_w, temp.sum_wm, temp.vmin, temp.vmax, dmin, dmax)
+
+    def one_slab(i, carry):
+        # a capacity that is no multiple of the slab ends in a slab
+        # clamped back over rows the trip before already drained:
+        # those keep what they have (``fresh`` is all true otherwise)
+        start = jnp.minimum(i * slab, rows - slab)
+        cut = lambda x: lax.dynamic_slice_in_dim(x, start, slab, 0)
+        mean, weight, mn, mx = (cut(x) for x in carry[:4])
+        sum_w, sum_wm, vmin, vmax, imin, imax = (cut(x) for x in reads)
+        drained, pcts = _drain_and_quantile_rows(
+            TDigest(mean, weight, mn, mx),
+            temp._replace(sum_w=sum_w, sum_wm=sum_wm, vmin=vmin, vmax=vmax),
+            imin, imax, qs, compression, use_pallas)
+        new = tuple(drained) + (pcts,)
+        if rows % slab:
+            fresh = start + jnp.arange(slab) >= i * slab
+            new = tuple(
+                jnp.where(fresh.reshape((slab,) + (1,) * (x.ndim - 1)), x,
+                          cut(old)) for x, old in zip(new, carry))
+        return tuple(lax.dynamic_update_slice_in_dim(old, x, start, 0)
+                     for old, x in zip(carry, new))
+
+    trips = (jnp.asarray(n, jnp.int32) + (slab - 1)) // slab
+    out = lax.fori_loop(
+        0, jnp.minimum(trips, -(-rows // slab)), one_slab,
+        planes + (jnp.zeros((rows, qs.shape[0]), state.mean.dtype),))
+    return TDigest(*out[:4]), out[4]
+
+
+def flush_rows_run(rows: int, n: int) -> int:
+    """Rows ``drain_and_quantile`` works on for ``n`` live rows of a
+    ``rows``-row batch (the host's count of what the loop above does)."""
+    from veneur_tpu.ops import tdigest_pallas
+
+    slab = tdigest_pallas._FLUSH_SLAB_ROWS
+    if rows <= slab:
+        return rows
+    return min(-(-n // slab) * slab, rows)
+
+
+def _drain_and_quantile_rows(state: TDigest, temp: TempCentroids, dmin,
+                             dmax, qs: jax.Array, compression: float,
+                             use_pallas: bool):
+    """``drain_and_quantile`` over every row it is given."""
     from veneur_tpu.ops import tdigest_pallas
 
     mn = jnp.minimum(jnp.minimum(state.min, temp.vmin), dmin)
@@ -925,8 +990,8 @@ def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
         t_mean, t_w = lax.sort((t_mean, temp.sum_w), dimension=-1,
                                num_keys=1, is_stable=False)
         nm, nw, pcts = tdigest_pallas.drain_quantile(
-            state.mean, state.weight, t_mean, t_w, mn, mx,
-            jnp.asarray(qs, state.mean.dtype), compression, state.capacity)
+            state.mean, state.weight, t_mean, t_w, mn, mx, qs, compression,
+            state.capacity)
         return TDigest(mean=nm, weight=nw, min=mn, max=mx), pcts
     drained = drain_temp(state, temp, compression, use_pallas=use_pallas)
     drained = drained._replace(min=mn, max=mx)

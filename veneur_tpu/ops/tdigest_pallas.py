@@ -41,6 +41,14 @@ _KCHUNK = 16         # output bins reduced per inner step
 # 1 GiB); the slab loop unrolls into a handful of kernel launches that XLA
 # schedules back-to-back over the same HBM planes.
 _MAX_SLAB_ROWS = 1 << 20
+# Rows one trip of the live-row-bounded flush works on
+# (tdigest.drain_and_quantile with a row count): 16 kernel blocks. A
+# batch of at most this many rows is the straight-line program.
+# Swept on a v5e at 2^20 reserved rows (PERF.md, PR 30), 320 / 205,280
+# live rows: 512 rows 1.2 / 30.3 ms, 1,024 1.3 / 29.0, 2,048 1.4-1.6 /
+# 28.4, 4,096 2.0 / 28.7, 8,192 2.7 / 31.6, 32,768 5.9 / 33.4; the
+# straight-line program 155.6 ms whatever is live.
+_FLUSH_SLAB_ROWS = 2048
 
 
 def _row_slabs(total: int):
