@@ -66,12 +66,13 @@ def _pack(lines: list, limit: int) -> list:
 
 
 class Round:
-    """One interval's lines, as datagrams in send order, with what was
-    sent to each series: ``values[g]`` is ``[series, samples]`` float64
-    and ``last[g]`` the sample of each series that is sent last."""
+    """One interval's lines as ``units``, the datagrams ``(payload,
+    lines)`` in send order, with what was sent to each series:
+    ``values[g]`` is ``[series, samples]`` float64 and ``last[g]`` the
+    sample of each series that is sent last."""
 
-    def __init__(self, datagrams, values, last, lines):
-        self.datagrams = datagrams
+    def __init__(self, units, values, last, lines):
+        self.units = units
         self.values = values
         self.last = last
         self.lines = lines

@@ -12,6 +12,8 @@ import importlib
 import json
 import os
 
+from benchmark import feeds
+
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
@@ -68,16 +70,33 @@ class Cell:
                 out.append((m, spec))
         return out
 
+    def polled_sections(self) -> set:
+        """The sections of ``/debug/vars`` that every poll has to keep:
+        what the cell's per-layer metrics' readers say they read from
+        the polls (a reader's ``polled(args)``, where it has one)."""
+        out = set()
+        for _m, spec in self.per_layer():
+            polled = getattr(reader(spec["reader"]), "polled", None)
+            if polled is not None:
+                out.update(polled(spec["args"]))
+        return out
+
     def generator(self):
         """The traffic mix's generator module, found by name."""
         return importlib.import_module(
             "benchmark.generators." + self.traffic["generator"])
 
+    def feed(self):
+        """The module that carries the mix to the server, found by the
+        mix's ``feed``."""
+        return importlib.import_module(
+            "benchmark.feeds." + self.traffic.get("feed", feeds.DEFAULT))
+
     def server_config_text(self, ports: dict) -> str:
         """The configuration as the server reads it: the file's
         ``server`` keys, one ``key: <JSON value>`` a line (which is
-        YAML), with ``{statsd_port}``, ``{http_port}`` and
-        ``{receiver_port}`` filled in."""
+        YAML), with ``{http_port}``, ``{receiver_port}`` and the feed's
+        own ports filled in."""
         lines = []
         for key, value in self.config["server"].items():
             text = json.dumps(value)

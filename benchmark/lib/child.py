@@ -135,11 +135,15 @@ class Child:
 class Watcher(threading.Thread):
     """Polls /debug/vars at 1 Hz while the window runs: the overload
     level and the per-interval spill tallies reset, and the lane backlog
-    is a level. Keeps every poll for the ``vars_path`` reader."""
+    and a mesh's shard occupancy are levels that only exist while an
+    interval is live. Keeps of every poll the lanes' totals, the
+    overload level and the ``sections`` of ``/debug/vars`` that the
+    cell's metrics ask for (``readers/vars_path.py`` ``polled``)."""
 
-    def __init__(self, child: Child):
+    def __init__(self, child: Child, sections=()):
         super().__init__(daemon=True)
         self.child = child
+        self.sections = set(sections) - {"ingest_fleet", "overload"}
         self.done = threading.Event()
         self.polls: list = []
         self.max_level = 0
@@ -165,7 +169,8 @@ class Watcher(threading.Thread):
                 "ingest_fleet": [{"totals": f.get("totals", {})}
                                  for f in v.get("ingest_fleet", [])],
                 "overload": {"level": ov.get("level", 0),
-                             "pressure": ov.get("pressure", 0.0)}})
+                             "pressure": ov.get("pressure", 0.0)},
+                **{name: v[name] for name in self.sections if name in v}})
 
     def stop(self):
         self.done.set()

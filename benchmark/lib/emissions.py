@@ -17,7 +17,29 @@ def percentile_suffix(q: float) -> str:
     return "%gpercentile" % (q * 100.0)
 
 
-POINT_STAMP = re.compile(rb'"points":\[\[(\d+),')
+def forwarded(group: dict) -> bool:
+    """Whether the group's series reach the server as forwards of other
+    instances: it states how many forwarders report a series
+    (``fan_in``), or it is their messages' ``marker``."""
+    return "fan_in" in group or bool(group.get("marker"))
+
+
+def columns(group: dict, percentiles: list) -> list:
+    """The rows a series of the group has in an emission. A histogram
+    that was forwarded with mixed scope has its percentiles only: count,
+    min and max are the forwarders' own to emit."""
+    if group["type"] != "h":
+        return ["value"]
+    local = [] if forwarded(group) else [SUFFIX_COUNT, SUFFIX_MIN,
+                                         SUFFIX_MAX]
+    return local + [percentile_suffix(q) for q in percentiles]
+
+
+# The sink writes a body in either of two hands: its native encoder's,
+# with nothing between the tokens and whole seconds, and the standard
+# library's, with a space after each colon and comma and seconds as a
+# float (the groups that only a global has go that way).
+POINT_STAMP = re.compile(rb'"points": ?\[\[(\d+)[.,]')
 
 
 def assign_emissions(bodies: list, ticks: list) -> list:
@@ -51,12 +73,9 @@ class Emission:
     got no row; for a counter or a gauge group ``cols["value"]``.
     ``dup`` counts rows seen twice, ``stray`` rows outside a group."""
 
-    def __init__(self, groups: list, suffixes: list):
-        self.cols = []
-        for g in groups:
-            names = suffixes if g["type"] == "h" else ["value"]
-            self.cols.append({s: np.full(int(g["series"]), np.nan)
-                              for s in names})
+    def __init__(self, groups: list, percentiles: list):
+        self.cols = [{s: np.full(int(g["series"]), np.nan)
+                      for s in columns(g, percentiles)} for g in groups]
         self.dup = 0
         self.stray = 0
         self.rows = 0
@@ -70,14 +89,12 @@ def parse(bodies: list, owner: list, n_flushes: int, groups: list,
     flush. Rows of type ``rate`` (counters and a histogram's ``count``)
     come back from rates to counts: the sink divides them by the
     interval (sinks/datadog.py ``_serialize_block``)."""
-    suffixes = [SUFFIX_COUNT, SUFFIX_MIN, SUFFIX_MAX] + [
-        percentile_suffix(q) for q in percentiles]
-    out = [Emission(groups, suffixes) for _ in range(n_flushes)]
+    out = [Emission(groups, percentiles) for _ in range(n_flushes)]
     prefixes = [g["prefix"].encode() for g in groups]
     pattern = re.compile(
-        rb'"metric":"(' + b"|".join(re.escape(p) for p in prefixes)
-        + rb')(\d+)(?:\.([\w.]+))?","points":\[\[\d+,([^\]]+)\]\]'
-        rb'(?:,"tags":\[[^\]]*\])?,"type":"(\w+)"')
+        rb'"metric": ?"(' + b"|".join(re.escape(p) for p in prefixes)
+        + rb')(\d+)(?:\.([\w.]+))?", ?"points": ?\[\[[\d.]+, ?([^\]]+)\]\]'
+        rb'(?:, ?"tags": ?\[[^\]]*\])?, ?"type": ?"(\w+)"')
     index = {p: i for i, p in enumerate(prefixes)}
     for (stamp, path, encoding, raw), k in zip(bodies, owner):
         if k < 0 or k >= n_flushes:
@@ -106,10 +123,10 @@ def parse(bodies: list, owner: list, n_flushes: int, groups: list,
             inside = mine & (idx < int(grp["series"]))
             em.stray += int((mine & ~inside).sum())
             if grp["type"] == "h":
-                for s in suffixes:
+                for s, col in em.cols[g].items():
                     m = inside & (suf == s.encode())
-                    _put(em, em.cols[g][s], idx[m], val[m])
-                known = np.isin(suf, [s.encode() for s in suffixes])
+                    _put(em, col, idx[m], val[m])
+                known = np.isin(suf, [s.encode() for s in em.cols[g]])
                 em.stray += int((inside & ~known).sum())
             else:
                 m = inside & (suf == b"")
@@ -128,10 +145,15 @@ def _put(em: Emission, col: np.ndarray, idx: np.ndarray,
 def lines_in(em: Emission, groups: list) -> int:
     """Lines an emission accounts for, read from outside: a histogram
     row's ``count`` is its lines; a counter or gauge row stands for the
-    one line a round sends that series."""
+    one line a round sends that series. Of forwarded groups the marker's
+    rows alone say it: each carries the entries of the messages it
+    stands for, and an entry is such a group's line."""
     total = 0
     for g, cols in zip(groups, em.cols):
-        if g["type"] == "h":
+        if forwarded(g):
+            if g.get("marker"):
+                total += int(np.nansum(cols["value"]))
+        elif g["type"] == "h":
             total += int(np.nansum(cols[SUFFIX_COUNT]))
         else:
             total += int((~np.isnan(cols["value"])).sum()) * int(

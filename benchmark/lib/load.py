@@ -1,7 +1,7 @@
-"""The two ends the harness holds: the open-loop paced sender at the
-child's UDP statsd port, and the loopback receiver that stands where
-Datadog's API would. Both stamp ``time.time()``, the clock the flush
-timeline's ``wall_start`` is on."""
+"""The end the harness holds whatever feeds the server
+(``benchmark/feeds/``): the loopback receiver that stands where
+Datadog's API would. It stamps ``time.time()``, the clock the flush
+timeline's ``wall_start`` and the feeds' due times are on."""
 
 from __future__ import annotations
 
@@ -15,36 +15,6 @@ def free_port(kind: int) -> int:
     with socket.socket(socket.AF_INET, kind) as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
-
-
-class Sender:
-    """Open loop: every datagram has a due time fixed before the round
-    starts, and the sender never waits for the server."""
-
-    def __init__(self, port: int, sockets: int):
-        self.addr = ("127.0.0.1", port)
-        self.socks = [socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
-                      for _ in range(sockets)]
-
-    def send(self, datagrams: list, start: float, span_s: float) -> list:
-        """Lines due evenly over ``[start, start + span_s]``; returns
-        [(due, sent, n_lines)] a datagram."""
-        total = sum(n for _p, n in datagrams) or 1
-        log, done = [], 0
-        for i, (payload, n) in enumerate(datagrams):
-            due = start + span_s * done / total
-            now = time.time()
-            if now < due:
-                time.sleep(due - now)
-                now = time.time()
-            self.socks[i % len(self.socks)].sendto(payload, self.addr)
-            log.append((due, now, n))
-            done += n
-        return log
-
-    def close(self):
-        for s in self.socks:
-            s.close()
 
 
 class Receiver:

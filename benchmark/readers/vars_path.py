@@ -16,6 +16,12 @@ def _dig(obj, path: str):
     return obj
 
 
+def polled(args: dict) -> list:
+    """The section of ``/debug/vars`` that every poll has to keep for
+    this metric."""
+    return [args["path"].split(".")[0]] if args["at"] == "poll_max" else []
+
+
 def read(args: dict, ctx: dict):
     at, path = args["at"], args["path"]
     if at in ("start", "end"):

@@ -6,15 +6,17 @@
 Starts ``python -m veneur_tpu.cli.server`` as a child (the only process
 that holds the chip; this parent never imports JAX while it lives) on
 the cell's configuration, with the Datadog sink pointed at a receiver
-on loopback. Sends the cell's traffic at the child's UDP statsd port,
-one round an interval from each tick, for ``seconds // interval`` whole
-intervals after a warm-up round of the same shape. Then compares what
-the receiver got with the float64 reference and prints the contract's
-line last. Everything else goes on earlier lines, one JSON object each,
-and into ``benchmark/out/<workload>/``.
+on loopback. Sends the cell's traffic through the mix's feed (datagrams
+at the statsd port, or forwards at the import port), one round an
+interval from each tick, for ``seconds // interval`` whole intervals
+after two warm-up rounds of the same shape. Then compares what the
+receiver got with the float64 reference and prints the contract's line
+last. Everything else goes on earlier lines, one JSON object each, and
+into ``benchmark/out/<workload>/``.
 
-A cell, its configuration, its traffic mix, the mix's generator, every
-per-layer metric and its reader are files found by name (``README.md``).
+A cell, its configuration, its traffic mix, the mix's generator and
+feed, every per-layer metric and its reader are files found by name
+(``README.md``).
 """
 
 from __future__ import annotations
@@ -34,7 +36,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 from benchmark.lib import cells, emissions, reference  # noqa: E402
 from benchmark.lib.child import Child, Watcher  # noqa: E402
-from benchmark.lib.load import Receiver, Sender, free_port  # noqa: E402
+from benchmark.lib.load import Receiver, free_port  # noqa: E402
 
 NO_ACCELERATOR = 3
 # Two warm-up rounds of the window's own shape: the first loaded flush
@@ -114,16 +116,17 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
 
     receiver = receiver or Receiver()
     receiver.start()
-    ports = {"statsd_port": free_port(socket.SOCK_DGRAM),
-             "http_port": free_port(socket.SOCK_STREAM),
-             "receiver_port": receiver.port}
+    feed_kind = cell.feed()
+    ports = {name: free_port(kind) for name, kind in feed_kind.PORTS.items()}
+    ports.update(http_port=free_port(socket.SOCK_STREAM),
+                 receiver_port=receiver.port)
     config_path = os.path.join(out_dir, "config.yaml")
     with open(config_path, "w") as f:
         f.write(cell.server_config_text(ports))
     child = Child(config_path, os.path.join(out_dir, "server.log"),
                   ports["http_port"], dict(os.environ))
-    sender = Sender(ports["statsd_port"], int(traffic["sockets"]))
-    watcher = Watcher(child)
+    feed = feed_kind.Feed(ports, traffic)
+    watcher = Watcher(child, cell.polled_sections())
     box: dict = {}
     tracer = None
     try:
@@ -133,7 +136,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
                   for k in range(1 - WARM_ROUNDS, n_rounds + 1)}
         rep.line(phase="load_built", rounds=len(rounds),
                  lines_per_round=rounds[0].lines,
-                 datagrams_per_round=len(rounds[0].datagrams),
+                 units_per_round=len(rounds[0].units),
                  lines_per_s=round(rounds[0].lines / interval, 1),
                  seconds=round(time.time() - t0, 2))
         if not child.wait_ready(900.0):
@@ -152,12 +155,14 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         #    program compiles or loads), then WARM_ROUNDS rounds of the
         #    window's own shape, so that nothing compiles inside it --
         first = child.published()
-        sender.send(gen.warm_lines(traffic), time.time(), 0.0)
+        feed.send(gen.warm_lines(traffic), time.time(), 0.0)
         tick = child.wait_flushes(first + 1, 900.0)
         warm_log = []
+        started = {}                # when each round's span began
         for w in range(WARM_ROUNDS):
-            warm_log += sender.send(rounds[1 - WARM_ROUNDS + w].datagrams,
-                                    tick, span)
+            started[1 - WARM_ROUNDS + w] = tick
+            warm_log += feed.send(rounds[1 - WARM_ROUNDS + w].units,
+                                  tick, span)
             tick = child.wait_flushes(first + 2 + w, 4 * interval + 900.0,
                                       quiet_until=tick + span)
         first += WARM_ROUNDS - 1
@@ -184,7 +189,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
                     _xprof, args=(child, trace_seconds, box))
                 tracer.daemon = True
                 tracer.start()
-            sent = sender.send(rounds[k].datagrams, tick, span)
+            started[k] = tick
+            sent = feed.send(rounds[k].units, tick, span)
             rep.line(phase="round", k=k, worst_lag_s=worst_lag(sent))
             send_log += sent
             tick = child.wait_flushes(first + 2 + k, 4 * interval + 900.0,
@@ -204,7 +210,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         timeline = child.timeline()
     finally:
         watcher.stop()
-        sender.close()
+        feed.close()
         rc = child.stop()
         receiver.stop()
 
@@ -225,9 +231,11 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     # window is late, and the run's totals say so
     by_round = {window.start - 1 + k: rounds[k]
                 for k in range(1 - WARM_ROUNDS, n_rounds + 1)}
-    verdict = reference.compare(ems, by_round, window, groups,
-                                percentiles,
-                                float(cell.config["rank_error_limit"]))
+    verdict = reference.compare(
+        ems, by_round, window, groups, percentiles,
+        float(cell.config["rank_error_limit"]),
+        {window.start - 1 + k: (tick, span, interval)
+         for k, tick in started.items()})
     rep.line(phase="compared", bodies=len(bodies),
              rows=sum(e.rows for e in ems),
              bodies_late=sum(1 for b, k in zip(bodies, owner)
@@ -244,10 +252,11 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
              measures=e2e["measures"])
 
     lines_sent = e2e["lines_sent"]
-    datagrams = (len(send_log) + len(warm_log)
-                 + len(gen.warm_lines(traffic)))
+    # an import that arrives while a flush runs waits for it
+    feed.checks(rep, vars_last, interval + max(
+        e["total_duration_ns"] for e in timeline) / 1e9)
     _run_checks(rep, cell, timeline, window, vars_last, watcher, child, rc,
-                datagrams, ems)
+                ems)
 
     numbers = dict(verdict["numbers"])
     failed_checks = [c for c in rep.failed
@@ -340,27 +349,17 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
 
 
 def _run_checks(rep: Report, cell, timeline, window, v, watcher, child, rc,
-                datagrams, ems) -> None:
-    """What the configuration guarantees besides the numbers: nothing
-    lost, nothing refused, no hidden fallback (``chip_smoke.py``'s)."""
-    totals = v["ingest_fleet"][0]["totals"]
-    lanes = v["ingest_fleet"][0]["per_lane"]
-    rep.check("datagrams_received", totals["packets"] == datagrams,
-              sent=datagrams, received=totals["packets"],
-              lines_parsed=totals["parsed"])
-    rep.check("native_ingest", all(ln["native_decode"] and ln["recvmmsg"]
-                                   for ln in lanes))
+                ems) -> None:
+    """What the configuration guarantees besides the numbers, whatever
+    feeds it: nothing refused, no hidden fallback (``chip_smoke.py``'s).
+    The feed's own checks say that nothing was lost on its way in."""
     ov = v["overload"]
-    lane_shed = {k: totals[k] for k in (
-        "shed_packets", "shed_records", "shed_chunks", "quarantined",
-        "parse_errors")}
     rep.check("nothing_shed_quarantined_spilled",
               not any(ov["shed"].values())
               and not any(ov["quarantined"].values())
-              and not watcher.spilled and not any(lane_shed.values())
-              and not v.get("packet_errors") and not v.get("packet_drops"),
+              and not watcher.spilled,
               shed=ov["shed"], quarantined=ov["quarantined"],
-              spilled=watcher.spilled, lane_shed=lane_shed)
+              spilled=watcher.spilled)
     rep.check("overload_level_zero", watcher.max_level == 0
               and ov["level"] == 0 and ov["level_changes"] == 0,
               max_level_seen=watcher.max_level,
@@ -370,10 +369,15 @@ def _run_checks(rep: Report, cell, timeline, window, v, watcher, child, rc,
     # a flush's fresh generation places its planes on first write, so a
     # read between intervals may find none: the watcher keeps the last
     planes = device.get("digest_planes") or watcher.digest_planes
+    # a cell on several chips is one store sharded over a mesh of them
+    mesh = v.get("mesh", {}) if cell.chips > 1 else {"devices": 1}
     rep.check("platform", device.get("platform") == "tpu"
               and device.get("count") == cell.chips
-              and planes.get("platform") == "tpu",
-              device=device, chips_wanted=cell.chips)
+              and planes.get("platform") == "tpu"
+              and mesh.get("devices") == cell.chips
+              and len(set(planes.get("devices", []))) == cell.chips,
+              device=device, chips_wanted=cell.chips, planes=planes,
+              mesh_devices=mesh.get("devices"), mesh_axes=mesh.get("axes"))
     rungs = sorted({s["rung"] for k in window for s in
                     timeline[k]["stages"] if "rung" in s})
     rep.check("rung", rungs == ["pallas"], rungs_seen=rungs)

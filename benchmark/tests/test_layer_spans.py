@@ -48,7 +48,11 @@ def test_every_one_reads_a_number_on_a_rehearsal(tmp_path):
     manifest = cells.read_json(
         os.path.join(TESTS, "rehearsal", "manifest.json"))
     declared = _declared()
-    manifest["per_layer"] += [declared[name] for name in NEW]
+    # without the list of the accepted cells they are read in: the
+    # rehearsal's cells have names of their own
+    manifest["per_layer"] += [
+        {k: v for k, v in declared[name].items() if k != "workloads"}
+        for name in NEW]
     path = tmp_path / "manifest.json"
     path.write_text(json.dumps(manifest))
     proc = subprocess.run(

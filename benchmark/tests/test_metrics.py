@@ -10,7 +10,7 @@ import pytest
 from benchmark.lib import emissions
 
 GROUPS = [{"prefix": "t.h.", "type": "h", "series": 100, "samples": 1}]
-SUFFIXES = ["count", "min", "max"]
+PERCENTILES = []
 
 
 def _run(stall_s):
@@ -23,7 +23,7 @@ def _run(stall_s):
         t += lag[k] + 10.0
     ems = []
     for k in range(4):
-        em = emissions.Emission(GROUPS, SUFFIXES)
+        em = emissions.Emission(GROUPS, PERCENTILES)
         em.last_stamp = ticks[k] + lag[k]
         em.bodies = 1
         if k >= 1:
@@ -63,7 +63,7 @@ def test_warm_up_lines_carried_into_the_window_are_no_window_lines():
     # round, the oldest at their flush, look a flush younger.
     lag = 0.5
     ticks = [100.0 + 10.5 * k for k in range(4)]
-    ems = [emissions.Emission(GROUPS, SUFFIXES) for _ in ticks]
+    ems = [emissions.Emission(GROUPS, PERCENTILES) for _ in ticks]
     for k, em in enumerate(ems):
         em.last_stamp = ticks[k] + lag
         em.cols[0]["count"][:] = 1.0 if k else np.nan
@@ -83,7 +83,7 @@ def test_warm_up_lines_carried_into_the_window_are_no_window_lines():
 def test_a_line_no_emission_holds_waits_for_ever():
     lag = 0.5
     ticks = [100.0, 110.5]
-    ems = [emissions.Emission(GROUPS, SUFFIXES) for _ in ticks]
+    ems = [emissions.Emission(GROUPS, PERCENTILES) for _ in ticks]
     for k, em in enumerate(ems):
         em.last_stamp = ticks[k] + lag
     ems[1].cols[0]["count"][:50] = 1.0           # half the lines lost

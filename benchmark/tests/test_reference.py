@@ -10,7 +10,7 @@ import copy
 import numpy as np
 import pytest
 
-from benchmark.generators import series_groups
+from benchmark.generators import forwarded_groups, series_groups
 from benchmark.lib import emissions, reference
 
 PERCENTILES = [0.5, 0.75, 0.99]
@@ -161,3 +161,115 @@ def test_rank_error_measure():
     assert reference.rank_error(ordered, np.array([59.5]), 0.5)[0] == \
         pytest.approx(0.1)
     assert reference.rank_error(ordered, np.array([np.nan]), 0.5)[0] == 1.0
+
+
+# -- forwarded groups: a global's answer is a merge over forwarders -------
+
+FORWARDED = {
+    "guard_s": 0.5, "forwarders": 8, "stagger": True, "late_share": 0.125,
+    "late_after_s": 0.05, "message_metrics": 100, "compression": 100,
+    "groups": [
+        {"prefix": "t.t.", "type": "h", "series": 120, "fan_in": 4,
+         "samples": 64,
+         "values": {"dist": "lognormal_64ths", "mu": 3.0, "sigma": 0.25,
+                    "scale_low": 0.5, "scale_high": 20.0}},
+        {"prefix": "t.p.", "type": "h", "series": 16, "fan_in": 1,
+         "samples": 1, "values": {"dist": "quarters", "high": 400000}},
+        {"prefix": "t.c.", "type": "c", "series": 120, "fan_in": 4,
+         "values": {"dist": "integers", "low": 1, "high": 1000}},
+        {"prefix": "t.g.", "type": "g", "series": 120, "fan_in": 4,
+         "values": {"dist": "quarters", "high": 400000}},
+        {"prefix": "t.m.", "type": "c", "marker": True, "series": 16}]}
+MARKER = 4
+
+
+def _forwarded_case(seed, precision, moved=None):
+    groups = FORWARDED["groups"]
+    rounds = {k: forwarded_groups.build(FORWARDED, seed, k - 1)
+              for k in WINDOW}
+    ems = reference.synthesize(rounds, WINDOW, 6, groups, PERCENTILES,
+                               precision, moved)
+    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, 0.02)
+    return rounds, ems, out
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
+def test_forwarded_reference_in_its_own_place_is_correct(seed):
+    for precision in ("float64", "float32"):
+        rounds, ems, out = _forwarded_case(seed, precision)
+        assert _correct(out["numbers"]), out["numbers"]
+        assert out["lines_late"] == 0
+    # one forwarder of the eight is due after the tick: its two messages
+    # stand in the next emission, on time there
+    late = rounds[WINDOW[0]].late
+    assert late.sum() == 2
+    first = ems[WINDOW[0]].cols[MARKER]["value"]
+    after = ems[WINDOW[-1] + 1].cols[MARKER]["value"]
+    assert np.isnan(first[late]).all() and not np.isnan(first[~late]).any()
+    assert (after[late] == rounds[WINDOW[-1]].entries[late]).all()
+    assert np.isnan(after[~late]).all()
+    # a forwarded histogram has its percentiles and nothing else
+    assert sorted(ems[3].cols[0]) == sorted(
+        emissions.percentile_suffix(q) for q in PERCENTILES)
+
+
+@pytest.mark.parametrize("seed", [1, 8, 2**31 + 6])
+def test_forwarded_control_in_bfloat16_is_not_correct(seed):
+    numbers = _forwarded_case(seed, "bfloat16")[2]["numbers"]
+    assert not _correct(numbers)
+    assert numbers["scalar_rows_wrong"]["value"] > 0
+    # the probe series: one forwarder, one sample, back rounded
+    assert numbers["rank_error_max"]["value"] > 0.2
+
+
+def test_a_late_forward_is_late_not_wrong():
+    rounds = _forwarded_case(6, "float64")[0]
+    slot = 3        # forwarder 1's second message, due before the tick
+    assert not rounds[3].late[slot]
+    _r, ems, out = _forwarded_case(6, "float64", {(3, slot): 4})
+    assert _correct(out["numbers"]), out["numbers"]
+    assert out["lines_late"] == rounds[3].entries[slot]
+    assert emissions.lines_in(ems[4], FORWARDED["groups"]) == \
+        rounds[4].lines + rounds[3].entries[slot]
+
+
+def test_a_forward_that_no_emission_holds_is_unaccounted():
+    rounds, _ems, out = _forwarded_case(6, "float64", {(3, 3): 99})
+    assert not _correct(out["numbers"])
+    assert out["numbers"]["lines_unaccounted"]["value"] == \
+        rounds[3].entries[3]
+
+
+def test_a_forward_merged_twice_is_flagged():
+    groups = FORWARDED["groups"]
+    rounds, ems, _out = _forwarded_case(6, "float64")
+    # slot 3's entries of round 3 once more: its marker and its counters
+    ems[3].cols[MARKER]["value"][3] *= 2
+    mine = rounds[3].slot[2] == 3
+    ems[3].cols[2]["value"][:] += np.where(
+        mine, rounds[3].values[2], 0.0).sum(axis=1)
+    numbers = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES,
+                                0.02)["numbers"]
+    assert numbers["lines_unaccounted"]["value"] == rounds[3].entries[3]
+    assert numbers["scalar_rows_wrong"]["value"] == mine.any(axis=1).sum()
+
+
+def test_a_stray_count_row_of_a_forwarded_histogram_is_stray():
+    groups = FORWARDED["groups"]
+    body = (b'{"series":[{"metric":"t.t.007.count","points":[[100,4.0]],'
+            b'"type":"rate"},{"metric":"t.t.007.50percentile",'
+            b'"points":[[100,4.5]],"type":"gauge"}]}')
+    ems = emissions.parse([(100.5, "/api/v1/series", "identity", body)],
+                          [0], 1, groups, PERCENTILES, 10.0)
+    assert ems[0].stray == 1
+    assert ems[0].cols[0][emissions.percentile_suffix(0.5)][7] == 4.5
+
+
+def test_rank_error_among_few_samples():
+    # two samples: the reference's own interpolated median lies between
+    # them and is charged nothing; a value outside them is
+    two = np.array([[1.0, 3.0, np.nan, np.nan]])
+    n = np.array([2])
+    assert reference.rank_error(two, np.array([2.0]), 0.5, n)[0] == 0.0
+    assert reference.rank_error(two, np.array([3.5]), 0.5, n)[0] == 0.5
+    assert reference.rank_error(two, np.array([1.0]), 0.5, n)[0] == 0.0

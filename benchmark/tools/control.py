@@ -29,8 +29,10 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--seconds", type=float, default=0.0)
+    ap.add_argument("--manifest", default="")
+    ap.add_argument("--traffic-dir", default="")
     args = ap.parse_args(argv)
-    cell = cells.Cell(args.workload)
+    cell = cells.Cell(args.workload, args.manifest, args.traffic_dir)
     seconds = args.seconds or cell.manifest["run_seconds"]
     n_rounds = max(1, int(seconds // cell.interval_s))
     groups = cell.traffic["groups"]
