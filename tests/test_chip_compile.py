@@ -231,8 +231,96 @@ def test_mesh_programs_shard_state_over_series(one_chip_flush, mesh,
     stack_f = _f32((shards, CHUNK), st)
     stack_i = _i32((shards, CHUNK), st)
     imp = _mesh_import_routed.lower(
-        m_temp, m_digest, m_rows, m_rows, stack_i, stack_f, stack_f,
-        stack_i, stack_f, stack_f, mesh, COMPRESSION, K).compile()
+        m_temp, m_digest, m_rows, m_rows, _i32((shards,), named(s)),
+        stack_i, stack_f, stack_f, stack_i, stack_f, stack_f, mesh,
+        COMPRESSION).compile()
     assert "tpu_custom_call" in imp.as_text()
     assert (imp.memory_analysis().argument_size_in_bytes
             <= 0.55 * state_bytes + (8 << 20))
+
+
+def test_global_at_deployment_size_fits_a_chip(topo, kernel_admitted):
+    """``global-fanin64`` as it is deployed: 2^22 digest rows over the
+    four chips of a host (series 4 x hosts 1). The planes are made in
+    shards, the routed import (with its row-local drain, the kernel
+    inside) and the flush compile, and none of the three asks a device
+    for more than its quarter of the state and a chunk's worth beside
+    it. The import holds no collective: two threads dispatch it and
+    the flush."""
+    from veneur_tpu.core.mesh_store import (_digest_specs,
+                                            _mesh_flush_digests,
+                                            _mesh_import_routed,
+                                            _mesh_init_digests)
+    from veneur_tpu.parallel.mesh import fleet_mesh
+
+    rows = 1 << 22
+    mesh = fleet_mesh(topo.devices, hosts=1)
+    assert dict(mesh.shape) == {"series": 4, "hosts": 1}
+    temp_spec, dig_spec, _sk, s = _digest_specs()
+    named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    digest, temp = _digest_state(rows)
+    quarter = (_nbytes(digest) + _nbytes(temp)) // 4
+    m_digest = _on(digest, jax.tree.map(named, dig_spec))
+    m_temp = _on(temp, jax.tree.map(named, temp_spec))
+    m_rows = _f32((rows,), named(s))
+
+    init = _mesh_init_digests.lower(mesh, rows, K, COMPRESSION).compile()
+    made = init.memory_analysis()
+    assert made.output_size_in_bytes <= quarter + (16 << 20)
+    assert made.temp_size_in_bytes <= 1 << 20
+
+    st = named(P("series", None))
+    stack_f = _f32((4, CHUNK), st)
+    stack_i = _i32((4, CHUNK), st)
+    imp = _mesh_import_routed.lower(
+        m_temp, m_digest, m_rows, m_rows, _i32((4,), named(s)),
+        stack_i, stack_f, stack_f, stack_i, stack_f, stack_f, mesh,
+        COMPRESSION).compile()
+    text = imp.as_text()
+    assert "tpu_custom_call" in text
+    for collective in ("all-reduce", "all-gather", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text
+    held = imp.memory_analysis()
+    assert held.argument_size_in_bytes <= quarter + (16 << 20)
+    # donated planes are updated in place; what is left is chunk-sized
+    assert held.temp_size_in_bytes <= 2 * quarter
+
+    flush = _mesh_flush_digests.lower(
+        m_digest, m_temp, m_rows, m_rows, _f32((4,), named(P())), mesh,
+        COMPRESSION).compile()
+    assert (flush.memory_analysis().argument_size_in_bytes
+            <= quarter + (16 << 20))
+
+
+def test_dense_import_holds_the_kernel(one_chip, kernel_admitted):
+    """The dense global's import at 2^20 rows on one chip: the row-local
+    drain is one loop whose body holds the kernel, once, works on a
+    slab of the rows to drain and copies no whole plane a trip."""
+    import re
+
+    from veneur_tpu.core.store import _ingest_centroids
+    from veneur_tpu.ops.tdigest import ROW_DRAIN_SLAB_ROWS
+
+    rows = 1 << 20
+    digest, temp = (_on(t, one_chip) for t in _digest_state(rows))
+    plane = _f32((rows,), one_chip)
+    imp = _ingest_centroids.lower(
+        digest, temp, plane, plane, _i32((CHUNK,), one_chip),
+        _f32((CHUNK,), one_chip), _f32((CHUNK,), one_chip),
+        _i32((CHUNK,), one_chip), _f32((CHUNK,), one_chip),
+        _f32((CHUNK,), one_chip), _i32((), one_chip), COMPRESSION,
+        True).compile()
+    text = imp.as_text()
+    bodies = []
+    for body in re.findall(r"body=%?([\w.\-]+)", text):
+        start = text.index("%" + body + " (")
+        bodies.append(text[start:text.index("\n}", start)])
+    drains = [b for b in bodies if "tpu_custom_call" in b]
+    assert len(drains) == 1
+    assert f"f32[{ROW_DRAIN_SLAB_ROWS},{K}]" in drains[0]
+    whole = f"f32[{rows},{K}]"
+    for line in drains[0].splitlines():
+        if re.search(r"= \S+ (copy|transpose)\(", line):
+            assert whole not in line.split("=")[1].split("(")[0], line
+    assert imp.memory_analysis().alias_size_in_bytes >= 4 * rows * K * 4

@@ -180,6 +180,51 @@ class TestFleetConfig:
         with pytest.raises(ValueError, match="slab"):
             MetricStore(mesh=mesh, digest_storage="slab")
 
+    def test_sharded_needs_mesh(self):
+        cfg = Config(digest_storage="sharded")
+        cfg.apply_defaults()
+        with pytest.raises(ValueError, match="mesh_enabled"):
+            cfg.validate()
+        with pytest.raises(ValueError, match="mesh"):
+            MetricStore(digest_storage="sharded")
+
+    def test_sharded_is_the_mesh_dense_store(self, mesh):
+        from veneur_tpu.core.mesh_store import MeshDigestGroup
+
+        cfg = Config(digest_storage="sharded", mesh_enabled=True)
+        cfg.apply_defaults()
+        cfg.validate()
+        for storage in ("sharded", "dense"):
+            store = MetricStore(initial_capacity=32, chunk=128, mesh=mesh,
+                                digest_storage=storage)
+            assert type(store.histograms) is MeshDigestGroup
+            assert type(store.timers) is MeshDigestGroup
+
+    def test_global_fanin64_asks_for_sharded_planes(self, tmp_path):
+        """The benchmark's deployment names the storage a build from
+        before the sharded allocation does not know: such a build
+        refuses the file at load (its ``validate`` raises on an unknown
+        ``digest_storage``), this one reads it."""
+        import json
+        import os
+        import re
+
+        from veneur_tpu.config import read_config
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               "global-fanin64.json")) as f:
+            server = json.load(f)["server"]
+        assert server["digest_storage"] == "sharded"
+        path = tmp_path / "config.yaml"
+        # as the harness writes it: a key and its JSON value a line,
+        # the ports filled in
+        path.write_text(re.sub(r"\{\w+_port\}", "1", "".join(
+            f"{k}: {json.dumps(v)}\n" for k, v in server.items())))
+        cfg = read_config(str(path), environ={})
+        assert cfg.mesh_enabled and cfg.mesh_hosts == 1
+        assert cfg.store_initial_capacity == 1 << 22
+
 
 class TestStableRowIds:
     """The id contract of the mesh groups: ``_row`` hands out LOGICAL

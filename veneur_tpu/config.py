@@ -144,7 +144,12 @@ class Config:
     # series capacity plan of core/slab.py; grows one slab at a time), or
     # "tiered" (core/tiered.py: cold series in a packed u16/bf16 quantized
     # pool at ~228 B/row, promotion to dense full-K slots on sustained
-    # activity — the 5-10x series-capacity plan at realistic density)
+    # activity — the 5-10x series-capacity plan at realistic density),
+    # or "sharded" (a mesh global's dense planes, made and grown in
+    # shards over the series axis, never whole on one device: what
+    # "dense" gives under mesh_enabled, said outright, so that a build
+    # whose mesh store cannot make them so does not know the value and
+    # refuses the file at load; needs mesh_enabled)
     digest_storage: str = "dense"
     # tiered store: packed-pool centroid slots per series (power of two
     # >= 8; more slots = finer cold-row quantiles, more resident bytes)
@@ -406,10 +411,16 @@ class Config:
             from veneur_tpu.crash import SentryReporter
 
             SentryReporter(self.sentry_dsn)  # raises on malformed DSN
-        if self.digest_storage not in ("dense", "slab", "tiered"):
+        if self.digest_storage not in ("dense", "slab", "tiered",
+                                       "sharded"):
             raise ValueError(
-                f"digest_storage must be 'dense', 'slab' or 'tiered', "
-                f"got {self.digest_storage!r}")
+                f"digest_storage must be 'dense', 'slab', 'tiered' or "
+                f"'sharded', got {self.digest_storage!r}")
+        if self.digest_storage == "sharded" and not self.mesh_enabled:
+            raise ValueError(
+                "digest_storage: sharded is the mesh store's dense "
+                "planes made in shards and needs mesh_enabled: true "
+                "(one chip runs digest_storage: dense)")
         pk = self.tier_pool_centroids
         if pk < 8 or pk & (pk - 1):
             raise ValueError(

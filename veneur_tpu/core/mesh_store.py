@@ -26,8 +26,9 @@ Layout (cf. ``parallel/mesh.py``; shard placement in ``fleet/router.py``):
   stacks sharded over the series axis: each device receives exactly its
   own rows' sub-chunk (whole centroid runs, order preserved) and bins
   only that — no replicated full-chunk binning, no device-side
-  re-scatter. The shift-guard DECISION still psums over the series axis
-  so every shard takes the same drain the dense store would.
+  re-scatter. A row that already holds bin mass is drained before its
+  run is binned: a decision of the row, which its shard takes alone,
+  so the import holds no collective and agrees with the dense store.
 
 The compiled programs are module-level ``jax.jit`` definitions taking the
 ``Mesh`` as a static argument (one compile per mesh per dtype-config, all
@@ -38,6 +39,7 @@ static-analysis compiled-program inventory and under the
 
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import Optional
 
@@ -47,6 +49,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from veneur_tpu.core.bucketing import pow2_cap
 from veneur_tpu.core.store import (IMPORT_DRAIN_BATCH, _GROW_FACTOR,
                                    DigestGroup, HeavyHitterGroup,
                                    ScalarGroup, SetGroup)
@@ -73,17 +76,52 @@ def _relocal(rows: jax.Array, s_loc: int) -> jax.Array:
     return jnp.where((r >= start) & (r < start + s_loc), r - start, s_loc)
 
 
-def _blocked_pad(arr: jax.Array, shards: int, old_block: int,
-                 fill=0) -> jax.Array:
-    """Double every shard's contiguous block of dim 0 in place: reshape
-    to per-shard blocks, pad each block, reshape back. The device twin
-    of ``ShardPlacement.grow`` — physical row (shard, local) moves from
-    ``shard*B + local`` to ``shard*2B + local`` on both sides."""
-    rest = arr.shape[1:]
-    a = arr.reshape((shards, old_block) + rest)
-    pad = [(0, 0), (0, old_block)] + [(0, 0)] * len(rest)
-    return jnp.pad(a, pad, constant_values=fill).reshape(
-        (shards * old_block * 2,) + rest)
+@partial(jax.jit, static_argnums=(1, 2))
+def _blocked_pad(arr: jax.Array, mesh: Mesh, fill=0) -> jax.Array:
+    """Double every shard's contiguous block of dim 0, each on the
+    device that holds it: the device twin of ``ShardPlacement.grow`` —
+    physical row (shard, local) moves from ``shard*B + local`` to
+    ``shard*2B + local`` on both sides, and no plane is ever whole on
+    one device."""
+    spec = P(SERIES_AXIS, *([None] * (arr.ndim - 1)))
+
+    def local_pad(x):
+        pad = [(0, x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+        return jnp.pad(x, pad, constant_values=fill)
+
+    return shard_map(local_pad, mesh=mesh, in_specs=(spec,),
+                     out_specs=spec, check_vma=False)(arr)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _mesh_init_digests(mesh: Mesh, capacity: int, k: int,
+                       compression: float):
+    """A digest group's empty device state, every plane made in shards:
+    each device of the series axis fills its own block. Built whole and
+    then placed, the planes of a deployment's 2^22 rows are 7 GB on the
+    first device before a byte moves (PR 24 read 5.29 GB on the fullest
+    of four devices against 4.03 GB on one: the mesh bought no memory)."""
+    temp_spec, dig_spec, _, s = _digest_specs()
+    s_loc = capacity // mesh.shape[SERIES_AXIS]
+
+    def local_init():
+        return (td_ops.init_temp(s_loc, k, compression),
+                td_ops.init((s_loc,), compression, k),
+                jnp.full((s_loc,), jnp.inf, jnp.float32),
+                jnp.full((s_loc,), -jnp.inf, jnp.float32))
+
+    return shard_map(local_init, mesh=mesh, in_specs=(),
+                     out_specs=(temp_spec, dig_spec, s, s),
+                     check_vma=False)()
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _mesh_zero_registers(mesh: Mesh, capacity: int, m: int):
+    """A set group's empty ``[capacity, m]`` registers, made in shards."""
+    s_loc = capacity // mesh.shape[SERIES_AXIS]
+    return shard_map(lambda: jnp.zeros((s_loc, m), jnp.int8), mesh=mesh,
+                     in_specs=(), out_specs=P(SERIES_AXIS, None),
+                     check_vma=False)()
 
 
 def _add_temp(a: td_ops.TempCentroids,
@@ -167,51 +205,44 @@ def _mesh_ingest_samples(temp, digest, rows, vals, wts, mesh: Mesh,
                      check_vma=False)(temp, digest, rows, vals, wts)
 
 
-@partial(jax.jit, donate_argnums=(0, 1, 2, 3), static_argnums=(10, 11, 12))
-def _mesh_import_routed(temp, digest, dmin, dmax, rows, means, wts,
+@partial(jax.jit, donate_argnums=(0, 1, 2, 3), static_argnums=(11, 12))
+def _mesh_import_routed(temp, digest, dmin, dmax, drains, rows, means, wts,
                         srows, smins, smaxs, mesh: Mesh,
-                        compression: float, k: int):
+                        compression: float):
     """Shard-routed centroid import: the staged chunk arrives as a
     ``[shards, b]`` stack partitioned by the fleet router's placement
     (``route_stack``), sharded over the series axis — each device bins
     ONLY its own rows' sub-chunk (whole sorted centroid runs: a row's
-    run lives on exactly one shard, so the run-skew aliasing the old
-    replicated path avoided by replicating cannot occur either). The
-    guard masses psum over the series axis: summed over the disjoint
-    sub-chunks they equal the dense store's whole-chunk decision."""
+    run lives on exactly one shard). A row that already holds bin mass
+    is drained before its run is binned
+    (``td_ops.ingest_centroids_rowdrained``): a decision of the row,
+    so every shard takes it alone, the program holds no collective,
+    and the result is the dense store's on the same data. ``drains``
+    ([shards] int32) counts the dispatches in which a shard drained."""
     temp_spec, dig_spec, _, s = _digest_specs()
     st = P(SERIES_AXIS, None)  # [shards, b] stacks: dim 0 = shard
 
-    def local_import(temp, digest, dmin, dmax, rows, means, wts,
+    def local_import(temp, digest, dmin, dmax, drains, rows, means, wts,
                      srows, smins, smaxs):
         s_loc = temp.sum_w.shape[0]
         rows_l = _relocal(rows.reshape(-1), s_loc)
-        means = means.reshape(-1)
-        wts = wts.reshape(-1)
-        temp, digest = _guarded_drain(temp, digest, rows_l, means, wts,
-                                      s_loc, SERIES_AXIS, compression)
-        binned = td_ops.ingest_chunk(
-            td_ops.init_temp(s_loc, k, compression),
-            rows_l, means, wts, compression,
-            update_stats=False,
-            acc_seg_w=temp.seg_w, acc_seg_wm=temp.seg_wm)
         # imported centroids feed percentiles only, never local stats
         # (samplers.go:473-480)
-        temp = temp._replace(sum_w=temp.sum_w + binned.sum_w,
-                             sum_wm=temp.sum_wm + binned.sum_wm,
-                             seg_w=temp.seg_w + binned.seg_w,
-                             seg_wm=temp.seg_wm + binned.seg_wm)
+        digest, temp, drained = td_ops.ingest_centroids_rowdrained(
+            digest, temp, rows_l, means.reshape(-1), wts.reshape(-1),
+            compression)
         sr = _relocal(srows.reshape(-1), s_loc)
         dmin = dmin.at[sr].min(smins.reshape(-1), mode="drop")
         dmax = dmax.at[sr].max(smaxs.reshape(-1), mode="drop")
-        return temp, digest, dmin, dmax
+        return temp, digest, dmin, dmax, drains + drained
 
     return shard_map(local_import, mesh=mesh,
-                     in_specs=(temp_spec, dig_spec, s, s, st, st, st,
+                     in_specs=(temp_spec, dig_spec, s, s, s, st, st, st,
                                st, st, st),
-                     out_specs=(temp_spec, dig_spec, s, s),
-                     check_vma=False)(temp, digest, dmin, dmax, rows,
-                                      means, wts, srows, smins, smaxs)
+                     out_specs=(temp_spec, dig_spec, s, s, s),
+                     check_vma=False)(temp, digest, dmin, dmax, drains,
+                                      rows, means, wts, srows, smins,
+                                      smaxs)
 
 
 @partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6))
@@ -233,6 +264,17 @@ def _mesh_flush_digests(digest, temp, dmin, dmax, qs, mesh: Mesh,
                      in_specs=(dig_spec, temp_spec, s, s, P()),
                      out_specs=(dig_spec, sk, s, s, s, s, s),
                      check_vma=False)(digest, temp, dmin, dmax, qs)
+
+
+@jax.jit
+def _mesh_gather_rows(arrays, rows):
+    """The flush's way back to interner order, as one program: every
+    array's rows ``rows`` (physical rows are shard-placed, not
+    sequential). ``rows`` comes padded to the pow2 bucket of the live
+    count, so an interval whose series count moves compiles nothing
+    (taken op by op, each new count compiled some ten small programs
+    inside the flush: the compiles PR 31 counted in a warm window)."""
+    return tuple(a[rows] for a in arrays)
 
 
 @partial(jax.jit, donate_argnums=(0,), static_argnums=(4, 5))
@@ -378,29 +420,16 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         self.shards = mesh.shape[SERIES_AXIS]
         self.hosts = mesh.shape.get(HOSTS_AXIS, 1)
         self.router = router
-        self._sk = NamedSharding(mesh, P(SERIES_AXIS, None))
-        self._s = NamedSharding(mesh, P(SERIES_AXIS))
         cap = _round_up(capacity, self.shards)
         self.placement = (ShardPlacement(self.shards, cap)
                           if router is not None else None)
         self._ext_rows: Optional[np.ndarray] = None  # bank mode
         super().__init__(cap, _round_up(chunk, self.hosts), compression)
 
-    def _place(self):
-        temp_sh = td_ops.TempCentroids(
-            sum_w=self._sk, sum_wm=self._sk, seg_w=self._sk,
-            seg_wm=self._sk, count=self._s, vsum=self._s,
-            vmin=self._s, vmax=self._s, recip=self._s)
-        dig_sh = td_ops.TDigest(mean=self._sk, weight=self._sk,
-                                min=self._s, max=self._s)
-        self.temp = jax.device_put(self.temp, temp_sh)
-        self.digest = jax.device_put(self.digest, dig_sh)
-        self.dmin = jax.device_put(self.dmin, self._s)
-        self.dmax = jax.device_put(self.dmax, self._s)
-
     def _init_device(self):
-        super()._init_device()
-        self._place()
+        self.temp, self.digest, self.dmin, self.dmax = _mesh_init_digests(
+            self.mesh, self.capacity, self.k, self.compression)
+        self._device_dirty = False
 
     def _grow(self):
         """x2 growth that preserves the shard-blocked layout: every
@@ -408,31 +437,26 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         recomputes physical ids to match — a tail pad would hand the
         new rows entirely to the last shard."""
         self._drain_staging()
-        old_block = self.capacity // self.shards
         self.capacity *= _GROW_FACTOR
-        sh, ob = self.shards, old_block
         # nothing placed yet: the first touch allocates at the new size
         if "temp" in self.__dict__:
+            def pad(x, fill=0.0):
+                return _blocked_pad(x, self.mesh, fill)
+
             self.temp = td_ops.TempCentroids(
-                sum_w=_blocked_pad(self.temp.sum_w, sh, ob),
-                sum_wm=_blocked_pad(self.temp.sum_wm, sh, ob),
-                seg_w=_blocked_pad(self.temp.seg_w, sh, ob),
-                seg_wm=_blocked_pad(self.temp.seg_wm, sh, ob),
-                count=_blocked_pad(self.temp.count, sh, ob),
-                vsum=_blocked_pad(self.temp.vsum, sh, ob),
-                vmin=_blocked_pad(self.temp.vmin, sh, ob, fill=np.inf),
-                vmax=_blocked_pad(self.temp.vmax, sh, ob, fill=-np.inf),
-                recip=_blocked_pad(self.temp.recip, sh, ob),
-            )
+                sum_w=pad(self.temp.sum_w), sum_wm=pad(self.temp.sum_wm),
+                seg_w=pad(self.temp.seg_w), seg_wm=pad(self.temp.seg_wm),
+                count=pad(self.temp.count), vsum=pad(self.temp.vsum),
+                vmin=pad(self.temp.vmin, np.inf),
+                vmax=pad(self.temp.vmax, -np.inf),
+                recip=pad(self.temp.recip))
             self.digest = td_ops.TDigest(
-                mean=_blocked_pad(self.digest.mean, sh, ob, fill=np.inf),
-                weight=_blocked_pad(self.digest.weight, sh, ob),
-                min=_blocked_pad(self.digest.min, sh, ob, fill=np.inf),
-                max=_blocked_pad(self.digest.max, sh, ob, fill=-np.inf),
-            )
-            self.dmin = _blocked_pad(self.dmin, sh, ob, fill=np.inf)
-            self.dmax = _blocked_pad(self.dmax, sh, ob, fill=-np.inf)
-            self._place()
+                mean=pad(self.digest.mean, np.inf),
+                weight=pad(self.digest.weight),
+                min=pad(self.digest.min, np.inf),
+                max=pad(self.digest.max, -np.inf))
+            self.dmin = pad(self.dmin, np.inf)
+            self.dmax = pad(self.dmax, -np.inf)
         if self.placement is not None:
             self.placement.grow()
         # re-point staging padding at the new out-of-range row id
@@ -452,11 +476,21 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
                 jnp.asarray(vals), jnp.asarray(wts), self.mesh,
                 self.compression, self.k)
 
+    def _no_drains(self) -> jax.Array:
+        """The drain counter's zero, placed as the import program
+        returns it: handed over as NumPy it would be another signature,
+        and the program's second call another compile."""
+        return jax.device_put(np.zeros(self.shards, np.int32),
+                              NamedSharding(self.mesh, P(SERIES_AXIS)))
+
     def _drain_imports(self):
         if self._imp_fill == 0 and self._imp_stat_fill == 0:
             return
         self._device_dirty = True
         nf, ns = self._imp_fill, self._imp_stat_fill
+        self.imp_dispatches += 1
+        self.imp_centroids += nf
+        t0 = time.monotonic_ns()
         rows = self._to_phys(self._imp_rows[:nf])
         means = self._imp_means[:nf]
         wts = self._imp_wts[:nf]
@@ -464,20 +498,34 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         smins = self._imp_stat_mins[:ns]
         smaxs = self._imp_stat_maxs[:ns]
         self._new_import_buffers()
+        # every stack at the staging buffers' own width: the fullest
+        # shard's share of a chunk is the traffic's to choose, and a
+        # width that followed it (its pow2 bucket) compiled a program
+        # for each bucket it met, inside the interval (PR 31 counted 10
+        # and 11 in a warm window); one shape, compiled before ready
         r_st, (m_st, w_st) = route_stack(
             self.shards, self._shard_of_phys(rows), rows, [means, wts],
-            self.capacity)
+            self.capacity, width=self.chunk)
         sr_st, (mn_st, mx_st) = route_stack(
             self.shards, self._shard_of_phys(srows), srows,
-            [smins, smaxs], self.capacity)
+            [smins, smaxs], self.capacity, width=self.chunk)
+        if self._imp_drains is None:
+            self._imp_drains = self._no_drains()
+        t1 = time.monotonic_ns()
+        # the stacks go in as the NumPy arrays they are: the program
+        # puts each shard on its device. Through jnp.asarray they land
+        # whole on the first device and are resharded from there, and
+        # on four chips that made every call wait for the one before
+        # (50 ms a call against 4 ms, my chip run, PR 33): the workers
+        # held the store lock for as long as the device was busy
         with obs_kernels.scope("drain.digest.mesh"):
-            self.temp, self.digest, self.dmin, self.dmax = \
-                _mesh_import_routed(
+            (self.temp, self.digest, self.dmin, self.dmax,
+             self._imp_drains) = _mesh_import_routed(
                     self.temp, self.digest, self.dmin, self.dmax,
-                    jnp.asarray(r_st), jnp.asarray(m_st),
-                    jnp.asarray(w_st), jnp.asarray(sr_st),
-                    jnp.asarray(mn_st), jnp.asarray(mx_st), self.mesh,
-                    self.compression, self.k)
+                    self._imp_drains, r_st, m_st, w_st, sr_st, mn_st,
+                    mx_st, self.mesh, self.compression)
+        self.imp_route_ns += t1 - t0
+        self.imp_dispatch_ns += time.monotonic_ns() - t1
 
     def _run_flush(self, qs, use_pallas: bool, n: int):
         # the sharded programs compile once per mesh; the compute
@@ -503,19 +551,57 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
 
         sel = _select_stats(want_stats)
         qs = jnp.asarray(list(percentiles) + [0.5], jnp.float32)
-        rows = jnp.asarray(self._flush_rows(n), jnp.int32)
+        # padded to the count's pow2 bucket with row 0; _flush_collect
+        # cuts what was fetched back to n
+        rows = np.zeros(min(pow2_cap(n), self.capacity), np.int32)
+        rows[:n] = self._flush_rows(n)
         with obs_rec.maybe_stage("compute"), \
                 obs_kernels.scope("flush.digest.mesh"):
             digest, pcts, count, vsum, vmin, vmax, recip = \
                 self._run_flush(qs, use_pallas, n)
             planes = ()
             if want_digests:
-                planes = (digest.mean[rows], digest.weight[rows],
-                          digest.min[rows], digest.max[rows])
+                planes = (digest.mean, digest.weight, digest.min,
+                          digest.max)
             stats = {"pcts": pcts, "count": count, "sum": vsum,
                      "min": vmin, "max": vmax, "recip": recip}
-            refs = planes + tuple(stats[nm][rows] for nm in sel)
+            refs = _mesh_gather_rows(
+                planes + tuple(stats[nm] for nm in sel), jnp.asarray(rows))
         return (sel, False, None, refs)
+
+    def _flush_collect(self, pending, n: int, percentiles,
+                       want_digests) -> dict:
+        """The gather brought the count's pow2 bucket of rows: what
+        the base class fetched is cut back to ``n`` here."""
+        out = super()._flush_collect(pending, n, percentiles, want_digests)
+        return {name: rows[:n] for name, rows in out.items()}
+
+    @requires_lock("store")
+    def warm_import(self, percentiles) -> None:
+        """Compile, or load from the persistent cache, what an
+        import-fed interval of this group runs, before the first
+        forward arrives: the planes' initialiser, one import dispatch
+        that stages nothing, one flush of no row. The planes go again
+        afterwards: a group nothing has touched holds no device memory.
+        The programs are the module's, keyed by mesh and shapes, so
+        every group of this size and each generation's twin finds them
+        compiled."""
+        rows = np.full((self.shards, self.chunk), self.capacity, np.int32)
+        zeros = np.zeros((self.shards, self.chunk), np.float32)
+        with obs_kernels.scope("drain.digest.mesh"):
+            temp, digest, dmin, dmax, _ = _mesh_import_routed(
+                self.temp, self.digest, self.dmin, self.dmax,
+                self._no_drains(), rows, zeros, zeros, rows, zeros, zeros,
+                self.mesh, self.compression)
+        with obs_kernels.scope("flush.digest.mesh"):
+            out = _mesh_flush_digests(
+                digest, temp, dmin, dmax,
+                jnp.asarray(list(percentiles) + [0.5], jnp.float32),
+                self.mesh, self.compression)
+        jax.block_until_ready(out)  # lint: ok(lock-across-blocking) start-up, before any listener opens: nobody waits on the lock yet
+        for name in DigestGroup._DEVICE_STATE:
+            self.__dict__.pop(name, None)
+        self._device_dirty = False
 
     @requires_lock("store")
     def snapshot_begin(self):
@@ -607,28 +693,23 @@ class MeshSetGroup(_PlacementMixin, SetGroup):
         self.shards = mesh.shape[SERIES_AXIS]
         self.hosts = mesh.shape.get(HOSTS_AXIS, 1)
         self.router = router
-        self._sk = NamedSharding(mesh, P(SERIES_AXIS, None))
         cap = _round_up(capacity, self.shards)
         self.placement = (ShardPlacement(self.shards, cap)
                           if router is not None else None)
         self._ext_rows = None
         super().__init__(cap, _round_up(chunk, self.hosts), precision)
-        self.registers = jax.device_put(self.registers, self._sk)
 
     def _grow(self):
         self._drain_staging()
-        old_block = self.capacity // self.shards
         self.capacity *= _GROW_FACTOR
-        self.registers = jax.device_put(
-            _blocked_pad(self.registers, self.shards, old_block),
-            self._sk)
+        self.registers = _blocked_pad(self.registers, self.mesh)
         if self.placement is not None:
             self.placement.grow()
         self._rows[self._fill:] = self.capacity
 
     def _reset_registers(self):
-        self.registers = jax.device_put(
-            jnp.zeros((self.capacity, self.m), jnp.int8), self._sk)
+        self.registers = _mesh_zero_registers(self.mesh, self.capacity,
+                                              self.m)
         self._device_dirty = False
 
     def _drain_samples(self):
@@ -793,14 +874,12 @@ class MeshHeavyHitterGroup(_PlacementMixin, HeavyHitterGroup):
 
     def _grow(self):
         self._drain_samples()
-        old_block = self.capacity // self.shards
         self.capacity *= _GROW_FACTOR
-        sh, ob = self.shards, old_block
         self.sketch = self.sketch._replace(
-            topk_hi=_blocked_pad(self.sketch.topk_hi, sh, ob),
-            topk_lo=_blocked_pad(self.sketch.topk_lo, sh, ob),
-            topk_counts=_blocked_pad(self.sketch.topk_counts, sh, ob),
-            sids=_blocked_pad(self.sketch.sids, sh, ob))
+            topk_hi=_blocked_pad(self.sketch.topk_hi, self.mesh),
+            topk_lo=_blocked_pad(self.sketch.topk_lo, self.mesh),
+            topk_counts=_blocked_pad(self.sketch.topk_counts, self.mesh),
+            sids=_blocked_pad(self.sketch.sids, self.mesh))
         self._place_sketch()
         self.placement.grow()
         sids = np.zeros(self.capacity + 1, np.uint32)

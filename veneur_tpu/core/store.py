@@ -576,21 +576,23 @@ def _ingest_samples(digest: td_ops.TDigest, temp: td_ops.TempCentroids,
                                        compression, use_pallas=use_pallas)
 
 
-@partial(jax.jit, donate_argnums=(0, 1, 2, 3), static_argnums=(10, 11))
+@partial(jax.jit, donate_argnums=(0, 1, 2, 3), static_argnums=(11, 12))
 def _ingest_centroids(digest: td_ops.TDigest, temp: td_ops.TempCentroids,
                       dmin, dmax, rows, means,
-                      weights, stat_rows, stat_mins, stat_maxs, compression,
-                      use_pallas=True):
+                      weights, stat_rows, stat_mins, stat_maxs, drains,
+                      compression, use_pallas=True):
     """Fold imported digest centroids into the bin accumulators WITHOUT
     touching the local scalar stats (samplers.go:473-480). Imported
     per-digest min/max land in separate dmin/dmax arrays that only bound the
-    final digest. Shift-guarded like the sample path."""
-    digest, temp = td_ops.ingest_chunk_guarded(
+    final digest. A row that already holds bin mass is drained before its
+    run is binned (td_ops.ingest_centroids_rowdrained); ``drains`` (an
+    int32 scalar) counts the dispatches in which any was."""
+    digest, temp, drained = td_ops.ingest_centroids_rowdrained(
         digest, temp, rows, means, weights, compression,
-        update_stats=False, use_pallas=use_pallas)
+        use_pallas=use_pallas)
     dmin = dmin.at[stat_rows].min(stat_mins, mode="drop")
     dmax = dmax.at[stat_rows].max(stat_maxs, mode="drop")
-    return digest, temp, dmin, dmax
+    return digest, temp, dmin, dmax, drains + drained
 
 
 @partial(jax.jit, donate_argnums=(0, 1), static_argnums=(6, 7))
@@ -738,6 +740,17 @@ class DigestGroup(OverloadLimited):
         self.compression = compression
         self.k = td_ops.size_bound(compression)
         self._device_dirty = False
+        # the import path's counters of this generation (read at its
+        # flush: timeline ``import_digests``) and the ns its drains
+        # spent routing a chunk to shards and dispatching it (summed by
+        # the store into the ``import.route`` / ``import.dispatch``
+        # stages); ``_imp_drains`` is the device's own count of the
+        # dispatches that drained a row before binning
+        self.imp_dispatches = 0
+        self.imp_centroids = 0
+        self.imp_route_ns = 0
+        self.imp_dispatch_ns = 0
+        self._imp_drains = None
         self._init_staging()
 
     _DEVICE_STATE = ("temp", "digest", "dmin", "dmax")
@@ -982,15 +995,21 @@ class DigestGroup(OverloadLimited):
         stat_maxs = self._imp_stat_maxs[:cap]
         imp_rows, imp_means, imp_wts = (self._imp_rows, self._imp_means,
                                         self._imp_wts)
+        self.imp_dispatches += 1
+        self.imp_centroids += self._imp_fill
         self._new_import_buffers()
+        t0 = time.monotonic_ns()
         with obs_kernels.scope("drain.digest.dense"):
-            self.digest, self.temp, self.dmin, self.dmax = \
-                _ingest_centroids(
+            (self.digest, self.temp, self.dmin, self.dmax,
+             self._imp_drains) = _ingest_centroids(
                     self.digest, self.temp, self.dmin, self.dmax,
                     jnp.asarray(imp_rows), jnp.asarray(imp_means),
                     jnp.asarray(imp_wts), jnp.asarray(stat_rows),
                     jnp.asarray(stat_mins), jnp.asarray(stat_maxs),
+                    np.int32(0) if self._imp_drains is None
+                    else self._imp_drains,
                     self.compression, self._pallas_allowed())
+        self.imp_dispatch_ns += time.monotonic_ns() - t0
 
     def _drain_staging(self):
         self._drain_samples()
@@ -1050,6 +1069,9 @@ class DigestGroup(OverloadLimited):
         with the group state intact for the store's re-merge rung."""
         with obs_rec.maybe_stage("drain"):
             self._drain_staging()
+            if self.imp_dispatches:
+                obs_rec.note(import_dispatches=self.imp_dispatches,
+                             import_centroids=self.imp_centroids)
         n = len(self.interner)
         if n == 0:
             res = self._flush_empty()
@@ -1158,7 +1180,11 @@ class DigestGroup(OverloadLimited):
             if packed:
                 (out["packed_counts"], out["packed_means"],
                  out["packed_weights"]) = _fetch_packed(*packed_refs, n)
-            fetched = jax.device_get(refs)
+            fetched, drains = jax.device_get((refs, self._imp_drains))
+            if drains is not None:
+                # per dispatch and device program: a mesh's shards each
+                # count their own
+                obs_rec.note(import_guard_drains=int(np.sum(drains)))
         if packed:
             out["digest_min"], out["digest_max"] = fetched[:2]
             fetched = fetched[2:]
@@ -1310,8 +1336,7 @@ class SetGroup(OverloadLimited):
         self.chunk = chunk
         self.precision = precision
         self.m = hll_ops.num_registers(precision)
-        self.registers = jnp.zeros((capacity, self.m), jnp.int8)
-        self._device_dirty = False
+        self._reset_registers()
         self._init_staging()
 
     def _init_staging(self):
@@ -2145,6 +2170,9 @@ class MetricStore:
                 "the mesh supersedes it — run the mesh dense, or "
                 "digest_storage: tiered (fleet mode composes with the "
                 "tiered packed-pool residency; fleet/mesh_tiered.py)")
+        if mesh is None and digest_storage == "sharded":
+            raise ValueError(
+                "digest_storage: sharded needs a mesh (mesh_enabled)")
         if mesh is not None:
             # one router for every mesh group: a series owns the same
             # shard across scalars, digests, sets and heavy hitters
@@ -2315,6 +2343,10 @@ class MetricStore:
         self._native_table = None
         self._mlist_table = None
         self._kind_groups = None
+        # import_columnar's clock, drained by take_import_stages
+        self.import_ns = dict.fromkeys(
+            ("messages", "decode", "lock_wait", "intern", "stage",
+             "route", "dispatch"), 0)
         # set by the ingest-lane fleet (veneur_tpu/ingest/): invoked by
         # snapshot_state so sealed-but-unmerged lane chunks reach the
         # checkpoint
@@ -2667,13 +2699,22 @@ class MetricStore:
             self.sets.import_registers(key, tags, registers)
 
     @acquires_lock("store")
-    def import_columnar(self, dec, data: bytes) -> Tuple[int, int]:
+    def import_columnar(self, dec, data: bytes,
+                        decode_ns: int = 0) -> Tuple[int, int]:
         """Merge a natively-decoded MetricList (native/egress.py
         DecodedMetricList) in one pass: C++ row assignment, numpy bulk
         staging per payload kind — the import-side twin of process_batch,
         and the fix for the 35k series/s Python-decode ceiling the
         round-2 verdict flagged. ``data`` is the original request bytes
         (set register spans point into it). Returns (n_ok, n_err).
+
+        The message's way through is clocked into ``import_ns`` (four
+        clock reads a message, the merger's pattern): ``decode_ns`` as
+        the caller measured it, the wait for the store lock, interning
+        (the native table's assign and the miss loop, with a mesh's
+        first-sight placement), staging (the bulk appends), and out of
+        staging what the digest groups' drains spent routing chunks to
+        shards and dispatching them.
 
         Reference path: importsrv.SendMetrics group-by-worker +
         ImportMetricGRPC → per-sampler Merge (importsrv/server.go:101-132,
@@ -2683,7 +2724,9 @@ class MetricStore:
 
         PB_TIMER = 4
         n_err = 0
+        t0 = time.monotonic_ns()
         with self._lock:
+            t1 = time.monotonic_ns()
             if self._mlist_table is None:
                 self._mlist_table = egress.MListInternTable()
             table = self._mlist_table
@@ -2724,6 +2767,8 @@ class MetricStore:
                     rows[i] = row
                     table.put(t, pay, name_b, tags_b, row)
 
+            t2 = time.monotonic_ns()
+            routed0, dispatched0 = self._digest_drain_ns()
             ok = rows != egress.MISS
             n_err += int((~ok).sum())
             payload = dec.payload
@@ -2815,7 +2860,49 @@ class MetricStore:
                     log.debug("store rejected imported topk sketch: %s", e)
 
             self.imported += n_ok
+            routed, dispatched = self._digest_drain_ns()
+            routed -= routed0
+            dispatched -= dispatched0
+            ns = self.import_ns
+            ns["messages"] += 1
+            ns["decode"] += decode_ns
+            ns["lock_wait"] += t1 - t0
+            ns["intern"] += t2 - t1
+            ns["route"] += routed
+            ns["dispatch"] += dispatched
+            ns["stage"] += (time.monotonic_ns() - t2 - routed
+                            - dispatched)
             return n_ok, n_err
+
+    def _digest_drain_ns(self) -> Tuple[int, int]:
+        """What the digest groups' import drains have spent so far
+        routing chunks to shards and dispatching them (a slab group
+        keeps no such clock)."""
+        groups = (self.histograms, self.timers)
+        return (sum(getattr(g, "imp_route_ns", 0) for g in groups),
+                sum(getattr(g, "imp_dispatch_ns", 0) for g in groups))
+
+    @acquires_lock("store")
+    def warm_import(self, percentiles) -> None:
+        """A mesh global's start: have the import path's programs
+        compiled before a listener opens (MeshDigestGroup.warm_import);
+        histograms and timers are one shape, so one of them does."""
+        warm = getattr(self.histograms, "warm_import", None)
+        if warm is not None:
+            with self._lock:
+                warm(percentiles)
+
+    @acquires_lock("store")
+    def take_import_stages(self) -> Optional[Dict[str, int]]:
+        """The import path's ns by stage, and its messages, since the
+        last call (the flusher publishes them as the interval's
+        ``import.*`` stages); None where nothing was imported."""
+        with self._lock:
+            taken = self.import_ns
+            if not taken["messages"]:
+                return None
+            self.import_ns = dict.fromkeys(taken, 0)
+        return taken
 
     # -- ingest-lane merge (veneur_tpu/ingest/) ----------------------------
 

@@ -263,10 +263,19 @@ def collect_vars(server) -> dict:
             out["event_queue_depth"] = q.qsize()
     except Exception as e:  # pragma: no cover - diagnostic only
         out["lanes_error"] = repr(e)
+    threads = None
     imp = getattr(server, "import_server", None)
     if imp is not None:
-        out["grpc_import"] = {"received": imp.received,
-                              "errors": imp.import_errors}
+        from veneur_tpu.forward.grpc_forward import WORKER_THREAD_PREFIX
+
+        threads = thread_cpu()
+        out["grpc_import"] = {
+            "received": imp.received, "errors": imp.import_errors,
+            # the pool's threads as one number: a reader of one path
+            # cannot sum obs.threads' grpc-import_0, _1, ... itself
+            "workers_cpu_s": round(sum(
+                t["cpu_s"] for name, t in threads.items()
+                if name.startswith(WORKER_THREAD_PREFIX)), 4)}
     nimp = getattr(server, "native_import_server", None)
     if nimp is not None:
         out["native_import"] = {"received": nimp.received,
@@ -337,7 +346,7 @@ def collect_vars(server) -> dict:
             from veneur_tpu.obs import kernels
 
             section = {"kernels": kernels.snapshot(),
-                       "threads": thread_cpu()}
+                       "threads": threads or thread_cpu()}
             timeline = server.obs_timeline
             if timeline is not None:
                 section["timeline"] = timeline.snapshot()

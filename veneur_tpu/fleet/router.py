@@ -322,22 +322,32 @@ def inverse_perm(perm: np.ndarray, capacity: int) -> np.ndarray:
 def route_stack(shards: int, shard_idx: np.ndarray,
                 rows: np.ndarray, arrays: Sequence[np.ndarray],
                 sentinel_row: int,
-                min_width: int = 8) -> Tuple[np.ndarray, list]:
+                min_width: int = 8,
+                width: Optional[int] = None) -> Tuple[np.ndarray, list]:
     """Partition one staged chunk into a ``[shards, b]`` stack whose
     dim 0 shards over the series axis — each device then receives
     exactly its own rows' sub-chunk (whole, order-preserved) and bins
     only that, instead of binning a replicated full chunk and dropping
     foreign rows. ``b`` is the pow2 bucket of the fullest shard's count
     (``core/bucketing.py`` ladder: the compiled-program variant count
-    stays log-bounded). Padding rows carry ``sentinel_row`` and zeroed
+    stays log-bounded), or ``width`` where the caller gives one: a
+    staging buffer of fixed size reaches the device at that one shape
+    whatever share of it the fullest shard owns, so the traffic never
+    chooses a program. Padding rows carry ``sentinel_row`` and zeroed
     payloads, the drop convention every scatter program shares."""
     from veneur_tpu.core.bucketing import pow2_cap
 
     per_shard: List[np.ndarray] = []
     for s in range(shards):
         per_shard.append(np.flatnonzero(shard_idx == s))
-    width = max(min_width, max((len(ix) for ix in per_shard), default=0))
-    b = pow2_cap(width)
+    fullest = max((len(ix) for ix in per_shard), default=0)
+    if width is None:
+        b = pow2_cap(max(min_width, fullest))
+    elif fullest > width:
+        raise ValueError(f"route_stack: a shard owns {fullest} of the "
+                         f"chunk's entries, over the width {width}")
+    else:
+        b = width
     out_rows = np.full((shards, b), sentinel_row, rows.dtype)
     out_arrays = [np.zeros((shards, b) + a.shape[1:], a.dtype)
                   for a in arrays]

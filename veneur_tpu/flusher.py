@@ -135,7 +135,27 @@ def _publish_interval(server, span, rec, timeline):
         for stage in ("lock_wait", "remap", "stage"):
             rec.record_abs(f"ingest.merge.{stage}", rec.t0_ns,
                            rec.t0_ns + merger[stage], off_path=True)
+    imports = _take_import_stages(server)
+    if imports:
+        # a global's import path over the same stretch, clocked a
+        # message at a time by its gRPC workers (core/store.py
+        # import_columnar): cumulative and off-path like the merger's
+        messages = imports.pop("messages")
+        for stage, ns in imports.items():
+            rec.record_abs(f"import.{stage}", rec.t0_ns, rec.t0_ns + ns,
+                           off_path=True)
     entry = rec.finish()
+    if imports:
+        entry["import"] = {"messages": messages}
+    # what the retired digest groups' import drains counted (DigestGroup
+    # notes them on its drain and fetch stages)
+    staged = [s for s in entry["stages"] if "import_dispatches" in s]
+    if staged:
+        entry["import_digests"] = {
+            "dispatches": sum(s["import_dispatches"] for s in staged),
+            "centroids": sum(s["import_centroids"] for s in staged),
+            "guard_drains": sum(s.get("import_guard_drains", 0)
+                                for s in entry["stages"])}
     if hops:
         tids = sorted({h["trace_id"] for h in hops if h.get("trace_id")})
         if tids:
@@ -228,6 +248,21 @@ def _count_by(records: list, key: str) -> dict:
         if k:
             out[k] = out.get(k, 0) + 1
     return out
+
+
+def _take_import_stages(server):
+    """The store's import-path clock since the last interval
+    (MetricStore.take_import_stages); None where nothing was imported
+    or the store keeps none."""
+    take = getattr(getattr(server, "store", None), "take_import_stages",
+                   None)
+    if take is None:
+        return None
+    try:
+        return take()
+    except Exception:  # pragma: no cover - telemetry only
+        log.exception("import stage drain failed")
+        return None
 
 
 def _drain_ingest_stages(server):

@@ -284,6 +284,9 @@ class GRPCForwarder:
         self._channel.close()
 
 
+WORKER_THREAD_PREFIX = "grpc-import"
+
+
 class ImportServer:
     """The global tier's gRPC ingest (importsrv/server.go:37-147).
 
@@ -316,7 +319,11 @@ class ImportServer:
         # series) easily passes gRPC's 4 MB default — 20k digests with
         # ~50 centroids each is ~20 MB on the wire
         self._grpc = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=workers),
+            # named, so /debug/vars obs.threads tells the workers'
+            # CPU from the rest (grpc_import.workers_cpu_s sums them)
+            futures.ThreadPoolExecutor(
+                max_workers=workers,
+                thread_name_prefix=WORKER_THREAD_PREFIX),
             options=[("grpc.max_receive_message_length", _MAX_MESSAGE),
                      ("grpc.max_send_message_length", _MAX_MESSAGE)])
         deserializer = ((lambda b: b) if self._native
@@ -341,14 +348,19 @@ class ImportServer:
         if self._native:
             # request is raw bytes: C++ decode + intern, numpy bulk apply
             from veneur_tpu.native import egress
+            from veneur_tpu.obs import kernels as obs_kernels
 
-            # zero-copy views: import_columnar only gathers/stages from
-            # them and they die with close() below
-            dec = egress.decode_metric_list(request, copy=False)
-            try:
-                n_ok, n_err = self._store.import_columnar(dec, request)
-            finally:
-                dec.close()
+            with obs_kernels.host_scope("import"):
+                t_dec = time.monotonic_ns()
+                # zero-copy views: import_columnar only gathers/stages
+                # from them and they die with close() below
+                dec = egress.decode_metric_list(request, copy=False)
+                try:
+                    n_ok, n_err = self._store.import_columnar(
+                        dec, request,
+                        decode_ns=time.monotonic_ns() - t_dec)
+                finally:
+                    dec.close()
             if n_err:
                 with self._lock:
                     self.import_errors += n_err

@@ -147,6 +147,15 @@ PROGRAM_SCOPES: Dict[str, Tuple[str, Optional[Tuple[str, str]]]] = {
     "veneur_tpu/core/mesh_store.py::_mesh_import_routed":
         ("drain.digest.mesh",
          ("veneur_tpu.core.mesh_store", "_mesh_import_routed")),
+    "veneur_tpu/core/mesh_store.py::_mesh_init_digests":
+        ("flush.digest.mesh",
+         ("veneur_tpu.core.mesh_store", "_mesh_init_digests")),
+    "veneur_tpu/core/mesh_store.py::_mesh_zero_registers":
+        ("flush.set.mesh",
+         ("veneur_tpu.core.mesh_store", "_mesh_zero_registers")),
+    "veneur_tpu/core/mesh_store.py::_blocked_pad":
+        ("drain.digest.mesh",
+         ("veneur_tpu.core.mesh_store", "_blocked_pad")),
     "veneur_tpu/core/mesh_store.py::_mesh_flush_digests":
         ("flush.digest.mesh",
          ("veneur_tpu.core.mesh_store", "_mesh_flush_digests")),

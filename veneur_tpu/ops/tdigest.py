@@ -865,34 +865,119 @@ def ingest_chunk_guarded(digest: TDigest, temp: TempCentroids,
     return digest, temp
 
 
-def drain_temp(state: TDigest, temp: TempCentroids,
-               compression: float = DEFAULT_COMPRESSION,
-               use_pallas: bool = True) -> TDigest:
-    """Merge the accumulated temp centroids into the digests (one compress
-    per interval — the batched mergeAllTemps). ``use_pallas=False``
-    forces the sort-based XLA path (compute-breaker fallback rung)."""
+# Rows one trip of the import path's row-local drain compresses: eight
+# kernel blocks. A staged chunk of whole digests (dozens of centroids a
+# row) touches fewer rows than this and takes one trip; lone centroids
+# over many rows take more. Swept on a v5e at 2^20 rows and 16,384
+# staged centroids (PERF.md, PR 33), a dispatch by the host's clock at
+# 256 / 1,024 / 4,096 rows: a merging chunk of 256 rows 55.1 / 53.1 /
+# 54.5 ms, lone centroids over 2,048 held rows 55.4 / 52.2 / 53.8.
+ROW_DRAIN_SLAB_ROWS = 1024
+
+
+def ingest_centroids_rowdrained(digest: TDigest, temp: TempCentroids,
+                                rows: jax.Array, means: jax.Array,
+                                weights: jax.Array,
+                                compression: float = DEFAULT_COMPRESSION,
+                                use_pallas: bool = True):
+    """The import path's ingest: a forwarded digest's centroids merge
+    the way a t-digest merges, row by row. Before the chunk is binned,
+    every row it brings mass to that already holds bin mass is drained
+    into its digest (a compress over those rows alone), so its run is
+    binned by its own exact ranks into empty bins and meets the earlier
+    mass in a compress, not in a bin.
+
+    The chunk-wide shift guard of the sample path does not do here:
+    two forwarders' digests of one series are two distributions by
+    nature, and binned against the 8-anchor summary of the first the
+    second aliases (rank errors of 0.04-0.24 were read wherever 1 % of
+    a staging chunk's mass did not happen to lie in disjoint rows). The
+    decision is taken where the aliasing happens, the row. The rows to
+    drain are compressed ``ROW_DRAIN_SLAB_ROWS`` at a time in a loop
+    whose trip count is ``ceil(rows to drain / slab)``: one compiled
+    compress for every count, no trip where nothing is held, its cost
+    bounded by the chunk (at most one row a staged centroid) and never
+    by the rows reserved. Nothing is decided across rows, so a shard of
+    a mesh takes it alone and agrees with the dense store.
+
+    Imported centroids feed percentiles only, never the local scalar
+    stats (samplers.go:473-480). Returns (digest, temp, drained): the
+    last is 1 where any row was drained, as an int32 scalar."""
+    num_series = temp.sum_w.shape[0]
+    rows = rows.astype(jnp.int32)
+    slab = min(ROW_DRAIN_SLAB_ROWS, rows.shape[0])
+    inb = (rows < num_series) & (weights > 0)
+    held = temp.seg_w[jnp.minimum(rows, num_series - 1)].sum(axis=1)
+    # one entry a row: the sorted candidates' run starts, sorted once
+    # more to the front; the rest carry the sentinel the scatters drop
+    cand = jnp.sort(jnp.where(inb & (held > 0), rows, num_series))
+    first = jnp.concatenate([jnp.ones((1,), bool), cand[1:] != cand[:-1]])
+    touched = jnp.sort(jnp.where(first, cand, num_series))
+    count = jnp.sum(touched < num_series)
+    touched = jnp.concatenate([touched, jnp.full(
+        ((-rows.shape[0]) % slab,), num_series, jnp.int32)])
+    zk = jnp.zeros((slab, temp.sum_w.shape[1]), temp.sum_w.dtype)
+    za = jnp.zeros((slab, temp.seg_w.shape[1]), temp.seg_w.dtype)
+
+    def drain_slab(i, planes):
+        mean, weight, sum_w, sum_wm, seg_w, seg_wm = planes
+        to = lax.dynamic_slice_in_dim(touched, i * slab, slab)
+        at = jnp.minimum(to, num_series - 1)
+        m, w = _merge_bins(mean[at], weight[at], sum_w[at], sum_wm[at],
+                           compression, digest.capacity, use_pallas)
+        return (mean.at[to].set(m, mode="drop"),
+                weight.at[to].set(w, mode="drop"),
+                sum_w.at[to].set(zk, mode="drop"),
+                sum_wm.at[to].set(zk, mode="drop"),
+                seg_w.at[to].set(za, mode="drop"),
+                seg_wm.at[to].set(za, mode="drop"))
+
+    mean, weight, sum_w, sum_wm, seg_w, seg_wm = lax.fori_loop(
+        0, (count + slab - 1) // slab, drain_slab,
+        (digest.mean, digest.weight, temp.sum_w, temp.sum_wm, temp.seg_w,
+         temp.seg_wm))
+    digest = digest._replace(mean=mean, weight=weight)
+    temp = temp._replace(sum_w=sum_w, sum_wm=sum_wm, seg_w=seg_w,
+                         seg_wm=seg_wm)
+    temp = ingest_chunk(temp, rows, means, weights, compression,
+                        update_stats=False)
+    return digest, temp, (count > 0).astype(jnp.int32)
+
+
+def _merge_bins(mean, weight, sum_w, sum_wm, compression: float,
+                capacity: int, use_pallas: bool):
+    """One compress of [R, K] digests with their [R, K] temp bins: the
+    new (mean, weight) planes."""
     from veneur_tpu.ops import tdigest_pallas
 
-    t_live = temp.sum_w > 0
-    t_mean = jnp.where(t_live, temp.sum_wm / jnp.where(t_live, temp.sum_w, 1.0),
+    t_live = sum_w > 0
+    t_mean = jnp.where(t_live, sum_wm / jnp.where(t_live, sum_w, 1.0),
                        jnp.inf)
-    if use_pallas and tdigest_pallas.pallas_ok(state.mean):
+    if use_pallas and tdigest_pallas.pallas_ok(mean):
         # bin means are NOT monotone in bin index once several chunks with
         # shifting distributions accumulate, so the temp half needs a real
         # sort. Measured on v5e: lax.sort + presorted kernel beats the
         # in-kernel bitonic sort (sort_b) in the fused pipeline — the
         # kernel is VMEM-temporary-bound, so the 28 extra in-VMEM stages
         # cost more than XLA's external sort passes.
-        t_mean, t_w = lax.sort((t_mean, temp.sum_w), dimension=-1,
+        t_mean, t_w = lax.sort((t_mean, sum_w), dimension=-1,
                                num_keys=1, is_stable=False)
-        new_mean, new_weight = tdigest_pallas.compress_presorted(
-            state.mean, state.weight, t_mean, t_w, compression,
-            state.capacity)
-    else:
-        mean = jnp.concatenate([state.mean, t_mean], axis=-1)
-        weight = jnp.concatenate([state.weight, temp.sum_w], axis=-1)
-        new_mean, new_weight = _compress(mean, weight, compression,
-                                         state.capacity)
+        return tdigest_pallas.compress_presorted(
+            mean, weight, t_mean, t_w, compression, capacity)
+    return _compress(jnp.concatenate([mean, t_mean], axis=-1),
+                     jnp.concatenate([weight, sum_w], axis=-1),
+                     compression, capacity)
+
+
+def drain_temp(state: TDigest, temp: TempCentroids,
+               compression: float = DEFAULT_COMPRESSION,
+               use_pallas: bool = True) -> TDigest:
+    """Merge the accumulated temp centroids into the digests (one compress
+    per interval — the batched mergeAllTemps). ``use_pallas=False``
+    forces the sort-based XLA path (compute-breaker fallback rung)."""
+    new_mean, new_weight = _merge_bins(state.mean, state.weight, temp.sum_w,
+                                       temp.sum_wm, compression,
+                                       state.capacity, use_pallas)
     return TDigest(
         mean=new_mean,
         weight=new_weight,

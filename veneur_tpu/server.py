@@ -744,6 +744,14 @@ class Server:
         log.info("device: platform=%s device_kind=%s count=%d",
                  dev["platform"], dev["device_kind"], dev["count"])
         self._started_wall = time.time()
+        if self.store.mesh is not None and cfg.grpc_address:
+            # a sharded global compiles its import and flush programs
+            # for minutes when cold: before any listener opens and the
+            # ops port says ready, not under the first forward
+            t0 = time.monotonic()
+            self.store.warm_import(cfg.percentiles)
+            log.info("mesh import programs ready in %.1fs",
+                     time.monotonic() - t0)
         if self.checkpointer is not None:
             self.checkpointer.restore()
         if self.handoff_manager is not None:
