@@ -324,3 +324,182 @@ def test_dense_import_holds_the_kernel(one_chip, kernel_admitted):
         if re.search(r"= \S+ (copy|transpose)\(", line):
             assert whole not in line.split("=")[1].split("(")[0], line
     assert imp.memory_analysis().alias_size_in_bytes >= 4 * rows * K * 4
+
+
+def _result_elements(line):
+    """Element counts of the arrays an HLO instruction produces (a
+    tuple's members each), and its opcode."""
+    import re
+
+    rhs = line.split(" = ", 1)[1]
+    if rhs.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape, rest = rhs[:end + 1], rhs[end + 1:]
+    else:
+        shape, _, rest = rhs.partition(" ")
+    counts = [int(np.prod([int(d) for d in dims.split(",") if d]))
+              for dims in re.findall(r"\w+\[([\d,]*)\]", shape)]
+    return counts, re.match(r"\s*([\w\-]+)\(", rest).group(1)
+
+
+def _computations(text):
+    """name -> lines of every computation of an optimised HLO module."""
+    import re
+
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and " = " in line:
+            comps[name].append(line)
+    return comps
+
+
+def _called(line):
+    import re
+
+    names = re.findall(
+        r"(?:calls|to_apply|body|condition|true_computation|"
+        r"false_computation)=%?([\w.\-]+)", line)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+        names += [n.strip().lstrip("%") for n in group.split(",")]
+    return names
+
+
+def _plane_traffic(text, plane):
+    """What an ingest program does to arrays of ``plane`` elements
+    outside the guard ``conditional``'s branches: (whiles that carry
+    one and are no part of the row drain's loop, copies that produce
+    one, other producers that are not an in-place update)."""
+    comps = _computations(text)
+
+    def reach(names):
+        seen, todo = set(), list(names)
+        while todo:
+            n = todo.pop()
+            if n in seen or n not in comps:
+                continue
+            seen.add(n)
+            for line in comps[n]:
+                todo += _called(line)
+        return seen
+
+    def holds_kernel(names):
+        return any("tpu_custom_call" in ln for c in reach(names)
+                   for ln in comps[c])
+
+    every = [line for lines in comps.values() for line in lines]
+    guarded = reach(n for line in every if " conditional(" in line
+                    for n in _called(line))
+    # the row drain: the loop that holds the kernel, and what it calls
+    # (a loop in it that moves a row's window a trip converts nothing)
+    drain = reach(n for line in every if " while(" in line
+                  and holds_kernel(_called(line)) for n in _called(line))
+    in_place = ("scatter", "dynamic-update-slice")
+    passed = ("parameter", "get-tuple-element", "bitcast", "tuple",
+              "conditional", "constant")
+    loops, copies, others = [], [], []
+    for name, lines in comps.items():
+        if name in guarded:
+            continue
+        for line in lines:
+            counts, op = _result_elements(line)
+            if plane not in counts:
+                continue
+            if op in passed + in_place:
+                continue
+            # an async start's tuple names its operands too; what it
+            # produces is read off its done (a copy's off its start)
+            if op.endswith("-start") and op != "copy-start":
+                continue
+            if op == "while":
+                if name not in drain and not holds_kernel(_called(line)):
+                    loops.append(line)
+            elif op.startswith("copy"):
+                if op != "copy-done":  # counted at its copy-start
+                    copies.append(line)
+            elif op == "fusion":
+                # in place: what makes the plane inside is an update
+                made = [_result_elements(ln) for c in _called(line)
+                        for ln in comps[c]]
+                made = {o for counts, o in made if plane in counts} - set(
+                    passed)
+                if not made or made - set(in_place):
+                    others.append(line)
+            else:
+                others.append(line)
+    return loops, copies, others
+
+
+@pytest.fixture(scope="module")
+def mesh_series4(topo):
+    from veneur_tpu.parallel.mesh import fleet_mesh
+
+    return fleet_mesh(topo.devices, hosts=1)
+
+
+@pytest.mark.parametrize("program", ["_ingest_samples", "_ingest_centroids",
+                                     "_mesh_import_routed"])
+def test_ingest_converts_no_bin_plane(program, one_chip, mesh_series4,
+                                      kernel_admitted):
+    """An ingest dispatch costs the chunk, not the rows reserved: held
+    ``[S, K]`` the temp's bin planes live column-major on the chip, and
+    each of the two bin scatters relaid its whole plane flat (a
+    ``copy``) and back (a K-trip ``while`` of plane-sized
+    ``dynamic-update-slice``): 30.6 of a dispatch's 40.6 ms at 2^20
+    rows whatever the chunk carried (PERF.md, PR 34). With the planes
+    held flat nothing outside the guard's drain branch produces a
+    plane-sized array but the in-place scatters; the import's
+    row-local drain still relays the digest planes around its loop
+    (at most six copies: PERF.md section 7), and its loop, the one
+    that holds the kernel, is the only one that carries a plane.
+    Compiled at the 2^20 rows a chip of the benchmark's cells reserves:
+    at this file's ``ROWS`` a plane fits the chip's fast memory and the
+    compiler stages it there whole, which is no conversion."""
+    rows = 1 << 20
+    chunk_i, chunk_f = _i32((CHUNK,), one_chip), _f32((CHUNK,), one_chip)
+    if program == "_ingest_samples":
+        from veneur_tpu.core.store import _ingest_samples
+
+        digest, temp = (_on(t, one_chip) for t in _digest_state(rows))
+        compiled = _ingest_samples.lower(
+            digest, temp, chunk_i, chunk_f, chunk_f, COMPRESSION,
+            True).compile()
+        most_copies = 0
+    elif program == "_ingest_centroids":
+        from veneur_tpu.core.store import _ingest_centroids
+
+        digest, temp = (_on(t, one_chip) for t in _digest_state(rows))
+        plane = _f32((rows,), one_chip)
+        compiled = _ingest_centroids.lower(
+            digest, temp, plane, plane, chunk_i, chunk_f, chunk_f, chunk_i,
+            chunk_f, chunk_f, _i32((), one_chip), COMPRESSION,
+            True).compile()
+        most_copies = 6
+    else:
+        from veneur_tpu.core.mesh_store import (_digest_specs,
+                                                _mesh_import_routed)
+
+        mesh = mesh_series4
+        temp_spec, dig_spec, _sk, s = _digest_specs()
+        named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+        digest, temp = _digest_state(4 * rows)  # a device's block: rows
+        m_rows = _f32((4 * rows,), named(s))
+        st = named(P("series", None))
+        stack_f, stack_i = _f32((4, CHUNK), st), _i32((4, CHUNK), st)
+        compiled = _mesh_import_routed.lower(
+            _on(temp, jax.tree.map(named, temp_spec)),
+            _on(digest, jax.tree.map(named, dig_spec)), m_rows, m_rows,
+            _i32((4,), named(s)), stack_i, stack_f, stack_f, stack_i,
+            stack_f, stack_f, mesh, COMPRESSION).compile()
+        most_copies = 6
+    loops, copies, others = _plane_traffic(compiled.as_text(), rows * K)
+    assert not loops, loops
+    assert not others, others
+    assert len(copies) <= most_copies, copies

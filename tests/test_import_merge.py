@@ -154,7 +154,7 @@ def test_a_row_is_drained_only_where_it_holds_mass():
     np.testing.assert_array_equal(per_row[:4], 16.0)   # drained
     np.testing.assert_array_equal(per_row[4:], 0.0)    # left alone
     np.testing.assert_array_equal(
-        np.asarray(temp.sum_w.sum(axis=1))[:8], 16.0)
+        np.asarray(temp.bins()[0].sum(axis=1))[:8], 16.0)
     # the scalar stats are the local samples': imports leave them
     assert float(temp.count.sum()) == 0.0
 
@@ -181,7 +181,7 @@ def test_a_row_drains_the_same_in_any_trip(slab, monkeypatch):
             jnp.asarray(np.full(n, 50.0, np.float32)), jnp.ones(n))
         assert int(drained) == 1
         return (np.asarray(d.mean), np.asarray(d.weight),
-                np.asarray(t.sum_w.sum(axis=1)))
+                np.asarray(t.bins()[0].sum(axis=1)))
 
     want_m, want_w, _ = second(np.arange(3, dtype=np.int32))
     monkeypatch.setattr(td_ops, "ROW_DRAIN_SLAB_ROWS", slab)
@@ -195,3 +195,73 @@ def test_a_row_drains_the_same_in_any_trip(slab, monkeypatch):
     # a drained row holds the new centroid alone, the others both
     np.testing.assert_array_equal(few_t[:8], [1, 1, 1, 8, 8, 8, 8, 8])
     np.testing.assert_array_equal(all_t[:8], 1.0)
+
+
+def _plain_rowdrained(digest, planes, rows, means, weights):
+    """``ingest_centroids_rowdrained`` as it was written on ``[S, K]`` /
+    ``[S, A]`` planes (``planes`` = sum_w, sum_wm, seg_w, seg_wm), every
+    held row drained at once: the reference the flat planes answer to."""
+    import jax.numpy as jnp
+
+    sum_w, sum_wm, seg_w, seg_wm = planes
+    s, k = sum_w.shape
+    held = np.asarray(seg_w.sum(axis=1))
+    r = np.asarray(rows)
+    live = (r < s) & (np.asarray(weights) > 0)
+    drain = np.unique(r[live][held[r[live]] > 0])
+    if len(drain):
+        at = jnp.asarray(drain)
+        m, w = td_ops._merge_bins(digest.mean[at], digest.weight[at],
+                                  sum_w[at], sum_wm[at], 100.0, k, False)
+        digest = digest._replace(mean=digest.mean.at[at].set(m),
+                                 weight=digest.weight.at[at].set(w))
+        sum_w, sum_wm, seg_w, seg_wm = (
+            p.at[at].set(0.0) for p in (sum_w, sum_wm, seg_w, seg_wm))
+    r, v, w, b = td_ops.bin_flat_samples(rows, means, weights, s, k, 100.0,
+                                         acc_seg_w=seg_w, acc_seg_wm=seg_wm)
+    sg = td_ops.seg_of_bins(b, k)
+    return digest, (sum_w.at[r, b].add(w, mode="drop"),
+                    sum_wm.at[r, b].add(w * v, mode="drop"),
+                    seg_w.at[r, sg].add(w, mode="drop"),
+                    seg_wm.at[r, sg].add(w * v, mode="drop"))
+
+
+@pytest.mark.parametrize("slab", [4, 1024])
+@pytest.mark.parametrize("shape", ["digests", "lone_centroids"])
+def test_flat_planes_equal_the_plain_planes(shape, slab, monkeypatch):
+    """Four forwarders' chunks through the row-local drain: the flat
+    temp's bins and anchors and the digests are those of the ``[S, K]``
+    formulation bit for bit, whether the held rows drain in one trip or
+    in many; padding rows (== S) drop."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(td_ops, "ROW_DRAIN_SLAB_ROWS", slab)
+    s, n = 48, 512
+    k = td_ops.size_bound(100.0)
+    rng = np.random.default_rng(17)
+    temp, digest = td_ops.init_temp(s, k, 100.0), td_ops.init((s,), 100.0)
+    plain_digest = digest
+    plain = (jnp.zeros((s, k)), jnp.zeros((s, k)),
+             jnp.zeros((s, td_ops.BELOW_MASS_ANCHORS)),
+             jnp.zeros((s, td_ops.BELOW_MASS_ANCHORS)))
+    for forwarder in range(4):
+        if shape == "digests":   # 32 rows x 16 centroids, a sorted run each
+            rows = np.repeat(rng.permutation(s)[:32], 16).astype(np.int32)
+            vals = np.sort(rng.lognormal(0, 1, (32, 16)), axis=1).reshape(-1)
+        else:
+            rows = rng.integers(0, s, n).astype(np.int32)
+            vals = rng.lognormal(0, 1, n)
+        rows[::9] = s
+        chunk = (jnp.asarray(np.sort(rows)), jnp.asarray(
+            vals.astype(np.float32)), jnp.ones(n, jnp.float32))
+        digest, temp, drained = td_ops.ingest_centroids_rowdrained(
+            digest, temp, *chunk, use_pallas=False)
+        assert int(drained) == (forwarder > 0)
+        plain_digest, plain = _plain_rowdrained(plain_digest, plain, *chunk)
+        for got, want in zip(temp.bins() + temp.anchors(), plain):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(digest.weight),
+                                      np.asarray(plain_digest.weight))
+        np.testing.assert_array_equal(np.asarray(digest.mean),
+                                      np.asarray(plain_digest.mean))
+    assert float(temp.count.sum()) == 0.0

@@ -62,14 +62,19 @@ class TestPlanesAreMadeInShards:
             _held_in_quarters(name, arr, devices)
         # nothing of a plane's whole size was ever made on one device:
         # what the initialiser left alive is the sharded planes alone
+        # (the temp's planes are flat: K bins or A anchors a row)
+        sizes = {4096 * n for n in (1, group.k, 8)}
         whole = [a for a in jax.live_arrays()
-                 if id(a) not in before and a.shape[:1] == (4096,)
+                 if id(a) not in before and a.ndim and a.shape[0] in sizes
                  and len(a.sharding.device_set) == 1]
         assert whole == []
+        was = {name: arr.shape[0] for name, arr in _planes(group).items()}
         group._grow()
         assert group.capacity == 8192
+        assert (group.temp.num_series, group.temp.capacity) == (8192,
+                                                                group.k)
         for name, arr in _planes(group).items():
-            assert arr.shape[0] == 8192, name
+            assert arr.shape[0] == 2 * was[name], name
             _held_in_quarters(name, arr, devices)
 
     def test_growth_keeps_every_shard_block_in_place(self, mesh):
@@ -83,6 +88,29 @@ class TestPlanesAreMadeInShards:
         np.testing.assert_array_equal(
             got[:, :4], np.arange(16, dtype=np.float32).reshape(4, 4))
         assert np.isinf(got[:, 4:]).all()
+
+    @pytest.mark.parametrize("plane", ["sum_w", "seg_wm"])
+    def test_growth_keeps_the_temps_blocks_in_their_order(self, mesh,
+                                                          plane):
+        """A shard's block of a flat temp plane is a ``TempCentroids``
+        plane of its rows (bins row by row, anchors anchor by anchor):
+        grown, each block is what ``grow_temp`` makes of it alone."""
+        from veneur_tpu.ops import tdigest as td_ops
+
+        group = MeshDigestGroup(mesh, 16, 64, 100.0,
+                                router=ShardRouter(4))
+        shape = getattr(group.temp, plane).shape
+        stamp = np.arange(1, shape[0] + 1, dtype=np.float32)
+        group.temp = group.temp._replace(**{plane: jax.device_put(
+            stamp, getattr(group.temp, plane).sharding)})
+        group._grow()
+        got = np.asarray(getattr(group.temp, plane)).reshape(4, -1)
+        for block, was in zip(got, stamp.reshape(4, -1)):
+            alone = td_ops.grow_temp(
+                td_ops.init_temp(4, group.k, 100.0)._replace(
+                    **{plane: jax.numpy.asarray(was)}), 4)
+            np.testing.assert_array_equal(
+                block, np.asarray(getattr(alone, plane)))
 
     def test_set_registers(self, mesh):
         group = MeshSetGroup(mesh, 64, 64, 10, router=ShardRouter(4))

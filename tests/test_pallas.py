@@ -104,12 +104,13 @@ class TestCompressKernel:
         xd = td.drain_temp(state, temp, C)
         xq = np.asarray(td.quantile(xd, qs))
         # fused kernel (interpret mode), fed the same sorted halves
-        t_live = temp.sum_w > 0
+        sum_w, sum_wm = temp.bins()
+        t_live = sum_w > 0
         t_mean = jnp.where(t_live,
-                           temp.sum_wm / jnp.where(t_live, temp.sum_w, 1.0),
+                           sum_wm / jnp.where(t_live, sum_w, 1.0),
                            jnp.inf)
         import jax.lax as lax
-        t_mean, t_w = lax.sort((t_mean, temp.sum_w), dimension=-1,
+        t_mean, t_w = lax.sort((t_mean, sum_w), dimension=-1,
                                num_keys=1, is_stable=False)
         mn = jnp.minimum(jnp.minimum(state.min, temp.vmin), dmin)
         mx = jnp.maximum(jnp.maximum(state.max, temp.vmax), dmax)
@@ -139,12 +140,13 @@ class TestCompressKernel:
                                               qs, C)
         assert np.allclose(np.asarray(pcts), 5.0), np.asarray(pcts)
         # fused kernel path, fed a digest whose first live bin is mid-row
-        t_live = temp.sum_w > 0
+        sum_w, sum_wm = temp.bins()
+        t_live = sum_w > 0
         t_mean = jnp.where(t_live,
-                           temp.sum_wm / jnp.where(t_live, temp.sum_w, 1.0),
+                           sum_wm / jnp.where(t_live, sum_w, 1.0),
                            jnp.inf)
         import jax.lax as lax
-        t_mean, t_w = lax.sort((t_mean, temp.sum_w), dimension=-1,
+        t_mean, t_w = lax.sort((t_mean, sum_w), dimension=-1,
                                num_keys=1, is_stable=False)
         nm, nw, pq = tp.drain_quantile(
             state.mean, state.weight, t_mean, t_w, temp.vmin, temp.vmax,

@@ -331,3 +331,172 @@ class TestLiveRowBound:
         for n in (1, 70, 256):
             fn(*state, np.int32(n))
         assert len(traces) == 1
+
+
+# ---------------------------------------------------------------------------
+# The temp's flat planes against the plain [S, K] formulation
+# ---------------------------------------------------------------------------
+
+
+class PlainTemp:
+    """The bin accumulation as it was written before the planes went
+    flat, kept here as the reference: ``[S, K]`` / ``[S, A]`` planes,
+    ``.at[r, b].add``, the guard's whole-width drain."""
+
+    def __init__(self, rows):
+        self.rows = rows
+        self.sum_w = jnp.zeros((rows, K), jnp.float32)
+        self.sum_wm = jnp.zeros((rows, K), jnp.float32)
+        self.seg_w = jnp.zeros((rows, td.BELOW_MASS_ANCHORS), jnp.float32)
+        self.seg_wm = jnp.zeros((rows, td.BELOW_MASS_ANCHORS), jnp.float32)
+        self.digest = td.init((rows,), C, K)
+
+    def ingest(self, rows, values, weights, guarded=False,
+               use_pallas=False):
+        if guarded and bool(td.shift_pred(self.seg_w, self.seg_wm, rows,
+                                          values, weights, self.rows)):
+            mean, weight = td._merge_bins(
+                self.digest.mean, self.digest.weight, self.sum_w,
+                self.sum_wm, C, K, use_pallas)
+            self.digest = self.digest._replace(mean=mean, weight=weight)
+            self.sum_w, self.sum_wm = (jnp.zeros_like(self.sum_w),) * 2
+            self.seg_w, self.seg_wm = (jnp.zeros_like(self.seg_w),) * 2
+        r, v, w, b = td.bin_flat_samples(
+            rows, values, weights, self.rows, K, C, acc_seg_w=self.seg_w,
+            acc_seg_wm=self.seg_wm)
+        vz = jnp.where(w > 0, v, 0.0)
+        sg = td.seg_of_bins(b, K)
+        self.sum_w = self.sum_w.at[r, b].add(w, mode="drop")
+        self.sum_wm = self.sum_wm.at[r, b].add(w * vz, mode="drop")
+        self.seg_w = self.seg_w.at[r, sg].add(w, mode="drop")
+        self.seg_wm = self.seg_wm.at[r, sg].add(w * vz, mode="drop")
+
+
+def assert_planes_equal(temp, plain):
+    """Bit for bit: bins and anchors, through both of the temp's views."""
+    for got, want in zip(temp.bins() + temp.anchors(),
+                         (plain.sum_w, plain.sum_wm, plain.seg_w,
+                          plain.seg_wm)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(temp.sum_w), np.asarray(plain.sum_w).reshape(-1))
+    np.testing.assert_array_equal(
+        np.asarray(temp.seg_w), np.asarray(plain.seg_w).T.reshape(-1))
+
+
+def _flat_case(name):
+    """(rows reserved, [(rows, values, weights) a chunk])."""
+    rng = np.random.default_rng(5)
+
+    def chunk(series, n, rows, shift=0.0):
+        return (rng.integers(0, series, n).astype(np.int32),
+                (shift + rng.lognormal(0, 1, n)).astype(np.float32),
+                np.ones(n, np.float32))
+
+    if name == "one_chunk":
+        return 64, [chunk(64, 2048, 64)]
+    if name == "sixteen_chunks_same_rows":
+        return 64, [chunk(16, 1024, 64) for _ in range(16)]
+    if name == "padding_rows_and_zero_weights":
+        out = []
+        for _ in range(3):
+            r, v, w = chunk(32, 1024, 32)
+            r[::3] = 32          # the padding sentinel: rows == S
+            w[1::5] = 0.0
+            out.append((r, v, w))
+        return 32, out
+    if name == "spread_over_every_row":
+        # one sample a row over the whole capacity, twice
+        return 4096, [(rng.permutation(4096).astype(np.int32),
+                       rng.lognormal(0, 1, 4096).astype(np.float32),
+                       np.ones(4096, np.float32)) for _ in range(2)]
+    if name == "a_group_of_eight_rows":
+        return 8, [chunk(8, 512, 8) for _ in range(4)]
+    assert name == "guard_drain_in_the_middle"
+    return 16, ([chunk(16, 1024, 16) for _ in range(3)]
+                + [chunk(16, 1024, 16, shift=1e4)]
+                + [chunk(16, 1024, 16, shift=1e4) for _ in range(2)])
+
+
+FLAT_CASES = ["one_chunk", "sixteen_chunks_same_rows",
+              "padding_rows_and_zero_weights", "spread_over_every_row",
+              "a_group_of_eight_rows", "guard_drain_in_the_middle"]
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["xla_rung", "kernel_rung"])
+@pytest.mark.parametrize("case", FLAT_CASES)
+def test_flat_accumulation_equals_the_plain_planes(case, use_pallas):
+    """``ingest_chunk_guarded`` on the flat planes leaves the bins, the
+    anchors and the digest of the ``[S, K]`` formulation, bit for bit
+    (the scatter keeps its order of additions in both)."""
+    rows, chunks = _flat_case(case)
+    plain = PlainTemp(rows)
+    temp, digest = td.init_temp(rows, K, C), td.init((rows,), C, K)
+    step = jax.jit(lambda d, t, *c: td.ingest_chunk_guarded(
+        d, t, *c, C, use_pallas=use_pallas))
+    for c in chunks:
+        c = tuple(jnp.asarray(x) for x in c)
+        plain.ingest(*c, guarded=True, use_pallas=use_pallas)
+        digest, temp = step(digest, temp, *c)
+        assert_planes_equal(temp, plain)
+    np.testing.assert_array_equal(np.asarray(digest.weight),
+                                  np.asarray(plain.digest.weight))
+    np.testing.assert_array_equal(np.asarray(digest.mean),
+                                  np.asarray(plain.digest.mean))
+    drained = bool(np.asarray(digest.weight).any())
+    assert drained is (case == "guard_drain_in_the_middle")
+
+
+class TestFlatPlanes:
+    def test_shape_contract(self):
+        temp = td.init_temp(24, K, C)
+        assert (temp.num_series, temp.capacity) == (24, K)
+        assert temp.sum_w.shape == temp.sum_wm.shape == (24 * K,)
+        assert temp.seg_w.shape == (td.BELOW_MASS_ANCHORS * 24,)
+        assert [x.shape for x in temp.bins()] == [(24, K)] * 2
+        assert [x.shape for x in temp.anchors()] == [
+            (24, td.BELOW_MASS_ANCHORS)] * 2
+
+    def test_windows_of_a_bin_plane(self):
+        plane = jnp.arange(10 * K, dtype=jnp.float32)
+        whole = np.asarray(plane).reshape(10, K)
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(lambda s: td.bin_rows(plane, s, 4, K))(3)),
+            whole[3:7])
+        at = jnp.asarray([9, 0, 4, 4], jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(td.gather_bin_rows(plane, at, K)), whole[[9, 0, 4, 4]])
+        # the first two of three rows moved out; the third stays
+        rows = jnp.asarray([2, 7, 5], jnp.int32)
+        left, left2, w, wm = jax.jit(
+            lambda n: td.take_bin_rows(plane, 2 * plane, rows, n, K))(2)
+        np.testing.assert_array_equal(np.asarray(w)[:2], whole[[2, 7]])
+        np.testing.assert_array_equal(np.asarray(wm)[:2], 2 * whole[[2, 7]])
+        assert not np.asarray(w)[2].any() and not np.asarray(wm)[2].any()
+        left, left2 = (np.asarray(x).reshape(10, K) for x in (left, left2))
+        assert not left[[2, 7]].any() and not left2[[2, 7]].any()
+        keep = [0, 1, 3, 4, 5, 6, 8, 9]
+        np.testing.assert_array_equal(left[keep], whole[keep])
+        np.testing.assert_array_equal(left2[keep], 2 * whole[keep])
+
+    @pytest.mark.parametrize("pad", [8, 24])
+    def test_grown_temp_keeps_every_row(self, pad):
+        rng = np.random.default_rng(9)
+        c = (jnp.asarray(rng.integers(0, 8, 512).astype(np.int32)),
+             jnp.asarray(rng.lognormal(0, 1, 512).astype(np.float32)),
+             jnp.ones(512, jnp.float32))
+        small = td.ingest_chunk(td.init_temp(8, K, C), *c, C)
+        grown = td.grow_temp(small, pad)
+        assert grown.num_series == 8 + pad and grown.capacity == K
+        for g, s in zip(grown.bins() + grown.anchors(),
+                        small.bins() + small.anchors()):
+            np.testing.assert_array_equal(np.asarray(g)[:8], np.asarray(s))
+            assert not np.asarray(g)[8:].any()
+        np.testing.assert_array_equal(np.asarray(grown.vmin)[8:], np.inf)
+        # and bins on as one that was made at that size
+        big = td.ingest_chunk(td.init_temp(8 + pad, K, C), *c, C)
+        again = td.ingest_chunk(grown, *c, C)
+        twice = td.ingest_chunk(big, *c, C)
+        for g, w in zip(again, twice):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -24,18 +24,18 @@ class TestShiftGuard:
                                jnp.ones(flat.size, jnp.float32))
         # same range again: no shift
         assert not bool(td.shift_pred(
-            temp.seg_w, temp.seg_wm, jnp.asarray(flat),
+            *temp.anchors(), jnp.asarray(flat),
             jnp.asarray(low.astype(np.float32)),
             jnp.ones(flat.size, jnp.float32), rows))
         # disjoint range: shift
         assert bool(td.shift_pred(
-            temp.seg_w, temp.seg_wm, jnp.asarray(flat),
+            *temp.anchors(), jnp.asarray(flat),
             jnp.asarray((low + 1000).astype(np.float32)),
             jnp.ones(flat.size, jnp.float32), rows))
         # empty accumulator never triggers
         fresh = td.init_temp(rows)
         assert not bool(td.shift_pred(
-            fresh.seg_w, fresh.seg_wm, jnp.asarray(flat),
+            *fresh.anchors(), jnp.asarray(flat),
             jnp.asarray(low.astype(np.float32)),
             jnp.ones(flat.size, jnp.float32), rows))
         # nor do rows below the minimum accumulated mass (1-2 samples
@@ -46,7 +46,7 @@ class TestShiftGuard:
                                jnp.asarray(low[:rows].astype(np.float32)),
                                jnp.ones(rows, jnp.float32))
         assert not bool(td.shift_pred(
-            tiny.seg_w, tiny.seg_wm, jnp.asarray(flat),
+            *tiny.anchors(), jnp.asarray(flat),
             jnp.asarray((low + 1000).astype(np.float32)),
             jnp.ones(flat.size, jnp.float32), rows))
 
@@ -66,13 +66,13 @@ class TestShiftGuard:
         one = np.arange(rows, dtype=np.int32)
         # even a fully DISJOINT 1-sample-per-row chunk stays quiet...
         assert not bool(td.shift_pred(
-            temp.seg_w, temp.seg_wm, jnp.asarray(one),
+            *temp.anchors(), jnp.asarray(one),
             jnp.full(rows, 1e6, jnp.float32),
             jnp.ones(rows, jnp.float32), rows))
         # ...while a >=4-sample disjoint chunk still fires
         four = np.repeat(np.arange(rows, dtype=np.int32), 4)
         assert bool(td.shift_pred(
-            temp.seg_w, temp.seg_wm, jnp.asarray(four),
+            *temp.anchors(), jnp.asarray(four),
             jnp.full(four.size, 1e6, jnp.float32),
             jnp.ones(four.size, jnp.float32), rows))
 

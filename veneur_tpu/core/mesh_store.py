@@ -76,18 +76,22 @@ def _relocal(rows: jax.Array, s_loc: int) -> jax.Array:
     return jnp.where((r >= start) & (r < start + s_loc), r - start, s_loc)
 
 
-@partial(jax.jit, static_argnums=(1, 2))
-def _blocked_pad(arr: jax.Array, mesh: Mesh, fill=0) -> jax.Array:
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _blocked_pad(arr: jax.Array, mesh: Mesh, fill=0,
+                 planes: int = 1) -> jax.Array:
     """Double every shard's contiguous block of dim 0, each on the
     device that holds it: the device twin of ``ShardPlacement.grow`` —
     physical row (shard, local) moves from ``shard*B + local`` to
     ``shard*2B + local`` on both sides, and no plane is ever whole on
-    one device."""
+    one device. A block that is a flat stack of ``planes`` planes of
+    its rows (the temp's anchors) has each of them doubled."""
     spec = P(SERIES_AXIS, *([None] * (arr.ndim - 1)))
 
     def local_pad(x):
-        pad = [(0, x.shape[0])] + [(0, 0)] * (x.ndim - 1)
-        return jnp.pad(x, pad, constant_values=fill)
+        y = x.reshape((planes, x.shape[0] // planes) + x.shape[1:])
+        pad = [(0, 0), (0, y.shape[1])] + [(0, 0)] * (x.ndim - 1)
+        return jnp.pad(y, pad, constant_values=fill).reshape(
+            (-1,) + x.shape[1:])
 
     return shard_map(local_pad, mesh=mesh, in_specs=(spec,),
                      out_specs=spec, check_vma=False)(arr)
@@ -137,9 +141,11 @@ def _add_temp(a: td_ops.TempCentroids,
 
 def _digest_specs():
     sk, s = P(SERIES_AXIS, None), P(SERIES_AXIS)
-    temp_spec = td_ops.TempCentroids(sum_w=sk, sum_wm=sk, seg_w=sk,
-                                     seg_wm=sk, count=s, vsum=s,
-                                     vmin=s, vmax=s, recip=s)
+    # the temp's flat planes split into the shards' row blocks, each
+    # block in a ``TempCentroids``' own order
+    temp_spec = td_ops.TempCentroids(sum_w=s, sum_wm=s, seg_w=s, seg_wm=s,
+                                     count=s, vsum=s, vmin=s, vmax=s,
+                                     recip=s)
     dig_spec = td_ops.TDigest(mean=sk, weight=sk, min=s, max=s)
     return temp_spec, dig_spec, sk, s
 
@@ -151,7 +157,7 @@ def _guarded_drain(temp, digest, rows_l, vals, wts, s_loc, axes,
     the shift/total masses over ``axes`` so every shard takes the same
     drain the dense store would on the same data."""
     shifted, total = td_ops.shift_masses(
-        temp.seg_w, temp.seg_wm, rows_l, vals, wts, s_loc)
+        *temp.anchors(), rows_l, vals, wts, s_loc)
     shifted = lax.psum(shifted, axes)
     total = lax.psum(total, axes)
     pred = shifted > td_ops.SHIFT_GUARD_FRAC * jnp.maximum(
@@ -180,7 +186,7 @@ def _mesh_ingest_samples(temp, digest, rows, vals, wts, mesh: Mesh,
     h = P(HOSTS_AXIS)
 
     def local_ingest(temp, digest, rows, vals, wts):
-        s_loc = temp.sum_w.shape[0]
+        s_loc = temp.num_series
         rows_l = _relocal(rows, s_loc)
         # hosts-sharded chunk: the guard masses psum over BOTH axes
         # (each shard sees its sub-chunk x its rows)
@@ -224,7 +230,7 @@ def _mesh_import_routed(temp, digest, dmin, dmax, drains, rows, means, wts,
 
     def local_import(temp, digest, dmin, dmax, drains, rows, means, wts,
                      srows, smins, smaxs):
-        s_loc = temp.sum_w.shape[0]
+        s_loc = temp.num_series
         rows_l = _relocal(rows.reshape(-1), s_loc)
         # imported centroids feed percentiles only, never local stats
         # (samplers.go:473-480)
@@ -440,12 +446,14 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         self.capacity *= _GROW_FACTOR
         # nothing placed yet: the first touch allocates at the new size
         if "temp" in self.__dict__:
-            def pad(x, fill=0.0):
-                return _blocked_pad(x, self.mesh, fill)
+            def pad(x, fill=0.0, planes=1):
+                return _blocked_pad(x, self.mesh, fill, planes)
 
+            anchors = td_ops.BELOW_MASS_ANCHORS
             self.temp = td_ops.TempCentroids(
                 sum_w=pad(self.temp.sum_w), sum_wm=pad(self.temp.sum_wm),
-                seg_w=pad(self.temp.seg_w), seg_wm=pad(self.temp.seg_wm),
+                seg_w=pad(self.temp.seg_w, planes=anchors),
+                seg_wm=pad(self.temp.seg_wm, planes=anchors),
                 count=pad(self.temp.count), vsum=pad(self.temp.vsum),
                 vmin=pad(self.temp.vmin, np.inf),
                 vmax=pad(self.temp.vmax, -np.inf),
@@ -618,7 +626,8 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
             return snap, None
         rows = jnp.asarray(self._flush_rows(n), jnp.int32)
         refs = (self.digest.mean[rows], self.digest.weight[rows],
-                self.temp.sum_w[rows], self.temp.sum_wm[rows],
+                td_ops.gather_bin_rows(self.temp.sum_w, rows, self.k),
+                td_ops.gather_bin_rows(self.temp.sum_wm, rows, self.k),
                 self.dmin[rows], self.dmax[rows],
                 self.digest.min[rows], self.digest.max[rows],
                 self.temp.count[rows], self.temp.vsum[rows],

@@ -137,7 +137,6 @@ def _guard_drain_slab(temp: TempSlab, digest: DigestSlab, rows, values,
     stationary traffic pays one cheap reduction, never the drain. Temp
     scalar stats survive (interval aggregates; only the bins move)."""
     k = temp.sum_w.shape[0] // slab
-    a = td_ops.BELOW_MASS_ANCHORS
     pred = td_ops.shift_pred(temp.seg_w, temp.seg_wm, rows, values,
                              weights, slab)
 
@@ -148,15 +147,10 @@ def _guard_drain_slab(temp: TempSlab, digest: DigestSlab, rows, values,
             mean=d.mean.reshape(slab, k).astype(jnp.float32),
             weight=d.weight.reshape(slab, k).astype(jnp.float32),
             min=d.dmin, max=d.dmax)
-        t32 = td_ops.TempCentroids(
-            sum_w=t.sum_w.reshape(slab, k),
-            sum_wm=t.sum_wm.reshape(slab, k),
-            seg_w=t.seg_w.reshape(slab, a),
-            seg_wm=t.seg_wm.reshape(slab, a),
-            count=t.count, vsum=t.vsum, vmin=t.vmin, vmax=t.vmax,
-            recip=t.recip)
-        drained = td_ops.drain_temp(d32, t32, compression,
-                                    use_pallas=use_pallas)
+        # the drain reads the flat bin planes and the scalar stats; the
+        # anchors (in the slab's row-major order) ride along unread
+        drained = td_ops.drain_temp(d32, td_ops.TempCentroids(*t),
+                                    compression, use_pallas=use_pallas)
         d2 = DigestSlab(
             mean=drained.mean.astype(dt).reshape(-1),
             weight=drained.weight.astype(dt).reshape(-1),
@@ -260,13 +254,8 @@ def _flush_slab(digest: DigestSlab, temp: TempSlab, qs, slab: int,
         mean=digest.mean.reshape(slab, k).astype(jnp.float32),
         weight=digest.weight.reshape(slab, k).astype(jnp.float32),
         min=digest.dmin, max=digest.dmax)
-    a = td_ops.BELOW_MASS_ANCHORS
-    t = td_ops.TempCentroids(
-        sum_w=temp.sum_w.reshape(slab, k), sum_wm=temp.sum_wm.reshape(slab, k),
-        seg_w=temp.seg_w.reshape(slab, a),
-        seg_wm=temp.seg_wm.reshape(slab, a),
-        count=temp.count, vsum=temp.vsum, vmin=temp.vmin, vmax=temp.vmax,
-        recip=temp.recip)
+    # as in _guard_drain_slab: the anchors ride along unread
+    t = td_ops.TempCentroids(*temp)
     inf = jnp.full((slab,), jnp.inf, jnp.float32)
     drained, pcts = td_ops.drain_and_quantile(d, t, inf, -inf, qs,
                                               compression,

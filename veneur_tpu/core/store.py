@@ -823,19 +823,7 @@ class DigestGroup(OverloadLimited):
         pad = self.capacity - old
         # nothing placed yet: the first touch allocates at the new size
         if "temp" in self.__dict__:
-            self.temp = td_ops.TempCentroids(
-                sum_w=jnp.pad(self.temp.sum_w, ((0, pad), (0, 0))),
-                sum_wm=jnp.pad(self.temp.sum_wm, ((0, pad), (0, 0))),
-                seg_w=jnp.pad(self.temp.seg_w, ((0, pad), (0, 0))),
-                seg_wm=jnp.pad(self.temp.seg_wm, ((0, pad), (0, 0))),
-                count=jnp.pad(self.temp.count, (0, pad)),
-                vsum=jnp.pad(self.temp.vsum, (0, pad)),
-                vmin=jnp.pad(self.temp.vmin, (0, pad),
-                             constant_values=np.inf),
-                vmax=jnp.pad(self.temp.vmax, (0, pad),
-                             constant_values=-np.inf),
-                recip=jnp.pad(self.temp.recip, (0, pad)),
-            )
+            self.temp = td_ops.grow_temp(self.temp, pad)
             self.digest = td_ops.TDigest(
                 mean=jnp.pad(self.digest.mean, ((0, pad), (0, 0)),
                              constant_values=np.inf),
@@ -1229,7 +1217,8 @@ class DigestGroup(OverloadLimited):
         if n == 0:
             return snap, None
         refs = (self.digest.mean[:n], self.digest.weight[:n],
-                self.temp.sum_w[:n], self.temp.sum_wm[:n],
+                td_ops.bin_rows(self.temp.sum_w, 0, n, self.k),
+                td_ops.bin_rows(self.temp.sum_wm, 0, n, self.k),
                 self.dmin[:n], self.dmax[:n],
                 self.digest.min[:n], self.digest.max[:n],
                 self.temp.count[:n], self.temp.vsum[:n],

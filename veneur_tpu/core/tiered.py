@@ -355,7 +355,7 @@ def _promote_rows_impl(pool: PoolSlab, temp: td_ops.TempCentroids, ddmin,
     dense accumulators, and the pool rows clear — counts conserved
     exactly. rows are slab-LOCAL (>= slab is padding); slots are dense
     slot ids (rows past the dense capacity drop, which padding uses)."""
-    nslots = temp.sum_w.shape[0]
+    nslots = temp.num_series
     valid = rows < slab
     rc = jnp.minimum(rows, slab - 1)
     sl = jnp.where(valid, slots, nslots)
@@ -1281,7 +1281,8 @@ class TieredDigestGroup(OverloadLimited):
             d = self._dense
             dense_refs = (
                 d.digest.mean[:nd], d.digest.weight[:nd],
-                d.temp.sum_w[:nd], d.temp.sum_wm[:nd], d.dmin[:nd],
+                td_ops.bin_rows(d.temp.sum_w, 0, nd, d.k),
+                td_ops.bin_rows(d.temp.sum_wm, 0, nd, d.k), d.dmin[:nd],
                 d.dmax[:nd], d.digest.min[:nd], d.digest.max[:nd],
                 d.temp.count[:nd], d.temp.vsum[:nd], d.temp.vmin[:nd],
                 d.temp.vmax[:nd], d.temp.recip[:nd])

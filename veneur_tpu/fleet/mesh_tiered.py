@@ -72,8 +72,8 @@ def _pool_spec() -> PoolSlab:
 
 
 def _temp_spec():
-    sk, s = P(SERIES_AXIS, None), P(SERIES_AXIS)
-    return td_ops.TempCentroids(sum_w=sk, sum_wm=sk, seg_w=sk, seg_wm=sk,
+    s = P(SERIES_AXIS)
+    return td_ops.TempCentroids(sum_w=s, sum_wm=s, seg_w=s, seg_wm=s,
                                 count=s, vsum=s, vmin=s, vmax=s, recip=s)
 
 
@@ -620,7 +620,8 @@ class MeshTieredDigestGroup(TieredDigestGroup):
             slots = jnp.asarray(self._dense_slots, jnp.int32)
             dense_refs = (
                 d.digest.mean[slots], d.digest.weight[slots],
-                d.temp.sum_w[slots], d.temp.sum_wm[slots],
+                td_ops.gather_bin_rows(d.temp.sum_w, slots, d.k),
+                td_ops.gather_bin_rows(d.temp.sum_wm, slots, d.k),
                 d.dmin[slots], d.dmax[slots], d.digest.min[slots],
                 d.digest.max[slots], d.temp.count[slots],
                 d.temp.vsum[slots], d.temp.vmin[slots],

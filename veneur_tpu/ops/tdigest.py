@@ -671,21 +671,120 @@ class TempCentroids(NamedTuple):
     of the reference's tempCentroids list, plus the Histo sampler's local
     scalar stats (samplers.go:467-494).
 
+    The bin planes are FLAT: ingest only ever writes them by scatter,
+    and on the chip a scatter is native in a flat array alone. Held
+    ``[S, K]`` the planes live column-major there, and every ingest
+    dispatch relaid a whole plane flat for its scatter and back in a
+    K-trip loop: 30.6 of a dispatch's 40.6 ms at 2^20 rows, set by the
+    rows reserved whatever the chunk carried (PERF.md, PR 34). sum_w /
+    sum_wm hold bin ``b`` of row ``r`` at ``r*K + b`` (a row's bins
+    contiguous: the drains read them a window of rows at a time,
+    ``bin_rows`` / ``gather_bin_rows``).
+
     seg_w/seg_wm are the incremental BELOW_MASS_ANCHORS-segment anchor
     summary (updated by the same scatters that fill the bins): the
     quantile-anchoring correction and the shift guard read ONLY these
-    [S, A] planes, never the full [S, K] bins — keeping the per-chunk
-    ingest cost at scatter level."""
+    planes, never the full bins — keeping the per-chunk ingest cost at
+    scatter level. They hold anchor ``a`` of row ``r`` at ``a*S + r``:
+    both readers take every row at once, and ``anchor_rows``' ``[S, A]``
+    view of that order is the one the chip computes in (row-major
+    ``r*A + a`` read 5.5 ms a dispatch slower there: PERF.md, PR 34)."""
 
-    sum_w: jax.Array       # [S, K] per-bin weight
-    sum_wm: jax.Array      # [S, K] per-bin weighted mean sum
-    seg_w: jax.Array       # [S, A] anchor-segment weight
-    seg_wm: jax.Array      # [S, A] anchor-segment weighted mean sum
+    sum_w: jax.Array       # [S*K] per-bin weight
+    sum_wm: jax.Array      # [S*K] per-bin weighted mean sum
+    seg_w: jax.Array       # [A*S] anchor-segment weight
+    seg_wm: jax.Array      # [A*S] anchor-segment weighted mean sum
     count: jax.Array       # [S] total weight
     vsum: jax.Array        # [S] weighted sample sum
     vmin: jax.Array        # [S]
     vmax: jax.Array        # [S]
     recip: jax.Array       # [S] weighted reciprocal sum (for hmean)
+
+    @property
+    def num_series(self) -> int:
+        return self.count.shape[0]
+
+    @property
+    def capacity(self) -> int:
+        return self.sum_w.shape[0] // self.count.shape[0]
+
+    def bins(self):
+        """(sum_w, sum_wm) as ``[S, K]``: a relayout of both whole
+        planes on the chip, for a drain of every row."""
+        shape = (self.num_series, self.capacity)
+        return self.sum_w.reshape(shape), self.sum_wm.reshape(shape)
+
+    def anchors(self):
+        """(seg_w, seg_wm) as the ``[S, A]`` views the guard and the
+        quantile anchoring read."""
+        return (anchor_rows(self.seg_w, self.num_series),
+                anchor_rows(self.seg_wm, self.num_series))
+
+
+def anchor_rows(seg: jax.Array, num_series: int) -> jax.Array:
+    """A flat anchor plane (``a*S + r``) as ``[S, A]``."""
+    return seg.reshape(-1, num_series).T
+
+
+def bin_rows(plane: jax.Array, start, rows: int, width: int) -> jax.Array:
+    """Rows ``[start, start + rows)`` of a flat ``[S*width]`` bin plane
+    as ``[rows, width]``; ``start`` may be traced. What is relaid is the
+    window, never the plane."""
+    return lax.dynamic_slice_in_dim(plane, start * width, rows * width
+                                    ).reshape(rows, width)
+
+
+def gather_bin_rows(plane: jax.Array, rows: jax.Array,
+                    width: int) -> jax.Array:
+    """Rows ``rows`` ([R] int32, in range) of a flat ``[S*width]`` bin
+    plane as ``[R, width]``, a window a row (the snapshots' read; the
+    row drain moves its rows out with ``take_bin_rows``)."""
+    return jax.vmap(
+        lambda r: lax.dynamic_slice_in_dim(plane, r * width, width))(rows)
+
+
+def take_bin_rows(sum_w: jax.Array, sum_wm: jax.Array, rows: jax.Array,
+                  n, width: int):
+    """Move the bins of ``rows[:n]`` ([R] int32, in range; ``n`` traced)
+    out of the flat planes: (sum_w, sum_wm) with those rows zeroed and
+    their bins as two ``[R, width]`` slabs, zero past ``n``. One loop
+    of ``n`` trips, each a window of a row read and zeroed in place
+    (2.8 ms a 1,024 held rows): as a gather and a scatter of windows
+    the chip runs one serial loop an operation over all ``R`` entries,
+    held or not (7.2 ms; 0.5 on ``[S, K]`` planes, which paid 30 ms of
+    conversion a dispatch for it; PERF.md, PR 34)."""
+    blank = jnp.zeros((rows.shape[0], width), sum_w.dtype)
+    zero = jnp.zeros((width,), sum_w.dtype)
+
+    def one(i, carry):
+        sum_w, sum_wm, w, wm = carry
+        at = rows[i] * width
+        w = lax.dynamic_update_slice_in_dim(
+            w, lax.dynamic_slice_in_dim(sum_w, at, width)[None], i, 0)
+        wm = lax.dynamic_update_slice_in_dim(
+            wm, lax.dynamic_slice_in_dim(sum_wm, at, width)[None], i, 0)
+        return (lax.dynamic_update_slice_in_dim(sum_w, zero, at, 0),
+                lax.dynamic_update_slice_in_dim(sum_wm, zero, at, 0), w, wm)
+
+    return lax.fori_loop(0, n, one, (sum_w, sum_wm, blank, blank))
+
+
+def grow_temp(temp: TempCentroids, pad: int) -> TempCentroids:
+    """``temp`` with ``pad`` empty rows appended (the one place outside
+    the ingest that knows the planes' orders)."""
+    def anchor_pad(seg):
+        return jnp.pad(seg.reshape(-1, temp.num_series),
+                       ((0, 0), (0, pad))).reshape(-1)
+
+    return TempCentroids(
+        sum_w=jnp.pad(temp.sum_w, (0, pad * temp.capacity)),
+        sum_wm=jnp.pad(temp.sum_wm, (0, pad * temp.capacity)),
+        seg_w=anchor_pad(temp.seg_w), seg_wm=anchor_pad(temp.seg_wm),
+        count=jnp.pad(temp.count, (0, pad)),
+        vsum=jnp.pad(temp.vsum, (0, pad)),
+        vmin=jnp.pad(temp.vmin, (0, pad), constant_values=jnp.inf),
+        vmax=jnp.pad(temp.vmax, (0, pad), constant_values=-jnp.inf),
+        recip=jnp.pad(temp.recip, (0, pad)))
 
 
 def init_temp(num_series: int, capacity: int | None = None,
@@ -696,10 +795,10 @@ def init_temp(num_series: int, capacity: int | None = None,
     # donation-safety pass (lint/deviceflow.py DISTINCT_BUFFER_INITS)
     # flags any field sharing a buffer name here.
     return TempCentroids(
-        sum_w=jnp.zeros((num_series, k), jnp.float32),
-        sum_wm=jnp.zeros((num_series, k), jnp.float32),
-        seg_w=jnp.zeros((num_series, BELOW_MASS_ANCHORS), jnp.float32),
-        seg_wm=jnp.zeros((num_series, BELOW_MASS_ANCHORS), jnp.float32),
+        sum_w=jnp.zeros((num_series * k,), jnp.float32),
+        sum_wm=jnp.zeros((num_series * k,), jnp.float32),
+        seg_w=jnp.zeros((BELOW_MASS_ANCHORS * num_series,), jnp.float32),
+        seg_wm=jnp.zeros((BELOW_MASS_ANCHORS * num_series,), jnp.float32),
         count=jnp.zeros((num_series,), jnp.float32),
         vsum=jnp.zeros((num_series,), jnp.float32),
         vmin=jnp.full((num_series,), jnp.inf, jnp.float32),
@@ -716,36 +815,44 @@ def ingest_chunk(temp: TempCentroids, rows: jax.Array, values: jax.Array,
                  acc_seg_wm: jax.Array | None = None) -> TempCentroids:
     """Fold one flat chunk of samples into the temp accumulator.
 
-    acc_seg_w/acc_seg_wm default to ``temp``'s own anchor summary (the
-    quantile-anchoring state for bin coherence); the mesh store passes
-    them explicitly because it bins each chunk into a FRESH temp and
-    index-adds the delta after a hosts-axis collective.
+    acc_seg_w/acc_seg_wm (flat, ``temp``'s order) default to ``temp``'s
+    own anchor summary (the quantile-anchoring state for bin
+    coherence); the mesh store passes them explicitly because it bins
+    each chunk into a FRESH temp and index-adds the delta after a
+    hosts-axis collective.
 
-    All scatters use mode='drop' so padding (rows == S) is free. Repeated
+    All scatters use mode='drop' so padding (rows == S) is free: its
+    flat index lies past the plane's end. Repeated
     chunks accumulate into the same bins, with bin ids anchored to the
     estimated GLOBAL quantile against the accumulated state (see
     bin_flat_samples' acc_* args), so bins stay value-coherent across
-    chunks even under ordered arrival. The [S, A] anchor summary is
+    chunks even under ordered arrival. The anchor summary is
     maintained by two extra scatters here.
 
     update_stats=False skips the local scalar stats: used when re-binning
     *imported* digest centroids, which contribute to percentiles but not to
     the host-local min/max/sum/avg/count/hmean (samplers.go:473-480).
     """
-    num_series, capacity = temp.sum_w.shape
+    num_series, capacity = temp.num_series, temp.capacity
     if acc_seg_w is None:
         acc_seg_w, acc_seg_wm = temp.seg_w, temp.seg_wm
-    r, v, w, b = bin_flat_samples(rows, values, weights, num_series, capacity,
-                                  compression, acc_seg_w=acc_seg_w,
-                                  acc_seg_wm=acc_seg_wm)
+    r, v, w, b = bin_flat_samples(
+        rows, values, weights, num_series, capacity, compression,
+        acc_seg_w=anchor_rows(acc_seg_w, num_series),
+        acc_seg_wm=anchor_rows(acc_seg_wm, num_series))
     live = w > 0
     vz = jnp.where(live, v, 0.0)
-    sg = seg_of_bins(b, capacity)
+    # a padding row's bins lie past the plane's end by themselves; its
+    # anchors would land in the next anchor's rows
+    flat = r * capacity + b
+    flat_seg = jnp.where(r < num_series,
+                         seg_of_bins(b, capacity) * num_series + r,
+                         temp.seg_w.shape[0])
     temp = temp._replace(
-        sum_w=temp.sum_w.at[r, b].add(w, mode="drop"),
-        sum_wm=temp.sum_wm.at[r, b].add(w * vz, mode="drop"),
-        seg_w=temp.seg_w.at[r, sg].add(w, mode="drop"),
-        seg_wm=temp.seg_wm.at[r, sg].add(w * vz, mode="drop"),
+        sum_w=temp.sum_w.at[flat].add(w, mode="drop"),
+        sum_wm=temp.sum_wm.at[flat].add(w * vz, mode="drop"),
+        seg_w=temp.seg_w.at[flat_seg].add(w, mode="drop"),
+        seg_wm=temp.seg_wm.at[flat_seg].add(w * vz, mode="drop"),
     )
     if not update_stats:
         return temp
@@ -846,9 +953,8 @@ def ingest_chunk_guarded(digest: TDigest, temp: TempCentroids,
     drain — they are interval aggregates, only the BINS move into the
     digest. Returns (digest, temp). ``use_pallas=False`` keeps the
     guard drain off the Pallas kernel (compute-breaker degradation)."""
-    num_series = temp.sum_w.shape[0]
-    pred = shift_pred(temp.seg_w, temp.seg_wm, rows, values, weights,
-                      num_series)
+    pred = shift_pred(*temp.anchors(), rows, values, weights,
+                      temp.num_series)
 
     def do_drain(args):
         d, t = args
@@ -872,6 +978,9 @@ def ingest_chunk_guarded(digest: TDigest, temp: TempCentroids,
 # staged centroids (PERF.md, PR 33), a dispatch by the host's clock at
 # 256 / 1,024 / 4,096 rows: a merging chunk of 256 rows 55.1 / 53.1 /
 # 54.5 ms, lone centroids over 2,048 held rows 55.4 / 52.2 / 53.8.
+# Again with the temp's planes flat (PERF.md, PR 34), 128 / 256 / 512 /
+# 1,024 rows: a merging chunk of 256 rows 13.8 / 13.7 / 14.1 / 14.0 ms,
+# lone centroids over 16,384 held rows 65.0 / 64.9 / 64.7 / 55.7.
 ROW_DRAIN_SLAB_ROWS = 1024
 
 
@@ -903,11 +1012,11 @@ def ingest_centroids_rowdrained(digest: TDigest, temp: TempCentroids,
     Imported centroids feed percentiles only, never the local scalar
     stats (samplers.go:473-480). Returns (digest, temp, drained): the
     last is 1 where any row was drained, as an int32 scalar."""
-    num_series = temp.sum_w.shape[0]
+    num_series, k = temp.num_series, temp.capacity
     rows = rows.astype(jnp.int32)
     slab = min(ROW_DRAIN_SLAB_ROWS, rows.shape[0])
     inb = (rows < num_series) & (weights > 0)
-    held = temp.seg_w[jnp.minimum(rows, num_series - 1)].sum(axis=1)
+    held = temp.anchors()[0].sum(axis=1)[jnp.minimum(rows, num_series - 1)]
     # one entry a row: the sorted candidates' run starts, sorted once
     # more to the front; the rest carry the sentinel the scatters drop
     cand = jnp.sort(jnp.where(inb & (held > 0), rows, num_series))
@@ -916,21 +1025,22 @@ def ingest_centroids_rowdrained(digest: TDigest, temp: TempCentroids,
     count = jnp.sum(touched < num_series)
     touched = jnp.concatenate([touched, jnp.full(
         ((-rows.shape[0]) % slab,), num_series, jnp.int32)])
-    zk = jnp.zeros((slab, temp.sum_w.shape[1]), temp.sum_w.dtype)
-    za = jnp.zeros((slab, temp.seg_w.shape[1]), temp.seg_w.dtype)
+    lanes = jnp.arange(BELOW_MASS_ANCHORS, dtype=jnp.int32) * num_series
 
     def drain_slab(i, planes):
         mean, weight, sum_w, sum_wm, seg_w, seg_wm = planes
         to = lax.dynamic_slice_in_dim(touched, i * slab, slab)
         at = jnp.minimum(to, num_series - 1)
-        m, w = _merge_bins(mean[at], weight[at], sum_w[at], sum_wm[at],
-                           compression, digest.capacity, use_pallas)
+        sum_w, sum_wm, t_w, t_wm = take_bin_rows(
+            sum_w, sum_wm, at, jnp.minimum(count - i * slab, slab), k)
+        m, w = _merge_bins(mean[at], weight[at], t_w, t_wm, compression,
+                           digest.capacity, use_pallas)
+        to_a = jnp.where(to[:, None] < num_series, lanes + to[:, None],
+                         seg_w.shape[0])
         return (mean.at[to].set(m, mode="drop"),
-                weight.at[to].set(w, mode="drop"),
-                sum_w.at[to].set(zk, mode="drop"),
-                sum_wm.at[to].set(zk, mode="drop"),
-                seg_w.at[to].set(za, mode="drop"),
-                seg_wm.at[to].set(za, mode="drop"))
+                weight.at[to].set(w, mode="drop"), sum_w, sum_wm,
+                seg_w.at[to_a].set(0.0, mode="drop"),
+                seg_wm.at[to_a].set(0.0, mode="drop"))
 
     mean, weight, sum_w, sum_wm, seg_w, seg_wm = lax.fori_loop(
         0, (count + slab - 1) // slab, drain_slab,
@@ -975,8 +1085,8 @@ def drain_temp(state: TDigest, temp: TempCentroids,
     """Merge the accumulated temp centroids into the digests (one compress
     per interval — the batched mergeAllTemps). ``use_pallas=False``
     forces the sort-based XLA path (compute-breaker fallback rung)."""
-    new_mean, new_weight = _merge_bins(state.mean, state.weight, temp.sum_w,
-                                       temp.sum_wm, compression,
+    new_mean, new_weight = _merge_bins(state.mean, state.weight,
+                                       *temp.bins(), compression,
                                        state.capacity, use_pallas)
     return TDigest(
         mean=new_mean,
@@ -1010,13 +1120,14 @@ def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
 
     slab = tdigest_pallas._FLUSH_SLAB_ROWS
     qs = jnp.asarray(qs, state.mean.dtype)
-    if n is None or state.mean.shape[0] <= slab:
-        return _drain_and_quantile_rows(state, temp, dmin, dmax, qs,
-                                        compression, use_pallas)
-    rows = state.mean.shape[0]
+    rows, k = state.mean.shape
+    if n is None or rows <= slab:
+        return _drain_and_quantile_rows(
+            state, *temp.bins(), temp.vmin, temp.vmax, dmin, dmax, qs,
+            compression, use_pallas)
     # the slab's inputs: temp's anchors and scalar stats are not read
     planes = (state.mean, state.weight, state.min, state.max)
-    reads = (temp.sum_w, temp.sum_wm, temp.vmin, temp.vmax, dmin, dmax)
+    reads = (temp.vmin, temp.vmax, dmin, dmax)
 
     def one_slab(i, carry):
         # a capacity that is no multiple of the slab ends in a slab
@@ -1025,11 +1136,11 @@ def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
         start = jnp.minimum(i * slab, rows - slab)
         cut = lambda x: lax.dynamic_slice_in_dim(x, start, slab, 0)
         mean, weight, mn, mx = (cut(x) for x in carry[:4])
-        sum_w, sum_wm, vmin, vmax, imin, imax = (cut(x) for x in reads)
         drained, pcts = _drain_and_quantile_rows(
             TDigest(mean, weight, mn, mx),
-            temp._replace(sum_w=sum_w, sum_wm=sum_wm, vmin=vmin, vmax=vmax),
-            imin, imax, qs, compression, use_pallas)
+            bin_rows(temp.sum_w, start, slab, k),
+            bin_rows(temp.sum_wm, start, slab, k),
+            *(cut(x) for x in reads), qs, compression, use_pallas)
         new = tuple(drained) + (pcts,)
         if rows % slab:
             fresh = start + jnp.arange(slab) >= i * slab
@@ -1057,29 +1168,31 @@ def flush_rows_run(rows: int, n: int) -> int:
     return min(-(-n // slab) * slab, rows)
 
 
-def _drain_and_quantile_rows(state: TDigest, temp: TempCentroids, dmin,
-                             dmax, qs: jax.Array, compression: float,
+def _drain_and_quantile_rows(state: TDigest, sum_w, sum_wm, vmin, vmax,
+                             dmin, dmax, qs: jax.Array, compression: float,
                              use_pallas: bool):
-    """``drain_and_quantile`` over every row it is given."""
+    """``drain_and_quantile`` over every row it is given: ``[R, K]``
+    digests with their ``[R, K]`` temp bins and ``[R]`` extrema."""
     from veneur_tpu.ops import tdigest_pallas
 
-    mn = jnp.minimum(jnp.minimum(state.min, temp.vmin), dmin)
-    mx = jnp.maximum(jnp.maximum(state.max, temp.vmax), dmax)
+    mn = jnp.minimum(jnp.minimum(state.min, vmin), dmin)
+    mx = jnp.maximum(jnp.maximum(state.max, vmax), dmax)
     if use_pallas and tdigest_pallas.pallas_ok(state.mean):
-        t_live = temp.sum_w > 0
+        t_live = sum_w > 0
         t_mean = jnp.where(
-            t_live, temp.sum_wm / jnp.where(t_live, temp.sum_w, 1.0),
-            jnp.inf)
+            t_live, sum_wm / jnp.where(t_live, sum_w, 1.0), jnp.inf)
         # external sort + presorted kernel: measured faster than sort_b
-        # (see drain_temp)
-        t_mean, t_w = lax.sort((t_mean, temp.sum_w), dimension=-1,
+        # (see _merge_bins)
+        t_mean, t_w = lax.sort((t_mean, sum_w), dimension=-1,
                                num_keys=1, is_stable=False)
         nm, nw, pcts = tdigest_pallas.drain_quantile(
             state.mean, state.weight, t_mean, t_w, mn, mx, qs, compression,
             state.capacity)
         return TDigest(mean=nm, weight=nw, min=mn, max=mx), pcts
-    drained = drain_temp(state, temp, compression, use_pallas=use_pallas)
-    drained = drained._replace(min=mn, max=mx)
+    new_mean, new_weight = _merge_bins(state.mean, state.weight, sum_w,
+                                       sum_wm, compression, state.capacity,
+                                       use_pallas)
+    drained = TDigest(mean=new_mean, weight=new_weight, min=mn, max=mx)
     return drained, quantile(drained, qs)
 
 
