@@ -11,7 +11,7 @@ as no backend has been initialized yet.
 ``VENEUR_TPU_TESTS=1`` inverts the gate: the CPU forcing is skipped so
 jax picks the real accelerator, and ONLY ``@pytest.mark.tpu`` tests run
 (the kernels' hardware smoke subset, ``tests/test_tpu_smoke.py``; the
-served path's proof on the chip is ``chip_smoke.py`` at the repo root).
+served path's proof on the chip is a cell of ``benchmark/run.py``).
 
 ``VENEUR_MULTIDEVICE_TESTS=1`` opts into the ``@pytest.mark.multidevice``
 lane: fleet-scale tests that NEED the 8-device virtual mesh and more

@@ -5,7 +5,8 @@ The TPU's compiler is installed here and compiles for a topology that is
 described, not attached: what it refuses — a misaligned slice, too much
 fast memory, a kernel that cannot be partitioned, a program that does
 not fit 16 GB — it refuses before any chip time is spent. Nothing runs,
-so this says nothing about results or times; ``chip_smoke.py`` does.
+so this says nothing about results or times; a cell of
+``benchmark/run.py`` on the chip does.
 
 Only one process may load the TPU's library, so the topology is
 described inside a module-scoped fixture (never at import time, not

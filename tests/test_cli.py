@@ -1,10 +1,12 @@
-"""CLI tests: veneur-emit packet builders + live round trip, and the
-veneur-prometheus exposition parser/translator.
+"""CLI tests: veneur-emit packet builders + live round trip, the
+veneur-prometheus exposition parser/translator, and where the server's
+entry point places the compile cache.
 
 Ports the emit packet-builder tests (cmd/veneur-emit/main_test.go) and
 the prometheus translation semantics (cmd/veneur-prometheus/main.go).
 """
 
+import os
 import re
 import socket
 import time
@@ -13,6 +15,8 @@ import pytest
 
 from veneur_tpu.cli import emit, prometheus
 from veneur_tpu.protocol.gen.ssf import sample_pb2
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def parse_args(argv):
@@ -159,3 +163,35 @@ class TestPrometheusTranslation:
     def test_prefix(self):
         pkts = self.run(prefix="veneur")
         assert any(p.startswith(b"veneur.temperature:") for p in pkts)
+
+
+class TestCompileCachePlacement:
+    @pytest.fixture()
+    def restore(self):
+        import jax
+
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_sets_nothing_when_the_variable_is_set(self, monkeypatch,
+                                                   restore):
+        import jax
+
+        from veneur_tpu.cli.server import place_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        was = jax.config.jax_compilation_cache_dir
+        assert place_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == was
+
+    def test_names_the_fixed_in_checkout_path(self, monkeypatch, restore):
+        import jax
+
+        from veneur_tpu.cli.server import place_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(ROOT, ".jax_cache")
+        assert place_compile_cache() == want
+        assert place_compile_cache() == want  # no pid, no time, no temp
+        assert jax.config.jax_compilation_cache_dir == want

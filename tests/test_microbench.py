@@ -5,7 +5,8 @@ native batch parse, columnar Datadog serialize+deflate, and native
 MetricList decode. Like the Go benches they record numbers rather than
 assert thresholds (CI hosts vary) — each test prints ns/op and asserts
 only that the op ran; `python -m pytest tests/test_microbench.py -s`
-shows the table. bench.py remains the system-level suite.
+shows the table. The system-level numbers are the benchmark's cells
+(``benchmark/run.py``), taken on the chip.
 """
 
 import time
@@ -76,7 +77,7 @@ def test_bench_scalar_tdigest_add_quantile():
 def test_bench_batched_kernel_ops():
     """The batched XLA path those scalar walks are replaced by: per-series
     cost of one full drain+quantile over 4096 series (CPU here; the TPU
-    numbers live in bench.py)."""
+    numbers are ``flush_digests.device_s`` in the benchmark's cells)."""
     import jax.numpy as jnp
 
     from veneur_tpu.ops import tdigest as td_ops

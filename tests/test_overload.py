@@ -337,6 +337,25 @@ class TestComputeLadder:
         assert any(m.name == "lat.count" for m in final)
 
 
+def test_rung_reads_xla_on_the_cpu():
+    """``rung`` says ``pallas`` only when the kernel was admitted into
+    the program; off the TPU the same dispatch is the XLA program."""
+    import time
+
+    from veneur_tpu.obs import recorder as obs_rec
+
+    store = MetricStore(initial_capacity=32, chunk=128)
+    assert store.compute.snapshot()["last_rung"] is None
+    for v in (1.0, 2.0, 3.0):
+        store.process_metric(parse_metric(b"lat:%f|h" % v))
+    rec = obs_rec.StageRecorder()
+    with obs_rec.activate(rec), rec.stage("store.histograms"):
+        store.flush([0.5], HistogramAggregates.from_names(["count"]),
+                    is_local=False, now=int(time.time()))
+    assert store.compute.snapshot()["last_rung"] == "xla"
+    assert store.compute.fallback_total == 0
+
+
 class TestOverloadSamples:
     def test_emitted_names_and_deltas(self, fake_clock):
         from veneur_tpu import flusher

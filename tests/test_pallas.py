@@ -1,9 +1,11 @@
 """The fused Pallas compress kernel vs the XLA compress.
 
 Runs the kernel in interpreter mode (no TPU in CI; the real lowering is
-exercised on hardware by bench.py), asserting the merge of two sorted
-centroid lists produces a digest whose mass is exact and whose quantiles
-agree with the sort-based XLA `_compress` within the t-digest tolerance.
+compiled by ``tests/test_chip_compile.py`` and run on hardware by the
+benchmark's cells, which require rung ``pallas``), asserting the merge
+of two sorted centroid lists produces a digest whose mass is exact and
+whose quantiles agree with the sort-based XLA `_compress` within the
+t-digest tolerance.
 The only sanctioned deviation is the kernel's polynomial asin
 (|err| <= 6.8e-5 rad), which can shift bin edges by < 0.003 of a bin.
 """

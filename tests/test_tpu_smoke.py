@@ -2,9 +2,10 @@
 normally run on the virtual CPU mesh, executed on the REAL accelerator.
 
 Run it with ``VENEUR_TPU_TESTS=1`` in a process that may hold the chip.
-It checks the kernels alone; ``chip_smoke.py`` at the repo root is the
-proof that the served path (``veneur_tpu.cli.server``, UDP in, sink
-out) is right on the chip, with one process per chip. Accuracy bounds match the reference's own test envelopes
+It checks the kernels alone; the proof that the served path
+(``veneur_tpu.cli.server``, UDP or forwards in, sink out) is right on
+the chip is a cell of ``benchmark/run.py``, with one process per chip.
+Accuracy bounds match the reference's own test envelopes
 (t-digest eps=.02 over 100k uniform samples, histo_test.go:11-25; HLL
 ~2% at precision 14)."""
 

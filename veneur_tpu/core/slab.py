@@ -278,9 +278,9 @@ def _flush_slab(digest: DigestSlab, temp: TempSlab, qs, slab: int,
 def _pack_slab(mean_flat, weight_flat, dmin, dmax, slab: int, k: int):
     """Compact + quantize one slab's drained digest planes ON DEVICE so
     the forward path never fetches raw f32 ``[S, K]`` planes (the 881 MB
-    device→host transfer that blew the flush interval at 1M series —
-    VERDICT round-3 weak #1; the reference forwards at fleet cardinality
-    every interval, flusher.go:292-473).
+    device→host transfer that blew the flush interval at 1M series;
+    the reference forwards at fleet cardinality every interval,
+    flusher.go:292-473).
 
     Means quantize to uint16 against the row's [dmin, dmax] span
     (absolute error ≤ span/65535 — orders of magnitude inside the

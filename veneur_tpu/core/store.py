@@ -1926,8 +1926,8 @@ class PackedDigestPlanes(NamedTuple):
     centroids, 4 bytes each (u16 range-quantized mean + u16 bfloat16
     weight bits), produced on device by ``core/slab.py:_pack_slab`` so
     a million-series forward never fetches raw ``[S, K]`` f32 planes
-    (VERDICT round-3 weak #1; reference forwards at fleet cardinality
-    every interval, flusher.go:292-473). Row r owns
+    (the reference forwards at fleet cardinality every interval,
+    flusher.go:292-473). Row r owns
     ``means_q[starts[r]:starts[r]+counts[r]]`` with
     ``mean = dmin[r] + q/65535 * (dmax[r]-dmin[r])``."""
 

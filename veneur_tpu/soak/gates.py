@@ -223,7 +223,7 @@ def run_gates(scenario: SoakScenario, monitor: SteadyStateMonitor,
 
 
 def gate_vector(results: List[GateResult]) -> dict:
-    """The machine-checked gate vector (lands in BENCH_rNN.json)."""
+    """The machine-checked gate vector (``SoakReport.vector``)."""
     return {
         "all_ok": all(r.ok for r in results),
         "gates": {r.name: {"ok": r.ok, "value": r.value,
