@@ -64,6 +64,13 @@ def mesh(topo):
 
 
 @pytest.fixture(scope="module")
+def mesh_series4(topo):
+    from veneur_tpu.parallel.mesh import fleet_mesh
+
+    return fleet_mesh(topo.devices, hosts=1)
+
+
+@pytest.fixture(scope="module")
 def kernel_admitted():
     """``pallas_ok`` asks ``jax.default_backend()``, which is the CPU
     here: steer it in the test, as the ops would answer on the chip."""
@@ -165,26 +172,34 @@ def test_flush_loop_updates_the_planes_in_place(one_chip_flush):
     holds the kernel, works on a slab and writes it back into the
     carried planes (the output planes alias the donated digest's; a
     copy of a whole plane a trip would eat the gain)."""
+    body_text = _flush_loop_body(one_chip_flush.as_text(),
+                                 [f"f32[{ROWS},{K}]"])
+    updates = [ln for ln in body_text.splitlines()
+               if " dynamic-update-slice(" in ln and f"f32[{ROWS},{K}]" in ln]
+    assert len(updates) == 2  # digest mean and weight
+    planes = 2 * ROWS * K * 4
+    assert one_chip_flush.memory_analysis().alias_size_in_bytes >= planes
+
+
+def _flush_loop_body(text, wholes):
+    """The body of a flush program's one loop: it holds the kernel,
+    works on a slab, and copies or transposes none of the ``wholes``
+    (shapes of whole planes, as the HLO prints them)."""
     import re
 
     from veneur_tpu.ops.tdigest_pallas import _FLUSH_SLAB_ROWS
 
-    text = one_chip_flush.as_text()
     assert len(re.findall(r" while\(", text)) == 1
     body = re.search(r"body=%?([\w.\-]+)", text).group(1)
     start = text.index("%" + body + " (")  # the body's definition
     body_text = text[start:text.index("\n}", start)]
     assert "tpu_custom_call" in body_text
     assert f"f32[{_FLUSH_SLAB_ROWS},{K}]" in body_text
-    whole = f"f32[{ROWS},{K}]"
     for line in body_text.splitlines():
         if re.search(r"= \S+ (copy|transpose)\(", line):
-            assert whole not in line.split("=")[1].split("(")[0], line
-    updates = [ln for ln in body_text.splitlines()
-               if " dynamic-update-slice(" in ln and whole in ln]
-    assert len(updates) == 2  # digest mean and weight
-    planes = 2 * ROWS * K * 4
-    assert one_chip_flush.memory_analysis().alias_size_in_bytes >= planes
+            for whole in wholes:
+                assert whole not in line.split("=")[1].split("(")[0], line
+    return body_text
 
 
 def test_hll_insert_and_estimate(one_chip):
@@ -219,7 +234,8 @@ def test_mesh_programs_shard_state_over_series(one_chip_flush, mesh,
     m_rows = _f32((ROWS,), named(s))
 
     flush = _mesh_flush_digests.lower(
-        m_digest, m_temp, m_rows, m_rows, _f32((4,), named(P())), mesh,
+        m_digest, m_temp, m_rows, m_rows, _f32((4,), named(P())),
+        _i32((mesh.shape["series"],), named(s)), mesh,
         COMPRESSION).compile()
     assert "tpu_custom_call" in flush.as_text()
     per_device = flush.memory_analysis().argument_size_in_bytes
@@ -240,7 +256,58 @@ def test_mesh_programs_shard_state_over_series(one_chip_flush, mesh,
             <= 0.55 * state_bytes + (8 << 20))
 
 
-def test_global_at_deployment_size_fits_a_chip(topo, kernel_admitted):
+DEPLOYED_ROWS = 1 << 22  # global-fanin64's store_initial_capacity
+
+
+def _deployed_state(mesh):
+    """Shapes of ``global-fanin64``'s digest state on the series-4 mesh:
+    (digest, temp, a ``[rows]`` plane, bytes of a device's quarter)."""
+    from veneur_tpu.core.mesh_store import _digest_specs
+
+    temp_spec, dig_spec, _sk, s = _digest_specs()
+    named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    digest, temp = _digest_state(DEPLOYED_ROWS)
+    quarter = (_nbytes(digest) + _nbytes(temp)) // 4
+    return (_on(digest, jax.tree.map(named, dig_spec)),
+            _on(temp, jax.tree.map(named, temp_spec)),
+            _f32((DEPLOYED_ROWS,), named(s)), quarter)
+
+
+@pytest.fixture(scope="module")
+def deployed_flush(mesh_series4, kernel_admitted):
+    """``_mesh_flush_digests`` at ``global-fanin64``'s size on four
+    described chips (two tests read it; it compiles once)."""
+    from veneur_tpu.core.mesh_store import _mesh_flush_digests
+
+    mesh = mesh_series4
+    m_digest, m_temp, m_rows, _ = _deployed_state(mesh)
+    return _mesh_flush_digests.lower(
+        m_digest, m_temp, m_rows, m_rows,
+        _f32((4,), NamedSharding(mesh, P())),
+        _i32((4,), NamedSharding(mesh, P("series"))), mesh,
+        COMPRESSION).compile()
+
+
+def test_mesh_flush_loop_runs_a_shards_live_rows(deployed_flush):
+    """The sharded global's flush for the chip (PERF.md, PR 36): every
+    shard runs one loop over the slabs that hold its own live rows. The
+    body holds the kernel on a slab, relays neither a digest plane nor
+    a flat bin plane of the block (the straight-line program took both
+    bin planes ``[S, K]`` whole: 6 ms a flush), the program holds no
+    collective, so each shard's trip count is its own, and the donated
+    digest planes are updated in place."""
+    block = DEPLOYED_ROWS // 4
+    text = deployed_flush.as_text()
+    _flush_loop_body(text, [f"f32[{block},{K}]", f"f32[{block * K}]"])
+    for collective in ("all-reduce", "all-gather", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text
+    assert (deployed_flush.memory_analysis().alias_size_in_bytes
+            >= 2 * block * K * 4)
+
+
+def test_global_at_deployment_size_fits_a_chip(mesh_series4, deployed_flush,
+                                               kernel_admitted):
     """``global-fanin64`` as it is deployed: 2^22 digest rows over the
     four chips of a host (series 4 x hosts 1). The planes are made in
     shards, the routed import (with its row-local drain, the kernel
@@ -248,22 +315,15 @@ def test_global_at_deployment_size_fits_a_chip(topo, kernel_admitted):
     for more than its quarter of the state and a chunk's worth beside
     it. The import holds no collective: two threads dispatch it and
     the flush."""
-    from veneur_tpu.core.mesh_store import (_digest_specs,
-                                            _mesh_flush_digests,
-                                            _mesh_import_routed,
+    from veneur_tpu.core.mesh_store import (_mesh_import_routed,
                                             _mesh_init_digests)
-    from veneur_tpu.parallel.mesh import fleet_mesh
 
-    rows = 1 << 22
-    mesh = fleet_mesh(topo.devices, hosts=1)
+    rows = DEPLOYED_ROWS
+    mesh = mesh_series4
     assert dict(mesh.shape) == {"series": 4, "hosts": 1}
-    temp_spec, dig_spec, _sk, s = _digest_specs()
     named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
-    digest, temp = _digest_state(rows)
-    quarter = (_nbytes(digest) + _nbytes(temp)) // 4
-    m_digest = _on(digest, jax.tree.map(named, dig_spec))
-    m_temp = _on(temp, jax.tree.map(named, temp_spec))
-    m_rows = _f32((rows,), named(s))
+    s = P("series")
+    m_digest, m_temp, m_rows, quarter = _deployed_state(mesh)
 
     init = _mesh_init_digests.lower(mesh, rows, K, COMPRESSION).compile()
     made = init.memory_analysis()
@@ -287,10 +347,7 @@ def test_global_at_deployment_size_fits_a_chip(topo, kernel_admitted):
     # donated planes are updated in place; what is left is chunk-sized
     assert held.temp_size_in_bytes <= 2 * quarter
 
-    flush = _mesh_flush_digests.lower(
-        m_digest, m_temp, m_rows, m_rows, _f32((4,), named(P())), mesh,
-        COMPRESSION).compile()
-    assert (flush.memory_analysis().argument_size_in_bytes
+    assert (deployed_flush.memory_analysis().argument_size_in_bytes
             <= quarter + (16 << 20))
 
 
@@ -436,13 +493,6 @@ def _plane_traffic(text, plane):
             else:
                 others.append(line)
     return loops, copies, others
-
-
-@pytest.fixture(scope="module")
-def mesh_series4(topo):
-    from veneur_tpu.parallel.mesh import fleet_mesh
-
-    return fleet_mesh(topo.devices, hosts=1)
 
 
 @pytest.mark.parametrize("program", ["_ingest_samples", "_ingest_centroids",

@@ -12,6 +12,7 @@ Runs on the conftest-forced 8-device virtual CPU mesh.
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -217,6 +218,195 @@ class TestMeshGlobalServerE2E:
                     assert abs(got - exact) / span < 0.10, (i, q, got, exact)
         finally:
             gserver.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the flush runs each shard's live rows (PERF.md, PR 36)
+# ---------------------------------------------------------------------------
+
+BLOCK = 4096            # a shard's rows: two of the flush loop's slabs
+FLUSH_QS = [0.5, 0.75, 0.99, 0.5]
+
+
+class _NamedShards:
+    """A router that reads a series' shard off its name (``s.<shard>.<i>``):
+    the fills of a test are then the test's to choose."""
+
+    def shard_for(self, name, mtype, joined_tags):
+        return int(name.split(".")[1])
+
+
+def _routed_rows(group, fills):
+    """Intern ``fills[s]`` series on shard ``s``, the shards taking
+    turns, so that physical rows are no prefix of the logical ones."""
+    left = list(fills)
+    while any(left):
+        for shard, more in enumerate(left):
+            if more:
+                i = left[shard] = more - 1
+                group._row(p.MetricKey(name=f"s.{shard}.{i}",
+                                       type="histogram"), [])
+    return np.arange(sum(fills))
+
+
+def _feed(group, rows, seed):
+    """Local samples on every row of ``rows`` and a forwarded digest on
+    every fifth (rows as the group's staging takes them)."""
+    rng = np.random.default_rng(seed)
+    for _ in range(2):
+        group.sample_many(rows.astype(np.int32),
+                          rng.normal(100, 10, len(rows)).astype(np.float32),
+                          np.ones(len(rows), np.float32))
+    held = rows[::5].astype(np.int32)
+    means = np.sort(rng.normal(90, 20, (len(held), 16)).astype(np.float32))
+    group.import_centroids_bulk(
+        np.repeat(held, 16), means.reshape(-1),
+        np.ones(means.size, np.float32), held, means[:, 0], means[:, -1])
+    group._drain_staging()
+
+
+def _group(mesh, source, fills):
+    """A loaded ``MeshDigestGroup`` whose shards hold ``fills`` live
+    rows, placed by ``source``; returns it with its live-row count."""
+    from veneur_tpu.core.mesh_store import MeshDigestGroup
+
+    block = np.arange(4) * BLOCK
+    if source == "router":
+        g = MeshDigestGroup(mesh, 4 * BLOCK, 4096, 100.0,
+                            router=_NamedShards())
+        rows = _routed_rows(g, fills)
+    elif source == "grown":
+        # the blocks double mid-interval, with rows held and staged
+        g = MeshDigestGroup(mesh, 2 * BLOCK, 4096, 100.0,
+                            router=_NamedShards())
+        early = [min(f, BLOCK // 2) for f in fills]
+        _feed(g, _routed_rows(g, early), 3)
+        for shard, f in enumerate(fills):
+            for i in range(early[shard], f):
+                g._row(p.MetricKey(name=f"s.{shard}.late{i}",
+                                   type="histogram"), [])
+        assert g.capacity == 4 * BLOCK
+        rows = np.arange(sum(early), sum(fills))
+    elif source == "bank":
+        # slot mode: the owner hands out each block's slots in order
+        g = MeshDigestGroup(mesh, 4 * BLOCK, 4096, 100.0)
+        rows = np.concatenate([block[s] + np.arange(f)
+                               for s, f in enumerate(fills)])
+        rows = np.random.default_rng(5).permutation(rows)
+        g._ext_rows = rows.astype(np.int64)
+    else:
+        # no router: logical rows are the physical ones, in order
+        g = MeshDigestGroup(mesh, 4 * BLOCK, 4096, 100.0)
+        rows = np.arange(sum(fills))
+    _feed(g, rows, 4)
+    return g, sum(fills)
+
+
+@jax.jit
+def _straight_line(digest, temp, dmin, dmax, qs):
+    """The flush over every reserved row, on one device."""
+    from veneur_tpu.ops import tdigest as td_ops
+
+    return td_ops.drain_and_quantile(digest, temp, dmin, dmax, qs, 100.0)
+
+
+class TestMeshFlushLiveRows:
+    """``_mesh_flush_digests`` handed the shards' fills against the
+    straight-line program over every reserved row: the same bits on
+    every live row, and nothing touched past a fill."""
+
+    @pytest.mark.parametrize("source,fills", [
+        ("router", (0, 1, 2047, 2049)),
+        ("router", (BLOCK, 2049, 0, 1)),
+        ("bank", (2049, 0, BLOCK, 2047)),
+        ("direct", (BLOCK, 2049, 0, 0)),
+        ("direct", (2047, 0, 0, 0)),
+        ("grown", (2049, 1, 0, 2047)),
+    ])
+    def test_live_rows_bit_for_bit(self, mesh, source, fills):
+        from veneur_tpu.core.mesh_store import _mesh_flush_digests
+
+        g, n = _group(mesh, source, fills)
+        assert tuple(int(f) for f in g._shard_fills(n)) == fills
+        state = jax.tree.map(np.asarray,
+                             (g.digest, g.temp, g.dmin, g.dmax))
+        qs = np.asarray(FLUSH_QS, np.float32)
+        want_digest, want_pcts = jax.tree.map(
+            np.asarray, _straight_line(*state, qs))
+        got = jax.tree.map(np.asarray, _mesh_flush_digests(
+            g.digest, g.temp, g.dmin, g.dmax, jnp.asarray(qs),
+            g._per_shard(fills), mesh, 100.0))
+        local = np.arange(4 * BLOCK) % BLOCK
+        live = local < np.repeat(fills, BLOCK)
+        # the gather's rows are live rows, every one of them once
+        perm = g._flush_rows(n)
+        assert live[perm].all() and len(set(perm.tolist())) == n == live.sum()
+        # rows the loop ran: the slabs that hold a shard's live rows
+        ran = local < np.repeat([-(-f // 2048) * 2048 for f in fills], BLOCK)
+        got_digest, got_pcts = got[0], got[1]
+        for name in ("mean", "weight", "min", "max"):
+            have, want = getattr(got_digest, name), getattr(want_digest,
+                                                            name)
+            assert np.array_equal(have[ran], want[ran]), name
+            # past the last slab run a row is left as it was
+            was = getattr(state[0], name)
+            assert np.array_equal(have[~ran], was[~ran]), name
+        # (an empty row of a slab that ran reads NaN on both sides)
+        assert np.array_equal(got_pcts[ran], want_pcts[ran], equal_nan=True)
+        assert not got_pcts[~ran].any()
+        assert (got_pcts[live] > 0).all()
+        for have, name in zip(got[2:], ("count", "vsum", "vmin", "vmax",
+                                        "recip")):
+            assert np.array_equal(have, getattr(state[1], name)), name
+
+    def test_group_flush_reads_what_the_whole_planes_give(self, mesh):
+        """Through the group: the emitted percentiles and the drained
+        digests of a routed interval, in interner order."""
+        fills = (2049, 1, 0, 2047)
+        g, n = _group(mesh, "router", fills)
+        state = jax.tree.map(np.asarray,
+                             (g.digest, g.temp, g.dmin, g.dmax))
+        perm = g._flush_rows(n)
+        want_digest, want_pcts = jax.tree.map(np.asarray, _straight_line(
+            *state, np.asarray(FLUSH_QS, np.float32)))
+        _, out = g.flush(FLUSH_QS[:3])
+        assert np.array_equal(out["percentiles"], want_pcts[perm, :3])
+        assert np.array_equal(out["median"], want_pcts[perm, 3])
+        assert np.array_equal(out["digest_mean"], want_digest.mean[perm])
+        assert np.array_equal(out["digest_weight"],
+                              want_digest.weight[perm])
+        assert np.array_equal(out["count"], state[1].count[perm])
+
+    def test_warm_import_and_a_first_flush_are_one_program(self, mesh):
+        """The fills go in under ``warm_import``'s signature: an import
+        and the interval's first flush compile nothing more, so the
+        import cell's ``start.compiles_in_window`` stays 0."""
+        from veneur_tpu.core.mesh_store import (MeshDigestGroup,
+                                                _mesh_flush_digests,
+                                                _mesh_import_routed)
+        from veneur_tpu.fleet.router import ShardRouter
+
+        # a shape no other test of this process compiles
+        g = MeshDigestGroup(mesh, 4 * 3072, 1024, 100.0,
+                            router=ShardRouter(4))
+        flushes = _mesh_flush_digests._cache_size()
+        imports = _mesh_import_routed._cache_size()
+        g.warm_import(FLUSH_QS[:3])
+        assert _mesh_flush_digests._cache_size() == flushes + 1
+        assert _mesh_import_routed._cache_size() == imports + 1
+        assert "temp" not in g.__dict__  # warmed, and holding nothing
+        means = np.sort(np.random.default_rng(1).normal(
+            50, 5, 32).astype(np.float32))
+        for i in range(40):
+            g.import_centroids(
+                p.MetricKey(name=f"warm.h{i}", type="histogram"), [],
+                means, np.ones(32, np.float32), float(means[0]),
+                float(means[-1]))
+        _, out = g.flush(FLUSH_QS[:3])
+        assert out["median"] == pytest.approx(np.full(40, np.median(means)),
+                                              rel=0.02)
+        assert _mesh_flush_digests._cache_size() == flushes + 1
+        assert _mesh_import_routed._cache_size() == imports + 1
 
 
 @pytest.mark.multidevice

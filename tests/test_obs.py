@@ -260,6 +260,47 @@ class TestServerTimeline:
             "live": sum(s["rows_live"] for s in compute.values()),
             "run": sum(s["rows_run"] for s in compute.values())}
 
+    def test_mesh_flush_rows_live_and_run(self):
+        """A mesh group's flush says the same: rows_run is the sum of
+        the slabs each shard ran for its own fill, not the rows
+        reserved (what benchmark metric mesh_flush.rows_run reads)."""
+        from veneur_tpu.config import Config
+        from veneur_tpu.core.mesh_store import MeshDigestGroup
+        from veneur_tpu.ops import tdigest as td_ops
+        from veneur_tpu.server import Server
+        from veneur_tpu.sinks import ChannelMetricSink
+
+        # four shards of two slabs each
+        cfg = Config(statsd_listen_addresses=[], interval="86400s",
+                     percentiles=[0.5], obs_timeline_intervals=4,
+                     store_initial_capacity=4 * 4096, store_chunk=128,
+                     mesh_enabled=True, mesh_hosts=2)
+        sink = ChannelMetricSink()
+        srv = Server(cfg, metric_sinks=[sink])
+        srv.start()
+        try:
+            histo = srv.store.histograms
+            assert isinstance(histo, MeshDigestGroup)
+            for i in range(9):
+                srv.handle_metric_packet(b"mesh.to%d:3.5|h" % i)
+            fills = histo.placement.fills.copy()
+            block = histo.capacity // histo.shards
+            srv.flush()
+            sink.get_flush()
+            e = srv.obs_timeline.entries()[-1]
+        finally:
+            srv.shutdown()
+        compute = {s["name"]: s for s in e["stages"] if "rows_run" in s}
+        stage = compute["store.dispatch.histograms.compute"]
+        assert fills.sum() == 9 and block == 4096
+        assert stage["rows_live"] == 9
+        assert stage["rows_run"] == sum(
+            td_ops.flush_rows_run(block, int(f)) for f in fills)
+        assert stage["rows_run"] == 2048 * np.count_nonzero(fills)
+        assert e["digest_flush_rows"] == {
+            "live": sum(s["rows_live"] for s in compute.values()),
+            "run": sum(s["rows_run"] for s in compute.values())}
+
     def test_flush_timeline_endpoint_schema_and_bound(self, obs_server):
         srv, sink = obs_server
         for _ in range(6):  # ring holds 4 (obs_timeline_intervals)

@@ -251,25 +251,29 @@ def _mesh_import_routed(temp, digest, dmin, dmax, drains, rows, means, wts,
                                       smaxs)
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6))
-def _mesh_flush_digests(digest, temp, dmin, dmax, qs, mesh: Mesh,
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(6, 7))
+def _mesh_flush_digests(digest, temp, dmin, dmax, qs, fills, mesh: Mesh,
                         compression: float):
     """Per-interval flush: row-local compress + quantile per shard — the
     merge already happened at scatter time (a series's whole fleet
     state lives on its owning shard), so the flush itself needs no
-    collective at all."""
+    collective at all. ``fills`` ([shards] int32, an element a shard)
+    is each block's live rows: a shard's rows are handed out as a
+    prefix of its block, so each runs the slabs that hold its own
+    (``td_ops.drain_and_quantile``'s ``n``) and leaves the rest of the
+    rows reserved alone, one compiled variant whatever the fills."""
     temp_spec, dig_spec, sk, s = _digest_specs()
 
-    def local_flush(digest, temp, dmin, dmax, qs):
-        drained, pcts = td_ops.drain_and_quantile(digest, temp, dmin,
-                                                  dmax, qs, compression)
+    def local_flush(digest, temp, dmin, dmax, qs, fills):
+        drained, pcts = td_ops.drain_and_quantile(
+            digest, temp, dmin, dmax, qs, compression, n=fills[0])
         return (drained, pcts, temp.count, temp.vsum, temp.vmin,
                 temp.vmax, temp.recip)
 
     return shard_map(local_flush, mesh=mesh,
-                     in_specs=(dig_spec, temp_spec, s, s, P()),
+                     in_specs=(dig_spec, temp_spec, s, s, P(), s),
                      out_specs=(dig_spec, sk, s, s, s, s, s),
-                     check_vma=False)(digest, temp, dmin, dmax, qs)
+                     check_vma=False)(digest, temp, dmin, dmax, qs, fills)
 
 
 @jax.jit
@@ -411,6 +415,20 @@ class _PlacementMixin:
         # physical == logical
         return np.arange(n, dtype=np.int64)
 
+    def _shard_fills(self, n: int) -> np.ndarray:
+        """Live rows of every shard's block for the ``n`` logical rows
+        being flushed: within a block rows are handed out as a prefix,
+        whoever places them (the three cases of ``_flush_rows``)."""
+        if self.placement is not None:
+            return self.placement.fills
+        block = self.capacity // self.shards
+        if self._ext_rows is not None:  # bank mode: highest slot + 1
+            fills = np.zeros(self.shards, np.int64)
+            slots = np.asarray(self._ext_rows[:n], np.int64)
+            np.maximum.at(fills, slots // block, slots % block + 1)
+            return fills
+        return np.clip(n - np.arange(self.shards) * block, 0, block)
+
 
 class MeshDigestGroup(_PlacementMixin, DigestGroup):
     """A DigestGroup whose device state is sharded over a fleet mesh.
@@ -484,12 +502,18 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
                 jnp.asarray(vals), jnp.asarray(wts), self.mesh,
                 self.compression, self.k)
 
-    def _no_drains(self) -> jax.Array:
-        """The drain counter's zero, placed as the import program
-        returns it: handed over as NumPy it would be another signature,
-        and the program's second call another compile."""
-        return jax.device_put(np.zeros(self.shards, np.int32),
+    def _per_shard(self, values) -> jax.Array:
+        """A number a shard, each on its shard's devices, as the
+        programs take and return such counts: handed over as NumPy it
+        would be another signature, and the program's second call
+        another compile."""
+        return jax.device_put(np.asarray(values, np.int32),
                               NamedSharding(self.mesh, P(SERIES_AXIS)))
+
+    def _shard_zeros(self) -> jax.Array:
+        """A zero a shard: the drain counter's start, and the fills of
+        a flush of no row."""
+        return self._per_shard(np.zeros(self.shards))
 
     def _drain_imports(self):
         if self._imp_fill == 0 and self._imp_stat_fill == 0:
@@ -518,7 +542,7 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
             self.shards, self._shard_of_phys(srows), srows,
             [smins, smaxs], self.capacity, width=self.chunk)
         if self._imp_drains is None:
-            self._imp_drains = self._no_drains()
+            self._imp_drains = self._shard_zeros()
         t1 = time.monotonic_ns()
         # the stacks go in as the NumPy arrays they are: the program
         # puts each shard on its device. Through jnp.asarray they land
@@ -539,11 +563,18 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         # the sharded programs compile once per mesh; the compute
         # ladder's retry re-runs the same program here (the mesh path
         # has no separate kernel variant to fall back to). Rows are
-        # placed by shard, not as a prefix: no live-row bound here
+        # placed by shard, and inside a shard as a prefix: each shard
+        # runs its own live rows
+        fills = self._shard_fills(n)
+        block = self.capacity // self.shards
+        obs_rec.note(rows_live=n,
+                     rows_run=sum(td_ops.flush_rows_run(block, int(f))
+                                  for f in fills))
         return _mesh_flush_digests(self.digest, self.temp, self.dmin,
                                    self.dmax,
                                    jnp.asarray(qs, jnp.float32),
-                                   self.mesh, self.compression)
+                                   self._per_shard(fills), self.mesh,
+                                   self.compression)
 
     def _flush_dispatch(self, n: int, percentiles, want_digests,
                         want_stats, use_pallas: bool):
@@ -599,13 +630,13 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         with obs_kernels.scope("drain.digest.mesh"):
             temp, digest, dmin, dmax, _ = _mesh_import_routed(
                 self.temp, self.digest, self.dmin, self.dmax,
-                self._no_drains(), rows, zeros, zeros, rows, zeros, zeros,
+                self._shard_zeros(), rows, zeros, zeros, rows, zeros, zeros,
                 self.mesh, self.compression)
         with obs_kernels.scope("flush.digest.mesh"):
             out = _mesh_flush_digests(
                 digest, temp, dmin, dmax,
                 jnp.asarray(list(percentiles) + [0.5], jnp.float32),
-                self.mesh, self.compression)
+                self._shard_zeros(), self.mesh, self.compression)
         jax.block_until_ready(out)  # lint: ok(lock-across-blocking) start-up, before any listener opens: nobody waits on the lock yet
         for name in DigestGroup._DEVICE_STATE:
             self.__dict__.pop(name, None)

@@ -1108,14 +1108,16 @@ def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
     quantile.
 
     ``n`` (a traced int32 scalar) says that only rows ``[:n]`` are live —
-    the interner hands rows out as a dense prefix. The same pipeline
-    then runs slab by slab (``tdigest_pallas._FLUSH_SLAB_ROWS`` rows) in
-    a loop whose trip count is ``ceil(n / slab)``: one compiled program
-    for every ``n``, its work bounded by the interval's series and not
-    by the rows reserved. Rows past the last slab run keep their input
-    values (their percentiles read 0); nothing reads them. A batch of
-    at most one slab, or no ``n`` (the mesh's shard-routed rows are not
-    a prefix), is the straight-line program."""
+    the interner hands rows out as a dense prefix, and so does a mesh's
+    placement inside every shard's block (there ``n`` is the shard's
+    own fill). The same pipeline then runs slab by slab
+    (``tdigest_pallas._FLUSH_SLAB_ROWS`` rows) in a loop whose trip
+    count is ``ceil(n / slab)``: one compiled program for every ``n``,
+    its work bounded by the interval's series and not by the rows
+    reserved. Rows past the last slab run keep their input values
+    (their percentiles read 0); nothing reads them. A batch of at most
+    one slab, or no ``n`` (the slab bank, whose rows are no prefix), is
+    the straight-line program."""
     from veneur_tpu.ops import tdigest_pallas
 
     slab = tdigest_pallas._FLUSH_SLAB_ROWS
