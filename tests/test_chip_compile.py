@@ -351,6 +351,66 @@ def test_global_at_deployment_size_fits_a_chip(mesh_series4, deployed_flush,
             <= quarter + (16 << 20))
 
 
+def test_datagram_fed_mesh_at_deployment_size_fits_a_chip(mesh,
+                                                          kernel_admitted):
+    """``mesh4-hist1m`` as it is deployed: 2^22 digest rows on the
+    default mesh of a four-chip host (series 2 x hosts 2), every row
+    on the two chips of its hosts pair. The hosts-sharded sample
+    ingest and the flush compile for the described chips, each inside
+    a chip's memory beside the fresh generation a swap holds; the
+    ingest carries the hosts-axis collectives, the flush none. A
+    dispatch puts every plane of a fresh shard-sized temp through
+    them: 2^21 rows x 229 float32 and the guard's two masses."""
+    from veneur_tpu.core.mesh_store import (MeshDigestGroup, _digest_specs,
+                                            _mesh_flush_digests,
+                                            _mesh_ingest_samples)
+
+    rows = DEPLOYED_ROWS
+    assert dict(mesh.shape) == {"series": 2, "hosts": 2}
+    temp_spec, dig_spec, _sk, s = _digest_specs()
+    named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    digest, temp = _digest_state(rows)
+    half = (_nbytes(digest) + _nbytes(temp)) // 2  # a device's share
+    assert half == (1 << 21) * (229 + 210) * 4     # 3.68 GB
+    m_digest = _on(digest, jax.tree.map(named, dig_spec))
+    m_temp = _on(temp, jax.tree.map(named, temp_spec))
+    m_rows = _f32((rows,), named(s))
+    h = named(P("hosts"))
+
+    ingest = _mesh_ingest_samples.lower(
+        m_temp, m_digest, _i32((), named(P())), _i32((CHUNK,), h),
+        _f32((CHUNK,), h), _f32((CHUNK,), h), mesh, COMPRESSION,
+        K).compile()
+    text = ingest.as_text()
+    assert "all-reduce" in text
+    held = ingest.memory_analysis()
+    assert held.argument_size_in_bytes <= half + (16 << 20)
+    # the program's own peak beside the generation it works on, and the
+    # fresh twin a swap holds beside both: under the chip's 16 GB
+    peak = (held.argument_size_in_bytes + held.temp_size_in_bytes
+            + held.output_size_in_bytes - held.alias_size_in_bytes)
+    assert peak + half < 16e9, peak
+    # what one device sends through hosts-axis collectives a dispatch,
+    # as the timeline's mesh_ingest.collective_bytes counts it
+    group = MeshDigestGroup.__new__(MeshDigestGroup)
+    group.hosts, group.shards, group.capacity, group.k = 2, 2, rows, K
+    assert group.sample_collective_bytes() == 4 * ((1 << 21) * 229 + 2) \
+        == 1_920_991_240
+    group.hosts = 1  # series 4 x hosts 1: no hosts-axis collective
+    assert group.sample_collective_bytes() == 0
+
+    flush = _mesh_flush_digests.lower(
+        m_digest, m_temp, m_rows, m_rows, _f32((4,), named(P())),
+        _i32((2,), named(s)), mesh, COMPRESSION).compile()
+    text = flush.as_text()
+    assert "tpu_custom_call" in text
+    for collective in ("all-reduce", "all-gather", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text
+    assert (flush.memory_analysis().argument_size_in_bytes
+            <= half + (32 << 20))
+
+
 def test_dense_import_holds_the_kernel(one_chip, kernel_admitted):
     """The dense global's import at 2^20 rows on one chip: the row-local
     drain is one loop whose body holds the kernel, once, works on a

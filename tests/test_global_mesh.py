@@ -232,6 +232,8 @@ class _NamedShards:
     """A router that reads a series' shard off its name (``s.<shard>.<i>``):
     the fills of a test are then the test's to choose."""
 
+    place_ns = 0  # the router's clock of the rows placed
+
     def shard_for(self, name, mtype, joined_tags):
         return int(name.split(".")[1])
 
@@ -378,7 +380,7 @@ class TestMeshFlushLiveRows:
         assert np.array_equal(out["count"], state[1].count[perm])
 
     def test_warm_import_and_a_first_flush_are_one_program(self, mesh):
-        """The fills go in under ``warm_import``'s signature: an import
+        """The fills go in under the warm-up's signature: an import
         and the interval's first flush compile nothing more, so the
         import cell's ``start.compiles_in_window`` stays 0."""
         from veneur_tpu.core.mesh_store import (MeshDigestGroup,
@@ -391,7 +393,7 @@ class TestMeshFlushLiveRows:
                             router=ShardRouter(4))
         flushes = _mesh_flush_digests._cache_size()
         imports = _mesh_import_routed._cache_size()
-        g.warm_import(FLUSH_QS[:3])
+        g.warm(FLUSH_QS[:3], samples=False)
         assert _mesh_flush_digests._cache_size() == flushes + 1
         assert _mesh_import_routed._cache_size() == imports + 1
         assert "temp" not in g.__dict__  # warmed, and holding nothing

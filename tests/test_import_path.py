@@ -313,6 +313,8 @@ def test_import_programs_are_warmed_where_forwards_are_taken(
     with caplog.at_level(logging.INFO, logger="veneur.server"):
         server, _sink = _global(grpc_address=grpc_address)
         server.shutdown()
-    warmed = [r for r in caplog.records
-              if "mesh import programs ready" in r.getMessage()]
-    assert len(warmed) == (1 if grpc_address else 0)
+    warmed = [r.getMessage() for r in caplog.records
+              if "mesh programs ready" in r.getMessage()]
+    assert len(warmed) == 1  # every mesh warms its flush and gather
+    assert ("imports" in warmed[0]) == bool(grpc_address)
+    assert "samples" not in warmed[0]  # no datagram listener either way

@@ -679,6 +679,92 @@ class TestMergerStages:
                 lane.sock.close()
 
 
+@pytest.fixture()
+def mesh_lane_server(monkeypatch):
+    """A datagram-fed server on a 2 x 2 mesh of four of the process's
+    devices (the program builds its mesh from every visible device:
+    the test steers that)."""
+    import jax
+
+    from veneur_tpu.config import Config
+    from veneur_tpu.parallel.mesh import fleet_mesh
+    from veneur_tpu.server import Server
+    from veneur_tpu.sinks import ChannelMetricSink
+
+    monkeypatch.setattr(
+        "veneur_tpu.fleet.build_mesh",
+        lambda config: fleet_mesh(jax.devices()[:4], hosts=2))
+    cfg = Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                 num_readers=2, interval="86400s",
+                 http_address="127.0.0.1:0", percentiles=[0.5, 0.99],
+                 obs_timeline_intervals=8, store_initial_capacity=128,
+                 store_chunk=64, mesh_enabled=True,
+                 digest_storage="sharded")
+    chan = ChannelMetricSink()
+    srv = Server(cfg, metric_sinks=[chan])
+    srv.start()
+    yield srv, chan
+    srv.shutdown()
+
+
+class TestMeshSampleStages:
+    """A mesh fed datagrams: what the merger spends placing first-sight
+    rows, the host's side of the sample dispatches, the flush's gather,
+    and the counters of the retired groups' sample path."""
+
+    LINES = ([b"m.h%d:%d.5|h" % (i % 50, i) for i in range(150)]
+             + [b"m.c%d:1|c" % i for i in range(25)])
+
+    @pytest.mark.parametrize("stage", [
+        "ingest.merge.route", "ingest.dispatch.mesh",
+        "store.dispatch.histograms.gather"])
+    def test_stage_is_in_the_timeline(self, mesh_lane_server, stage):
+        srv, chan = mesh_lane_server
+        for _ in range(2):  # every series first-sight again in the second
+            send_and_merge(srv, self.LINES)
+            srv.flush()
+            chan.get_flush()
+            st = stages_of(srv.obs_timeline.entries()[-1])
+            assert st[stage]["duration_ns"] > 0
+        if stage == "ingest.merge.route":
+            assert st[stage]["off_path"] is True
+            assert st[stage]["duration_ns"] \
+                <= st["ingest.merge.remap"]["duration_ns"]
+        elif stage == "ingest.dispatch.mesh":
+            assert st[stage]["off_path"] is True
+            assert st[stage]["dispatches"] == 3  # 150 samples / 64
+        else:
+            parent = st["store.dispatch.histograms"]
+            assert parent["start_ns"] <= st[stage]["start_ns"]
+            assert (st[stage]["start_ns"] + st[stage]["duration_ns"]
+                    <= parent["start_ns"] + parent["duration_ns"])
+
+    def test_counters_of_the_sample_path(self, mesh_lane_server):
+        srv, chan = mesh_lane_server
+        send_and_merge(srv, self.LINES)
+        srv.flush()
+        chan.get_flush()
+        counted = srv.obs_timeline.entries()[-1]["mesh_ingest"]
+        assert counted["dispatches"] == 3 and counted["samples"] == 150
+        # a device's 64 rows x (2 x 104 + 2 x 8 + 5) float32 and the
+        # guard's two masses, a dispatch
+        assert counted["collective_bytes"] == 3 * 4 * (64 * 229 + 2)
+        assert counted["guard_drains"] == 0
+        names = srv.obs_timeline.entries()[-1]["stages"]
+        assert not [s for s in names if s["name"].startswith("import.")]
+
+    def test_a_store_without_a_mesh_has_none_of_them(self, lane_server):
+        srv, chan, _post = lane_server
+        send_and_merge(srv, self.LINES)
+        srv.flush()
+        chan.get_flush()
+        entry = srv.obs_timeline.entries()[-1]
+        assert "mesh_ingest" not in entry
+        assert not {"ingest.merge.route", "ingest.dispatch.mesh",
+                    "store.dispatch.histograms.gather"} & set(
+                        stages_of(entry))
+
+
 class TestDispatchAndFetchParts:
     @pytest.mark.parametrize("parent,child", [
         ("store.dispatch.histograms", "store.dispatch.histograms.drain"),

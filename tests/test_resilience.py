@@ -729,8 +729,10 @@ class TestProxyBreakers:
                             breaker_reset_timeout="60s"),
                 discoverer=StaticDiscoverer(dests))
             proxy.refresh_destinations()
+            # enough keys that no draw of the three ports leaves a
+            # destination without one (30 did, once in a few hundred)
             metrics = [{"name": f"fan.m{i}", "type": "counter",
-                        "tags": [], "value": 1} for i in range(30)]
+                        "tags": [], "value": 1} for i in range(240)]
             by_dest = {}
             for m in metrics:
                 by_dest.setdefault(proxy.ring.get(metric_ring_key(m)),

@@ -242,7 +242,9 @@ class Config:
     # shard the global-tier store over a (series, hosts) device mesh;
     # only meaningful on a global instance (forward_address unset)
     mesh_enabled: bool = False
-    # mesh fan-in axis width (0 = auto: 2 when the device count is even)
+    # mesh fan-in axis width (0 = auto: 2 when the device count is even):
+    # a sample chunk is split over it and every digest row is held by
+    # each device along it, so an instance fed by forwards alone sets 1
     mesh_hosts: int = 0
 
     # ---- egress resilience (veneur_tpu/resilience/, docs/resilience.md) --

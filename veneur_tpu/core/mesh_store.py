@@ -155,7 +155,8 @@ def _guarded_drain(temp, digest, rows_l, vals, wts, s_loc, axes,
     """The dense/slab stores' shift guard, mesh form: the drain is
     row-local (no collective inside the cond), but the DECISION psums
     the shift/total masses over ``axes`` so every shard takes the same
-    drain the dense store would on the same data."""
+    drain the dense store would on the same data. Returns the decision
+    too (the same on every device), for the dispatch's drain count."""
     shifted, total = td_ops.shift_masses(
         *temp.anchors(), rows_l, vals, wts, s_loc)
     shifted = lax.psum(shifted, axes)
@@ -172,27 +173,31 @@ def _guarded_drain(temp, digest, rows_l, vals, wts, s_loc, axes,
                         seg_wm=jnp.zeros_like(t.seg_wm))
         return t2, d2
 
-    return lax.cond(pred, do_drain, lambda a: a, (temp, digest))
+    temp, digest = lax.cond(pred, do_drain, lambda a: a, (temp, digest))
+    return temp, digest, pred
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6, 7))
-def _mesh_ingest_samples(temp, digest, rows, vals, wts, mesh: Mesh,
+@partial(jax.jit, donate_argnums=(0, 1, 2), static_argnums=(6, 7, 8))
+def _mesh_ingest_samples(temp, digest, drains, rows, vals, wts, mesh: Mesh,
                          compression: float, k: int):
     """Hosts-sharded sample ingest: each device bins its hosts-axis
     slice of the chunk against its series block, then ONE psum merges
-    the additive bin deltas over ICI (``collectives.merge_temp``)."""
+    the additive bin deltas over ICI (``collectives.merge_temp``).
+    ``drains`` (int32, the same on every device) counts the dispatches
+    whose shift guard drained the temp: the mesh's decision is one for
+    all shards (``_guarded_drain``)."""
     hosts = mesh.shape.get(HOSTS_AXIS, 1)
     temp_spec, dig_spec, _, _ = _digest_specs()
     h = P(HOSTS_AXIS)
 
-    def local_ingest(temp, digest, rows, vals, wts):
+    def local_ingest(temp, digest, drains, rows, vals, wts):
         s_loc = temp.num_series
         rows_l = _relocal(rows, s_loc)
         # hosts-sharded chunk: the guard masses psum over BOTH axes
         # (each shard sees its sub-chunk x its rows)
         axes = (SERIES_AXIS, HOSTS_AXIS) if hosts > 1 else SERIES_AXIS
-        temp, digest = _guarded_drain(temp, digest, rows_l, vals, wts,
-                                      s_loc, axes, compression)
+        temp, digest, drained = _guarded_drain(
+            temp, digest, rows_l, vals, wts, s_loc, axes, compression)
         # bin into a FRESH temp (the delta rides the hosts-axis
         # collective) but anchor bin ids on the ACCUMULATED bins so
         # ordered arrival stays value-coherent across chunks (the
@@ -203,12 +208,14 @@ def _mesh_ingest_samples(temp, digest, rows, vals, wts, mesh: Mesh,
             acc_seg_w=temp.seg_w, acc_seg_wm=temp.seg_wm)
         if hosts > 1:
             binned = collectives.merge_temp(binned, HOSTS_AXIS)
-        return _add_temp(temp, binned), digest
+        return (_add_temp(temp, binned), digest,
+                drains + drained.astype(jnp.int32))
 
     return shard_map(local_ingest, mesh=mesh,
-                     in_specs=(temp_spec, dig_spec, h, h, h),
-                     out_specs=(temp_spec, dig_spec),
-                     check_vma=False)(temp, digest, rows, vals, wts)
+                     in_specs=(temp_spec, dig_spec, P(), h, h, h),
+                     out_specs=(temp_spec, dig_spec, P()),
+                     check_vma=False)(temp, digest, drains, rows, vals,
+                                      wts)
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2, 3), static_argnums=(11, 12))
@@ -274,6 +281,11 @@ def _mesh_flush_digests(digest, temp, dmin, dmax, qs, fills, mesh: Mesh,
                      in_specs=(dig_spec, temp_spec, s, s, P(), s),
                      out_specs=(dig_spec, sk, s, s, s, s, s),
                      check_vma=False)(digest, temp, dmin, dmax, qs, fills)
+
+
+# The least rows a flush gathers: a slab of the flush loop. An interval
+# of fewer live series takes the variant the warm-up compiled.
+GATHER_MIN_ROWS = 2048
 
 
 @jax.jit
@@ -357,6 +369,7 @@ class _PlacementMixin:
         """Assign a freshly interned logical row to its shard (the
         overflow row routes by its own interned identity, so every
         instance of the fleet places it identically)."""
+        t0 = time.monotonic_ns()
         mtype = (self._overflow_type if row == self._overflow_row
                  else key.type)
         shard = self.router.shard_for(self.interner.names[row], mtype,
@@ -364,6 +377,9 @@ class _PlacementMixin:
         while self.placement.full(shard):
             self._grow()
         self.placement.assign(row, shard)
+        # the store's groups share one router, and every caller holds
+        # the store lock: a reader takes the difference over its hold
+        self.router.place_ns += time.monotonic_ns() - t0
 
     @requires_lock("store")
     def _row(self, key, tags) -> int:
@@ -448,6 +464,14 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         self.placement = (ShardPlacement(self.shards, cap)
                           if router is not None else None)
         self._ext_rows: Optional[np.ndarray] = None  # bank mode
+        # the sample path's counters of this generation, read at its
+        # flush like the import's (timeline ``mesh_ingest`` and the
+        # ``ingest.dispatch.mesh`` stage); ``_smp_drains`` is the
+        # device's own count of the dispatches whose shift guard drained
+        self.smp_dispatches = 0
+        self.smp_samples = 0
+        self.smp_dispatch_ns = 0
+        self._smp_drains = None
         super().__init__(cap, _round_up(chunk, self.hosts), compression)
 
     def _init_device(self):
@@ -494,13 +518,62 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         if self._fill == 0:
             return
         self._device_dirty = True
+        self.smp_dispatches += 1
+        self.smp_samples += self._fill
         rows, vals, wts = self._rows, self._vals, self._wts
         self._new_sample_buffers()
+        t0 = time.monotonic_ns()
+        if self._smp_drains is None:
+            self._smp_drains = self._replicated_zero()
+        # the chunk goes in as the NumPy arrays it is, as the import's
+        # stacks do: the program puts each hosts-axis slice on its
+        # devices; through jnp.asarray it landed whole on the first
+        # device and was resharded from there
         with obs_kernels.scope("drain.digest.mesh"):
-            self.temp, self.digest = _mesh_ingest_samples(
-                self.temp, self.digest, jnp.asarray(self._to_phys(rows)),
-                jnp.asarray(vals), jnp.asarray(wts), self.mesh,
-                self.compression, self.k)
+            self.temp, self.digest, self._smp_drains = \
+                _mesh_ingest_samples(
+                    self.temp, self.digest, self._smp_drains,
+                    self._to_phys(rows), vals, wts, self.mesh,
+                    self.compression, self.k)
+        self.smp_dispatch_ns += time.monotonic_ns() - t0
+
+    def sample_collective_bytes(self) -> int:
+        """Bytes one device puts through hosts-axis collectives in one
+        sample dispatch, from the shapes the program is called with:
+        every plane of the fresh shard-sized temp
+        (``collectives.merge_temp``: a psum, pmin or pmax each) and the
+        guard's two float32 masses. Nothing where the hosts axis is 1."""
+        if self.hosts == 1:
+            return 0
+        s_loc = self.capacity // self.shards
+        per_row = 2 * self.k + 2 * td_ops.BELOW_MASS_ANCHORS + 5
+        return 4 * (s_loc * per_row + 2)
+
+    def _replicated_zero(self) -> jax.Array:
+        """The sample path's drain counter at its start: one int32, the
+        same on every device, as the program returns it."""
+        return jax.device_put(np.int32(0), NamedSharding(self.mesh, P()))
+
+    def _guard_counters(self) -> dict:
+        return dict(super()._guard_counters(),
+                    mesh_ingest_guard_drains=self._smp_drains)
+
+    def _note_drains(self) -> None:
+        super()._note_drains()
+        rec = obs_rec.current()
+        if rec is None or not self.smp_dispatches:
+            return
+        rec.note(mesh_ingest_dispatches=self.smp_dispatches,
+                 mesh_ingest_samples=self.smp_samples,
+                 mesh_ingest_collective_bytes=(
+                     self.smp_dispatches * self.sample_collective_bytes()))
+        # the host's side of the generation's sample dispatches
+        # (_to_phys, handing the chunk over, the call), cumulative and
+        # off-path like the merger's stages: most of them ran in the
+        # merger thread during the interval
+        rec.record_abs("ingest.dispatch.mesh", rec.t0_ns,
+                       rec.t0_ns + self.smp_dispatch_ns, off_path=True,
+                       dispatches=self.smp_dispatches)
 
     def _per_shard(self, values) -> jax.Array:
         """A number a shard, each on its shard's devices, as the
@@ -590,20 +663,25 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
 
         sel = _select_stats(want_stats)
         qs = jnp.asarray(list(percentiles) + [0.5], jnp.float32)
-        # padded to the count's pow2 bucket with row 0; _flush_collect
-        # cuts what was fetched back to n
-        rows = np.zeros(min(pow2_cap(n), self.capacity), np.int32)
-        rows[:n] = self._flush_rows(n)
         with obs_rec.maybe_stage("compute"), \
                 obs_kernels.scope("flush.digest.mesh"):
             digest, pcts, count, vsum, vmin, vmax, recip = \
                 self._run_flush(qs, use_pallas, n)
-            planes = ()
-            if want_digests:
-                planes = (digest.mean, digest.weight, digest.min,
-                          digest.max)
-            stats = {"pcts": pcts, "count": count, "sum": vsum,
-                     "min": vmin, "max": vmax, "recip": recip}
+        planes = ()
+        if want_digests:
+            planes = (digest.mean, digest.weight, digest.min, digest.max)
+        stats = {"pcts": pcts, "count": count, "sum": vsum,
+                 "min": vmin, "max": vmax, "recip": recip}
+        # the host's side of the way back to interner order: the
+        # placement's permutation, handing it over, the gather's call
+        with obs_rec.maybe_stage("gather"), \
+                obs_kernels.scope("flush.digest.mesh"):
+            # padded with row 0 to the count's pow2 bucket, a slab at
+            # least (the bucket the warm-up compiled); _flush_collect
+            # cuts what was fetched back to n
+            rows = np.zeros(min(max(pow2_cap(n), GATHER_MIN_ROWS),
+                                self.capacity), np.int32)
+            rows[:n] = self._flush_rows(n)
             refs = _mesh_gather_rows(
                 planes + tuple(stats[nm] for nm in sel), jnp.asarray(rows))
         return (sel, False, None, refs)
@@ -616,28 +694,39 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         return {name: rows[:n] for name, rows in out.items()}
 
     @requires_lock("store")
-    def warm_import(self, percentiles) -> None:
-        """Compile, or load from the persistent cache, what an
-        import-fed interval of this group runs, before the first
-        forward arrives: the planes' initialiser, one import dispatch
-        that stages nothing, one flush of no row. The planes go again
-        afterwards: a group nothing has touched holds no device memory.
-        The programs are the module's, keyed by mesh and shapes, so
-        every group of this size and each generation's twin finds them
-        compiled."""
-        rows = np.full((self.shards, self.chunk), self.capacity, np.int32)
-        zeros = np.zeros((self.shards, self.chunk), np.float32)
-        with obs_kernels.scope("drain.digest.mesh"):
-            temp, digest, dmin, dmax, _ = _mesh_import_routed(
-                self.temp, self.digest, self.dmin, self.dmax,
-                self._shard_zeros(), rows, zeros, zeros, rows, zeros, zeros,
-                self.mesh, self.compression)
-        with obs_kernels.scope("flush.digest.mesh"):
-            out = _mesh_flush_digests(
-                digest, temp, dmin, dmax,
-                jnp.asarray(list(percentiles) + [0.5], jnp.float32),
-                self._shard_zeros(), self.mesh, self.compression)
-        jax.block_until_ready(out)  # lint: ok(lock-across-blocking) start-up, before any listener opens: nobody waits on the lock yet
+    def warm(self, percentiles, want_stats=None, samples: bool = True,
+             imports: bool = True) -> None:
+        """Compile, or load from the persistent cache, what an interval
+        of this group runs, before the first datagram or forward
+        arrives: the planes' initialiser, for each way in that is open
+        one dispatch that stages nothing (``samples``: the hosts-sharded
+        sample ingest; ``imports``: the routed import), one flush of no
+        row and its gather, through the flush's own dispatch and so
+        under its signatures. The planes go again afterwards: a group
+        nothing has touched holds no device memory. The programs are
+        the module's, keyed by mesh and shapes, so every group of this
+        size and each generation's twin finds them compiled."""
+        if samples:
+            with obs_kernels.scope("drain.digest.mesh"):
+                self.temp, self.digest, _ = _mesh_ingest_samples(
+                    self.temp, self.digest, self._replicated_zero(),
+                    np.full(self.chunk, self.capacity, np.int32),
+                    np.zeros(self.chunk, np.float32),
+                    np.zeros(self.chunk, np.float32), self.mesh,
+                    self.compression, self.k)
+        if imports:
+            rows = np.full((self.shards, self.chunk), self.capacity,
+                           np.int32)
+            zeros = np.zeros((self.shards, self.chunk), np.float32)
+            with obs_kernels.scope("drain.digest.mesh"):
+                (self.temp, self.digest, self.dmin, self.dmax,
+                 _) = _mesh_import_routed(
+                    self.temp, self.digest, self.dmin, self.dmax,
+                    self._shard_zeros(), rows, zeros, zeros, rows, zeros,
+                    zeros, self.mesh, self.compression)
+        pending = self._flush_dispatch(0, percentiles, False, want_stats,
+                                       True)
+        jax.block_until_ready(pending)  # lint: ok(lock-across-blocking) start-up, before any listener opens: nobody waits on the lock yet
         for name in DigestGroup._DEVICE_STATE:
             self.__dict__.pop(name, None)
         self._device_dirty = False

@@ -1057,9 +1057,7 @@ class DigestGroup(OverloadLimited):
         with the group state intact for the store's re-merge rung."""
         with obs_rec.maybe_stage("drain"):
             self._drain_staging()
-            if self.imp_dispatches:
-                obs_rec.note(import_dispatches=self.imp_dispatches,
-                             import_centroids=self.imp_centroids)
+            self._note_drains()
         n = len(self.interner)
         if n == 0:
             res = self._flush_empty()
@@ -1072,6 +1070,20 @@ class DigestGroup(OverloadLimited):
                 pending, n, percentiles, want_digests),
             self.digest.mean)
         return lambda: self._flush_commit(fin())
+
+    def _note_drains(self) -> None:
+        """What this generation's drains counted, on the flush's open
+        ``drain`` stage (the flusher sums the notes into the timeline
+        entry)."""
+        if self.imp_dispatches:
+            obs_rec.note(import_dispatches=self.imp_dispatches,
+                         import_centroids=self.imp_centroids)
+
+    def _guard_counters(self) -> dict:
+        """The device's own drain counts, by the name they are noted
+        under: fetched with the flush's results, in the one transfer
+        (None where the path never ran)."""
+        return {"import_guard_drains": self._imp_drains}
 
     def _flush_empty(self):
         """The n==0 flush path: skip the flush program AND the
@@ -1168,11 +1180,13 @@ class DigestGroup(OverloadLimited):
             if packed:
                 (out["packed_counts"], out["packed_means"],
                  out["packed_weights"]) = _fetch_packed(*packed_refs, n)
-            fetched, drains = jax.device_get((refs, self._imp_drains))
-            if drains is not None:
-                # per dispatch and device program: a mesh's shards each
-                # count their own
-                obs_rec.note(import_guard_drains=int(np.sum(drains)))
+            fetched, counters = jax.device_get(
+                (refs, self._guard_counters()))
+            for name, drains in counters.items():
+                if drains is not None:
+                    # per dispatch and device program: a mesh's shards
+                    # each count their own import drains
+                    obs_rec.note(**{name: int(np.sum(drains))})
         if packed:
             out["digest_min"], out["digest_max"] = fetched[:2]
             fetched = fetched[2:]
@@ -2872,14 +2886,19 @@ class MetricStore:
                 sum(getattr(g, "imp_dispatch_ns", 0) for g in groups))
 
     @acquires_lock("store")
-    def warm_import(self, percentiles) -> None:
-        """A mesh global's start: have the import path's programs
-        compiled before a listener opens (MeshDigestGroup.warm_import);
-        histograms and timers are one shape, so one of them does."""
-        warm = getattr(self.histograms, "warm_import", None)
+    def warm_mesh(self, percentiles, aggregates: HistogramAggregates,
+                  samples: bool, imports: bool) -> None:
+        """A mesh store's start, whatever feeds it: have the programs
+        an interval runs compiled before a listener opens
+        (MeshDigestGroup.warm), for the ways in that are open and with
+        the fetch selection the flushes will ask for; histograms and
+        timers are one shape, so one of them does."""
+        warm = getattr(self.histograms, "warm", None)
         if warm is not None:
+            _, want_stats = _digest_want(percentiles, aggregates, False,
+                                         "")
             with self._lock:
-                warm(percentiles)
+                warm(percentiles, want_stats, samples, imports)
 
     @acquires_lock("store")
     def take_import_stages(self) -> Optional[Dict[str, int]]:
@@ -2935,7 +2954,9 @@ class MetricStore:
         ``timing`` (the merger's ``IngestFleet.merge_ns``, or None with
         stage tracing off) gains the chunk's ns waiting for the lock
         (``lock_wait``), remapping every kind's lane rows (``remap``;
-        ``rows_interned`` counts the first-sight rows it interned) and
+        ``rows_interned`` counts the first-sight rows it interned; of
+        it ``route``, a mesh's placing of those rows on their shards,
+        read off the router's own clock) and
         staging them (``stage``): four clock reads a chunk, which is
         why the kinds are remapped first and staged after.
 
@@ -2951,6 +2972,8 @@ class MetricStore:
                 resolver.entries[kind].extend(new)
             interned = 0
             staged = []
+            router = self.shard_router if timing is not None else None
+            placed = router.place_ns if router is not None else 0
             for kind, span in chunk.spans.items():
                 remap, first_sight = self._lane_remap(kind, resolver,
                                                       span[0])
@@ -2976,6 +2999,8 @@ class MetricStore:
             if timing is not None:
                 timing["lock_wait"] += t1 - t0
                 timing["remap"] += t2 - t1
+                if router is not None:
+                    timing["route"] += router.place_ns - placed
                 timing["stage"] += time.monotonic_ns() - t2
                 timing["rows_interned"] += interned
         return chunk.raws

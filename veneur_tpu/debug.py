@@ -161,6 +161,8 @@ def device_section(store=None) -> dict:
              if stats and "peak_bytes_in_use" in stats]
     if peaks:
         out["peak_bytes_in_use"] = max(peaks)
+        if len(peaks) > 1:  # a mesh: every chip's own
+            out["peak_bytes_by_device"] = peaks
     return out
 
 

@@ -129,6 +129,11 @@ class ShardRouter:
         self._index: Dict[str, int] = {
             f"shard-{i}": i for i in range(shards)}
         self._ring = ConsistentRing(list(self._index), replicas=replicas)
+        # ns the store's groups have spent placing first-sight rows
+        # (the lookup here, the placement's assign and any growth):
+        # cumulative, written under the store lock
+        # (core/mesh_store.py _route_new_row)
+        self.place_ns = 0
 
     def shard_for(self, name: str, mtype: str, joined_tags: str) -> int:
         """The shard owning one series — the shared :func:`ring_key`

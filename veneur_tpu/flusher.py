@@ -132,7 +132,11 @@ def _publish_interval(server, span, rec, timeline):
                        rec.t0_ns + merger["merge"], off_path=True,
                        chunks=merger["chunks"],
                        rows_interned=merger["rows_interned"])
-        for stage in ("lock_wait", "remap", "stage"):
+        stages = ["lock_wait", "remap", "stage"]
+        if getattr(getattr(server, "store", None), "mesh", None) is not None:
+            # of the remap: a mesh's placing of the first-sight rows
+            stages.append("route")
+        for stage in stages:
             rec.record_abs(f"ingest.merge.{stage}", rec.t0_ns,
                            rec.t0_ns + merger[stage], off_path=True)
     imports = _take_import_stages(server)
@@ -155,6 +159,17 @@ def _publish_interval(server, span, rec, timeline):
             "dispatches": sum(s["import_dispatches"] for s in staged),
             "centroids": sum(s["import_centroids"] for s in staged),
             "guard_drains": sum(s.get("import_guard_drains", 0)
+                                for s in entry["stages"])}
+    # and the sample path of a mesh's retired digest groups
+    # (MeshDigestGroup notes them the same way)
+    sampled = [s for s in entry["stages"] if "mesh_ingest_dispatches" in s]
+    if sampled:
+        entry["mesh_ingest"] = {
+            "dispatches": sum(s["mesh_ingest_dispatches"] for s in sampled),
+            "samples": sum(s["mesh_ingest_samples"] for s in sampled),
+            "collective_bytes": sum(s["mesh_ingest_collective_bytes"]
+                                    for s in sampled),
+            "guard_drains": sum(s.get("mesh_ingest_guard_drains", 0)
                                 for s in entry["stages"])}
     if hops:
         tids = sorted({h["trace_id"] for h in hops if h.get("trace_id")})

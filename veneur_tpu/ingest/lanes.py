@@ -768,11 +768,12 @@ class IngestFleet:
         # stage_ns and diffed by the same reader: ns inside
         # _merge_chunk (``merge``) and, filled by import_lane_chunk,
         # the wait for the store lock, the lane-row remap with its
-        # first-sight interning, and the staging calls; plus chunks
-        # merged and rows interned. Written under _merge_lock only;
+        # first-sight interning (of it ``route``, a mesh's placing of
+        # those rows), and the staging calls; plus chunks merged and
+        # rows interned. Written under _merge_lock only;
         # None when stage tracing is off (no clock is read then).
         self.merge_ns: Optional[Dict[str, int]] = dict.fromkeys(
-            ("merge", "lock_wait", "remap", "stage", "chunks",
+            ("merge", "lock_wait", "remap", "route", "stage", "chunks",
              "rows_interned"), 0) if trace_stages else None
         self.unrouted_raws: list = []  # only without a raw_handler (tests)
         intern_limit = (intern_limit

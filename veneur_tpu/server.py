@@ -744,14 +744,20 @@ class Server:
         log.info("device: platform=%s device_kind=%s count=%d",
                  dev["platform"], dev["device_kind"], dev["count"])
         self._started_wall = time.time()
-        if self.store.mesh is not None and cfg.grpc_address:
-            # a sharded global compiles its import and flush programs
-            # for minutes when cold: before any listener opens and the
-            # ops port says ready, not under the first forward
+        if self.store.mesh is not None:
+            # a sharded store compiles its ingest, flush and gather
+            # programs for minutes when cold: before any listener opens
+            # and the ops port says ready, not under the first datagram
+            # or forward; a way in that is not open warms nothing
+            feeds = {"samples": bool(cfg.statsd_listen_addresses
+                                     or cfg.ssf_listen_addresses),
+                     "imports": bool(cfg.grpc_address)}
             t0 = time.monotonic()
-            self.store.warm_import(cfg.percentiles)
-            log.info("mesh import programs ready in %.1fs",
-                     time.monotonic() - t0)
+            self.store.warm_mesh(cfg.percentiles,
+                                 self.histogram_aggregates, **feeds)
+            log.info("mesh programs ready in %.1fs (warmed: flush, "
+                     "gather%s)", time.monotonic() - t0,
+                     "".join(", " + k for k, on in feeds.items() if on))
         if self.checkpointer is not None:
             self.checkpointer.restore()
         if self.handoff_manager is not None:
