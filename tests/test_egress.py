@@ -118,6 +118,144 @@ class TestDDSeriesJSON:
         assert json.loads(bodies[0])["series"][0]["points"][0][1] == 2.0
 
 
+class TestDDBodiesSideBySide:
+    """A block's bodies are encoded and deflated by several workers
+    inside one native call, and nobody can tell from the bytes."""
+
+    SUFFIXES = [b".min", b".max", b".count", b".50percentile",
+                b".99percentile", b""]
+
+    def _block(self, n_bodies, ragged, per_body=12):
+        """Emissions for ``n_bodies`` bodies of ``per_body`` (the last
+        one short where ``ragged``) over rows that take every turn of
+        the per-row pre-pass: escaped names, ``host:`` / ``device:``
+        tags, no tags at all."""
+        nem = n_bodies * per_body - (5 if ragged else 0)
+        nrows = max(1, nem // 3)
+        names = ['svc"%d\\lat\n' % r if r % 4 == 0 else "svc.%d.lat" % r
+                 for r in range(nrows)]
+        tags = [("", "env:prod,host:db%d" % r, "device:sd%d,k:v\"%d" % (r, r),
+                 "a:b,host:h,device:d,c:%d" % r)[r % 4] for r in range(nrows)]
+        rng = np.random.default_rng(nem)
+        return dict(
+            names=arenas(names), tags=arenas(tags), suffixes=self.SUFFIXES,
+            em_rows=rng.integers(0, nrows, nem).astype(np.uint32),
+            em_suffix=rng.integers(0, len(self.SUFFIXES), nem).astype(
+                np.uint8),
+            em_values=rng.gamma(2.0, 50.0, nem).astype(np.float32).astype(
+                np.float64),
+            em_type=(rng.random(nem) < 0.3).astype(np.uint8),
+            timestamp=1000, interval=10, default_host="h0",
+            common_tags_json=b'"team:x","q:\\"1"', max_per_body=per_body)
+
+    @pytest.mark.parametrize("level", [0, 1])
+    @pytest.mark.parametrize("workers", [1, 2, 8, 64])
+    @pytest.mark.parametrize("n_bodies,ragged", [
+        (1, False), (2, False), (7, False), (7, True)])
+    def test_bodies_are_the_one_worker_calls(self, n_bodies, ragged,
+                                             workers, level):
+        blk = self._block(n_bodies, ragged)
+        one, timing = {}, {}
+        want = egress.dd_series_bodies(**blk, compress_level=level,
+                                       workers=1, timing=one)
+        got = egress.dd_series_bodies(**blk, compress_level=level,
+                                      workers=workers, timing=timing)
+        assert got == want                       # byte for byte, in order
+        assert len(got) == n_bodies
+        assert one["workers"] == 1
+        assert timing["bodies"] == one["bodies"] == n_bodies
+        assert timing["workers"] == min(workers, n_bodies)
+        series = [json.loads(zlib.decompress(b) if level else b)["series"]
+                  for b in got]
+        nem = len(blk["em_rows"])
+        assert [len(s) for s in series] == \
+            [12] * (n_bodies - 1) + [nem - 12 * (n_bodies - 1)]
+        # spot-check the rows against the inputs, across a body's edge
+        flat = [m for s in series for m in s]
+        names = arenas_strings(blk["names"])
+        for e in (0, 11, 12, nem - 1)[:4 if n_bodies > 1 else 2]:
+            r, sfx = blk["em_rows"][e], blk["em_suffix"][e]
+            assert flat[e]["metric"] == \
+                names[r] + self.SUFFIXES[sfx].decode()
+            assert flat[e]["type"] == ("rate" if blk["em_type"][e]
+                                       else "gauge")
+            assert flat[e]["tags"][:2] == ["team:x", 'q:"1']
+
+    def test_workers_follow_the_bodies_and_the_cores(self, monkeypatch):
+        import os
+
+        for cores, want in ((1, [1, 1, 1]), (4, [1, 2, 2]), (13, [1, 6, 6]),
+                            (30, [1, 7, 8])):
+            monkeypatch.setattr(os, "sched_getaffinity",
+                                lambda _pid, n=cores: set(range(n)))
+            assert [egress.dd_workers(b) for b in (1, 7, 50)] == want
+
+    def test_no_emissions_make_no_body(self):
+        blk = self._block(1, False)
+        for key in ("em_rows", "em_suffix", "em_values", "em_type"):
+            blk[key] = blk[key][:0]
+        timing = {}
+        assert egress.dd_series_bodies(**blk, workers=8,
+                                       timing=timing) == []
+        assert timing["bodies"] == 0 and timing["workers"] == 1
+
+    def test_two_callers_at_once_get_their_own_bodies(self):
+        """The batch path beside a stream worker: two Python threads in
+        the native call at the same time, each with workers of its own."""
+        import threading
+
+        blocks = [self._block(7, True), self._block(5, False, per_body=40)]
+        want = [egress.dd_series_bodies(**b, workers=1) for b in blocks]
+        got = [[], []]
+        start = threading.Barrier(2)
+
+        def call(i):
+            start.wait()
+            for _ in range(20):
+                got[i].append(egress.dd_series_bodies(**blocks[i],
+                                                      workers=4))
+
+        threads = [threading.Thread(target=call, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        for i in range(2):
+            assert len(got[i]) == 20
+            assert all(bodies == want[i] for bodies in got[i])
+
+    def test_timing_splits_the_wall_and_sums_the_workers(self):
+        import time
+
+        blk = self._block(16, True, per_body=4000)
+        timing = {}
+        t0 = time.perf_counter_ns()
+        bodies = egress.dd_series_bodies(**blk, workers=8, timing=timing)
+        wall = time.perf_counter_ns() - t0
+        assert len(bodies) == 16 and timing["workers"] == 8
+        assert 0 < timing["deflate_ns"] and 0 < timing["encode_ns"]
+        assert timing["encode_ns"] + timing["deflate_ns"] <= wall
+        # the last worker's deflate is one of those summed
+        assert timing["deflate_cpu_ns"] >= timing["deflate_ns"]
+        assert timing["encode_cpu_ns"] > 0
+        # eight spans over one wall: the workers' seconds cover the split
+        assert timing["encode_cpu_ns"] + timing["deflate_cpu_ns"] \
+            >= timing["encode_ns"] + timing["deflate_ns"]
+        again = dict(timing)
+        egress.dd_series_bodies(**blk, workers=1, timing=timing)
+        assert timing["bodies"] == 32 and timing["workers"] == 8
+        for key in ("encode_ns", "deflate_ns", "encode_cpu_ns",
+                    "deflate_cpu_ns"):
+            assert timing[key] > again[key]      # a second block adds on
+
+
+def arenas_strings(arena):
+    blob, off, ln = arena
+    return [bytes(blob[o:o + n]).decode() for o, n in zip(off, ln)]
+
+
 class TestMetricListCodec:
     def _digest_planes(self, s=4, k=8, live=5):
         rng = np.random.default_rng(1)

@@ -877,6 +877,36 @@ class TestSerializerSplit:
             assert enc["duration_ns"] + dfl["duration_ns"] \
                 <= whole["duration_ns"], chunk
 
+    @pytest.mark.parametrize("per_body,workers", [(25000, 3), (7, 3),
+                                                  (7, 1)])
+    def test_serialize_stage_says_its_bodies_and_workers(
+            self, lane_server, monkeypatch, per_body, workers):
+        """``workers`` 1 is the serial path: a chunk of one body takes it
+        whatever the cores, a chunk of several where one worker is all
+        there is; the workers' summed seconds ride beside."""
+        from veneur_tpu.native import egress
+
+        monkeypatch.setattr(egress, "dd_workers",
+                            lambda n_bodies: min(n_bodies, workers))
+        srv, chan, post = lane_server
+        srv.metric_sinks[0].flush_max_per_body = per_body
+        send_and_merge(srv, TestMergerStages.LINES)
+        srv.flush()
+        chan.get_flush()
+        stages = [s for s in srv.obs_timeline.entries()[-1]["stages"]
+                  if s["name"] == "post.datadog.serialize"]
+        assert stages
+        assert sum(s["bodies"] for s in stages) == len(post.bodies)
+        for s in stages:
+            # a block's bodies: its rows over the body size, rounded up
+            assert s["workers"] == min(s["bodies"], workers), s
+            assert s["encode_cpu_ns"] > 0 and s["deflate_cpu_ns"] > 0
+        if per_body == 7:   # 40 histograms x 5 rows: many bodies a chunk
+            assert max(s["bodies"] for s in stages) > 20
+        else:
+            assert {s["bodies"] for s in stages} == {1}
+            assert {s["workers"] for s in stages} == {1}
+
 
 class TestCaptureAndThreads:
     def test_host_scope_is_not_a_dispatch(self):

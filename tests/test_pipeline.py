@@ -234,6 +234,69 @@ class TestStreamedSinkConservation:
         assert sink.chunk_rows_pending() == 0
         assert sink.chunk_rows_acked == total_first + stream2.rows
 
+    @pytest.mark.parametrize("workers", [1, 3])
+    @pytest.mark.parametrize("fail_calls,budget", [
+        ((), None), ((2,), None), ((3, 5), None),
+        (range(1, 1000), None), (range(1, 1000), 600)],
+        ids=["clean", "5xx-on-body-2", "5xx-on-bodies-3-and-5",
+             "always-5xx", "always-5xx-past-the-budget"])
+    def test_chunk_of_several_bodies_by_several_workers_conserves(
+            self, native_egress, monkeypatch, fail_calls, budget, workers):
+        """The matrix once more where a chunk's bodies are made side by
+        side: the sink gets the same bodies in the same order, so
+        ``acked + requeued + dropped == rows`` whatever the POSTs do."""
+        asked = []
+
+        def dd_workers(n_bodies):
+            asked.append((n_bodies, min(n_bodies, workers)))
+            return asked[-1][1]
+
+        monkeypatch.setattr(native_egress, "dd_workers", dd_workers)
+
+        class _Logged(_FaultyPost):
+            def __call__(self, url, payload, **kw):
+                self.payloads.append(payload)
+                return super().__call__(url, payload, **kw)
+
+        post = _Logged(fail_calls)
+        post.payloads = []
+        sink = make_dd_sink(post, flush_max_per_body=4)
+        if budget is not None:
+            sink.requeue_max_bytes = budget
+        s = make_store(flush_pipeline_depth=2)
+        fill(s, n_hist=9)     # the timers' chunk: 36 rows, nine bodies
+        stream = ChunkStream([sink], 7, depth=2)
+        s.flush([0.5], AGGS, is_local=False, now=7, forward=False,
+                columnar=True, stream=stream)
+        stream.close()
+        assert max(asked) == (9, min(9, workers))
+        assert (sink.chunk_rows_acked + sink.chunk_rows_pending()
+                + sink.chunk_rows_dropped) == stream.rows
+        assert post.acked_rows == sink.chunk_rows_acked
+        failed = sum(1 for c in fail_calls if c <= post.calls)
+        assert sink.flush_errors == failed
+        if budget is None:
+            assert sink.chunk_rows_dropped == 0
+            assert len(sink._requeued) == failed
+        else:
+            assert sink.chunk_rows_dropped > 0
+            assert sink.chunk_requeue_bytes() <= budget
+        # every body holds its rows, in the serial call's order
+        sizes = [len(json.loads(zlib.decompress(b))["series"])
+                 for b in post.payloads]
+        assert sum(sizes) == stream.rows and max(sizes) == 4
+        if workers > 1:
+            monkeypatch.setattr(native_egress, "dd_workers", lambda n: 1)
+            ref = _Logged()
+            ref.payloads = []
+            sink1 = make_dd_sink(ref, flush_max_per_body=4)
+            fill(s, n_hist=9)
+            stream1 = ChunkStream([sink1], 7, depth=2)
+            s.flush([0.5], AGGS, is_local=False, now=7, forward=False,
+                    columnar=True, stream=stream1)
+            stream1.close()
+            assert post.payloads == ref.payloads
+
     def test_requeued_body_failing_again_reparks_in_budget(
             self, native_egress):
         """A multi-interval outage holds every unacked body inside the

@@ -90,7 +90,7 @@ class _VtMetricBatch(ctypes.Structure):
 def _build() -> Optional[str]:
     try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
              "-o", _SO, _SRC, "-lz"],
             check=True, capture_output=True, timeout=120)
         return None
@@ -149,7 +149,8 @@ def _bind(lib):
         ctypes.c_int64, ctypes.c_int32,         # timestamp, interval
         ctypes.c_char_p, ctypes.c_char_p,       # host, common tags json
         ctypes.c_uint32, ctypes.c_int,          # max_per_body, level
-        ctypes.POINTER(ctypes.c_uint64),        # timing_ns[2] or NULL
+        ctypes.c_uint32,                        # workers
+        ctypes.POINTER(ctypes.c_uint64),        # timing_ns[6] or NULL
     ]
     lib.vt_bodies_free.argtypes = [ctypes.POINTER(_VtBodies)]
 
@@ -243,6 +244,14 @@ def _p(a: np.ndarray, ctype):
 # ---------------------------------------------------------------------------
 
 
+def dd_workers(n_bodies: int) -> int:
+    """Threads one ``dd_series_bodies`` call spreads its bodies over: one
+    a body, at most 8, and half the cores this process may run on, so a
+    flush that runs under ingest leaves the readers and the merger
+    theirs."""
+    return min(n_bodies, 8, max(1, len(os.sched_getaffinity(0)) // 2))
+
+
 def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
                      tags: Tuple[bytes, np.ndarray, np.ndarray],
                      suffixes: List[bytes],
@@ -252,7 +261,8 @@ def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
                      common_tags_json: bytes = b"",
                      max_per_body: int = 0,
                      compress_level: int = 1,
-                     timing: Optional[dict] = None) -> List[bytes]:
+                     timing: Optional[dict] = None,
+                     workers: Optional[int] = None) -> List[bytes]:
     """Serialize one columnar emission block into chunked (optionally
     deflated) ``{"series": [...]}`` bodies.
 
@@ -261,10 +271,18 @@ def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
     ``suffixes``), finalized value f64 (counters already divided by the
     interval), type code u8 (0 gauge / 1 rate).
 
-    ``timing``, where given, gains the native call's ``deflate_ns``
-    (inside zlib's ``deflate``, clocked a slab at a time) and
-    ``encode_ns`` (the rest of the call: the JSON encoding), added to
-    what the keys already hold.
+    The bodies are encoded and deflated side by side by ``workers``
+    threads inside the native call (``dd_workers`` of the body count
+    where not given; the call runs on the calling thread alone where that
+    is 1), and come back in order, byte for byte the one-worker call's.
+
+    ``timing``, where given, gains a split of the native call's wall:
+    ``deflate_ns`` (inside zlib's ``deflate``, clocked a slab at a time,
+    on the worker that finished last) and ``encode_ns`` (the rest of the
+    call: the JSON encoding); the same two summed over the workers'
+    spans, ``deflate_cpu_ns`` and ``encode_cpu_ns``; ``bodies``; each
+    added to what the key already holds. ``workers`` keeps the most a
+    call ran.
     """
     lib = _load()
     if lib is None:
@@ -290,7 +308,9 @@ def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
     name_off, name_len = _u32a(name_off), _u32a(name_len)
     tags_off, tags_len = _u32a(tags_off), _u32a(tags_len)
     u32, u8, f64 = ctypes.c_uint32, ctypes.c_uint8, ctypes.c_double
-    spent = (ctypes.c_uint64 * 2)() if timing is not None else None
+    if workers is None:
+        workers = dd_workers(-(-n // max_per_body) if max_per_body else 1)
+    spent = (ctypes.c_uint64 * 6)() if timing is not None else None
     bp = lib.vt_dd_series_json(
         name_arena, _p(name_off, u32), _p(name_len, u32),
         tags_arena, _p(tags_off, u32), _p(tags_len, u32),
@@ -299,11 +319,16 @@ def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
         _p(em_rows, u32), _p(em_suffix, u8), _p(em_values, f64),
         _p(em_type, u8),
         n, timestamp, interval, default_host.encode("utf-8"),
-        common_tags_json, max_per_body, compress_level, spent)
+        common_tags_json, max_per_body, compress_level, workers, spent)
     if timing is not None:
         total, deflate = int(spent[0]), int(spent[1])
-        timing["deflate_ns"] = timing.get("deflate_ns", 0) + deflate
-        timing["encode_ns"] = timing.get("encode_ns", 0) + total - deflate
+        for key, ns in (("deflate_ns", deflate),
+                        ("encode_ns", total - deflate),
+                        ("encode_cpu_ns", int(spent[2])),
+                        ("deflate_cpu_ns", int(spent[3])),
+                        ("bodies", int(spent[4]))):
+            timing[key] = timing.get(key, 0) + ns
+        timing["workers"] = max(timing.get("workers", 0), int(spent[5]))
     return _take_bodies(lib, bp)
 
 

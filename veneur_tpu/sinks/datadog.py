@@ -99,7 +99,8 @@ class DatadogMetricSink(MetricSink):
         self.retries = 0
         # deflate level for the native columnar serializer (level 1 runs
         # ~2x the throughput of zlib's default 6 at a ~12% ratio cost —
-        # the single-core deflate IS the large-flush bottleneck)
+        # deflate is most of what a large flush's serializer costs, a
+        # body a worker at a time: native/egress.py dd_workers)
         self.compress_level = compress_level
         self.metrics_flushed = 0
         self.flush_errors = 0
@@ -232,9 +233,13 @@ class DatadogMetricSink(MetricSink):
         t0_ns = time.monotonic_ns()
         t_marshal = time.perf_counter()
         bodies = []
-        # ns the native serializer spent encoding JSON and in deflate,
-        # summed over the chunk's blocks (native/egress.py)
-        native_ns = {"encode_ns": 0, "deflate_ns": 0}
+        # where the native serializer's wall went (encoding JSON, in
+        # deflate), the same summed over its workers, the bodies it made:
+        # added up over the chunk's blocks; the most workers a block's
+        # call ran (native/egress.py)
+        native_ns = dict.fromkeys(
+            ("encode_ns", "deflate_ns", "encode_cpu_ns", "deflate_cpu_ns",
+             "bodies", "workers"), 0)
         with host_scope(f"post.{self.name}.serialize"):
             for blk in chunk.blocks:
                 blk_bodies = self._serialize_block(blk, chunk.timestamp,
@@ -246,7 +251,11 @@ class DatadogMetricSink(MetricSink):
         t_marshal = time.perf_counter() - t_marshal
         if rec is not None:
             rec.record_abs(f"post.{self.name}.serialize", t0_ns,
-                           time.monotonic_ns(), chunk=chunk.seq)
+                           time.monotonic_ns(), chunk=chunk.seq,
+                           bodies=native_ns["bodies"],
+                           workers=native_ns["workers"],
+                           encode_cpu_ns=native_ns["encode_cpu_ns"],
+                           deflate_cpu_ns=native_ns["deflate_cpu_ns"])
             for part in ("encode", "deflate"):
                 rec.record_abs(f"post.{self.name}.serialize.{part}", t0_ns,
                                t0_ns + native_ns[part + "_ns"],
