@@ -1,7 +1,8 @@
 """The server child (the one process that holds the chip) and the
 thread that polls its ``/debug/vars`` while the load runs. Copied from
-``chip_smoke.py`` so that a later change to the smoke cannot move the
-yardstick; the parent never imports JAX."""
+the smoke that PR 35 deleted (``chip_smoke.py``), so that no change to
+the program's own tools can move the yardstick; the parent never imports
+JAX."""
 
 from __future__ import annotations
 

@@ -10,29 +10,13 @@ import zlib
 
 import numpy as np
 
+from benchmark import kinds
+
 SUFFIX_COUNT, SUFFIX_MIN, SUFFIX_MAX = "count", "min", "max"
 
 
 def percentile_suffix(q: float) -> str:
     return "%gpercentile" % (q * 100.0)
-
-
-def forwarded(group: dict) -> bool:
-    """Whether the group's series reach the server as forwards of other
-    instances: it states how many forwarders report a series
-    (``fan_in``), or it is their messages' ``marker``."""
-    return "fan_in" in group or bool(group.get("marker"))
-
-
-def columns(group: dict, percentiles: list) -> list:
-    """The rows a series of the group has in an emission. A histogram
-    that was forwarded with mixed scope has its percentiles only: count,
-    min and max are the forwarders' own to emit."""
-    if group["type"] != "h":
-        return ["value"]
-    local = [] if forwarded(group) else [SUFFIX_COUNT, SUFFIX_MIN,
-                                         SUFFIX_MAX]
-    return local + [percentile_suffix(q) for q in percentiles]
 
 
 # The sink writes a body in either of two hands: its native encoder's,
@@ -68,14 +52,17 @@ def assign_emissions(bodies: list, ticks: list) -> list:
 
 
 class Emission:
-    """One flush's rows for the mix's groups. For a histogram group
-    ``cols[suffix]`` is a ``[series]`` float64 array, NaN where the sink
-    got no row; for a counter or a gauge group ``cols["value"]``.
-    ``dup`` counts rows seen twice, ``stray`` rows outside a group."""
+    """One flush's rows for the mix's groups: ``cols[g]`` is what the
+    group's kind keeps of them (``kinds/<kind>.py`` ``table``; for a
+    rectangle of series ``cols[g][suffix]`` is a ``[series]`` float64
+    array, NaN where the sink got no row, ``"value"`` for a row with no
+    suffix). ``dup`` counts rows seen twice, ``stray`` rows outside a
+    group. ``flushes`` is how many emissions the run has: a kind whose
+    names grow with the rounds sizes its columns from it."""
 
-    def __init__(self, groups: list, percentiles: list):
-        self.cols = [{s: np.full(int(g["series"]), np.nan)
-                      for s in columns(g, percentiles)} for g in groups]
+    def __init__(self, groups: list, percentiles: list, flushes: int = 1):
+        self.cols = [kinds.of(g).table(g, percentiles, flushes)
+                     for g in groups]
         self.dup = 0
         self.stray = 0
         self.rows = 0
@@ -83,19 +70,49 @@ class Emission:
         self.bodies = 0
 
 
+def histogram_suffixes(percentiles: list) -> list:
+    """The rows of a histogram series that this instance aggregates."""
+    return [SUFFIX_COUNT, SUFFIX_MIN, SUFFIX_MAX] + [
+        percentile_suffix(q) for q in percentiles]
+
+
+def rectangle(series: int, suffixes: list) -> dict:
+    """Empty columns of ``series`` rows, one a suffix."""
+    return {s: np.full(int(series), np.nan) for s in suffixes}
+
+
+def land_rectangle(em: Emission, cols: dict, idx: np.ndarray,
+                   suf: np.ndarray, val: np.ndarray) -> None:
+    """Rows ``<prefix><idx>.<suffix>`` into the columns of their
+    suffixes (``"value"``: none); a row past the columns' end, or with a
+    suffix that is no column, is stray."""
+    inside = idx < len(next(iter(cols.values())))
+    known = np.zeros(len(idx), dtype=bool)
+    for s, col in cols.items():
+        m = inside & (suf == (b"" if s == "value" else s.encode()))
+        put(em, col, idx[m], val[m])
+        known |= m
+    em.stray += int((~known).sum())
+
+
 def parse(bodies: list, owner: list, n_flushes: int, groups: list,
           percentiles: list, interval_s: float) -> list:
     """Decompress and parse every series body into one ``Emission`` a
     flush. Rows of type ``rate`` (counters and a histogram's ``count``)
     come back from rates to counts: the sink divides them by the
-    interval (sinks/datadog.py ``_serialize_block``)."""
-    out = [Emission(groups, percentiles) for _ in range(n_flushes)]
+    interval (sinks/datadog.py ``_serialize_block``). A row is
+    ``<prefix><i>[.suffix]`` with its value and the text of its ``tags``
+    array (made into an array only for a kind that asks: ``tags()``);
+    where it lands is its group's kind's to say."""
+    out = [Emission(groups, percentiles, n_flushes)
+           for _ in range(n_flushes)]
     prefixes = [g["prefix"].encode() for g in groups]
     pattern = re.compile(
         rb'"metric": ?"(' + b"|".join(re.escape(p) for p in prefixes)
         + rb')(\d+)(?:\.([\w.]+))?", ?"points": ?\[\[[\d.]+, ?([^\]]+)\]\]'
-        rb'(?:, ?"tags": ?\[[^\]]*\])?, ?"type": ?"(\w+)"')
+        rb'(?:, ?"tags": ?\[([^\]]*)\])?, ?"type": ?"(\w+)"')
     index = {p: i for i, p in enumerate(prefixes)}
+    land = [kinds.of(g).land for g in groups]
     for (stamp, path, encoding, raw), k in zip(bodies, owner):
         if k < 0 or k >= n_flushes:
             continue
@@ -113,52 +130,36 @@ def parse(bodies: list, owner: list, n_flushes: int, groups: list,
         gi = np.array([index[f[0]] for f in found])
         idx = np.array([f[1] for f in found], dtype=np.int64)
         val = np.array([f[3] for f in found], dtype=np.float64)
-        rate = np.array([f[4] == b"rate" for f in found])
+        rate = np.array([f[5] == b"rate" for f in found])
         val[rate] = np.round(val[rate] * interval_s)
         suf = np.array([f[2] for f in found])
         for g, grp in enumerate(groups):
-            mine = gi == g
-            if not mine.any():
+            rows = np.flatnonzero(gi == g)
+            if not len(rows):
                 continue
-            inside = mine & (idx < int(grp["series"]))
-            em.stray += int((mine & ~inside).sum())
-            if grp["type"] == "h":
-                for s, col in em.cols[g].items():
-                    m = inside & (suf == s.encode())
-                    _put(em, col, idx[m], val[m])
-                known = np.isin(suf, [s.encode() for s in em.cols[g]])
-                em.stray += int((inside & ~known).sum())
-            else:
-                m = inside & (suf == b"")
-                _put(em, em.cols[g]["value"], idx[m], val[m])
-                em.stray += int((inside & (suf != b"")).sum())
+            if len(rows) == len(found):       # the whole body is one group's
+                rows = slice(None)
+            land[g](em, em.cols[g], grp, idx[rows], suf[rows],
+                    lambda: np.array([f[4] for f in found])[rows], val[rows])
     return out
 
 
-def _put(em: Emission, col: np.ndarray, idx: np.ndarray,
+def put(em: Emission, col: np.ndarray, idx: np.ndarray,
          val: np.ndarray) -> None:
     em.dup += int((~np.isnan(col[idx])).sum()) + len(idx) - len(
         np.unique(idx))
     col[idx] = val
 
 
-def lines_in(em: Emission, groups: list) -> int:
-    """Lines an emission accounts for, read from outside: a histogram
-    row's ``count`` is its lines; a counter or gauge row stands for the
-    one line a round sends that series. Of forwarded groups the marker's
-    rows alone say it: each carries the entries of the messages it
-    stands for, and an entry is such a group's line."""
-    total = 0
-    for g, cols in zip(groups, em.cols):
-        if forwarded(g):
-            if g.get("marker"):
-                total += int(np.nansum(cols["value"]))
-        elif g["type"] == "h":
-            total += int(np.nansum(cols[SUFFIX_COUNT]))
-        else:
-            total += int((~np.isnan(cols["value"])).sum()) * int(
-                g["samples"])
-    return total
+def lines_in(em: Emission, groups: list, sent=None) -> int:
+    """Lines an emission accounts for, read from outside; each group's
+    kind says what its rows stand for (a histogram row's ``count`` is
+    its lines). ``sent`` is the round that the emission flushed, where
+    the caller has it: a row of a series that gets another number of
+    lines every round stands for what that round sent it."""
+    return sum(kinds.of(grp).lines_in(
+        cols, grp, None if sent is None else sent.values[g])
+        for g, (grp, cols) in enumerate(zip(groups, em.cols)))
 
 
 def weighted_quantile(values: np.ndarray, weights: np.ndarray,
@@ -169,7 +170,8 @@ def weighted_quantile(values: np.ndarray, weights: np.ndarray,
 
 
 def end_to_end(send_log: list, emissions: list, ticks: list,
-               window: range, groups: list, carried_in: int = 0) -> dict:
+               window: range, groups: list, carried_in: int = 0,
+               rounds: dict = None) -> dict:
     """The measures taken from outside. Flush to last body: over the
     window's flushes, the last body's stamp minus the tick. Line age:
     over all lines sent in the window, the emission's stamp minus the
@@ -177,14 +179,16 @@ def end_to_end(send_log: list, emissions: list, ticks: list,
     totals, cumulated, against the send order. ``carried_in`` lines of
     the warm-up rounds missed their tick and stand first in the window's
     emissions: they are no window line's. A line no emission holds waits
-    for ever."""
+    for ever. ``rounds[k]`` is the round that emission k flushed
+    (``lines_in``)."""
+    rounds = rounds or {}
     lags = [emissions[k].last_stamp - ticks[k] for k in window
             if emissions[k].last_stamp is not None]
     due = np.array([d for d, _s, _n in send_log])
     n = np.array([n for _d, _s, n in send_log], dtype=np.float64)
     sent_before = np.cumsum(n) - n        # lines sent before this datagram
     # emissions from the window's first flush on, stragglers included
-    held = np.cumsum([lines_in(emissions[k], groups)
+    held = np.cumsum([lines_in(emissions[k], groups, rounds.get(k))
                       for k in range(window.start, len(emissions))])
     held = np.maximum(held - carried_in, 0)
     which = np.searchsorted(held, sent_before, "right")

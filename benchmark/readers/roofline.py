@@ -3,9 +3,11 @@ chip could take for the work (``work`` names a function of
 ``benchmark/lib/roofline.py``; its arguments come from the metric's file
 and, where a value is ``{"config": "<dotted path>"}``, from the cell's
 configuration, or ``{"traffic_series": "<type>"}``, the series of that
-type the mix keeps live in an interval) over the longest single event of
-the program in the trace. Nothing to read gives nothing, never 0."""
+type the mix keeps live in an interval, as each group's kind says) over
+the longest single event of the program in the trace. Nothing to read
+gives nothing, never 0."""
 
+from benchmark import kinds
 from benchmark.lib import cells, roofline
 from benchmark.readers.trace_program_time import program_seconds
 from benchmark.readers.vars_path import _dig
@@ -20,7 +22,8 @@ def read(args: dict, ctx: dict):
     shapes = {}
     for key, value in args["shapes"].items():
         if isinstance(value, dict) and "traffic_series" in value:
-            value = sum(int(g["series"]) for g in ctx["traffic"]["groups"]
+            value = sum(kinds.of(g).live_series(g)
+                        for g in ctx["traffic"]["groups"]
                         if g["type"] == value["traffic_series"])
         elif isinstance(value, dict):
             value = _dig(ctx["config"], value["config"])

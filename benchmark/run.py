@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import pickle
 import shutil
 import socket
 import subprocess
@@ -102,15 +103,60 @@ def _reduce_trace(trace_dir: str, out_path: str) -> dict:
     return cells.read_json(out_path)
 
 
+def read_input(cell: cells.Cell, kept: dict, rounds: dict) -> tuple:
+    """What a run's input says once the child has exited: the receiver's
+    bodies, the flush timeline and the send logs (``kept``; a run with
+    ``--keep-input`` writes them out, ``tools/replay.py`` reads them
+    again) against the rounds that were sent. Returns the emissions, the
+    measures taken from outside, the comparison's verdict and the
+    ``compared`` line of the report."""
+    groups = cell.traffic["groups"]
+    percentiles = cell.config["server"]["percentiles"]
+    interval = cell.interval_s
+    span = interval - float(cell.traffic["guard_s"])
+    window, timeline, bodies = kept["window"], kept["timeline"], kept["bodies"]
+    n_rounds = kept["n_rounds"]
+    ticks = [e["wall_start"] for e in timeline]
+    owner = emissions.assign_emissions(bodies, ticks)
+    ems = emissions.parse(bodies, owner, len(timeline), groups,
+                          percentiles, interval)
+    # the warm-up rounds too: a line of theirs that slips into the
+    # window is late, and the run's totals say so
+    by_round = {window.start - 1 + k: rounds[k]
+                for k in range(1 - WARM_ROUNDS, n_rounds + 1)}
+    warm = range(window.start - WARM_ROUNDS, window.start)
+    carried_in = max(0, sum(n for _due, _sent, n in kept["warm_log"]) - sum(
+        emissions.lines_in(ems[k], groups, by_round[k]) for k in warm))
+    e2e = emissions.end_to_end(kept["send_log"], ems, ticks, window, groups,
+                               carried_in, by_round)
+    verdict = reference.compare(
+        ems, by_round, window, groups, percentiles, cell.config,
+        {window.start - 1 + k: (tick, span, interval)
+         for k, tick in kept["started"].items()})
+    compared = dict(
+        bodies=len(bodies), rows=sum(e.rows for e in ems),
+        bodies_late=sum(1 for b, k in zip(bodies, owner)
+                        if 0 <= k < len(ticks) - 1
+                        and b[0] >= ticks[k + 1]),
+        lines_late=verdict["lines_late"],
+        lines_sent=e2e["lines_sent"], lines_held=e2e["lines_held"],
+        lines_carried_in=carried_in, rank_errors=verdict["rank_errors"],
+        emissions=[{"bodies": e.bodies, "rows": e.rows,
+                    "lines": emissions.lines_in(e, groups, by_round.get(k))}
+                   for k, e in enumerate(ems)],
+        flush_to_last_body_each_s=e2e["flush_to_last_body_each_s"],
+        measures=e2e["measures"])
+    return ems, e2e, verdict, compared
+
+
 def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
              rep: Report, out_dir: str, rehearse: bool = False,
-             receiver: Receiver = None, keep_trace: bool = False) -> dict:
+             receiver: Receiver = None, keep_trace: bool = False,
+             keep_input: bool = False) -> dict:
     """Drives one run; returns the contract's line as a dict, or
     ``{"refused": why}`` where the machine cannot run the cell."""
     traffic, interval = cell.traffic, cell.interval_s
     gen = cell.generator()
-    groups = traffic["groups"]
-    percentiles = cell.config["server"]["percentiles"]
     span = interval - float(traffic["guard_s"])
     n_rounds = max(1, int(seconds // interval))
 
@@ -216,40 +262,17 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
 
     # -- the child has exited: parse, compare, reduce --
     window = range(first + 2, first + 2 + n_rounds)
-    ticks = [e["wall_start"] for e in timeline]
+    kept = {"seed": seed, "n_rounds": n_rounds, "window": window,
+            "bodies": sorted(receiver.bodies, key=lambda b: b[0]),
+            "timeline": timeline, "send_log": send_log,
+            "warm_log": warm_log, "started": started}
+    if keep_input:
+        with open(os.path.join(out_dir, "input.pickle"), "wb") as f:
+            pickle.dump(kept, f)
     t0 = time.time()
-    bodies = sorted(receiver.bodies, key=lambda b: b[0])
-    owner = emissions.assign_emissions(bodies, ticks)
-    ems = emissions.parse(bodies, owner, len(timeline), groups,
-                          percentiles, interval)
-    warm = range(window.start - WARM_ROUNDS, window.start)
-    carried_in = max(0, sum(n for _due, _sent, n in warm_log) - sum(
-        emissions.lines_in(ems[k], groups) for k in warm))
-    e2e = emissions.end_to_end(send_log, ems, ticks, window, groups,
-                               carried_in)
-    # the warm-up rounds too: a line of theirs that slips into the
-    # window is late, and the run's totals say so
-    by_round = {window.start - 1 + k: rounds[k]
-                for k in range(1 - WARM_ROUNDS, n_rounds + 1)}
-    verdict = reference.compare(
-        ems, by_round, window, groups, percentiles,
-        float(cell.config["rank_error_limit"]),
-        {window.start - 1 + k: (tick, span, interval)
-         for k, tick in started.items()})
-    rep.line(phase="compared", bodies=len(bodies),
-             rows=sum(e.rows for e in ems),
-             bodies_late=sum(1 for b, k in zip(bodies, owner)
-                             if 0 <= k < len(ticks) - 1
-                             and b[0] >= ticks[k + 1]),
-             seconds=round(time.time() - t0, 2),
-             lines_late=verdict["lines_late"],
-             lines_sent=e2e["lines_sent"], lines_held=e2e["lines_held"],
-             lines_carried_in=carried_in, rank_errors=verdict["rank_errors"],
-             emissions=[{"bodies": e.bodies, "rows": e.rows,
-                         "lines": emissions.lines_in(e, groups)}
-                        for e in ems],
-             flush_to_last_body_each_s=e2e["flush_to_last_body_each_s"],
-             measures=e2e["measures"])
+    ems, e2e, verdict, compared = read_input(cell, kept, rounds)
+    rep.line(phase="compared", seconds=round(time.time() - t0, 2),
+             **compared)
 
     lines_sent = e2e["lines_sent"]
     # an import that arrives while a flush runs waits for it
@@ -351,7 +374,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
 def _run_checks(rep: Report, cell, timeline, window, v, watcher, child, rc,
                 ems) -> None:
     """What the configuration guarantees besides the numbers, whatever
-    feeds it: nothing refused, no hidden fallback (``chip_smoke.py``'s).
+    feeds it: nothing refused, no hidden fallback.
     The feed's own checks say that nothing was lost on its way in."""
     ov = v["overload"]
     rep.check("nothing_shed_quarantined_spilled",
@@ -416,6 +439,10 @@ def main(argv=None) -> int:
     ap.add_argument("--traffic-dir", default="",
                     help="another directory of traffic mixes")
     ap.add_argument("--keep-trace", action="store_true")
+    ap.add_argument("--keep-input", action="store_true",
+                    help="keep the receiver's bodies, the timeline and the "
+                         "send logs under out/<cell>/input.pickle, for "
+                         "tools/replay.py")
     args = ap.parse_args(argv)
 
     if not os.path.isdir(os.path.join(cells.ROOT, "veneur_tpu")):
@@ -429,7 +456,8 @@ def main(argv=None) -> int:
     try:
         line = run_cell(cell, args.seed, args.seconds, bool(args.trace),
                         rep, out_dir, rehearse=args.rehearse,
-                        keep_trace=args.keep_trace)
+                        keep_trace=args.keep_trace,
+                        keep_input=args.keep_input)
     finally:
         rep.close()
     if "refused" in line:

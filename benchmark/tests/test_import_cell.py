@@ -39,7 +39,8 @@ def test_file_loads_and_agrees_with_the_manifest(name):
     spec = cells.read_json(os.path.join(
         cells.BENCH_DIR, "layer_metrics", name + ".json"))
     entry = {m["name"]: m for m in _manifest()["per_layer"]}[name]
-    assert entry["workloads"] == [CELL]
+    # the cell it came with; a later cell on a mesh may read it too
+    assert entry["workloads"][0] == CELL
     assert spec["name"] == name
     assert {k: spec[k] for k in SHARED} == {k: entry[k] for k in SHARED}
     # a program without the span or the counter (the parent commit):

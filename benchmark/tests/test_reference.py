@@ -14,6 +14,7 @@ from benchmark.generators import forwarded_groups, series_groups
 from benchmark.lib import emissions, reference
 
 PERCENTILES = [0.5, 0.75, 0.99]
+LIMITS = {"rank_error_limit": 0.02}
 MIXES = {
     "wide": {"datagram_bytes": 1400, "groups": [
         {"prefix": "t.h.", "type": "h", "series": 3000, "samples": 1,
@@ -44,7 +45,7 @@ def _case(mix, seed, precision):
 
 def _numbers(groups, rounds, ems):
     return reference.compare(ems, rounds, WINDOW, groups, PERCENTILES,
-                             0.02)["numbers"]
+                             LIMITS)["numbers"]
 
 
 def _correct(numbers):
@@ -108,7 +109,7 @@ def test_a_late_line_is_late_not_wrong():
         col[9] = np.nan
     h4["count"][9] = 2
     h4["min"][9], h4["max"][9] = min(v3, v4), max(v3, v4)
-    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, 0.02)
+    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, LIMITS)
     assert _correct(out["numbers"])
     assert out["lines_late"] == 2
 
@@ -134,13 +135,13 @@ def test_a_warm_up_line_that_slips_into_the_window_is_late_not_wrong():
     c0 = rounds[span.start].values[1][3, 0]
     warm.cols[1]["value"][3] = np.nan
     first.cols[1]["value"][3] += c0
-    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, 0.02)
+    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, LIMITS)
     assert _correct(out["numbers"])
     assert out["numbers"]["lines_unaccounted"]["value"] == 0
     assert out["lines_late"] == 2
     # held to the window alone, the same emissions hold a line too many
     alone = {k: rounds[k] for k in WINDOW}
-    out = reference.compare(ems, alone, WINDOW, groups, PERCENTILES, 0.02)
+    out = reference.compare(ems, alone, WINDOW, groups, PERCENTILES, LIMITS)
     assert out["numbers"]["lines_unaccounted"]["value"] > 0
     assert not _correct(out["numbers"])
 
@@ -189,7 +190,7 @@ def _forwarded_case(seed, precision, moved=None):
               for k in WINDOW}
     ems = reference.synthesize(rounds, WINDOW, 6, groups, PERCENTILES,
                                precision, moved)
-    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, 0.02)
+    out = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES, LIMITS)
     return rounds, ems, out
 
 
@@ -249,7 +250,7 @@ def test_a_forward_merged_twice_is_flagged():
     ems[3].cols[2]["value"][:] += np.where(
         mine, rounds[3].values[2], 0.0).sum(axis=1)
     numbers = reference.compare(ems, rounds, WINDOW, groups, PERCENTILES,
-                                0.02)["numbers"]
+                                LIMITS)["numbers"]
     assert numbers["lines_unaccounted"]["value"] == rounds[3].entries[3]
     assert numbers["scalar_rows_wrong"]["value"] == mine.any(axis=1).sum()
 
