@@ -158,7 +158,8 @@ def test_flush_and_ingest_programs_hold_the_kernel(one_chip,
     assert "tpu_custom_call" in flush.as_text()
     ingest = _ingest_samples.lower(
         digest, temp, _i32((CHUNK,), one_chip), _f32((CHUNK,), one_chip),
-        _f32((CHUNK,), one_chip), COMPRESSION, True).compile()
+        _f32((CHUNK,), one_chip), _i32((), one_chip), _i32((), one_chip),
+        COMPRESSION, True).compile()
     assert "tpu_custom_call" in ingest.as_text()
     # one generation's planes, the flush's scratch and its outputs fit
     # the chip's 16 GB many times over at this size
@@ -444,6 +445,95 @@ def test_dense_import_holds_the_kernel(one_chip, kernel_admitted):
     assert imp.memory_analysis().alias_size_in_bytes >= 4 * rows * K * 4
 
 
+def test_sample_ingest_drains_held_rows_behind_a_branch(one_chip,
+                                                        kernel_admitted):
+    """The row-drained sample ingest at 2^20 rows on one chip: the
+    import path's drain, one loop whose body holds the kernel and works
+    on a slab of the rows to drain, inside a ``conditional``, so that a
+    chunk that meets no held row runs neither the loop nor what carries
+    the planes into it (a dispatch of ``dense``'s last ones or of
+    ``wide`` costs what it cost)."""
+    import re
+
+    from veneur_tpu.core.store import _ingest_samples
+    from veneur_tpu.ops.tdigest import ROW_DRAIN_SLAB_ROWS
+
+    rows = 1 << 20
+    digest, temp = (_on(t, one_chip) for t in _digest_state(rows))
+    compiled = _ingest_samples.lower(
+        digest, temp, _i32((CHUNK,), one_chip), _f32((CHUNK,), one_chip),
+        _f32((CHUNK,), one_chip), _i32((), one_chip), _i32((), one_chip),
+        COMPRESSION, True).compile()
+    text = compiled.as_text()
+    comps = _computations(text)
+    loops = [ln for lines in comps.values() for ln in lines
+             if " while(" in ln]
+    drains = [ln for ln in loops
+              if any("tpu_custom_call" in x for c in _called(ln)
+                     for x in comps.get(c, []))]
+    assert len(drains) == 1
+    body = re.search(r"body=%?([\w.\-]+)", drains[0]).group(1)
+    assert any(f"f32[{ROW_DRAIN_SLAB_ROWS},{K}]" in ln for ln in comps[body])
+    # the loop sits in a branch, not in the entry computation
+    entry = re.search(r"ENTRY %?([\w.\-]+)", text).group(1)
+    assert drains[0] not in comps[entry]
+    branches = {n for lines in comps.values() for ln in lines
+                if " conditional(" in ln for n in _called(ln)}
+    holder = [n for n, lines in comps.items() if drains[0] in lines]
+    assert set(holder) <= branches, (holder, branches)
+    # ... of the entry's one conditional, whose other branch, the chunk
+    # that drains nothing, makes no plane: the branches agree on their
+    # results' layout, and a row drain in a branch of its own made
+    # that chunk relay both digest planes row-major and back
+    outer = [ln for ln in comps[entry] if " conditional(" in ln]
+    assert len(outer) == 1
+    idle = [n for n in _called(outer[0])
+            if not any(drains[0] in comps[c] for c in _reach(comps, [n]))]
+    assert len(idle) == 1
+    made = [ln for ln in comps[idle[0]]
+            if rows * K in _result_elements(ln)[0]
+            and _result_elements(ln)[1] not in ("parameter", "tuple",
+                                                "get-tuple-element")]
+    assert not made, made
+    # every plane is updated in place: digest and bin planes aliased
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * rows * K * 4
+
+
+def _reach(comps, names):
+    """The computations ``names`` call, themselves included."""
+    seen, todo = set(), list(names)
+    while todo:
+        n = todo.pop()
+        if n in seen or n not in comps:
+            continue
+        seen.add(n)
+        for line in comps[n]:
+            todo += _called(line)
+    return seen
+
+
+def test_topk_update_at_the_cell_s_table(one_chip):
+    """The count-min update at ``standalone-hot1m``'s shapes (a 4 x 2^20
+    table, 4,096 rows of 32 places, a 16,384-line chunk) compiles for
+    one chip: the table is updated in place, the candidates are ranked
+    by two sorts of the chunk, and nothing of it needs more than a
+    fraction of the chip."""
+    from veneur_tpu.ops import countmin as cm
+
+    sk = _on(jax.eval_shape(lambda: cm.init(4096, 4, 1 << 20, 32)),
+             one_chip)
+    u32 = jax.ShapeDtypeStruct((CHUNK,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(cm.update, donate_argnums=(0,)).lower(
+        sk, _i32((CHUNK,), one_chip), u32, u32, u32,
+        _f32((CHUNK,), one_chip)).compile()
+    text = compiled.as_text()
+    assert " sort(" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * (1 << 20) * 4     # the table
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < (16 << 30) // 8
+
+
 def _result_elements(line):
     """Element counts of the arrays an HLO instruction produces (a
     tuple's members each), and its opcode."""
@@ -496,17 +586,7 @@ def _plane_traffic(text, plane):
     one and are no part of the row drain's loop, copies that produce
     one, other producers that are not an in-place update)."""
     comps = _computations(text)
-
-    def reach(names):
-        seen, todo = set(), list(names)
-        while todo:
-            n = todo.pop()
-            if n in seen or n not in comps:
-                continue
-            seen.add(n)
-            for line in comps[n]:
-                todo += _called(line)
-        return seen
+    reach = lambda names: _reach(comps, names)  # noqa: E731
 
     def holds_kernel(names):
         return any("tpu_custom_call" in ln for c in reach(names)
@@ -580,8 +660,8 @@ def test_ingest_converts_no_bin_plane(program, one_chip, mesh_series4,
 
         digest, temp = (_on(t, one_chip) for t in _digest_state(rows))
         compiled = _ingest_samples.lower(
-            digest, temp, chunk_i, chunk_f, chunk_f, COMPRESSION,
-            True).compile()
+            digest, temp, chunk_i, chunk_f, chunk_f, _i32((), one_chip),
+            _i32((), one_chip), COMPRESSION, True).compile()
         most_copies = 0
     elif program == "_ingest_centroids":
         from veneur_tpu.core.store import _ingest_centroids

@@ -97,6 +97,81 @@ class TestCountMinKernel:
         assert np.allclose(c1, 30.0)
 
 
+def _zipf_streams(seed, streams=4, lines=800, drains=2, keys=100_000,
+                  zipf_s=0.99):
+    """``drains`` batches of ``streams`` x ``lines`` key ids, Zipf over
+    ``keys`` keys (float64 NumPy, nothing of veneur_tpu): a list of
+    (rows, ids) and the exact frequency of every (stream, id)."""
+    rng = np.random.default_rng(seed)
+    law = np.arange(1, keys + 1, dtype=np.float64) ** -zipf_s
+    law /= law.sum()
+    names = rng.integers(1, 1 << 62, (streams, keys), dtype=np.uint64)
+    batches, exact = [], collections.Counter()
+    for _ in range(drains):
+        rows = np.repeat(np.arange(streams), lines)
+        ids = names[rows, rng.choice(keys, streams * lines, p=law)]
+        order = rng.permutation(len(rows))
+        batches.append((rows[order], ids[order]))
+        exact.update(zip(rows.tolist(), ids.tolist()))
+    return batches, exact
+
+
+class TestTopkKeptByEstimate:
+    """A drain brings a stream some 790 distinct candidates for K = 32
+    places: the list is kept by estimate, so no key is left out whose
+    exact frequency exceeds the list's last count, and no count is
+    under the exact frequency (the parent scattered candidates into a
+    4K ring by a hash, last writer wins, and lost keys of frequency
+    10-101 beside rows of frequency 1)."""
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_no_heavy_hitter_left_out_no_undercount(self, seed):
+        streams, k = 4, 32
+        batches, exact = _zipf_streams(seed, streams)
+        sk = cm.init(streams, depth=4, width=1 << 16, k=k)
+        pad = 1024  # a chunk's padding: out-of-range rows, count 0
+        for rows, ids in batches:
+            hi, lo = _split(np.concatenate([ids, np.zeros(pad, np.uint64)]))
+            rows = np.concatenate([rows, np.full(pad, streams)])
+            sk = cm.update(
+                sk, jnp.asarray(rows, jnp.int32),
+                jnp.asarray(rows + 7, jnp.uint32), hi, lo,
+                jnp.asarray(rows < streams, jnp.float32))
+        got_ids = (np.asarray(sk.topk_hi).astype(np.uint64) << np.uint64(32)
+                   | np.asarray(sk.topk_lo).astype(np.uint64))
+        got_ct = np.asarray(sk.topk_counts)
+        for r in range(streams):
+            assert (got_ct[r] > 0).all()        # 1,000+ keys for 32 places
+            listed = dict(zip(got_ids[r].tolist(), got_ct[r].tolist()))
+            assert len(listed) == k             # no key twice
+            under = [i for i, c in listed.items() if c < exact[(r, i)]]
+            assert not under
+            last = min(listed.values())
+            missed = [(i, f) for (row, i), f in exact.items()
+                      if row == r and i not in listed and f > last]
+            assert not missed, (r, last, sorted(missed)[:5])
+
+    def test_a_key_repeated_in_a_batch_is_one_candidate(self):
+        """1,000 lines of 8 keys: every key once in the list, at its
+        exact frequency."""
+        rng = np.random.default_rng(5)
+        ids = rng.integers(1, 1 << 62, 8, dtype=np.uint64)
+        stream = np.repeat(ids, 125)
+        rng.shuffle(stream)
+        sk = cm.init(2, depth=4, width=1 << 14, k=4)
+        hi, lo = _split(stream)
+        rows = jnp.ones(len(stream), jnp.int32)
+        sk = cm.update(sk, rows, rows.astype(jnp.uint32), hi, lo,
+                       jnp.ones(len(stream), jnp.float32))
+        assert (np.asarray(sk.topk_counts)[0] == 0).all()
+        np.testing.assert_array_equal(np.asarray(sk.topk_counts)[1],
+                                      [125.0] * 4)
+        got = (np.asarray(sk.topk_hi)[1].astype(np.uint64) << np.uint64(32)
+               | np.asarray(sk.topk_lo)[1].astype(np.uint64))
+        assert len(set(got.tolist())) == 4 and set(got.tolist()) <= set(
+            ids.tolist())
+
+
 class TestHeavyHitterStore:
     def test_end_to_end_topk_emission(self):
         store = MetricStore(initial_capacity=16, chunk=256)

@@ -413,3 +413,117 @@ class TestSighupReload:
             assert server.config.forward_address  # still local
         finally:
             server.shutdown()
+
+
+# -- one interval of a small zipf-churn mix against a float64 reference ------
+
+
+def _zipf_draw(rng, universe, lines, s=0.99):
+    import numpy as np
+
+    law = np.arange(1, universe + 1, dtype=np.float64) ** -s
+    return rng.choice(universe, lines, p=law / law.sum())
+
+
+def _rank_error(samples, x, q):
+    """Float64 NumPy order statistics, nothing of veneur_tpu: how far
+    ``q`` lies outside the rank interval of ``x`` among ``samples``; a
+    value strictly between two neighbours counts as either."""
+    import numpy as np
+
+    s = np.sort(np.asarray(samples, np.float64))
+    below, upto = (s < x).sum() / len(s), (s <= x).sum() / len(s)
+    if below <= q <= upto:
+        return 0.0
+    err = min(abs(below - q), abs(upto - q))
+    if below == upto and 0 < below < 1:
+        err = max(err - 1.0 / len(s), 0.0)
+    return err
+
+
+class TestZipfChurnInterval:
+    """6,000 Zipf(0.99) timer lines over 8,192 names, 500 counters, 500
+    gauges and 4 top-k streams of 500 lines over 100,000 keys through
+    the server's own packet path, store and flusher, with ``store_chunk``
+    cut to 512 so that a row's samples span a dozen ingest dispatches
+    and a stream's lines four count-min updates: counters, gauges,
+    ``count`` / ``min`` / ``max`` exact, percentiles by rank, the top-k
+    lists with no count under the exact one and no key left out that
+    exceeds a list's last."""
+
+    @pytest.mark.parametrize("seed", [11, 12])
+    def test_against_the_reference(self, seed):
+        import collections
+
+        import numpy as np
+
+        rng = np.random.default_rng(seed)
+        hist = collections.defaultdict(list)
+        lines = []
+        for name, v in zip(_zipf_draw(rng, 8192, 6000),
+                           rng.integers(0, 400000, 6000) / 4.0):
+            hist[f"z.{name}"].append(float(np.float32(v)))
+            lines.append(f"z.{name}:{float(v)!r}|h")
+        counters = rng.integers(1, 1000, 500)
+        lines += [f"c.{i}:{v}|c" for i, v in enumerate(counters)]
+        gauges = collections.defaultdict(float)
+        for i, v in zip(rng.integers(0, 500, 1000),
+                        rng.integers(0, 400000, 1000) / 4.0):
+            gauges[f"g.{i}"] = float(v)
+            lines.append(f"g.{i}:{float(v)!r}|g")
+        hot = collections.Counter()
+        for stream in range(4):
+            for key in _zipf_draw(rng, 100_000, 500):
+                hot[(f"hot.{stream}.topk", f"k{key}")] += 1
+                lines.append(f"hot.{stream}:k{key}|s|#veneurtopk")
+        # timers and top-k lines interleaved as they arrive; a gauge's
+        # writes keep their order
+        order = rng.permutation(len(lines))
+        gauge_at = sorted(i for i in order if "|g" in lines[i])
+        it = iter(gauge_at)
+        lines = [lines[next(it)] if "|g" in lines[i] else lines[i]
+                 for i in order]
+
+        server, sink = make_server(
+            store_initial_capacity=8192, store_chunk=512,
+            percentiles=[0.5, 0.75, 0.99], topk_k=32)
+        try:
+            for i in range(0, len(lines), 40):
+                server.handle_packet("\n".join(lines[i:i + 40]).encode())
+            server.flush()
+            batch = sink.get_flush()
+        finally:
+            server.shutdown()
+
+        got = {}
+        topk = collections.defaultdict(dict)
+        for m in batch:
+            if m.name.endswith(".topk"):
+                key = [t for t in m.tags if t.startswith("key:")][0][4:]
+                topk[m.name][key] = m.value
+            elif not m.name.startswith("veneur."):
+                got[m.name] = m.value
+        for i, v in enumerate(counters):
+            assert got[f"c.{i}"] == float(v)
+        for name, v in gauges.items():
+            assert got[name] == v
+        worst = 0.0
+        for name, samples in hist.items():
+            assert got[name + ".count"] == len(samples)
+            assert got[name + ".min"] == min(samples)
+            assert got[name + ".max"] == max(samples)
+            for q in (0.5, 0.75, 0.99):
+                x = got[f"{name}.{int(q * 100)}percentile"]
+                worst = max(worst, _rank_error(samples, x, q))
+        assert worst < 0.04, worst
+        live = {n for n in got if n.startswith("z.")}
+        assert len(live) == 6 * len(hist)       # no row for a name not sent
+        for stream in range(4):
+            name = f"hot.{stream}.topk"
+            listed = topk[name]
+            assert len(listed) == 32
+            exact = {k: f for (n, k), f in hot.items() if n == name}
+            assert not [k for k, c in listed.items() if c < exact[k]]
+            last = min(listed.values())
+            assert not [(k, f) for k, f in exact.items()
+                        if k not in listed and f > last]

@@ -216,13 +216,19 @@ class TestMergeRole:
 class TestStoreWiring:
     """digest_storage='slab' must be behaviorally identical to the dense
     store on the same traffic (the store-level oracle that makes the
-    capacity plan a product path, not a bench harness)."""
+    capacity plan a product path, not a bench harness): to the last
+    digit where no series' samples span a dispatch (``chunk=1024``),
+    and every exact row identical with every percentile inside the rank
+    bound where they do (``chunk=128``: since PR 40 the dense store
+    drains a row that holds bin mass before it bins more into it,
+    ops/tdigest.py ingest_chunk_rowdrained, and the slab store still
+    bins it against the anchor summary)."""
 
-    def _stores(self):
+    def _stores(self, chunk=1024):
         from veneur_tpu.core.store import MetricStore
 
-        dense = MetricStore(initial_capacity=64, chunk=128)
-        slab = MetricStore(initial_capacity=64, chunk=128,
+        dense = MetricStore(initial_capacity=64, chunk=chunk)
+        slab = MetricStore(initial_capacity=64, chunk=chunk,
                            digest_storage="slab", slab_rows=64)
         return dense, slab
 
@@ -230,18 +236,36 @@ class TestStoreWiring:
         from veneur_tpu.samplers.parser import (MetricKey, UDPMetric,
                                                 LOCAL_ONLY, MIXED_SCOPE)
 
+        sent = {}
         for i in range(150):
-            store.process_metric(UDPMetric(
-                key=MetricKey(name=f"lat{i % 20}", type="timer"),
-                value=float(rng.integers(1, 500)), tags=["route:a"],
-                sample_rate=1.0, scope=MIXED_SCOPE, digest=0))
-            store.process_metric(UDPMetric(
-                key=MetricKey(name=f"hist{i % 7}", type="histogram"),
-                value=float(rng.integers(1, 100)), tags=[],
-                sample_rate=0.5, scope=LOCAL_ONLY, digest=0))
+            for name, kind, high, tags, rate, scope in (
+                    (f"lat{i % 20}", "timer", 500, ["route:a"], 1.0,
+                     MIXED_SCOPE),
+                    (f"hist{i % 7}", "histogram", 100, [], 0.5,
+                     LOCAL_ONLY)):
+                value = float(rng.integers(1, high))
+                sent.setdefault(name, []).append(value)
+                store.process_metric(UDPMetric(
+                    key=MetricKey(name=name, type=kind), value=value,
+                    tags=tags, sample_rate=rate, scope=scope, digest=0))
         store.import_digest(MetricKey(name="fleet.lat", type="histogram"),
                             ["dc:x"], np.asarray([10.0, 20.0, 30.0]),
                             np.asarray([1.0, 2.0, 1.0]), 10.0, 30.0)
+        return sent
+
+    @staticmethod
+    def _rank_error(samples, x, q):
+        """How far ``q`` lies outside the rank interval of ``x`` among
+        ``samples`` (a value strictly between two neighbours counts as
+        either)."""
+        s = np.sort(np.asarray(samples, np.float64))
+        below, upto = (s < x).sum() / len(s), (s <= x).sum() / len(s)
+        if below <= q <= upto:
+            return 0.0
+        err = min(abs(below - q), abs(upto - q))
+        if below == upto and 0 < below < 1:
+            err = max(err - 1.0 / len(s), 0.0)
+        return err
 
     def test_store_parity_dense_vs_slab(self):
         from veneur_tpu.samplers.intermetric import HistogramAggregates
@@ -258,25 +282,69 @@ class TestStoreWiring:
             assert ms.timers == 20 and ms.local_histograms == 7
         assert outs[0] == outs[1]
 
-    def test_store_slab_forwardable(self):
-        """is_local=True: digests export for forwarding from the slab
-        store exactly as from the dense one."""
+    def test_store_parity_across_dispatches(self):
+        """A series' samples over several dispatches: min, max and count
+        of every series and the imported digest's rows identical, every
+        percentile of either store inside the rank bound of the samples
+        sent."""
+        from veneur_tpu.samplers.intermetric import HistogramAggregates
+
+        agg = HistogramAggregates.from_names(
+            ["min", "max", "count", "median"])
+        quantile_of = {"50percentile": 0.5, "99percentile": 0.99,
+                       "median": 0.5}
+        outs = []
+        for store in self._stores(chunk=128):
+            sent = self._drive(store, np.random.default_rng(9))
+            final, fwd, ms = store.flush([0.5, 0.99], agg, is_local=False,
+                                         now=1000, forward=False)
+            exact = []
+            for m in final:
+                series, _, suffix = m.name.rpartition(".")
+                if series in sent and suffix in quantile_of:
+                    assert self._rank_error(
+                        sent[series], m.value, quantile_of[suffix]) <= 0.02
+                else:
+                    exact.append((m.name, tuple(m.tags), round(m.value, 2)))
+            outs.append(sorted(exact))
+            assert ms.timers == 20 and ms.local_histograms == 7
+        assert len(outs[0]) == 3 * 27 + 3 and outs[0] == outs[1]
+
+    def _forwarded(self, chunk):
         from veneur_tpu.samplers.intermetric import HistogramAggregates
 
         agg = HistogramAggregates.from_names(["count"])
         fwds = []
-        for store in self._stores():
+        for store in self._stores(chunk):
             self._drive(store, np.random.default_rng(11))
             _, fwd, _ = store.flush([0.5], agg, is_local=True, now=0,
                                     forward=True)
             fwds.append(fwd)
         a, b = fwds
         assert len(a.timers) == len(b.timers) == 20
-        for (n1, t1, m1, w1, lo1, hi1), (n2, t2, m2, w2, lo2, hi2) in zip(
-                sorted(a.timers), sorted(b.timers)):
+        return zip(sorted(a.timers), sorted(b.timers))
+
+    def test_store_slab_forwardable(self):
+        """is_local=True: digests export for forwarding from the slab
+        store exactly as from the dense one."""
+        for (n1, t1, m1, w1, lo1, hi1), (n2, t2, m2, w2, lo2, hi2) in \
+                self._forwarded(chunk=1024):
             assert n1 == n2 and t1 == t2 and lo1 == lo2 and hi1 == hi2
             np.testing.assert_allclose(m1, m2, rtol=1e-6)
             np.testing.assert_allclose(w1, w2, rtol=1e-6)
+
+    def test_store_slab_forwardable_across_dispatches(self):
+        """Across dispatches the forwarded digests carry the same mass,
+        first moment and extrema; the centroids differ where the dense
+        store's row drain kept apart what the slab's bins merged."""
+        for (n1, t1, m1, w1, lo1, hi1), (n2, t2, m2, w2, lo2, hi2) in \
+                self._forwarded(chunk=128):
+            assert n1 == n2 and t1 == t2 and lo1 == lo2 and hi1 == hi2
+            assert np.sum(w1) == np.sum(w2)
+            np.testing.assert_allclose(np.dot(m1, w1), np.dot(m2, w2),
+                                       rtol=1e-5)
+            assert lo1 <= np.min(m1) and np.max(m1) <= hi1
+            assert lo2 <= np.min(m2) and np.max(m2) <= hi2
 
     def test_slab_group_grows(self):
         from veneur_tpu.core.slab import SlabDigestGroup

@@ -471,3 +471,97 @@ class TestFlushLiveRows:
         stage = next(s for s in rec.finish()["stages"]
                      if s["name"].endswith("compute"))
         assert (stage["rows_live"], stage["rows_run"]) == (n, run)
+
+
+class TestFlushCompilesNothing:
+    """Three flushes with three different live-row counts compile no
+    program after the first: what comes off the device is the count's
+    pow2 bucket of rows, cut to the count on the host. Sliced at the
+    count itself every new count compiled a program a shape inside the
+    flush (two a flush under churning names: PERF.md, PR 39)."""
+
+    COUNTS = (700, 650, 900)    # one bucket, three counts
+
+    @staticmethod
+    def _compiles():
+        from veneur_tpu.obs import kernels as obs_kernels
+
+        return obs_kernels._compile["programs"]
+
+    @staticmethod
+    def _keys(kind, n):
+        return [MetricKey(name=f"{kind}{i}", type=kind, joined_tags="")
+                for i in range(n)]
+
+    def _flushes(self, group, fill, flush, rows_of):
+        seen = []
+        for n in self.COUNTS:
+            fill(group, n)
+            before = self._compiles()
+            out = flush(group)
+            seen.append(self._compiles() - before)
+            assert rows_of(out) == n
+        assert seen[1:] == [0, 0], seen
+
+    def test_digest_group(self):
+        from veneur_tpu.core.store import DigestGroup
+
+        def fill(g, n):
+            rows = np.asarray([g._row(k, []) for k in
+                               self._keys("histogram", n)], np.int32)
+            g.sample_many(rows, np.arange(n, dtype=np.float32),
+                          np.ones(n, np.float32))
+
+        def flush(g):
+            snap = g.snapshot_state()       # the checkpoint's slices too
+            assert len(snap["count"]) == len(g)
+            return g.flush_begin([0.5, 0.99])()
+
+        self._flushes(DigestGroup(capacity=4096, chunk=1024), fill, flush,
+                      lambda out: len(out[1]["percentiles"]))
+
+    def test_digest_group_answers_are_the_rows_own(self):
+        """Cut on the host, every row still reads its own sample."""
+        from veneur_tpu.core.store import DigestGroup
+
+        g = DigestGroup(capacity=4096, chunk=1024)
+        n = 700
+        rows = np.asarray([g._row(k, []) for k in
+                           self._keys("histogram", n)], np.int32)
+        g.sample_many(rows, np.arange(n, dtype=np.float32) + 0.25,
+                      np.ones(n, np.float32))
+        _interner, out = g.flush([0.5])
+        for key in ("min", "max", "median"):
+            np.testing.assert_array_equal(
+                out[key], np.arange(n, dtype=np.float32) + 0.25, key)
+        assert out["percentiles"].shape == (n, 1)
+        assert out["digest_mean"].shape[0] == n
+
+    def test_set_group(self):
+        from veneur_tpu.core.store import SetGroup
+
+        def fill(g, n):
+            for i, k in enumerate(self._keys("set", n)):
+                g.sample(k, [], f"m{i}")
+
+        def flush(g):
+            assert len(g.snapshot_state()["registers"]) == len(g)
+            return g.flush_begin()()
+
+        self._flushes(SetGroup(capacity=4096, chunk=1024, precision=10),
+                      fill, flush, lambda out: len(out[1]))
+
+    def test_heavy_hitter_group(self):
+        from veneur_tpu.core.store import HeavyHitterGroup
+
+        def fill(g, n):
+            for i, k in enumerate(self._keys("set", n)):
+                g.sample(k, [], f"m{i % 7}")
+
+        def flush(g):
+            assert len(g.snapshot_state()["series"]) == len(g)
+            return g.flush_begin()()
+
+        self._flushes(
+            HeavyHitterGroup(capacity=4096, chunk=1024, width=1 << 12, k=4),
+            fill, flush, lambda out: len({row for row, _m, _c in out[1]}))

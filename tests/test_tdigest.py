@@ -341,7 +341,9 @@ class TestLiveRowBound:
 class PlainTemp:
     """The bin accumulation as it was written before the planes went
     flat, kept here as the reference: ``[S, K]`` / ``[S, A]`` planes,
-    ``.at[r, b].add``, the guard's whole-width drain."""
+    ``.at[r, b].add``, the sample path's two drains at whole width (the
+    held rows up to ``ROW_DRAIN_MAX_ARRIVALS``, then the shift guard's
+    every row)."""
 
     def __init__(self, rows):
         self.rows = rows
@@ -350,17 +352,44 @@ class PlainTemp:
         self.seg_w = jnp.zeros((rows, td.BELOW_MASS_ANCHORS), jnp.float32)
         self.seg_wm = jnp.zeros((rows, td.BELOW_MASS_ANCHORS), jnp.float32)
         self.digest = td.init((rows,), C, K)
+        self.drained, self.guard_drains = 0, 0
+
+    def _drain(self, which, use_pallas):
+        """Rows ``which`` ([S] bool) compressed into the digest, their
+        bins and anchors emptied."""
+        mean, weight = td._merge_bins(
+            self.digest.mean, self.digest.weight,
+            jnp.where(which[:, None], self.sum_w, 0.0),
+            jnp.where(which[:, None], self.sum_wm, 0.0), C, K, use_pallas)
+        self.digest = self.digest._replace(
+            mean=jnp.where(which[:, None], mean, self.digest.mean),
+            weight=jnp.where(which[:, None], weight, self.digest.weight))
+        keep = ~which[:, None]
+        self.sum_w, self.sum_wm = self.sum_w * keep, self.sum_wm * keep
+        self.seg_w, self.seg_wm = self.seg_w * keep, self.seg_wm * keep
 
     def ingest(self, rows, values, weights, guarded=False,
-               use_pallas=False):
-        if guarded and bool(td.shift_pred(self.seg_w, self.seg_wm, rows,
-                                          values, weights, self.rows)):
-            mean, weight = td._merge_bins(
-                self.digest.mean, self.digest.weight, self.sum_w,
-                self.sum_wm, C, K, use_pallas)
-            self.digest = self.digest._replace(mean=mean, weight=weight)
-            self.sum_w, self.sum_wm = (jnp.zeros_like(self.sum_w),) * 2
-            self.seg_w, self.seg_wm = (jnp.zeros_like(self.seg_w),) * 2
+               use_pallas=False, count=None):
+        """``guarded``: the sample path's two drains first. ``count``
+        ([S], the interval's weight a row so far) is what the row drain
+        reads beside the held mass."""
+        if guarded:
+            r = np.asarray(rows)
+            w = np.asarray(weights)
+            live = (r < self.rows) & (w > 0)
+            held = np.asarray(self.seg_w.sum(axis=1)) > 0
+            due = np.zeros(self.rows, bool)
+            at = np.minimum(r, self.rows - 1)
+            due[at[live & held[at] & (
+                np.asarray(count)[at] <= td.ROW_DRAIN_MAX_ARRIVALS * w)]] \
+                = True
+            self.drained = int(due.sum())
+            if due.any():
+                self._drain(jnp.asarray(due), use_pallas)
+            if bool(td.shift_pred(self.seg_w, self.seg_wm, rows, values,
+                                  weights, self.rows)):
+                self.guard_drains += 1
+                self._drain(jnp.ones(self.rows, bool), use_pallas)
         r, v, w, b = td.bin_flat_samples(
             rows, values, weights, self.rows, K, C, acc_seg_w=self.seg_w,
             acc_seg_wm=self.seg_wm)
@@ -412,40 +441,61 @@ def _flat_case(name):
                        np.ones(4096, np.float32)) for _ in range(2)]
     if name == "a_group_of_eight_rows":
         return 8, [chunk(8, 512, 8) for _ in range(4)]
+    if name == "sampled_rows_past_and_under_the_arrivals":
+        # rows 0-3 sent @0.01 (11 arrivals weigh 1,100), rows 4-7 @1.0
+        out = []
+        for _ in range(3):
+            r, v, w = chunk(8, 88, 8)
+            r[:] = np.arange(88) % 8
+            w[r < 4] = 100.0
+            out.append((r, v, w))
+        return 8, out
+    # 512 samples a row a chunk: the fourth chunk finds every row past
+    # ROW_DRAIN_MAX_ARRIVALS, so the step is the shift guard's to catch
     assert name == "guard_drain_in_the_middle"
-    return 16, ([chunk(16, 1024, 16) for _ in range(3)]
-                + [chunk(16, 1024, 16, shift=1e4)]
-                + [chunk(16, 1024, 16, shift=1e4) for _ in range(2)])
+    return 16, ([chunk(16, 8192, 16) for _ in range(3)]
+                + [chunk(16, 8192, 16, shift=1e4)]
+                + [chunk(16, 8192, 16, shift=1e4) for _ in range(2)])
 
 
 FLAT_CASES = ["one_chunk", "sixteen_chunks_same_rows",
               "padding_rows_and_zero_weights", "spread_over_every_row",
-              "a_group_of_eight_rows", "guard_drain_in_the_middle"]
+              "a_group_of_eight_rows",
+              "sampled_rows_past_and_under_the_arrivals",
+              "guard_drain_in_the_middle"]
 
 
 @pytest.mark.parametrize("use_pallas", [False, True],
                          ids=["xla_rung", "kernel_rung"])
 @pytest.mark.parametrize("case", FLAT_CASES)
 def test_flat_accumulation_equals_the_plain_planes(case, use_pallas):
-    """``ingest_chunk_guarded`` on the flat planes leaves the bins, the
-    anchors and the digest of the ``[S, K]`` formulation, bit for bit
-    (the scatter keeps its order of additions in both)."""
+    """``ingest_chunk_rowdrained`` on the flat planes leaves the bins,
+    the anchors and the digest of the ``[S, K]`` formulation, bit for
+    bit (the scatter keeps its order of additions in both), drains the
+    rows it drains, and leaves the step change to the shift guard once
+    the rows are past ``ROW_DRAIN_MAX_ARRIVALS``."""
     rows, chunks = _flat_case(case)
     plain = PlainTemp(rows)
     temp, digest = td.init_temp(rows, K, C), td.init((rows,), C, K)
-    step = jax.jit(lambda d, t, *c: td.ingest_chunk_guarded(
+    step = jax.jit(lambda d, t, *c: td.ingest_chunk_rowdrained(
         d, t, *c, C, use_pallas=use_pallas))
     for c in chunks:
         c = tuple(jnp.asarray(x) for x in c)
-        plain.ingest(*c, guarded=True, use_pallas=use_pallas)
-        digest, temp = step(digest, temp, *c)
+        plain.ingest(*c, guarded=True, use_pallas=use_pallas,
+                     count=temp.count)
+        digest, temp, drained = step(digest, temp, *c)
+        assert int(drained) == plain.drained
         assert_planes_equal(temp, plain)
     np.testing.assert_array_equal(np.asarray(digest.weight),
                                   np.asarray(plain.digest.weight))
     np.testing.assert_array_equal(np.asarray(digest.mean),
                                   np.asarray(plain.digest.mean))
-    drained = bool(np.asarray(digest.weight).any())
-    assert drained is (case == "guard_drain_in_the_middle")
+    assert bool(np.asarray(digest.weight).any()) is (case != "one_chunk")
+    assert plain.guard_drains == (case == "guard_drain_in_the_middle")
+    if case == "sampled_rows_past_and_under_the_arrivals":
+        # 11 arrivals @0.01 weigh 1,100: counted as arrivals the row is
+        # drained at every later chunk like its neighbours @1.0
+        assert plain.drained == 8
 
 
 class TestFlatPlanes:
@@ -500,3 +550,104 @@ class TestFlatPlanes:
         twice = td.ingest_chunk(big, *c, C)
         for g, w in zip(again, twice):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# -- sparse rows: a row's few samples an interval, a handful a chunk ----------
+
+SPARSE_SERIES = 256
+SPARSE_CHUNK = 8192
+SPARSE_QS = (0.5, 0.75, 0.99)
+SAMPLE_INGEST = jax.jit(
+    lambda d, t, r, v, w: td.ingest_chunk_rowdrained(
+        d, t, r, v, w, 100.0, False)[:2])
+_sparse_flush = jax.jit(lambda d, t, qs: td.drain_and_quantile(
+    d, t, jnp.full((SPARSE_SERIES,), jnp.inf),
+    jnp.full((SPARSE_SERIES,), -jnp.inf), qs, 100.0, use_pallas=False)[1])
+
+
+def _rank_error_f64(samples, x, q):
+    """How far ``q`` lies outside the rank interval of the emitted ``x``
+    among ``samples`` (float64 NumPy order statistics, nothing of
+    veneur_tpu): 0 inside it. Among a few samples a value strictly
+    between two neighbours counts as either of them, since an
+    interpolated quantile would otherwise be charged a sample's rank."""
+    s = np.sort(np.asarray(samples, np.float64))
+    below = (s < x).sum() / len(s)
+    upto = (s <= x).sum() / len(s)
+    if below <= q <= upto:
+        return 0.0
+    err = min(abs(below - q), abs(upto - q))
+    if below == upto and 0 < below < 1:
+        err = max(err - 1.0 / len(s), 0.0)
+    return err if np.isfinite(x) else 1.0
+
+
+@pytest.mark.parametrize("sample_rate", [1.0, 0.1, 0.01])
+@pytest.mark.parametrize("chunks", [1, 4, 12])
+@pytest.mark.parametrize("samples", [2, 3, 8, 60, 500])
+def test_sparse_rows_spread_over_chunks_stay_inside_the_bound(
+        samples, chunks, sample_rate):
+    """Rows of ``samples`` samples an interval, delivered a few a chunk
+    over ``chunks`` ingest dispatches, then the flush: every percentile
+    within 0.04 of its rank. Binned against the 8-anchor summary alone
+    (the parent) the spread cases alias value-distant samples into one
+    bin: 0.05-0.12 here, 0.3233 on the chip (PERF.md, PR 39). A line
+    sent ``|@0.1`` or ``|@0.01`` weighs 10 or 100: the row drain counts
+    arrivals, so such a row is held to the same bound."""
+    rows_each = 16 if samples == 500 else 128
+    worst = 0.0
+    for seed in range(3):
+        rng = np.random.default_rng(1000 * samples + 10 * chunks + seed)
+        vals = rng.integers(0, 400000, size=(rows_each, samples)) / 4.0
+        which = rng.integers(0, chunks, size=(rows_each, samples))
+        digest = td.init((SPARSE_SERIES,), 100.0)
+        temp = td.init_temp(SPARSE_SERIES, td.size_bound(100.0), 100.0)
+        for c in range(chunks):
+            r, j = np.nonzero(which == c)
+            order = rng.permutation(len(r))
+            rows = np.full(SPARSE_CHUNK, SPARSE_SERIES, np.int32)
+            v = np.zeros(SPARSE_CHUNK, np.float32)
+            w = np.zeros(SPARSE_CHUNK, np.float32)
+            rows[:len(r)] = r[order]
+            v[:len(r)] = vals[r, j][order]
+            w[:len(r)] = 1.0 / sample_rate
+            digest, temp = SAMPLE_INGEST(digest, temp, jnp.asarray(rows),
+                                         jnp.asarray(v), jnp.asarray(w))
+        pcts = np.asarray(_sparse_flush(
+            digest, temp, jnp.asarray(SPARSE_QS, jnp.float32)))
+        for i in range(rows_each):
+            for k, q in enumerate(SPARSE_QS):
+                worst = max(worst, _rank_error_f64(
+                    vals[i], float(pcts[i, k]), q))
+    assert worst < 0.04, worst
+
+
+@pytest.mark.parametrize("sample_rate", [1.0, 0.1, 0.01])
+def test_a_row_past_the_arrivals_keeps_the_anchored_binning(sample_rate):
+    """The row drain is for rows that still need it: one to which the
+    interval has brought more than ``ROW_DRAIN_MAX_ARRIVALS`` samples
+    is left alone (no drain counted, its bins keep their mass), one
+    under it is drained: by arrivals, whatever a sample weighs (150
+    samples sent ``|@0.1`` weigh 1,500)."""
+    digest = td.init((8,), 100.0)
+    temp = td.init_temp(8, td.size_bound(100.0), 100.0)
+    rng = np.random.default_rng(3)
+    big, few, weight = int(td.ROW_DRAIN_MAX_ARRIVALS) + 76, 150, \
+        1.0 / sample_rate
+    rows = np.concatenate([np.zeros(big, np.int32), np.ones(few, np.int32)])
+    step = jax.jit(lambda d, t, r, v: td.ingest_chunk_rowdrained(
+        d, t, r, v, jnp.full(r.shape, weight, jnp.float32), 100.0, False))
+    digest, temp, drained = step(digest, temp, jnp.asarray(rows),
+                                 jnp.asarray(rng.normal(0, 1, len(rows)),
+                                             jnp.float32))
+    assert int(drained) == 0                    # nothing held yet
+    digest, temp, drained = step(digest, temp, jnp.asarray(rows),
+                                 jnp.asarray(rng.normal(0, 1, len(rows)),
+                                             jnp.float32))
+    assert int(drained) == 1                    # row 1 alone
+    bins = np.asarray(temp.bins()[0]).sum(axis=1)
+    np.testing.assert_allclose(bins[:2], [2 * big * weight, few * weight],
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(digest.weight)[:2].sum(axis=1), [0.0, few * weight],
+        rtol=1e-6)

@@ -5,11 +5,12 @@ temp bins (0.44 rank error measured pre-fix); the quantile-anchored
 binning + cond-drain guard holds every swept regime inside the
 reference's eps=0.02 envelope (``tdigest/histo_test.go:11-25``)."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from veneur_tpu.analysis.tdigest_sweep import run_config
+from veneur_tpu.analysis.tdigest_sweep import (INGESTS, run_config,
+                                               sample_ingest)
 from veneur_tpu.ops import tdigest as td
 
 
@@ -76,9 +77,13 @@ class TestShiftGuard:
             jnp.full(four.size, 1e6, jnp.float32),
             jnp.ones(four.size, jnp.float32), rows))
 
-    def test_guarded_ingest_drains_into_digest(self):
+    @pytest.mark.parametrize("ingest", INGESTS)
+    def test_guarded_ingest_drains_into_digest(self, ingest):
         """A hard step change moves the accumulated bins into the digest
-        (weight appears there) and the final quantiles stay accurate."""
+        (weight appears there) and the final quantiles stay accurate:
+        by the shift guard alone (what the slab store runs), by the row
+        drain alone (the rows are under ``ROW_DRAIN_MAX_ARRIVALS``) and
+        by the dense store's sample path, which has both."""
         rows = 4
         n = 512
         rng = np.random.default_rng(1)
@@ -86,7 +91,7 @@ class TestShiftGuard:
                        axis=1)
         digest = td.init((rows,))
         temp = td.init_temp(rows)
-        guarded = jax.jit(td.ingest_chunk_guarded, static_argnums=(5, 6))
+        guarded = sample_ingest(ingest, td.DEFAULT_COMPRESSION)
         chunks = 8
         per = n // chunks
         flat = np.repeat(np.arange(rows, dtype=np.int32), per)
@@ -94,8 +99,7 @@ class TestShiftGuard:
             part = vals[:, c * per:(c + 1) * per].reshape(-1)
             digest, temp = guarded(digest, temp, jnp.asarray(flat),
                                    jnp.asarray(part),
-                                   jnp.ones(part.size, jnp.float32),
-                                   td.DEFAULT_COMPRESSION, True)
+                                   jnp.ones(part.size, jnp.float32))
         # sorted arrival trips the guard: mass reached the digest
         # before the final drain
         assert float(jnp.sum(digest.weight)) > 0
@@ -117,6 +121,17 @@ class TestSweepEnvelope:
     """Small sweep cells asserting the documented envelope; the full
     sweep (python -m veneur_tpu.analysis.tdigest_sweep) regenerates
     docs/tdigest_accuracy.*."""
+
+    def test_rows_past_the_row_drain_still_need_the_shift_guard(self):
+        """Ordered arrival at 256 samples a row a chunk: from the sixth
+        chunk on a row is past ``ROW_DRAIN_MAX_ARRIVALS`` and bins
+        against its anchors; only the shift guard keeps the step out of
+        them (PERF.md, PR 40: 0.149 without it)."""
+        cells = {i: run_config("sorted_asc", 100.0, "binned16", "float32",
+                               rows=4, n=4096, golden_rows=0, ingest=i)
+                 for i in ("rowdrained", "rowdrained_unguarded")}
+        assert cells["rowdrained"]["max_rank_err"] <= 0.02, cells
+        assert cells["rowdrained_unguarded"]["max_rank_err"] > 0.05, cells
 
     def test_ordered_arrival_binned_within_envelope(self):
         cell = run_config("sorted_asc", 100.0, "binned16", "float32",

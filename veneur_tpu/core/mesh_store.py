@@ -677,21 +677,14 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         with obs_rec.maybe_stage("gather"), \
                 obs_kernels.scope("flush.digest.mesh"):
             # padded with row 0 to the count's pow2 bucket, a slab at
-            # least (the bucket the warm-up compiled); _flush_collect
-            # cuts what was fetched back to n
+            # least (the bucket the warm-up compiled); the base class's
+            # _flush_collect cuts what it fetched back to n
             rows = np.zeros(min(max(pow2_cap(n), GATHER_MIN_ROWS),
                                 self.capacity), np.int32)
             rows[:n] = self._flush_rows(n)
             refs = _mesh_gather_rows(
                 planes + tuple(stats[nm] for nm in sel), jnp.asarray(rows))
         return (sel, False, None, refs)
-
-    def _flush_collect(self, pending, n: int, percentiles,
-                       want_digests) -> dict:
-        """The gather brought the count's pow2 bucket of rows: what
-        the base class fetched is cut back to ``n`` here."""
-        out = super()._flush_collect(pending, n, percentiles, want_digests)
-        return {name: rows[:n] for name, rows in out.items()}
 
     @requires_lock("store")
     def warm(self, percentiles, want_stats=None, samples: bool = True,
@@ -1020,11 +1013,13 @@ class MeshHeavyHitterGroup(_PlacementMixin, HeavyHitterGroup):
         if self._fill == 0:
             return
         self._device_dirty = True
+        self.dispatches += 1
         rows, hi, lo, wts = self._rows, self._hi, self._lo, self._wts
         self._new_sample_buffers()
         sids = self._sids_np[np.minimum(rows, self.capacity)]
-        self.sketch = self._update(self.sketch, self._to_phys(rows), sids,
-                                   hi, lo, wts)
+        with obs_kernels.scope("drain.topk.mesh"):
+            self.sketch = self._update(self.sketch, self._to_phys(rows),
+                                       sids, hi, lo, wts)
 
     def _scatter_rows(self, rows: np.ndarray) -> np.ndarray:
         return self._to_phys(rows)

@@ -59,7 +59,7 @@ import numpy as np
 
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.ops import tdigest_pallas
-from veneur_tpu.core.bucketing import pow2_cap
+from veneur_tpu.core.bucketing import bucketed, pow2_cap
 from veneur_tpu.core.locking import acquires_lock, requires_lock
 from veneur_tpu.obs import kernels as obs_kernels
 from veneur_tpu.obs import recorder as obs_rec
@@ -381,6 +381,31 @@ def begin_compute_ladder(compute, dispatch, collect, plane):
     return finish
 
 
+@bucketed("pow2")
+def live_bucket(n: int, capacity: int) -> int:
+    """Rows a flush or snapshot takes off the device for ``n`` live
+    ones: the count's pow2 bucket, at most the rows there are. The cut
+    to ``n`` is the host's, after the fetch (``cut_rows``)."""
+    return min(pow2_cap(n), capacity)
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _prefix_rows(arrays, rows: int):
+    """The first ``rows`` rows of every array, as fresh buffers, in one
+    program a bucket. Sliced op by op at the live count itself
+    (``x[:n]``) every new count compiled a program a shape inside the
+    flush: under churn no two intervals share one, and two compiles
+    made ``store.dispatch.histograms.compute`` 0.183-0.191 s (PERF.md,
+    PR 39)."""
+    return tuple(a[:rows] for a in arrays)
+
+
+def cut_rows(fetched, n: int):
+    """What ``_prefix_rows`` brought, cut to the ``n`` live rows on the
+    host."""
+    return tuple(a[:n] for a in fetched)
+
+
 @contextmanager
 def fetch_stage(refs):
     """One group's ``fetch`` stage (and ``veneur.fetch`` host scope in a
@@ -563,17 +588,24 @@ class ScalarGroup(OverloadLimited):
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6))
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(7, 8))
 def _ingest_samples(digest: td_ops.TDigest, temp: td_ops.TempCentroids,
-                    rows, values, weights, compression,
+                    rows, values, weights, drained, trips, compression,
                     use_pallas=True):
-    """Shift-guarded ingest (ops/tdigest.py ingest_chunk_guarded): a
-    distribution step drains the bins into the digest before re-binning,
-    so ordered/shifting arrival cannot alias values across bins.
-    ``use_pallas`` is a trace-time static: False keeps the guard drain
-    on the XLA path while the compute breaker is open."""
-    return td_ops.ingest_chunk_guarded(digest, temp, rows, values, weights,
-                                       compression, use_pallas=use_pallas)
+    """The sample path's ingest (ops/tdigest.py ingest_chunk_rowdrained):
+    the chunk's rows that hold bin mass and few samples are drained
+    into their digests before more is binned into them, and behind that
+    the shift guard drains every bin where the distribution steps, so
+    neither sparse nor ordered/shifting arrival aliases values across
+    bins. ``drained`` and ``trips`` (int32 scalars) count the rows
+    drained and the drain loop's trips. ``use_pallas`` is a trace-time
+    static: False keeps the drains on the XLA path while the compute
+    breaker is open."""
+    digest, temp, n = td_ops.ingest_chunk_rowdrained(
+        digest, temp, rows, values, weights, compression,
+        use_pallas=use_pallas)
+    return (digest, temp, drained + n,
+            trips + td_ops.row_drain_trips(n, rows.shape[0]))
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2, 3), static_argnums=(11, 12))
@@ -751,6 +783,11 @@ class DigestGroup(OverloadLimited):
         self.imp_route_ns = 0
         self.imp_dispatch_ns = 0
         self._imp_drains = None
+        # the sample path's: its dispatches, and the device's own
+        # counts of the rows they drained before binning into them and
+        # of the drain loop's trips (timeline ``ingest_samples``)
+        self.smp_dispatches = 0
+        self._row_drains = None
         self._init_staging()
 
     _DEVICE_STATE = ("temp", "digest", "dmin", "dmax")
@@ -958,13 +995,16 @@ class DigestGroup(OverloadLimited):
         if self._fill == 0:
             return
         self._device_dirty = True
+        self.smp_dispatches += 1
         rows, vals, wts = self._rows, self._vals, self._wts
         self._new_sample_buffers()
+        drained, trips = self._row_drains or (np.int32(0), np.int32(0))
         with obs_kernels.scope("drain.digest.dense"):
-            self.digest, self.temp = _ingest_samples(
+            self.digest, self.temp, drained, trips = _ingest_samples(
                 self.digest, self.temp, jnp.asarray(rows),
-                jnp.asarray(vals), jnp.asarray(wts), self.compression,
-                self._pallas_allowed())
+                jnp.asarray(vals), jnp.asarray(wts), drained, trips,
+                self.compression, self._pallas_allowed())
+        self._row_drains = (drained, trips)
 
     def _drain_imports(self):
         if self._imp_fill == 0 and self._imp_stat_fill == 0:
@@ -1078,12 +1118,17 @@ class DigestGroup(OverloadLimited):
         if self.imp_dispatches:
             obs_rec.note(import_dispatches=self.imp_dispatches,
                          import_centroids=self.imp_centroids)
+        if self._row_drains is not None:
+            obs_rec.note(ingest_samples_dispatches=self.smp_dispatches)
 
     def _guard_counters(self) -> dict:
         """The device's own drain counts, by the name they are noted
         under: fetched with the flush's results, in the one transfer
         (None where the path never ran)."""
-        return {"import_guard_drains": self._imp_drains}
+        rows, trips = self._row_drains or (None, None)
+        return {"import_guard_drains": self._imp_drains,
+                "ingest_samples_rows_drained": rows,
+                "ingest_samples_drain_trips": trips}
 
     def _flush_empty(self):
         """The n==0 flush path: skip the flush program AND the
@@ -1135,17 +1180,18 @@ class DigestGroup(OverloadLimited):
         # compute = the program's dispatch (plus any synchronous
         # compile and the quantiles' host->device put); it returns at
         # once. The dispatch PHASE does block, though, one group later:
-        # on the v5e (PERF.md, PR 30) the next group that has samples
-        # staged (self_timers always has) waits in its drain
-        # (flush_begin) for as long as the device still runs what is
-        # queued: the interval's last ingest dispatch (0.041 s) and the
-        # program enqueued here (0.3 ms for 320 live rows, 0.027 s for
-        # 205,280; 0.154 s before it was bound to the live rows), 0.043
-        # and 0.070 of a 0.051 and 0.078 s store.dispatch. A fresh
-        # twin's first touch allocates its planes there, and that host
-        # -> device put queues behind the running program (the CPU
-        # backend does the same). So fetch, opened by fetch.wait, finds
-        # the results ready (fetch.wait 0.1-0.9 ms on the chip).
+        # on the v5e the next group that has samples staged
+        # (self_timers always has) waits in its drain (flush_begin) for
+        # as long as the device still runs what is queued: the
+        # interval's last ingest dispatch (0.0044 s since PR 34, more
+        # where it drains held rows) and the program enqueued here
+        # (0.3 ms for 320 live rows, 0.027 s for 205,280), 0.016 and
+        # 0.036 of a 0.025 and 0.045 s store.dispatch (PERF.md section
+        # 5). A fresh twin's first touch allocates its planes there,
+        # and that host -> device put queues behind the running program
+        # (the CPU backend does the same). So fetch, opened by
+        # fetch.wait, finds the results ready (fetch.wait 0.1-0.9 ms on
+        # the chip).
         with obs_rec.maybe_stage("compute"), \
                 obs_kernels.scope("flush.digest.dense"):
             qs = jnp.asarray(list(percentiles) + [0.5], jnp.float32)
@@ -1159,13 +1205,16 @@ class DigestGroup(OverloadLimited):
                 packed_refs = _pack_slab(
                     digest.mean.reshape(-1), digest.weight.reshape(-1),
                     digest.min, digest.max, self.capacity, self.k)
-                planes = (digest.min[:n], digest.max[:n])
+                planes = (digest.min, digest.max)
             elif want_digests:
-                planes = (digest.mean[:n], digest.weight[:n],
-                          digest.min[:n], digest.max[:n])
+                planes = (digest.mean, digest.weight, digest.min,
+                          digest.max)
             stats = {"pcts": pcts, "count": count, "sum": vsum,
                      "min": vmin, "max": vmax, "recip": recip}
-            refs = planes + tuple(stats[nm][:n] for nm in sel)
+            # the count's pow2 bucket of rows; _flush_collect cuts what
+            # it fetched back to n
+            refs = _prefix_rows(planes + tuple(stats[nm] for nm in sel),
+                                live_bucket(n, self.capacity))
         return (sel, packed, packed_refs, refs)
 
     def _flush_collect(self, pending, n: int, percentiles,
@@ -1182,6 +1231,7 @@ class DigestGroup(OverloadLimited):
                  out["packed_weights"]) = _fetch_packed(*packed_refs, n)
             fetched, counters = jax.device_get(
                 (refs, self._guard_counters()))
+            fetched = cut_rows(fetched, n)
             for name, drains in counters.items():
                 if drains is not None:
                     # per dispatch and device program: a mesh's shards
@@ -1230,18 +1280,23 @@ class DigestGroup(OverloadLimited):
                 "joined": list(self.interner.joined)}
         if n == 0:
             return snap, None
-        refs = (self.digest.mean[:n], self.digest.weight[:n],
-                td_ops.bin_rows(self.temp.sum_w, 0, n, self.k),
-                td_ops.bin_rows(self.temp.sum_wm, 0, n, self.k),
-                self.dmin[:n], self.dmax[:n],
-                self.digest.min[:n], self.digest.max[:n],
-                self.temp.count[:n], self.temp.vsum[:n],
-                self.temp.vmin[:n], self.temp.vmax[:n],
-                self.temp.recip[:n])
+        # the count's pow2 bucket of rows (a checkpoint an interval
+        # compiles nothing new while the count wanders); the bin planes
+        # are flat, a row's bins contiguous
+        b = live_bucket(n, self.capacity)
+        refs = _prefix_rows(
+            (self.digest.mean, self.digest.weight,
+             self.dmin, self.dmax, self.digest.min, self.digest.max,
+             self.temp.count, self.temp.vsum, self.temp.vmin,
+             self.temp.vmax, self.temp.recip), b)
+        bins = _prefix_rows((self.temp.sum_w, self.temp.sum_wm),
+                            b * self.k)
 
         def finish():
-            (mean, weight, bin_w, bin_wm, imp_min, imp_max, dmn, dmx,
-             cnt, vsum, vmin, vmax, recip) = jax.device_get(refs)
+            (mean, weight, imp_min, imp_max, dmn, dmx, cnt, vsum, vmin,
+             vmax, recip) = cut_rows(jax.device_get(refs), n)
+            bin_w, bin_wm = (a.reshape(n, self.k) for a in cut_rows(
+                jax.device_get(bins), n * self.k))
             snap.update(flatten_digest_state(
                 np.asarray(mean, np.float32),
                 np.asarray(weight, np.float32),
@@ -1511,9 +1566,11 @@ class SetGroup(OverloadLimited):
 
         def finish():
             with fetch_stage((est_ref, reg_ref)):
-                estimates = (np.asarray(jax.device_get(est_ref))
+                # the refs hold the count's bucket of rows
+                estimates = (np.asarray(jax.device_get(est_ref))[:n]
                              if want_estimates else None)
-                registers = (np.asarray(jax.device_get(reg_ref), np.uint8)
+                registers = (np.asarray(jax.device_get(reg_ref),
+                                        np.uint8)[:n]
                              if want_registers else None)
             return interner, estimates, registers
 
@@ -1526,16 +1583,18 @@ class SetGroup(OverloadLimited):
     def _estimate_refs(self, n: int):
         """Device refs of the live rows' estimates, interner order (the
         mesh store gathers its shard-placed physical rows here)."""
-        return self._estimates()[:n]
+        return _prefix_rows((self._estimates(),),
+                            live_bucket(n, self.capacity))[0]
 
     def _register_refs(self, n: int):
         """Device refs of the live rows' registers, interner order."""
-        return self.registers[:n]
+        return _prefix_rows((self.registers,),
+                            live_bucket(n, self.capacity))[0]
 
     def _snapshot_refs(self, n: int):
         """Device refs of the live rows for the two-phase snapshot
         (override point for the mesh store's permutation gather)."""
-        return self.registers[:n]
+        return self._register_refs(n)
 
     def _reset_registers(self):
         self.registers = jnp.zeros((self.capacity, self.m), jnp.int8)
@@ -1557,7 +1616,8 @@ class SetGroup(OverloadLimited):
         refs = self._snapshot_refs(n)
 
         def finish():
-            snap["registers"] = np.asarray(jax.device_get(refs), np.uint8)
+            snap["registers"] = np.asarray(jax.device_get(refs),
+                                           np.uint8)[:n]
 
         return snap, finish
 
@@ -1611,6 +1671,8 @@ class HeavyHitterGroup(OverloadLimited):
         self.sketch = cm_ops.init(capacity, depth, width, k)
         self._device_dirty = False
         self._members: Dict[int, str] = {}
+        # this generation's sample dispatches (timeline ``topk``)
+        self.dispatches = 0
         self._update = jax.jit(cm_ops.update, donate_argnums=(0,))
         self._add_table = jax.jit(cm_ops.add_table, donate_argnums=(0,))
         self._inject = jax.jit(cm_ops.inject_candidates,
@@ -1731,10 +1793,13 @@ class HeavyHitterGroup(OverloadLimited):
         if self._fill == 0:
             return
         self._device_dirty = True
+        self.dispatches += 1
         rows, hi, lo, wts = self._rows, self._hi, self._lo, self._wts
         self._new_sample_buffers()
         sids = self._sids_np[rows]
-        self.sketch = self._update(self.sketch, rows, sids, hi, lo, wts)
+        with obs_kernels.scope("drain.topk.dense"):
+            self.sketch = self._update(self.sketch, rows, sids, hi, lo,
+                                       wts)
 
     def _drain_staging(self):
         self._drain_samples()
@@ -1792,6 +1857,9 @@ class HeavyHitterGroup(OverloadLimited):
         assembly later."""
         with obs_rec.maybe_stage("drain"):
             self._drain_samples()
+            if self.dispatches:
+                obs_rec.note(topk_dispatches=self.dispatches)
+                self.dispatches = 0
         n = len(self.interner)
         interner, self.interner = self.interner, Interner()
         if n == 0 and not self._device_dirty:
@@ -1813,7 +1881,7 @@ class HeavyHitterGroup(OverloadLimited):
             fwd = None
             if n:
                 with fetch_stage(refs):
-                    hi, lo, ct = jax.device_get(refs)
+                    hi, lo, ct = cut_rows(jax.device_get(refs), n)
                 # one pass builds both the emission rows and (when
                 # asked) the per-row forwardable candidate lists
                 by_row = {} if want_forward else None
@@ -1845,8 +1913,9 @@ class HeavyHitterGroup(OverloadLimited):
     def _live_topk(self, n: int):
         """Device refs of the live rows' top-k planes, interner order
         (override point for the mesh store's permutation gather)."""
-        return (self.sketch.topk_hi[:n], self.sketch.topk_lo[:n],
-                self.sketch.topk_counts[:n])
+        return _prefix_rows(
+            (self.sketch.topk_hi, self.sketch.topk_lo,
+             self.sketch.topk_counts), live_bucket(n, self.capacity))
 
     def _scatter_rows(self, rows: np.ndarray) -> np.ndarray:
         """Row ids as the device scatter sees them (override point for
@@ -1876,7 +1945,8 @@ class HeavyHitterGroup(OverloadLimited):
         members = dict(self._members)
 
         def finish():
-            hi, lo, ct, table = jax.device_get(refs)
+            *topk, table = jax.device_get(refs)
+            hi, lo, ct = cut_rows(topk, n)
             snap["table"] = np.asarray(table, np.float32)
             # vectorized live-slot extraction: no O(n*k) Python loop
             live_r, live_c = np.nonzero(np.asarray(ct) > 0)

@@ -171,6 +171,23 @@ def _publish_interval(server, span, rec, timeline):
                                     for s in sampled),
             "guard_drains": sum(s.get("mesh_ingest_guard_drains", 0)
                                 for s in entry["stages"])}
+    # and the dense sample path's: its dispatches, the rows they
+    # drained before binning into them, the drain loop's trips
+    sampled = [s for s in entry["stages"] if "ingest_samples_dispatches" in s]
+    if sampled:
+        entry["ingest_samples"] = {
+            "dispatches": sum(s["ingest_samples_dispatches"]
+                              for s in sampled),
+            "rows_drained": sum(s.get("ingest_samples_rows_drained", 0)
+                                for s in entry["stages"]),
+            "drain_trips": sum(s.get("ingest_samples_drain_trips", 0)
+                               for s in entry["stages"])}
+    # the heavy-hitter group's count-min updates (HeavyHitterGroup
+    # notes them on its drain stage)
+    topk = [s["topk_dispatches"] for s in entry["stages"]
+            if "topk_dispatches" in s]
+    if topk:
+        entry["topk"] = {"dispatches": sum(topk)}
     if hops:
         tids = sorted({h["trace_id"] for h in hops if h.get("trace_id")})
         if tids:

@@ -93,8 +93,7 @@ jax.monitoring.register_event_listener(_on_event)
 # fails when the inventory and this map drift apart — same contract as
 # the generated docs table). Third field: the importable module-level
 # jit binding for compile counting, or None when the program has no
-# module-level PjitFunction (ingest_chunk_guarded is jitted inline by
-# its callers and inside enclosing programs).
+# module-level PjitFunction.
 PROGRAM_SCOPES: Dict[str, Tuple[str, Optional[Tuple[str, str]]]] = {
     "veneur_tpu/core/store.py::_flush_digests":
         ("flush.digest.dense", ("veneur_tpu.core.store", "_flush_digests")),
@@ -103,8 +102,8 @@ PROGRAM_SCOPES: Dict[str, Tuple[str, Optional[Tuple[str, str]]]] = {
     "veneur_tpu/core/store.py::_ingest_centroids":
         ("drain.digest.dense",
          ("veneur_tpu.core.store", "_ingest_centroids")),
-    "veneur_tpu/ops/tdigest.py::ingest_chunk_guarded":
-        ("drain.digest.dense", None),
+    "veneur_tpu/core/store.py::_prefix_rows":
+        ("flush.digest.dense", ("veneur_tpu.core.store", "_prefix_rows")),
     "veneur_tpu/ops/tdigest_pallas.py::_compress_presorted_pallas":
         ("flush.digest.dense",
          ("veneur_tpu.ops.tdigest_pallas", "_compress_presorted_pallas")),

@@ -10,17 +10,28 @@ TPU-first design:
   need per-series tables (the classic shared-sketch trick). Updates are
   scatter-adds; estimates are a min over ``depth`` gathered rows.
 - the top-k list is per series, ``[S, K]`` id/count planes. Each drain
-  concatenates (current top-k ++ batch candidates), deduplicates by id
-  with a sort + segment-head mask (fixed shapes, no data-dependent
-  control flow), and keeps the K largest counts via ``lax.top_k``.
+  takes of every series' distinct keys in the batch the 4K with the
+  largest estimates (``_batch_candidates``), concatenates (current
+  top-k, its counts read again from the table, ++ those candidates),
+  deduplicates by id with a sort + segment-head mask (fixed shapes, no
+  data-dependent control flow), and keeps the K largest counts via
+  ``lax.top_k``.
 - keys are 64-bit hashes carried as (hi, lo) uint32 pairs — uint64 is
   unavailable without jax x64 — and every mixing step is a murmur3
   finalizer, matching ops/hll.py's member hashing so the native parser's
   member hash feeds both sketches.
 
-Estimates are upward-biased only (count-min guarantee); the top-k
-therefore never misses a true heavy hitter whose count clears the
-threshold, the property the golden tests assert against an exact dict.
+What the list guarantees: estimates are upward-biased only (the
+count-min guarantee), so no emitted count is under the key's exact
+frequency; and the list is kept by estimate, so a key that is left out
+has an exact frequency no larger than the last listed count. (A key's
+estimate at its last line is at least its frequency; if it is not among
+its batch's 4K candidates, or loses to the standing list, K keys of the
+series stand at or above it, and counts only grow until the flush.) An
+estimate exceeds the exact frequency by at most e / width of the
+table's mass with probability 1 - e^-depth, so a left-out key can
+exceed a listed key's *exact* frequency by no more than that. The
+golden tests assert both against an exact dict.
 """
 
 from __future__ import annotations
@@ -127,8 +138,8 @@ def update(sk: CountMin, rows: jax.Array, sids: jax.Array, hi: jax.Array,
     est = jnp.where(counts > 0, est, 0.0)
 
     # refresh the standing top-k entries from the table: their counts
-    # must track later increments even when the key loses its candidate
-    # slot to a ring collision this drain
+    # must track later increments even in a drain that does not bring
+    # the key again
     cur_ct = jnp.full(sk.topk_counts.shape, jnp.inf, jnp.float32)
     for d in range(depth):
         idx = _col_index(jnp.broadcast_to(sk.sids[:, None],
@@ -137,33 +148,41 @@ def update(sk: CountMin, rows: jax.Array, sids: jax.Array, hi: jax.Array,
         cur_ct = jnp.minimum(cur_ct, table[d, idx])
     cur_ct = jnp.where(sk.topk_counts > 0, cur_ct, 0.0)
 
-    # merge batch candidates into the per-series top-k lists:
-    # scatter each candidate's (id, est) into its series' candidate slot
-    # ring, then dedupe + select per series. A batch can carry more
-    # candidates than ring slots per series; colliding candidates
-    # overwrite (they re-enter on a later drain — top-k convergence only
-    # needs repeated exposure, not completeness per batch; standing
-    # members never rely on candidacy thanks to the refresh above).
-    ring = 4 * k  # candidate slots per series this drain
-    # salt the slot hash with the (monotonically growing) table mass so a
-    # pair of keys colliding this drain lands apart on a later one —
-    # a fixed slot hash would starve one of them forever
-    rsalt = _mix32(jnp.sum(table[0]).astype(jnp.uint32))
-    slot = _mix32(hi ^ lo ^ rsalt) % jnp.uint32(ring)
-    srows = jnp.where(counts > 0, rows, s).astype(jnp.int32)
-    cand_hi = jnp.zeros((s, ring), jnp.uint32).at[srows, slot].set(
-        hi, mode="drop")
-    cand_lo = jnp.zeros((s, ring), jnp.uint32).at[srows, slot].set(
-        lo, mode="drop")
-    cand_ct = jnp.zeros((s, ring), jnp.float32).at[srows, slot].set(
-        est, mode="drop")
-
+    cand_hi, cand_lo, cand_ct = _batch_candidates(rows, hi, lo, est, s,
+                                                  4 * k)
     all_hi = jnp.concatenate([sk.topk_hi, cand_hi], axis=1)
     all_lo = jnp.concatenate([sk.topk_lo, cand_lo], axis=1)
     all_ct = jnp.concatenate([cur_ct, cand_ct], axis=1)
     top_hi, top_lo, top_ct = _dedupe_topk(all_hi, all_lo, all_ct, k)
     return sk._replace(table=table, topk_hi=top_hi, topk_lo=top_lo,
                        topk_counts=top_ct)
+
+
+def _batch_candidates(rows, hi, lo, est, s: int, ring: int):
+    """A batch's candidates as ``[s, ring]`` planes: of every series'
+    distinct keys in the batch the ``ring`` with the largest estimates,
+    the largest first (``est`` 0 marks padding). Two sorts of the flat
+    batch and one scatter to (series, rank within the series): fixed
+    shapes, and no two candidates meet in a slot."""
+    srows = jnp.where(est > 0, rows, s).astype(jnp.int32)
+    # the lines of one (series, key) carry one estimate (the table
+    # after the batch's adds): keep the first of each run
+    r, h, lw, e = lax.sort((srows, hi, lo, est), dimension=-1, num_keys=3,
+                           is_stable=False)
+    dup = jnp.concatenate(
+        [jnp.zeros((1,), bool),
+         (r[1:] == r[:-1]) & (h[1:] == h[:-1]) & (lw[1:] == lw[:-1])])
+    r, neg, h, lw = lax.sort((r, -jnp.where(dup, 0.0, e), h, lw),
+                             dimension=-1, num_keys=2, is_stable=False)
+    at = jnp.arange(r.shape[0], dtype=jnp.int32)
+    head = jnp.concatenate([jnp.ones((1,), bool), r[1:] != r[:-1]])
+    rank = at - lax.cummax(jnp.where(head, at, 0), axis=0)
+    to = jnp.where((neg < 0) & (rank < ring), r, s)
+    blank = jnp.zeros((s, ring), jnp.uint32)
+    return (blank.at[to, rank].set(h, mode="drop"),
+            blank.at[to, rank].set(lw, mode="drop"),
+            jnp.zeros((s, ring), jnp.float32).at[to, rank].set(
+                -neg, mode="drop"))
 
 
 def _dedupe_topk(all_hi, all_lo, all_ct, k: int):

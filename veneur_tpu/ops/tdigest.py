@@ -940,40 +940,24 @@ def shift_pred(acc_seg_w: jax.Array, acc_seg_wm: jax.Array,
                                         jnp.finfo(jnp.float32).tiny)
 
 
-def ingest_chunk_guarded(digest: TDigest, temp: TempCentroids,
-                         rows: jax.Array, values: jax.Array,
-                         weights: jax.Array,
-                         compression: float = DEFAULT_COMPRESSION,
-                         update_stats: bool = True,
-                         use_pallas: bool = True):
-    """Shift-guarded ingest: ``shift_pred`` -> drain the temp bins into
-    the digest (lax.cond, so the drain costs nothing when not taken),
-    then ingest the chunk against re-anchored bins. The temp's scalar
-    stats (count/vsum/vmin/vmax/recip) survive a mid-interval guard
-    drain — they are interval aggregates, only the BINS move into the
-    digest. Returns (digest, temp). ``use_pallas=False`` keeps the
-    guard drain off the Pallas kernel (compute-breaker degradation)."""
-    pred = shift_pred(*temp.anchors(), rows, values, weights,
-                      temp.num_series)
-
-    def do_drain(args):
-        d, t = args
-        d2 = drain_temp(d, t, compression, use_pallas=use_pallas)
-        t2 = t._replace(sum_w=jnp.zeros_like(t.sum_w),
-                        sum_wm=jnp.zeros_like(t.sum_wm),
-                        seg_w=jnp.zeros_like(t.seg_w),
-                        seg_wm=jnp.zeros_like(t.seg_wm))
-        return d2, t2
-
-    digest, temp = lax.cond(pred, do_drain, lambda a: a, (digest, temp))
-    temp = ingest_chunk(temp, rows, values, weights, compression,
-                        update_stats)
-    return digest, temp
+def drain_every_bin(digest: TDigest, temp: TempCentroids,
+                    compression: float = DEFAULT_COMPRESSION,
+                    use_pallas: bool = True):
+    """The shift guard's drain (``shift_pred`` -> this, behind a
+    ``lax.cond``, then the chunk is binned against fresh anchors):
+    every row's bins into its digest, bins and anchors emptied. The
+    temp's scalar stats (count/vsum/vmin/vmax/recip) survive: they are
+    interval aggregates, only the BINS move. Returns (digest, temp)."""
+    digest = drain_temp(digest, temp, compression, use_pallas=use_pallas)
+    return digest, temp._replace(sum_w=jnp.zeros_like(temp.sum_w),
+                                 sum_wm=jnp.zeros_like(temp.sum_wm),
+                                 seg_w=jnp.zeros_like(temp.seg_w),
+                                 seg_wm=jnp.zeros_like(temp.seg_wm))
 
 
-# Rows one trip of the import path's row-local drain compresses: eight
-# kernel blocks. A staged chunk of whole digests (dozens of centroids a
-# row) touches fewer rows than this and takes one trip; lone centroids
+# Rows one trip of the row-local drain compresses: eight kernel blocks.
+# A staged chunk of whole digests (dozens of centroids a row) touches
+# fewer rows than this and takes one trip; lone centroids or samples
 # over many rows take more. Swept on a v5e at 2^20 rows and 16,384
 # staged centroids (PERF.md, PR 33), a dispatch by the host's clock at
 # 256 / 1,024 / 4,096 rows: a merging chunk of 256 rows 55.1 / 53.1 /
@@ -983,48 +967,69 @@ def ingest_chunk_guarded(digest: TDigest, temp: TempCentroids,
 # lone centroids over 16,384 held rows 65.0 / 64.9 / 64.7 / 55.7.
 ROW_DRAIN_SLAB_ROWS = 1024
 
+# The sample path drains a held row only while the interval has brought
+# it at most this many samples: arrivals, not weight (a timer sent
+# ``|@0.1`` weighs 10 a sample; ``held_rows`` reads a row's count in
+# units of the arriving sample's own weight). Binned against the
+# 8-anchor summary, a row's few samples a chunk alias: rows of 2 to
+# 1,023 samples an interval read rank errors of 0.03 to 0.32 on the
+# chip, rows of 1,024 and more 0.005-0.009 (PERF.md, PR 39). Past the
+# constant a row keeps the anchored binning, which holds the envelope
+# there, and costs a dispatch nothing (PERF.md, PR 40 has the sweep).
+ROW_DRAIN_MAX_ARRIVALS = 1024.0
 
-def ingest_centroids_rowdrained(digest: TDigest, temp: TempCentroids,
-                                rows: jax.Array, means: jax.Array,
-                                weights: jax.Array,
-                                compression: float = DEFAULT_COMPRESSION,
-                                use_pallas: bool = True):
-    """The import path's ingest: a forwarded digest's centroids merge
-    the way a t-digest merges, row by row. Before the chunk is binned,
-    every row it brings mass to that already holds bin mass is drained
-    into its digest (a compress over those rows alone), so its run is
-    binned by its own exact ranks into empty bins and meets the earlier
-    mass in a compress, not in a bin.
 
-    The chunk-wide shift guard of the sample path does not do here:
-    two forwarders' digests of one series are two distributions by
-    nature, and binned against the 8-anchor summary of the first the
-    second aliases (rank errors of 0.04-0.24 were read wherever 1 % of
-    a staging chunk's mass did not happen to lie in disjoint rows). The
-    decision is taken where the aliasing happens, the row. The rows to
-    drain are compressed ``ROW_DRAIN_SLAB_ROWS`` at a time in a loop
-    whose trip count is ``ceil(rows to drain / slab)``: one compiled
-    compress for every count, no trip where nothing is held, its cost
-    bounded by the chunk (at most one row a staged centroid) and never
-    by the rows reserved. Nothing is decided across rows, so a shard of
-    a mesh takes it alone and agrees with the dense store.
-
-    Imported centroids feed percentiles only, never the local scalar
-    stats (samplers.go:473-480). Returns (digest, temp, drained): the
-    last is 1 where any row was drained, as an int32 scalar."""
-    num_series, k = temp.num_series, temp.capacity
+def held_rows(temp: TempCentroids, rows: jax.Array, weights: jax.Array,
+              max_arrivals=None):
+    """The rows a chunk brings mass to that already hold bin mass, for
+    ``drain_rows``: (touched, count), the distinct rows sorted to the
+    front of a chunk-long array, the rest the sentinel ``num_series``,
+    and how many there are. ``max_arrivals`` (the sample path:
+    ``ROW_DRAIN_MAX_ARRIVALS``) leaves out a row whose ``temp.count``
+    has passed that many samples of the arriving sample's weight: a
+    series keeps one sample rate, so its count over the weight is its
+    arrivals, and no plane counts them."""
+    num_series = temp.num_series
     rows = rows.astype(jnp.int32)
-    slab = min(ROW_DRAIN_SLAB_ROWS, rows.shape[0])
-    inb = (rows < num_series) & (weights > 0)
-    held = temp.anchors()[0].sum(axis=1)[jnp.minimum(rows, num_series - 1)]
+    at = jnp.minimum(rows, num_series - 1)
+    mass = temp.anchors()[0].sum(axis=1)
+    if max_arrivals is None:
+        held = (mass > 0)[at]
+    else:
+        # a row at full width (its count, +inf where it holds nothing),
+        # so that the chunk gathers once
+        held = jnp.where(mass > 0, temp.count, jnp.inf)[at] \
+            <= max_arrivals * weights
+    due = (rows < num_series) & (weights > 0) & held
     # one entry a row: the sorted candidates' run starts, sorted once
     # more to the front; the rest carry the sentinel the scatters drop
-    cand = jnp.sort(jnp.where(inb & (held > 0), rows, num_series))
+    cand = jnp.sort(jnp.where(due, rows, num_series))
     first = jnp.concatenate([jnp.ones((1,), bool), cand[1:] != cand[:-1]])
     touched = jnp.sort(jnp.where(first, cand, num_series))
-    count = jnp.sum(touched < num_series)
+    return touched, jnp.sum(touched < num_series).astype(jnp.int32)
+
+
+def drain_rows(digest: TDigest, temp: TempCentroids, touched: jax.Array,
+               count, compression: float = DEFAULT_COMPRESSION,
+               use_pallas: bool = True):
+    """The row-local drain both ingest paths share: the ``count`` rows
+    at the front of ``touched`` (``held_rows``) are compressed into
+    their digests, bins and anchors emptied, so that the chunk's run of
+    such a row is binned by its own exact ranks into empty bins and
+    meets the earlier mass in a compress, not in a bin.
+
+    The rows are compressed ``ROW_DRAIN_SLAB_ROWS`` at a time in a loop
+    whose trip count is ``ceil(count / slab)``: one compiled compress
+    for every count, no trip where nothing is held, its cost bounded by
+    the chunk (at most one row a staged entry) and never by the rows
+    reserved. Nothing is decided across rows, so a shard of a mesh
+    takes it alone and agrees with the dense store. Returns (digest,
+    temp)."""
+    num_series, k = temp.num_series, temp.capacity
+    chunk_len = touched.shape[0]
+    slab = min(ROW_DRAIN_SLAB_ROWS, chunk_len)
     touched = jnp.concatenate([touched, jnp.full(
-        ((-rows.shape[0]) % slab,), num_series, jnp.int32)])
+        ((-chunk_len) % slab,), num_series, jnp.int32)])
     lanes = jnp.arange(BELOW_MASS_ANCHORS, dtype=jnp.int32) * num_series
 
     def drain_slab(i, planes):
@@ -1043,12 +1048,96 @@ def ingest_centroids_rowdrained(digest: TDigest, temp: TempCentroids,
                 seg_wm.at[to_a].set(0.0, mode="drop"))
 
     mean, weight, sum_w, sum_wm, seg_w, seg_wm = lax.fori_loop(
-        0, (count + slab - 1) // slab, drain_slab,
+        0, row_drain_trips(count, chunk_len), drain_slab,
         (digest.mean, digest.weight, temp.sum_w, temp.sum_wm, temp.seg_w,
          temp.seg_wm))
-    digest = digest._replace(mean=mean, weight=weight)
-    temp = temp._replace(sum_w=sum_w, sum_wm=sum_wm, seg_w=seg_w,
-                         seg_wm=seg_wm)
+    return (digest._replace(mean=mean, weight=weight),
+            temp._replace(sum_w=sum_w, sum_wm=sum_wm, seg_w=seg_w,
+                          seg_wm=seg_wm))
+
+
+def row_drain_trips(drained, chunk_len: int):
+    """Trips ``drain_rows``' loop makes for ``drained`` rows of a
+    ``chunk_len``-entry chunk."""
+    slab = min(ROW_DRAIN_SLAB_ROWS, chunk_len)
+    return (drained + slab - 1) // slab
+
+
+def ingest_chunk_rowdrained(digest: TDigest, temp: TempCentroids,
+                            rows: jax.Array, values: jax.Array,
+                            weights: jax.Array,
+                            compression: float = DEFAULT_COMPRESSION,
+                            use_pallas: bool = True):
+    """The sample path's ingest. A series' few samples an interval
+    arrive a handful a chunk; binned by their estimated quantile
+    against the row's 8-anchor summary, value-distant samples share a
+    bin (three samples 58,293.75 / 58,295.0 / one lower emitted a 99th
+    percentile under both upper ones: rank error 0.3233 on every chip
+    run of PERF.md, PR 39). So the rows of the chunk that hold bin mass
+    and at most ``ROW_DRAIN_MAX_ARRIVALS`` samples are drained first
+    (``drain_rows``, the import path's drain); the rows past it keep
+    the anchored binning behind the chunk-wide shift guard
+    (``shift_pred`` -> every bin drained into the digest), which is
+    asked once the held rows are out of the vote. The guard is still
+    needed there: ordered arrival at 256 samples a row a chunk reads
+    0.149 by rank without it from the sixth chunk on, 0.0024 with it
+    (docs/tdigest_accuracy.md, ``tdigest_sweep --guard``).
+
+    Both drains sit in one ``lax.cond`` taken where either has
+    something to do: the branches of a conditional agree on their
+    results' layout, and the row drain's loop holds the digest planes
+    row-major, so a branch of its own made the chunk that drains
+    nothing relay both planes there and back (four copies of 436 MB at
+    2^20 rows, compiled for a v5e; tests/test_chip_compile.py). As it
+    is, such a chunk, every one of a series' first sight and of a row
+    past the count, costs what the guard alone cost. The temp's scalar
+    stats survive the drains: they are interval aggregates, only the
+    BINS move into the digest. Returns (digest, temp, drained): the
+    rows drained, an int32 scalar."""
+    touched, count = held_rows(temp, rows, weights, ROW_DRAIN_MAX_ARRIVALS)
+    shifted = shift_pred(*temp.anchors(), rows, values, weights,
+                         temp.num_series)
+
+    def drains(args):
+        digest, temp = drain_rows(*args, touched, count, compression,
+                                  use_pallas)
+        # the drained rows hold nothing now and cannot vote
+        return lax.cond(
+            shift_pred(*temp.anchors(), rows, values, weights,
+                       temp.num_series),
+            lambda a: drain_every_bin(*a, compression, use_pallas),
+            lambda a: a, (digest, temp))
+
+    digest, temp = lax.cond((count > 0) | shifted, drains, lambda a: a,
+                            (digest, temp))
+    temp = ingest_chunk(temp, rows, values, weights, compression)
+    return digest, temp, count
+
+
+def ingest_centroids_rowdrained(digest: TDigest, temp: TempCentroids,
+                                rows: jax.Array, means: jax.Array,
+                                weights: jax.Array,
+                                compression: float = DEFAULT_COMPRESSION,
+                                use_pallas: bool = True):
+    """The import path's ingest: a forwarded digest's centroids merge
+    the way a t-digest merges, row by row: every row the chunk brings
+    mass to that already holds bin mass is drained first (``held_rows``,
+    ``drain_rows``).
+
+    The chunk-wide shift guard of the sample path does not do here:
+    two forwarders' digests of one series are two distributions by
+    nature, and binned against the 8-anchor summary of the first the
+    second aliases (rank errors of 0.04-0.24 were read wherever 1 % of
+    a staging chunk's mass did not happen to lie in disjoint rows). The
+    decision is taken where the aliasing happens, the row.
+
+    Imported centroids feed percentiles only, never the local scalar
+    stats (samplers.go:473-480). Returns (digest, temp, drained): the
+    last is 1 where any row was drained, as an int32 scalar."""
+    rows = rows.astype(jnp.int32)
+    touched, count = held_rows(temp, rows, weights)
+    digest, temp = drain_rows(digest, temp, touched, count, compression,
+                              use_pallas)
     temp = ingest_chunk(temp, rows, means, weights, compression,
                         update_stats=False)
     return digest, temp, (count > 0).astype(jnp.int32)
