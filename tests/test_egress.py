@@ -251,6 +251,66 @@ class TestDDBodiesSideBySide:
             assert timing[key] > again[key]      # a second block adds on
 
 
+class TestDDSeriesStream:
+    """The same bodies handed over as the workers make them
+    (``dd_series_stream``): the one-worker call's bytes and timing, and
+    the handle and its threads gone on every way out."""
+
+    SUFFIXES = TestDDBodiesSideBySide.SUFFIXES
+    _block = TestDDBodiesSideBySide._block
+
+    @pytest.mark.parametrize("level", [1, 6])
+    @pytest.mark.parametrize("bodies", ["1", "W", "W+1", "50"])
+    @pytest.mark.parametrize("workers", [1, 3, 8])
+    def test_bodies_and_timing_are_the_one_worker_calls(self, workers,
+                                                        bodies, level):
+        import time
+
+        n_bodies = {"1": 1, "W": workers, "W+1": workers + 1,
+                    "50": 50}[bodies]
+        blk = self._block(n_bodies, ragged=n_bodies > 1)
+        one, timing = {}, {}
+        want = egress.dd_series_bodies(**blk, compress_level=level,
+                                       workers=1, timing=one)
+        got, ready = [], []
+        t0 = time.monotonic_ns()
+        with egress.dd_series_stream(**blk, compress_level=level,
+                                     workers=workers,
+                                     timing=timing) as stream:
+            assert stream.count == n_bodies
+            for body, ready_ns in stream:
+                got.append(body)
+                ready.append(ready_ns)
+        t1 = time.monotonic_ns()
+        assert got == want                       # byte for byte, in order
+        assert sorted(timing) == sorted(one)
+        assert timing["bodies"] == one["bodies"] == n_bodies
+        assert one["workers"] == 1
+        assert timing["workers"] == min(workers, n_bodies)
+        for key in ("encode_ns", "deflate_ns", "encode_cpu_ns",
+                    "deflate_cpu_ns"):
+            assert timing[key] > 0 and one[key] > 0, key
+        # each body stamped when it was made, on time.monotonic_ns's
+        # clock (the sink sets its stages by it)
+        assert all(t0 <= r <= t1 for r in ready)
+
+    def test_a_consumer_that_raises_leaves_no_thread_and_no_handle(self):
+        blk = self._block(50, True)
+        before = egress.dd_stream_live()
+        with pytest.raises(RuntimeError, match="POST failed"):
+            with egress.dd_series_stream(**blk, workers=8) as stream:
+                assert egress.dd_stream_live() == (before[0] + 1,
+                                                   before[1] + 8)
+                for k, _made in enumerate(stream):
+                    if k == 2:
+                        raise RuntimeError("POST failed")
+        assert egress.dd_stream_live() == before
+        # a stream iterated to its end closes itself
+        stream = egress.dd_series_stream(**blk, workers=3)
+        assert len(list(stream)) == 50
+        assert egress.dd_stream_live() == before
+
+
 def arenas_strings(arena):
     blob, off, ln = arena
     return [bytes(blob[o:o + n]).decode() for o, n in zip(off, ln)]

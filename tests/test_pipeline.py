@@ -234,7 +234,7 @@ class TestStreamedSinkConservation:
         assert sink.chunk_rows_pending() == 0
         assert sink.chunk_rows_acked == total_first + stream2.rows
 
-    @pytest.mark.parametrize("workers", [1, 3])
+    @pytest.mark.parametrize("workers", [1, 3, 8])
     @pytest.mark.parametrize("fail_calls,budget", [
         ((), None), ((2,), None), ((3, 5), None),
         (range(1, 1000), None), (range(1, 1000), 600)],
@@ -243,8 +243,12 @@ class TestStreamedSinkConservation:
     def test_chunk_of_several_bodies_by_several_workers_conserves(
             self, native_egress, monkeypatch, fail_calls, budget, workers):
         """The matrix once more where a chunk's bodies are made side by
-        side: the sink gets the same bodies in the same order, so
-        ``acked + requeued + dropped == rows`` whatever the POSTs do."""
+        side and each is POSTed as it is made: the sink POSTs the
+        one-worker serial call's bodies in the same order, so ``acked +
+        requeued + dropped == rows`` whatever the POSTs do, and the
+        chunk's stages say what of its POSTs the serializer hid."""
+        from veneur_tpu import obs
+
         asked = []
 
         def dd_workers(n_bodies):
@@ -263,9 +267,14 @@ class TestStreamedSinkConservation:
         sink = make_dd_sink(post, flush_max_per_body=4)
         if budget is not None:
             sink.requeue_max_bytes = budget
+        chunks = []
+        flush_chunk = sink.flush_chunk
+        monkeypatch.setattr(sink, "flush_chunk",
+                            lambda c: flush_chunk(chunks.append(c) or c))
         s = make_store(flush_pipeline_depth=2)
         fill(s, n_hist=9)     # the timers' chunk: 36 rows, nine bodies
-        stream = ChunkStream([sink], 7, depth=2)
+        rec = obs.StageRecorder()
+        stream = ChunkStream([sink], 7, depth=2, rec=rec)
         s.flush([0.5], AGGS, is_local=False, now=7, forward=False,
                 columnar=True, stream=stream)
         stream.close()
@@ -285,17 +294,33 @@ class TestStreamedSinkConservation:
         sizes = [len(json.loads(zlib.decompress(b))["series"])
                  for b in post.payloads]
         assert sum(sizes) == stream.rows and max(sizes) == 4
-        if workers > 1:
-            monkeypatch.setattr(native_egress, "dd_workers", lambda n: 1)
-            ref = _Logged()
-            ref.payloads = []
-            sink1 = make_dd_sink(ref, flush_max_per_body=4)
-            fill(s, n_hist=9)
-            stream1 = ChunkStream([sink1], 7, depth=2)
-            s.flush([0.5], AGGS, is_local=False, now=7, forward=False,
-                    columnar=True, stream=stream1)
-            stream1.close()
-            assert post.payloads == ref.payloads
+        monkeypatch.setattr(native_egress, "dd_workers", lambda n: 1)
+        serial = []
+        for chunk in chunks:
+            for blk in chunk.blocks:
+                with sink._serialize_block(blk, chunk.timestamp) as bodies:
+                    serial.extend(body for body, _ready_ns in bodies)
+        assert post.payloads == serial
+        # each chunk's POSTs, and what of them the serializer did not
+        # hide; no chunk of one body POSTs one early
+        stages = rec.finish()["stages"]
+
+        def by_chunk(name):
+            return {st["chunk"]: st for st in stages if st["name"] == name}
+
+        made = by_chunk("post.datadog.serialize")
+        posted = by_chunk("post.datadog.post")
+        tails = by_chunk("post.datadog.post.tail")
+        assert sorted(made) == sorted(posted) == sorted(tails) \
+            == list(range(len(chunks)))
+        assert max(m["bodies"] for m in made.values()) == 9
+        assert 1 in {m["bodies"] for m in made.values()}
+        for seq, p in posted.items():
+            assert 0 <= p["bodies_posted_early"] < made[seq]["bodies"]
+            t = tails[seq]
+            assert p["start_ns"] <= t["start_ns"]
+            assert t["start_ns"] + t["duration_ns"] \
+                == p["start_ns"] + p["duration_ns"]
 
     def test_requeued_body_failing_again_reparks_in_budget(
             self, native_egress):

@@ -2,9 +2,10 @@
 
 The flush-egress twin of the ingest bindings in ``__init__.py``:
 
-- ``dd_series_bodies`` — columnar flush block → Datadog ``/api/v1/series``
-  JSON bodies, deflated in C++ (the vectorized finalize+serialize of
-  ``sinks/datadog/datadog.go:245-330``).
+- ``dd_series_stream`` / ``dd_series_bodies`` — columnar flush block →
+  Datadog ``/api/v1/series`` JSON bodies, deflated in C++ (the
+  vectorized finalize+serialize of ``sinks/datadog/datadog.go:245-330``),
+  handed over as they are made or as a list.
 - ``decode_metric_list`` / ``MListInternTable`` — forwardrpc.MetricList
   bytes → struct-of-arrays batch + series interning (the import-side
   equivalent of ``parse_lines`` + ``InternTable``; reference path
@@ -139,8 +140,8 @@ def _bind(lib):
     f32p = ctypes.POINTER(ctypes.c_float)
     f64p = ctypes.POINTER(ctypes.c_double)
 
-    lib.vt_dd_series_json.restype = ctypes.POINTER(_VtBodies)
-    lib.vt_dd_series_json.argtypes = [
+    lib.vt_dd_stream_begin.restype = ctypes.c_void_p
+    lib.vt_dd_stream_begin.argtypes = [
         ctypes.c_char_p, u32p, u32p,            # names
         ctypes.c_char_p, u32p, u32p,            # tags
         ctypes.c_uint32,                        # nrows
@@ -150,8 +151,15 @@ def _bind(lib):
         ctypes.c_char_p, ctypes.c_char_p,       # host, common tags json
         ctypes.c_uint32, ctypes.c_int,          # max_per_body, level
         ctypes.c_uint32,                        # workers
-        ctypes.POINTER(ctypes.c_uint64),        # timing_ns[6] or NULL
     ]
+    u64p = ctypes.POINTER(ctypes.c_uint64)
+    lib.vt_dd_stream_next.restype = ctypes.c_int
+    lib.vt_dd_stream_next.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint64,       # handle, body index
+        ctypes.POINTER(ctypes.c_void_p), u64p, u64p,  # ptr, len, ready ns
+    ]
+    lib.vt_dd_stream_end.argtypes = [ctypes.c_void_p, u64p]  # timing_ns[6]
+    lib.vt_dd_stream_live.argtypes = [u64p]
     lib.vt_bodies_free.argtypes = [ctypes.POINTER(_VtBodies)]
 
     lib.vt_sfx_datapoints_json.restype = ctypes.POINTER(_VtBodies)
@@ -245,14 +253,79 @@ def _p(a: np.ndarray, ctype):
 
 
 def dd_workers(n_bodies: int) -> int:
-    """Threads one ``dd_series_bodies`` call spreads its bodies over: one
-    a body, at most 8, and half the cores this process may run on, so a
-    flush that runs under ingest leaves the readers and the merger
-    theirs."""
+    """Native threads one block's ``dd_series_stream`` spreads its bodies
+    over: one a body, at most 8, and half the cores this process may run
+    on, so a flush that runs under ingest leaves the readers and the
+    merger theirs (the thread that takes the bodies, and POSTs them, is
+    one more)."""
     return min(n_bodies, 8, max(1, len(os.sched_getaffinity(0)) // 2))
 
 
-def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
+class DDSeriesStream:
+    """The bodies of one columnar emission block as the native workers
+    make them: an iterator of ``(body, ready_ns)``, body ``k`` as soon
+    as it is made (``ready_ns``: when, on ``time.monotonic_ns``'s
+    clock), while the workers go on with ``k+1..``. Each wait is a
+    native call that releases the GIL. ``close()`` (the ``with``
+    block's end, or the iterator's) stops the workers after the bodies
+    in hand, joins them, frees the handle and adds to ``timing``; it
+    runs on every way out, a consumer's exception included. The inputs
+    stay referenced here until then: the workers read them."""
+
+    def __init__(self, lib, handle, count: int, inputs: tuple,
+                 timing: Optional[dict]):
+        self._lib = lib
+        self._handle = handle
+        self._inputs = inputs
+        self._timing = timing
+        self._k = 0
+        self.count = count
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> Tuple[bytes, int]:
+        if self._k >= self.count:
+            self.close()
+            raise StopIteration
+        ptr, n, ready = ctypes.c_void_p(), ctypes.c_uint64(), \
+            ctypes.c_uint64()
+        self._lib.vt_dd_stream_next(self._handle, self._k,
+                                    ctypes.byref(ptr), ctypes.byref(n),
+                                    ctypes.byref(ready))
+        self._k += 1
+        return ctypes.string_at(ptr.value, n.value), ready.value
+
+    def close(self) -> None:
+        if self._handle is None:
+            return
+        spent = (ctypes.c_uint64 * 6)()
+        handle, self._handle = self._handle, None
+        self._lib.vt_dd_stream_end(handle, spent)
+        self._inputs = None
+        timing = self._timing
+        if timing is not None:
+            total, deflate = int(spent[0]), int(spent[1])
+            for key, ns in (("deflate_ns", deflate),
+                            ("encode_ns", total - deflate),
+                            ("encode_cpu_ns", int(spent[2])),
+                            ("deflate_cpu_ns", int(spent[3])),
+                            ("bodies", int(spent[4]))):
+                timing[key] = timing.get(key, 0) + ns
+            timing["workers"] = max(timing.get("workers", 0),
+                                    int(spent[5]))
+
+    def __enter__(self) -> "DDSeriesStream":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def __del__(self):
+        self.close()
+
+
+def dd_series_stream(names: Tuple[bytes, np.ndarray, np.ndarray],
                      tags: Tuple[bytes, np.ndarray, np.ndarray],
                      suffixes: List[bytes],
                      em_rows: np.ndarray, em_suffix: np.ndarray,
@@ -262,56 +335,51 @@ def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
                      max_per_body: int = 0,
                      compress_level: int = 1,
                      timing: Optional[dict] = None,
-                     workers: Optional[int] = None) -> List[bytes]:
+                     workers: Optional[int] = None) -> DDSeriesStream:
     """Serialize one columnar emission block into chunked (optionally
-    deflated) ``{"series": [...]}`` bodies.
+    deflated) ``{"series": [...]}`` bodies, handed over in order as
+    they are made (``DDSeriesStream``).
 
     names/tags: (arena bytes, offsets u32[S], lengths u32[S]).
     emissions: parallel arrays — row index u32, suffix index u8 (into
     ``suffixes``), finalized value f64 (counters already divided by the
     interval), type code u8 (0 gauge / 1 rate).
 
-    The bodies are encoded and deflated side by side by ``workers``
-    threads inside the native call (``dd_workers`` of the body count
-    where not given; the call runs on the calling thread alone where that
-    is 1), and come back in order, byte for byte the one-worker call's.
+    The per-row pre-pass runs in this call, on the calling thread; the
+    bodies are then encoded and deflated side by side by ``workers``
+    native threads (``dd_workers`` of the body count where not given),
+    byte for byte the one-worker bodies.
 
-    ``timing``, where given, gains a split of the native call's wall:
+    ``timing``, where given, gains at ``close()`` a split of the
+    serializer's wall, from this call to the last body made:
     ``deflate_ns`` (inside zlib's ``deflate``, clocked a slab at a time,
-    on the worker that finished last) and ``encode_ns`` (the rest of the
-    call: the JSON encoding); the same two summed over the workers'
-    spans, ``deflate_cpu_ns`` and ``encode_cpu_ns``; ``bodies``; each
-    added to what the key already holds. ``workers`` keeps the most a
-    call ran.
+    on the worker that made the last body) and ``encode_ns`` (the rest:
+    the JSON encoding); the same two summed over the workers' spans,
+    ``deflate_cpu_ns`` and ``encode_cpu_ns``; ``bodies``; each added to
+    what the key already holds. ``workers`` keeps the most a block ran.
     """
     lib = _load()
     if lib is None:
         raise RuntimeError(f"native egress unavailable: {_build_error}")
     if len(suffixes) > 255:
         raise ValueError("more than 255 emission suffixes")
-    suffix_blob = b"".join(suffixes)
-    s_off = np.zeros(max(len(suffixes), 1), np.uint32)
-    s_len = np.zeros(max(len(suffixes), 1), np.uint32)
-    pos = 0
-    for i, s in enumerate(suffixes):
-        s_off[i] = pos
-        s_len[i] = len(s)
-        pos += len(s)
+    suffix_blob, s_off, s_len, _ = _key_list(suffixes)
     em_rows = _u32a(em_rows)
     em_suffix = np.ascontiguousarray(em_suffix, np.uint8)
     em_values = np.ascontiguousarray(em_values, np.float64)
     em_type = np.ascontiguousarray(em_type, np.uint8)
     n = len(em_rows)
-    assert len(em_suffix) == n and len(em_values) == n and len(em_type) == n
+    if not (len(em_suffix) == len(em_values) == len(em_type) == n):
+        raise ValueError("emission arrays differ in length")
     name_arena, name_off, name_len = names
     tags_arena, tags_off, tags_len = tags
     name_off, name_len = _u32a(name_off), _u32a(name_len)
     tags_off, tags_len = _u32a(tags_off), _u32a(tags_len)
     u32, u8, f64 = ctypes.c_uint32, ctypes.c_uint8, ctypes.c_double
+    count = -(-n // max_per_body) if max_per_body else min(n, 1)
     if workers is None:
-        workers = dd_workers(-(-n // max_per_body) if max_per_body else 1)
-    spent = (ctypes.c_uint64 * 6)() if timing is not None else None
-    bp = lib.vt_dd_series_json(
+        workers = dd_workers(count)
+    handle = lib.vt_dd_stream_begin(
         name_arena, _p(name_off, u32), _p(name_len, u32),
         tags_arena, _p(tags_off, u32), _p(tags_len, u32),
         len(name_off),
@@ -319,17 +387,29 @@ def dd_series_bodies(names: Tuple[bytes, np.ndarray, np.ndarray],
         _p(em_rows, u32), _p(em_suffix, u8), _p(em_values, f64),
         _p(em_type, u8),
         n, timestamp, interval, default_host.encode("utf-8"),
-        common_tags_json, max_per_body, compress_level, workers, spent)
-    if timing is not None:
-        total, deflate = int(spent[0]), int(spent[1])
-        for key, ns in (("deflate_ns", deflate),
-                        ("encode_ns", total - deflate),
-                        ("encode_cpu_ns", int(spent[2])),
-                        ("deflate_cpu_ns", int(spent[3])),
-                        ("bodies", int(spent[4]))):
-            timing[key] = timing.get(key, 0) + ns
-        timing["workers"] = max(timing.get("workers", 0), int(spent[5]))
-    return _take_bodies(lib, bp)
+        common_tags_json, max_per_body, compress_level, workers)
+    return DDSeriesStream(
+        lib, handle, count,
+        (name_arena, name_off, name_len, suffix_blob, s_off, s_len,
+         em_rows, em_suffix, em_values, em_type), timing)
+
+
+def dd_series_bodies(*args, **kw) -> List[bytes]:
+    """``dd_series_stream``'s bodies drained into a list, in order;
+    the same arguments, the same ``timing``."""
+    with dd_series_stream(*args, **kw) as bodies:
+        return [body for body, _ready_ns in bodies]
+
+
+def dd_stream_live() -> Tuple[int, int]:
+    """(serializer handles begun and not closed, native worker threads
+    not joined) in this process."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(f"native egress unavailable: {_build_error}")
+    out = (ctypes.c_uint64 * 2)()
+    lib.vt_dd_stream_live(out)
+    return int(out[0]), int(out[1])
 
 
 def _key_list(keys: List[bytes]):

@@ -8,9 +8,10 @@
 // byte-bound egress paths native, operating on the store's columnar flush
 // output (flat numpy arrays + interner arenas) without per-row Python:
 //
-//  1. vt_dd_series_json — Datadog /api/v1/series bodies straight from
-//     columns, streaming zlib-deflated, chunked like the reference's
-//     flushMaxPerBody split (sinks/datadog/datadog.go:62-68 field layout
+//  1. vt_dd_stream_* — Datadog /api/v1/series bodies straight from
+//     columns, streaming zlib-deflated, handed over as they are made,
+//     chunked like the reference's flushMaxPerBody split
+//     (sinks/datadog/datadog.go:62-68 field layout
 //     incl. omitempty, :245-330 finalize rules: magic host:/device: tags,
 //     counters→rates).
 //  2. vt_mlist_decode / vt_mintern_* — forwardrpc.MetricList protobuf →
@@ -37,7 +38,9 @@
 
 #include <atomic>
 #include <cmath>
+#include <condition_variable>
 #include <cstring>
+#include <mutex>
 #include <system_error>
 #include <thread>
 #include <vector>
@@ -260,7 +263,7 @@ struct BodyWriter {
   z_stream zs;
   bool open = false;
   // ns inside deflate(), over every body of this writer: one clock
-  // read either side of a slab (vt_dd_series_json hands it back)
+  // read either side of a slab (vt_dd_stream_end hands it back)
   uint64_t deflate_ns = 0;
   static constexpr size_t kSlab = 1 << 20;
 
@@ -328,12 +331,140 @@ struct BodyWriter {
 //
 // The bodies of a block share nothing but those fragments: body k is
 // emissions [k*max_per_body, (k+1)*max_per_body), its own zlib stream.
-// `workers` threads (the caller is the first) take k from a counter,
-// each with its own BodyWriter, and write slot k of the list: the bodies
-// come back in order, byte for byte what one worker makes. One worker,
-// or one body, starts no thread.
+// A block is a stream of bodies behind a handle: vt_dd_stream_begin runs
+// the per-row pre-pass on the calling thread and starts `workers` threads
+// that take k from a counter, each with its own BodyWriter, and mark body
+// k ready as they finish it; vt_dd_stream_next(k) waits for body k and
+// hands it over, so the caller can POST body k while bodies k+1.. are
+// being made; vt_dd_stream_end joins the threads and frees the handle.
+// The bodies are byte for byte what one worker makes, in order. The
+// inputs are read until end: the caller keeps them alive until then.
 
-extern "C" VtBodies* vt_dd_series_json(
+namespace {
+
+// handles begun and not ended, worker threads started and not joined
+// (vt_dd_stream_live: what a test reads to see that end frees both)
+std::atomic<uint64_t> g_live_streams{0}, g_live_workers{0};
+
+struct DDStream {
+  // the block, borrowed from the caller
+  const char* name_arena;
+  const uint32_t *name_off, *name_len;
+  const char* suffix_blob;
+  const uint32_t *suffix_off, *suffix_len;
+  const uint32_t* em_rows;
+  const uint8_t* em_suffix;
+  const double* em_values;
+  const uint8_t* em_type;
+  uint64_t nem, n_bodies;
+  uint32_t max_per_body;
+  int level;
+  char ts_str[24], interval_str[16];
+  int ts_n, interval_n;
+  // per-row finalized fragments, all offsets into one scratch arena
+  Buf frag;
+  std::vector<uint64_t> tag_o, host_o, dev_o;
+  std::vector<uint32_t> tag_l, host_l, dev_l;
+  // body k: bytes, and the monotonic ns at which it was made; `made`
+  // and `ready_ns` are written under `mu`
+  std::vector<char*> ptrs;
+  std::vector<uint64_t> lens, ready_ns;
+  std::vector<uint8_t> made;
+  std::mutex mu;
+  std::condition_variable cv;
+  std::atomic<uint64_t> next{0};
+  std::atomic<bool> stop{false};
+  struct WorkerClock {
+    // span from the worker's start to its last body made (0 where it
+    // made none); ns inside deflate(); when its last body was made
+    uint64_t span_ns = 0, deflate_ns = 0, end_ns = 0;
+  };
+  std::vector<WorkerClock> clocks;
+  std::vector<std::thread> pool;
+  uint64_t call_t0 = 0, pool_t0 = 0;
+
+  void encode_body(uint64_t k, BodyWriter& w);
+  void work(WorkerClock* c);
+};
+
+// literal append with compile-time length (put_str's strlen doesn't
+// constant-fold through the out-of-line call and shows in profiles)
+#define PUT_LIT(buf, lit) (buf).put(lit, sizeof(lit) - 1)
+// body k into slot k; reads the inputs and the fragments, writes
+// nothing else
+void DDStream::encode_body(uint64_t k, BodyWriter& w) {
+  uint64_t e0 = k * max_per_body;
+  uint64_t e1 = e0 + max_per_body < nem ? e0 + max_per_body : nem;
+  w.begin(level);
+  PUT_LIT(w.sink(), "{\"series\":[");
+  for (uint64_t e = e0; e < e1; e++) {
+    Buf& b = w.sink();
+    uint32_t r = em_rows[e];
+    uint8_t s = em_suffix[e];
+    // one reserve for everything this emission can write, then raw puts
+    b.reserve(128 + name_len[r] + suffix_len[s] + tag_l[r] + host_l[r] +
+              dev_l[r]);
+    if (e > e0) b.put_ch(',');
+    PUT_LIT(b, "{\"metric\":\"");
+    put_json_str_body(b, name_arena + name_off[r], name_len[r]);
+    if (suffix_len[s]) b.put(suffix_blob + suffix_off[s], suffix_len[s]);
+    PUT_LIT(b, "\",\"points\":[[");
+    b.put(ts_str, ts_n);
+    b.put_ch(',');
+    put_double(b, em_values[e]);
+    PUT_LIT(b, "]]");
+    if (tag_l[r]) {  // omitempty, like the reference's DDMetric
+      PUT_LIT(b, ",\"tags\":[");
+      b.put(frag.p + tag_o[r], tag_l[r]);
+      b.put_ch(']');
+    }
+    if (em_type[e])
+      PUT_LIT(b, ",\"type\":\"rate\"");
+    else
+      PUT_LIT(b, ",\"type\":\"gauge\"");
+    if (host_l[r]) {
+      PUT_LIT(b, ",\"host\":\"");
+      b.put(frag.p + host_o[r], host_l[r]);
+      b.put_ch('"');
+    }
+    if (dev_l[r]) {
+      PUT_LIT(b, ",\"device_name\":\"");
+      b.put(frag.p + dev_o[r], dev_l[r]);
+      b.put_ch('"');
+    }
+    PUT_LIT(b, ",\"interval\":");
+    b.put(interval_str, interval_n);
+    b.put_ch('}');
+    w.maybe_drain();
+  }
+  PUT_LIT(w.sink(), "]}");
+  w.end_into(&ptrs[k], &lens[k]);
+}
+#undef PUT_LIT
+
+void DDStream::work(WorkerClock* c) {
+  BodyWriter w;
+  uint64_t t0 = mono_ns();
+  for (uint64_t k; !stop.load(std::memory_order_relaxed) &&
+                   (k = next.fetch_add(1, std::memory_order_relaxed)) <
+                       n_bodies;) {
+    encode_body(k, w);
+    uint64_t t = mono_ns();
+    {
+      std::lock_guard<std::mutex> lk(mu);
+      ready_ns[k] = t;
+      made[k] = 1;
+    }
+    cv.notify_all();
+    c->end_ns = t;
+  }
+  c->span_ns = c->end_ns ? c->end_ns - t0 : 0;
+  c->deflate_ns = w.deflate_ns;
+}
+
+}  // namespace
+
+extern "C" DDStream* vt_dd_stream_begin(
     const char* name_arena, const uint32_t* name_off, const uint32_t* name_len,
     const char* tags_arena, const uint32_t* tags_off, const uint32_t* tags_len,
     uint32_t nrows, const char* suffix_blob, const uint32_t* suffix_off,
@@ -341,20 +472,30 @@ extern "C" VtBodies* vt_dd_series_json(
     const uint8_t* em_suffix, const double* em_values, const uint8_t* em_type,
     uint64_t nem, int64_t timestamp, int32_t interval,
     const char* default_host, const char* common_tags_json,
-    uint32_t max_per_body, int compress_level, uint32_t workers,
-    uint64_t* timing_ns) {
+    uint32_t max_per_body, int compress_level, uint32_t workers) {
   (void)nsuffix;
-  // timing_ns (nullable), each slot added to. A split of the call's
-  // wall: [0] ns of the whole call, [1] ns of it inside deflate() on the
-  // worker that finished last; the rest is the JSON encoding. Summed
-  // over the workers (each one's span on the monotonic clock): [2] ns
-  // encoding, the pre-pass with it, [3] ns inside deflate(). [4] bodies
-  // made, [5] workers that ran.
-  uint64_t call_t0 = mono_ns();
-  // per-row finalized fragments, all offsets into one scratch arena
-  Buf frag;
-  std::vector<uint64_t> tag_o(nrows), host_o(nrows), dev_o(nrows);
-  std::vector<uint32_t> tag_l(nrows), host_l(nrows), dev_l(nrows);
+  DDStream* s = new DDStream();
+  g_live_streams.fetch_add(1);
+  s->call_t0 = mono_ns();
+  s->name_arena = name_arena;
+  s->name_off = name_off;
+  s->name_len = name_len;
+  s->suffix_blob = suffix_blob;
+  s->suffix_off = suffix_off;
+  s->suffix_len = suffix_len;
+  s->em_rows = em_rows;
+  s->em_suffix = em_suffix;
+  s->em_values = em_values;
+  s->em_type = em_type;
+  s->nem = nem;
+  s->level = compress_level;
+  s->tag_o.resize(nrows);
+  s->host_o.resize(nrows);
+  s->dev_o.resize(nrows);
+  s->tag_l.resize(nrows);
+  s->host_l.resize(nrows);
+  s->dev_l.resize(nrows);
+  Buf& frag = s->frag;
   uint32_t dh_len = static_cast<uint32_t>(strlen(default_host));
   uint32_t common_len = static_cast<uint32_t>(strlen(common_tags_json));
   for (uint32_t r = 0; r < nrows; r++) {
@@ -387,138 +528,114 @@ extern "C" VtBodies* vt_dd_series_json(
       }
       i = j + 1;
     }
-    tag_o[r] = t0;
-    tag_l[r] = static_cast<uint32_t>(frag.len - t0);
+    s->tag_o[r] = t0;
+    s->tag_l[r] = static_cast<uint32_t>(frag.len - t0);
     // host: magic tag else default (escaped)
     uint64_t h0 = frag.len;
     if (host_at != UINT64_MAX)
       put_json_str_body(frag, tags_arena + host_at, host_n);
     else
       put_json_str_body(frag, default_host, dh_len);
-    host_o[r] = h0;
-    host_l[r] = static_cast<uint32_t>(frag.len - h0);
+    s->host_o[r] = h0;
+    s->host_l[r] = static_cast<uint32_t>(frag.len - h0);
     uint64_t d0 = frag.len;
     if (dev_at != UINT64_MAX)
       put_json_str_body(frag, tags_arena + dev_at, dev_n);
-    dev_o[r] = d0;
-    dev_l[r] = static_cast<uint32_t>(frag.len - d0);
+    s->dev_o[r] = d0;
+    s->dev_l[r] = static_cast<uint32_t>(frag.len - d0);
   }
 
-  char ts_str[24];
-  int ts_n = snprintf(ts_str, sizeof ts_str, "%lld",
-                      static_cast<long long>(timestamp));
-  char interval_str[16];
-  int interval_n =
-      snprintf(interval_str, sizeof interval_str, "%d", interval);
+  s->ts_n = snprintf(s->ts_str, sizeof s->ts_str, "%lld",
+                     static_cast<long long>(timestamp));
+  s->interval_n =
+      snprintf(s->interval_str, sizeof s->interval_str, "%d", interval);
 
-  if (max_per_body == 0) max_per_body = UINT32_MAX;
-  const uint64_t n_bodies = (nem + max_per_body - 1) / max_per_body;
-  VtBodiesImpl* impl = new VtBodiesImpl();
-  impl->ptrs.assign(n_bodies, nullptr);
-  impl->lens.assign(n_bodies, 0);
-// literal append with compile-time length (put_str's strlen doesn't
-// constant-fold through the out-of-line call and shows in profiles)
-#define PUT_LIT(buf, lit) (buf).put(lit, sizeof(lit) - 1)
-  // body k into slot k; reads the inputs and the fragments, writes
-  // nothing else
-  auto encode_body = [&](uint64_t k, BodyWriter& w) {
-    uint64_t e0 = k * max_per_body;
-    uint64_t e1 = e0 + max_per_body < nem ? e0 + max_per_body : nem;
-    w.begin(compress_level);
-    PUT_LIT(w.sink(), "{\"series\":[");
-    for (uint64_t e = e0; e < e1; e++) {
-      Buf& b = w.sink();
-      uint32_t r = em_rows[e];
-      uint8_t s = em_suffix[e];
-      // one reserve for everything this emission can write, then raw puts
-      b.reserve(128 + name_len[r] + suffix_len[s] + tag_l[r] + host_l[r] +
-                dev_l[r]);
-      if (e > e0) b.put_ch(',');
-      PUT_LIT(b, "{\"metric\":\"");
-      put_json_str_body(b, name_arena + name_off[r], name_len[r]);
-      if (suffix_len[s]) b.put(suffix_blob + suffix_off[s], suffix_len[s]);
-      PUT_LIT(b, "\",\"points\":[[");
-      b.put(ts_str, ts_n);
-      b.put_ch(',');
-      put_double(b, em_values[e]);
-      PUT_LIT(b, "]]");
-      if (tag_l[r]) {  // omitempty, like the reference's DDMetric
-        PUT_LIT(b, ",\"tags\":[");
-        b.put(frag.p + tag_o[r], tag_l[r]);
-        b.put_ch(']');
-      }
-      if (em_type[e])
-        PUT_LIT(b, ",\"type\":\"rate\"");
-      else
-        PUT_LIT(b, ",\"type\":\"gauge\"");
-      if (host_l[r]) {
-        PUT_LIT(b, ",\"host\":\"");
-        b.put(frag.p + host_o[r], host_l[r]);
-        b.put_ch('"');
-      }
-      if (dev_l[r]) {
-        PUT_LIT(b, ",\"device_name\":\"");
-        b.put(frag.p + dev_o[r], dev_l[r]);
-        b.put_ch('"');
-      }
-      PUT_LIT(b, ",\"interval\":");
-      b.put(interval_str, interval_n);
-      b.put_ch('}');
-      w.maybe_drain();
-    }
-    PUT_LIT(w.sink(), "]}");
-    w.end_into(&impl->ptrs[k], &impl->lens[k]);
-  };
-#undef PUT_LIT
+  s->max_per_body = max_per_body ? max_per_body : UINT32_MAX;
+  s->n_bodies = (nem + s->max_per_body - 1) / s->max_per_body;
+  s->ptrs.assign(s->n_bodies, nullptr);
+  s->lens.assign(s->n_bodies, 0);
+  s->ready_ns.assign(s->n_bodies, 0);
+  s->made.assign(s->n_bodies, 0);
 
-  if (workers > n_bodies) workers = static_cast<uint32_t>(n_bodies);
+  if (workers > s->n_bodies) workers = static_cast<uint32_t>(s->n_bodies);
   if (workers < 1) workers = 1;
-  struct WorkerClock {
-    uint64_t span_ns = 0, deflate_ns = 0, end_ns = 0;
-  };
-  std::vector<WorkerClock> clocks(workers);
-  std::atomic<uint64_t> next{0};
-  auto work = [&](WorkerClock* c) {
-    BodyWriter w;
-    uint64_t t0 = mono_ns();
-    for (uint64_t k; (k = next.fetch_add(1, std::memory_order_relaxed)) <
-                     n_bodies;)
-      encode_body(k, w);
-    c->end_ns = mono_ns();
-    c->span_ns = c->end_ns - t0;
-    c->deflate_ns = w.deflate_ns;
-  };
-  uint64_t pool_t0 = mono_ns();
-  std::vector<std::thread> pool;
-  for (uint32_t i = 1; i < workers; i++) {
-    try {
-      pool.emplace_back(work, &clocks[i]);
-    } catch (const std::system_error&) {
-      break;  // no thread to be had: those that run take every body
+  s->clocks.resize(workers);
+  s->pool_t0 = mono_ns();
+  if (s->n_bodies > 0) {
+    for (uint32_t i = 0; i < workers; i++) {
+      g_live_workers.fetch_add(1);
+      try {
+        s->pool.emplace_back(&DDStream::work, s, &s->clocks[i]);
+      } catch (const std::system_error&) {
+        g_live_workers.fetch_sub(1);
+        break;  // no thread to be had: those that run take every body
+      }
     }
   }
-  work(&clocks[0]);
-  for (std::thread& t : pool) t.join();
-  uint64_t pool_ns = mono_ns() - pool_t0;
-  free(frag.p);
+  // no body, or no thread at all: the calling thread makes them now
+  if (s->pool.empty()) s->work(&s->clocks[0]);
+  return s;
+}
+
+// Wait for body k and hand it over: *ptr / *len stay the handle's until
+// vt_dd_stream_end; *ready_ns is the monotonic ns at which it was made.
+// Returns 0, or -1 where there is no body k.
+extern "C" int vt_dd_stream_next(DDStream* s, uint64_t k, const char** ptr,
+                                 uint64_t* len, uint64_t* ready_ns) {
+  if (k >= s->n_bodies) return -1;
+  std::unique_lock<std::mutex> lk(s->mu);
+  s->cv.wait(lk, [&] { return s->made[k] != 0; });
+  *ptr = s->ptrs[k];
+  *len = s->lens[k];
+  *ready_ns = s->ready_ns[k];
+  return 0;
+}
+
+// Stop the workers after the bodies they are making (all of them are
+// made already where the caller drained the stream), join them, and free
+// the handle with its bodies. timing_ns (nullable), each slot added to: a
+// split of the serializer's wall, from begin to the last body made: [0]
+// ns of it, [1] ns of it inside deflate() on the worker that made the
+// last body; the rest is the JSON encoding. Summed over the workers (each
+// one's span on the monotonic clock, to its last body): [2] ns encoding,
+// the pre-pass with it, [3] ns inside deflate(). [4] bodies made, [5]
+// workers that ran.
+extern "C" void vt_dd_stream_end(DDStream* s, uint64_t* timing_ns) {
+  if (!s) return;
+  s->stop.store(true);
+  for (std::thread& t : s->pool) t.join();
+  g_live_workers.fetch_sub(s->pool.size());
   if (timing_ns) {
-    const WorkerClock* last = &clocks[0];
+    const DDStream::WorkerClock* last = &s->clocks[0];
     uint64_t spans = 0, deflates = 0;
-    for (size_t i = 0; i <= pool.size(); i++) {
-      const WorkerClock& c = clocks[i];
+    size_t ran = s->pool.empty() ? 1 : s->pool.size();
+    for (size_t i = 0; i < ran; i++) {
+      const DDStream::WorkerClock& c = s->clocks[i];
       if (c.end_ns > last->end_ns) last = &c;
       spans += c.span_ns;
       deflates += c.deflate_ns;
     }
-    uint64_t total = mono_ns() - call_t0;
-    timing_ns[0] += total;
+    uint64_t bodies = 0;
+    for (uint8_t m : s->made) bodies += m;
+    // the last body made; with no body, the pre-pass alone
+    uint64_t made = last->end_ns ? last->end_ns : s->pool_t0;
+    timing_ns[0] += made - s->call_t0;
     timing_ns[1] += last->deflate_ns;
-    timing_ns[2] += total - pool_ns + spans - deflates;
+    timing_ns[2] += s->pool_t0 - s->call_t0 + spans - deflates;
     timing_ns[3] += deflates;
-    timing_ns[4] += n_bodies;
-    timing_ns[5] += pool.size() + 1;
+    timing_ns[4] += bodies;
+    timing_ns[5] += ran;
   }
-  return bodies_finish(impl);
+  for (char* p : s->ptrs) free(p);
+  free(s->frag.p);
+  delete s;
+  g_live_streams.fetch_sub(1);
+}
+
+// [0] handles begun and not ended, [1] worker threads not joined
+extern "C" void vt_dd_stream_live(uint64_t* out) {
+  out[0] = g_live_streams.load();
+  out[1] = g_live_workers.load();
 }
 
 // ---------------------------------------------------------------------------

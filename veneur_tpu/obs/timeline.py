@@ -30,9 +30,12 @@ def annotate_overlap(entry: dict) -> dict:
     - ``lanes`` — summed ns per lane. Leaf classification: a
       ``*.compute`` / ``*.fetch`` stage is device dispatch / transfer;
       ``serialize.<group>`` and ``post.<sink>.serialize`` are the
-      serialize lane; ``post.<sink>.post`` (streamed chunks) and the
+      serialize lane; ``post.<sink>.post`` (streamed chunks: it runs
+      under the chunk's ``serialize``, body ``k`` POSTed while ``k+1..``
+      are made, so the two lanes overlap in the wall) and the
       ``post.<sink>`` fan-out stages (their amended ``post_ns`` /
-      ``serialize_ns`` when present, wall-clock otherwise) are POST.
+      ``serialize_ns`` when present, wall-clock otherwise) are POST;
+      ``post.<sink>.post.tail`` is part of its parent and in no lane.
     - ``egress_wall_ns`` — wall-clock from the store drain's start to
       the last POST's end: what the interval actually costs.
     - ``overlap_ratio`` — egress_wall / Σlanes. A fully sequential

@@ -58,16 +58,13 @@ def _ok(status: int) -> bool:
     return status in (200, 202)
 
 
-def _body_rows(n: int, max_per_body: int, n_bodies: int) -> list:
+def _body_rows(n: int, max_per_body: int) -> list:
     """Per-body emission counts for one block's serialized bodies: the
-    native serializer (veneur_egress.cpp vt_dd_series_json) closes a
+    native serializer (veneur_egress.cpp vt_dd_stream_begin) closes a
     body at exactly ``max_per_body`` emissions, so every body holds
     max_per_body rows except the last — the split the per-chunk
     conservation accounting relies on."""
-    if n_bodies <= 1:
-        return [n]
-    return [max_per_body] * (n_bodies - 1) + \
-        [n - max_per_body * (n_bodies - 1)]
+    return [min(max_per_body, n - i) for i in range(0, n, max_per_body)]
 
 
 class DatadogMetricSink(MetricSink):
@@ -183,7 +180,8 @@ class DatadogMetricSink(MetricSink):
         n_metrics = 0
         t_marshal = time.perf_counter()
         for blk in batch.blocks:
-            bodies.extend(self._serialize_block(blk, batch.timestamp))
+            with self._serialize_block(blk, batch.timestamp) as stream:
+                bodies.extend(body for body, _ready_ns in stream)
             n_metrics += len(blk)
         t_marshal = time.perf_counter() - t_marshal
         threads = []
@@ -210,7 +208,12 @@ class DatadogMetricSink(MetricSink):
         serialize + deflate + POST ONE pipeline chunk the moment the
         store completes it, while later groups still compute/fetch.
         Runs on the interval's stream worker behind the same retry/
-        breaker/deadline ladder as the batch path.
+        breaker/deadline ladder as the batch path. Body ``k`` of a
+        block is POSTed as soon as the native serializer has made it,
+        while its workers make ``k+1..`` (``egress.dd_series_stream``,
+        as upstream POSTs a chunk the moment its goroutine has
+        marshalled it, datadog.go:324-330); the POSTs stay one at a
+        time, in body order, and the chunk returns after the last.
 
         Per-chunk conservation: every emission row either reaches a
         2xx body (``chunk_rows_acked``) or its serialized body parks
@@ -230,9 +233,9 @@ class DatadogMetricSink(MetricSink):
         # fall back to it)
         self.repost_requeued(getattr(chunk, "cycle", 0) or chunk.timestamp)
         rec = obs.current()
+        serialize = f"post.{self.name}.serialize"
+        post = f"post.{self.name}.post"
         t0_ns = time.monotonic_ns()
-        t_marshal = time.perf_counter()
-        bodies = []
         # where the native serializer's wall went (encoding JSON, in
         # deflate), the same summed over its workers, the bodies it made:
         # added up over the chunk's blocks; the most workers a block's
@@ -240,19 +243,28 @@ class DatadogMetricSink(MetricSink):
         native_ns = dict.fromkeys(
             ("encode_ns", "deflate_ns", "encode_cpu_ns", "deflate_cpu_ns",
              "bodies", "workers"), 0)
-        with host_scope(f"post.{self.name}.serialize"):
-            for blk in chunk.blocks:
-                blk_bodies = self._serialize_block(blk, chunk.timestamp,
-                                                   native_ns)
-                bodies.extend(zip(blk_bodies,
-                                  _body_rows(len(blk),
-                                             self.flush_max_per_body,
-                                             len(blk_bodies))))
-        t_marshal = time.perf_counter() - t_marshal
+        made_ns = t0_ns     # when the chunk's last body was made
+        posts = []          # each body's POST: (start ns, return ns)
+        sizes = []
+        for blk in chunk.blocks:
+            with host_scope(serialize):
+                stream = self._serialize_block(blk, chunk.timestamp,
+                                               native_ns)
+            with stream:
+                for nrows in _body_rows(len(blk), self.flush_max_per_body):
+                    with host_scope(serialize):
+                        body, ready_ns = next(stream)
+                    made_ns = max(made_ns, ready_ns)
+                    p0 = time.monotonic_ns()
+                    with host_scope(post):
+                        self._post_chunk_body(body, nrows)
+                    posts.append((p0, time.monotonic_ns()))
+                    sizes.append(len(body))
+        post_t0, post_t1 = (posts[0][0], posts[-1][1]) if posts \
+            else (made_ns, made_ns)
         if rec is not None:
-            rec.record_abs(f"post.{self.name}.serialize", t0_ns,
-                           time.monotonic_ns(), chunk=chunk.seq,
-                           bodies=native_ns["bodies"],
+            rec.record_abs(f"post.{self.name}.serialize", t0_ns, made_ns,
+                           chunk=chunk.seq, bodies=native_ns["bodies"],
                            workers=native_ns["workers"],
                            encode_cpu_ns=native_ns["encode_cpu_ns"],
                            deflate_cpu_ns=native_ns["deflate_cpu_ns"])
@@ -260,37 +272,39 @@ class DatadogMetricSink(MetricSink):
                 rec.record_abs(f"post.{self.name}.serialize.{part}", t0_ns,
                                t0_ns + native_ns[part + "_ns"],
                                chunk=chunk.seq)
-        t0_ns = time.monotonic_ns()
-        t_post = time.perf_counter()
-        with host_scope(f"post.{self.name}.post"):
-            for body, nrows in bodies:
-                self._post_chunk_body(body, nrows)
-        t_post = time.perf_counter() - t_post
-        if rec is not None:
-            rec.record_abs(f"post.{self.name}.post", t0_ns,
-                           time.monotonic_ns(), chunk=chunk.seq,
-                           rows=chunk.rows,
-                           bytes=sum(len(b) for b, _ in bodies))
+            # the POSTs overlap the serializer: `post` spans the first
+            # POST's start to the last one's return, its `tail` what
+            # the serializer did not hide (from the last body made)
+            rec.record_abs(f"post.{self.name}.post", post_t0, post_t1,
+                           chunk=chunk.seq, rows=chunk.rows,
+                           bytes=sum(sizes), bodies_posted_early=sum(
+                               1 for _p0, p1 in posts if p1 < made_ns))
+            rec.record_abs(f"post.{self.name}.post.tail",
+                           max(made_ns, post_t0), post_t1, chunk=chunk.seq)
         with self._err_lock:
             # chunk_* kinds: same part-tagged duration self-metrics as
             # the batch path, but NOT amended onto the post.<sink>
             # stage — the chunk's own post.<sink>.serialize/.post
             # stages already carry the lanes, and an amend on top
             # would double-bill annotate_overlap
-            self._telemetry.append(("chunk_marshal_s", t_marshal))
-            self._telemetry.append(("chunk_post_s", t_post))
-            self._telemetry.extend(("content_length_bytes", len(b))
-                                   for b, _ in bodies)
+            self._telemetry.append(("chunk_marshal_s",
+                                    (made_ns - t0_ns) / 1e9))
+            self._telemetry.append(("chunk_post_s",
+                                    (post_t1 - post_t0) / 1e9))
+            self._telemetry.extend(("content_length_bytes", n)
+                                   for n in sizes)
             self.chunks_flushed += 1
         self.metrics_flushed += chunk.rows
 
     def _serialize_block(self, blk, timestamp: int,
-                         timing: Optional[dict] = None) -> List[bytes]:
-        """One emission block → deflated series bodies: the
-        counter-to-rate finalization (datadog.go:295-297) + the native
-        serializer call, shared by the batch and streamed paths so the
-        wire format can never diverge between them. ``timing`` is
-        ``dd_series_bodies``'s: where the native call's time went."""
+                         timing: Optional[dict] = None):
+        """One emission block → its deflated series bodies, as the
+        native serializer makes them (``egress.DDSeriesStream``, closed
+        by the caller): the counter-to-rate finalization
+        (datadog.go:295-297) + the native call, shared by the batch and
+        streamed paths so the wire format can never diverge between
+        them. ``timing`` is ``dd_series_stream``'s: where the native
+        serializer's time went."""
         from veneur_tpu.core.columnar import TYPE_COUNTER
         from veneur_tpu.native import egress
 
@@ -298,7 +312,7 @@ class DatadogMetricSink(MetricSink):
         if (blk.type_codes == TYPE_COUNTER).any():
             values = np.where(blk.type_codes == TYPE_COUNTER,
                               values / self.interval, values)
-        return egress.dd_series_bodies(
+        return egress.dd_series_stream(
             blk.names, blk.tags, blk.suffixes, blk.rows,
             blk.suffix_idx, values, blk.type_codes,
             timestamp=timestamp, interval=int(self.interval),
