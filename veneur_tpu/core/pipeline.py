@@ -3,10 +3,9 @@ generation drain and the streaming consumers.
 
 The per-interval flush used to be a SUM of its stages — device compute,
 per-group device→host fetch, serialize/deflate, POST — because each ran
-to completion before the next started (the `6_egress_1m` timeline made
-that visible: 4.6 s = compute + fetch + serialize + POST, not their
-max). This module holds the two host-side lanes that turn it into a
-MAX-shaped pipeline (docs/internals.md "Life of a flush"):
+to completion before the next started. This module holds the two
+host-side lanes that overlap them (docs/internals.md "Life of a
+flush"):
 
 - :class:`SerializerLane` — ONE worker thread + a bounded handoff
   queue between the store's fetch loop and the emission/serialization
@@ -39,7 +38,6 @@ import threading
 import time
 from typing import List, NamedTuple, Optional
 
-from veneur_tpu.obs import kernels as obs_kernels
 from veneur_tpu.obs import recorder as obs_rec
 
 log = logging.getLogger("veneur.pipeline")
@@ -90,25 +88,22 @@ class SerializerLane:
 
     def _run(self) -> None:
         # the serializer inherits the interval's recorder so emit-side
-        # stream hooks (sink chunk stages) land in the same timeline
+        # stream hooks (sink chunk stages) land in the same timeline,
+        # and opens serialize.<group> on its own stage stack, so that
+        # the emission's leaves nest under it
         with obs_rec.activate(self._rec):
             while True:
                 item = self._q.get()
                 if item is None:
                     return
                 name, emit, result = item
-                t0 = time.monotonic_ns()
                 try:
-                    if self._err is None:
-                        with obs_kernels.host_scope(f"serialize.{name}"):
+                    with obs_rec.maybe_stage(f"serialize.{name}"):
+                        if self._err is None:
                             emit(result)
                 except BaseException as e:  # re-raised at close
                     self._err = e
                     log.exception("flush emission for %s failed", name)
-                finally:
-                    if self._rec is not None:
-                        self._rec.record_abs(f"serialize.{name}", t0,
-                                             time.monotonic_ns())
 
     def close(self) -> None:
         """Drain + join the worker; re-raise the first emit error."""
@@ -188,9 +183,11 @@ class ChunkStream:
         self._seq += 1
         self.chunks += 1
         self.rows += chunk.rows
-        for q, _t in self._workers:
-            if q is not self._fwd_q:
-                q.put(chunk)
+        # a wait: for room in each sink's queue
+        with obs_rec.maybe_stage("handoff"):
+            for q, _t in self._workers:
+                if q is not self._fwd_q:
+                    q.put(chunk)
 
     def emit_forward(self, name: str, attr: str, part, rows: int) -> None:
         """Hand one forwardable digest part to the forward lane."""
@@ -198,7 +195,8 @@ class ChunkStream:
             return
         self.forward_parts += 1
         self.forward_rows += int(rows)
-        self._fwd_q.put((name, attr, part, int(rows)))
+        with obs_rec.maybe_stage("handoff"):
+            self._fwd_q.put((name, attr, part, int(rows)))
 
     def _sink_worker(self, sink, q: "queue.Queue") -> None:
         # the interval's recorder rides along so the sink's chunk

@@ -1084,8 +1084,7 @@ class SlabDigestGroup(OverloadLimited):
         """Blocking half: fetch each dispatched slab's interned prefix
         in order, dispatching slab j+window while slab j's fetch
         blocks — device execution overlaps the host transfer instead
-        of idling behind it (the sum-vs-max gap the `6_egress_1m`
-        timeline exposed)."""
+        of idling behind it."""
         window = max(1, getattr(self, "_pipeline_window", 1))
         parts = []
         pk_counts, pk_means, pk_wts = [], [], []

@@ -48,7 +48,7 @@ import logging
 import math
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Tuple
@@ -408,15 +408,22 @@ def cut_rows(fetched, n: int):
 
 @contextmanager
 def fetch_stage(refs):
-    """One group's ``fetch`` stage (and ``veneur.fetch`` host scope in a
-    profiler capture) around the caller's ``jax.device_get``, opened by
-    ``fetch.wait``: the wait for the device to have produced ``refs``,
-    which the ``device_get`` would otherwise absorb unseen. What is
-    left of ``fetch`` after it is the transfer itself."""
-    with obs_rec.maybe_stage("fetch"), obs_kernels.host_scope("fetch"):
+    """One group's ``fetch`` stage around the caller's
+    ``jax.device_get``, in two leaves: ``fetch.wait``, the wait for the
+    device to have produced ``refs``, which the ``device_get`` would
+    otherwise absorb unseen, then ``fetch.copy`` (and its host scope in
+    a profiler capture), the transfer itself with the caller's cut."""
+    with obs_rec.maybe_stage("fetch"):
         with obs_rec.maybe_stage("wait"):
             jax.block_until_ready(refs)
-        yield
+        with obs_rec.maybe_stage("copy", scope=True):
+            yield
+
+
+def _no_stage(name: str, scope: bool = False):
+    """``obs_rec.maybe_stage``'s shape, recording nothing: for a caller
+    that is a leaf as a whole."""
+    return nullcontext()
 
 
 # ---------------------------------------------------------------------------
@@ -1134,26 +1141,28 @@ class DigestGroup(OverloadLimited):
         """The n==0 flush path: skip the flush program AND the
         device->host fetches (each fetch is a full host-device round
         trip)."""
-        interner, self.interner = self.interner, Interner()
-        if self._retired:
-            self._drop_device()
-        elif self._device_dirty:
-            # bulk paths can stage data without interning; never let
-            # it leak into the next interval's rows
-            self._init_device()
-            self._init_staging()
+        with obs_rec.maybe_stage("commit", scope=True):
+            interner, self.interner = self.interner, Interner()
+            if self._retired:
+                self._drop_device()
+            elif self._device_dirty:
+                # bulk paths can stage data without interning; never
+                # let it leak into the next interval's rows
+                self._init_device()
+                self._init_staging()
         return interner, {}
 
     def _flush_commit(self, out: dict):
         """Interner swap + device reset, only AFTER the device programs
         + fetches succeeded: on a ladder failure the group still holds
         its state for the store's re-merge rung."""
-        interner, self.interner = self.interner, Interner()
-        if self._retired:
-            self._drop_device()
-        else:
-            self._init_device()
-            self._init_staging()
+        with obs_rec.maybe_stage("commit", scope=True):
+            interner, self.interner = self.interner, Interner()
+            if self._retired:
+                self._drop_device()
+            else:
+                self._init_device()
+                self._init_staging()
         return interner, out
 
     def _flush_fetch(self, n: int, percentiles, want_digests, want_stats,
@@ -1541,28 +1550,29 @@ class SetGroup(OverloadLimited):
         execution overlaps it."""
         with obs_rec.maybe_stage("drain"):
             self._drain_staging()
-        n = len(self.interner)
-        interner, self.interner = self.interner, Interner()
-        if n == 0:
+        with obs_rec.maybe_stage("compute"):
+            n = len(self.interner)
+            interner, self.interner = self.interner, Interner()
+            if n == 0:
+                if self._retired:
+                    self.registers = None
+                    self._device_dirty = False
+                elif self._device_dirty:
+                    self._reset_registers()
+                    self._init_staging()
+                return lambda: (interner, None, None)
+            est_ref = self._estimate_refs(n) if want_estimates else None
+            reg_ref = self._register_refs(n) if want_registers else None
             if self._retired:
+                # retired generation: drop the [S, 2^p] plane now
+                # instead of allocating a third one (16 KiB/series at
+                # p=14); the sliced op outputs above keep the live rows
+                # alive until the fetch lands
                 self.registers = None
                 self._device_dirty = False
-            elif self._device_dirty:
+            else:
                 self._reset_registers()
                 self._init_staging()
-            return lambda: (interner, None, None)
-        est_ref = self._estimate_refs(n) if want_estimates else None
-        reg_ref = self._register_refs(n) if want_registers else None
-        if self._retired:
-            # retired generation: drop the [S, 2^p] plane now instead
-            # of allocating a third one (16 KiB/series at p=14); the
-            # sliced op outputs above keep the live rows alive until
-            # the fetch lands
-            self.registers = None
-            self._device_dirty = False
-        else:
-            self._reset_registers()
-            self._init_staging()
 
         def finish():
             with fetch_stage((est_ref, reg_ref)):
@@ -1860,21 +1870,23 @@ class HeavyHitterGroup(OverloadLimited):
             if self.dispatches:
                 obs_rec.note(topk_dispatches=self.dispatches)
                 self.dispatches = 0
-        n = len(self.interner)
-        interner, self.interner = self.interner, Interner()
-        if n == 0 and not self._device_dirty:
-            # pristine sketch: skip the device reallocation entirely
-            return lambda: (interner, [], None)
-        refs = self._live_topk(n) if n else None
-        table_ref = self.sketch.table if (n and want_forward) else None
-        members, self._members = self._members, {}
-        if self._retired:
-            self.sketch = None  # free the table now, never reused
-        else:
-            self._reset_sketch()
-            self._sids_np = np.zeros(self.capacity + 1, np.uint32)
-            self._new_sample_buffers()
-        self._device_dirty = False
+        with obs_rec.maybe_stage("compute"):
+            n = len(self.interner)
+            interner, self.interner = self.interner, Interner()
+            if n == 0 and not self._device_dirty:
+                # pristine sketch: skip the device reallocation entirely
+                return lambda: (interner, [], None)
+            refs = self._live_topk(n) if n else None
+            table_ref = self.sketch.table if (n and want_forward) \
+                else None
+            members, self._members = self._members, {}
+            if self._retired:
+                self.sketch = None  # free the table now, never reused
+            else:
+                self._reset_sketch()
+                self._sids_np = np.zeros(self.capacity + 1, np.uint32)
+                self._new_sample_buffers()
+            self._device_dirty = False
 
         def finish():
             out = []
@@ -3432,13 +3444,24 @@ class MetricStore:
         # it serializes overlapping flush() calls (only the flusher and
         # shutdown ever contend) while ingest proceeds on _lock
         with self._flush_gate:  # lint: ok(lock-across-blocking) the gate's entire job is to hold across the multi-second retired drain; ingest never waits on it (it proceeds on _lock)
-            with obs_rec.maybe_stage("swap"), \
-                    obs_kernels.host_scope("swap"):
+            with obs_rec.maybe_stage("swap"):
+                # the swap's two leaves: the wait for the lock (the
+                # merger and the import workers hold it a chunk at a
+                # time), then the twins under it
+                t0 = time.monotonic_ns()
                 with self._lock:
-                    gen = self._swap_generation()
-            return self._flush_generation(
+                    obs_rec.record_child("lock_wait", t0,
+                                         time.monotonic_ns())
+                    with obs_rec.maybe_stage("twins", scope=True):
+                        gen = self._swap_generation()
+            out = self._flush_generation(
                 gen, percentiles, aggregates, is_local, now, forward,
                 forward_topk, columnar, digest_format, stream)
+            # the retired generation's last reference: its groups' host
+            # state is freed here
+            with obs_rec.maybe_stage("release", scope=True):
+                del gen
+            return out
 
     # every group swapped per flush, in flush order (self_timers is the
     # dedicated self-telemetry group — the server's own stage durations,
@@ -3495,7 +3518,8 @@ class MetricStore:
         the overlapped dispatch→fetch→serialize pipeline otherwise,
         with each completed group streamed out through ``stream`` as
         its own egress chunk."""
-        ms = _summarize(g)
+        with obs_rec.maybe_stage("summarize", scope=True):
+            ms = _summarize(g)
         ms.processed = g.processed
         ms.imported = g.imported
         col: Optional["ColumnarFlush"] = None
@@ -3517,10 +3541,11 @@ class MetricStore:
                                 now, col)
             self._flush_scalars(g.gauges, MetricType.GAUGE, final, now,
                                 col)
-        if stream is not None and col is not None \
-                and len(col.blocks) > mark:
-            blocks = col.blocks[mark:]
-            stream.emit("scalars", blocks, sum(len(b) for b in blocks))
+            if stream is not None and col is not None \
+                    and len(col.blocks) > mark:
+                blocks = col.blocks[mark:]
+                stream.emit("scalars", blocks,
+                            sum(len(b) for b in blocks))
 
         # mixed histograms/timers: no percentiles on a local instance
         mixed_pcts = [] if is_local else list(percentiles)
@@ -3613,11 +3638,18 @@ class MetricStore:
         self._run_flush_units(units)
 
         # status checks are always local
-        self._flush_status(g.local_status_checks, final, now)
+        with obs_rec.maybe_stage("status", scope=True):
+            self._flush_status(g.local_status_checks, final, now)
 
-        # global counters/gauges: forwarded by locals, flushed by globals
-        if is_local:
-            if forward:
+        # global counters/gauges: forwarded by locals, flushed by
+        # globals, row by row either way
+        with obs_rec.maybe_stage("globals", scope=True):
+            if not is_local:
+                self._flush_scalars(g.global_counters, MetricType.COUNTER,
+                                    final, now, staged=False)
+                self._flush_scalars(g.global_gauges, MetricType.GAUGE,
+                                    final, now, staged=False)
+            elif forward:
                 interner, values, _, _ = \
                     g.global_counters.snapshot_and_reset()
                 for key, row in interner.rows.items():
@@ -3631,26 +3663,33 @@ class MetricStore:
             else:
                 g.global_counters.snapshot_and_reset()
                 g.global_gauges.snapshot_and_reset()
-        else:
-            self._flush_scalars(g.global_counters, MetricType.COUNTER,
-                                final, now)
-            self._flush_scalars(g.global_gauges, MetricType.GAUGE,
-                                final, now)
 
         return (col if col is not None else final), fwd, ms
 
     def _flush_scalars(self, group: ScalarGroup, mtype: MetricType,
-                       out: List[InterMetric], now: int, col=None):
-        interner, values, _, _ = group.flush_begin()()
+                       out: List[InterMetric], now: int, col=None,
+                       staged: bool = True):
+        """One scalar group's drain; ``staged`` gives its snapshot, its
+        block and the snapshot's release the leaves ``snapshot``,
+        ``block`` and ``release`` (the globals' row-by-row drain is a
+        leaf as a whole)."""
+        stage = obs_rec.maybe_stage if staged else _no_stage
+        with stage("snapshot", scope=True):
+            interner, values, _, _ = group.flush_begin()()
         if col is not None and len(interner):
             from veneur_tpu.core import columnar as cb
 
-            block = cb.scalar_block(
-                interner, values,
-                cb.TYPE_COUNTER if mtype == MetricType.COUNTER
-                else cb.TYPE_GAUGE)
+            with stage("block", scope=True):
+                block = cb.scalar_block(
+                    interner, values,
+                    cb.TYPE_COUNTER if mtype == MetricType.COUNTER
+                    else cb.TYPE_GAUGE)
             if not cb.has_sink_routing(block.tags[0]):
                 col.add_block(block)
+                # the snapshot's interner, a Python object a row, freed
+                # here and not unseen in the return
+                with stage("release", scope=True):
+                    del interner, values
                 return
             # sink-routed rows present (rare): per-row path keeps routing
         for key, row in interner.rows.items():
@@ -3717,7 +3756,8 @@ class MetricStore:
                             raise
                         fin = None
                 plan.append((name, series, fin, emit, group))
-        lane = SerializerLane(depth, obs_rec.current())
+        with obs_rec.maybe_stage("lane_wait"):  # its thread's start
+            lane = SerializerLane(depth, obs_rec.current())
         try:
             for name, series, fin, emit, group in plan:
                 if fin is None:
@@ -3729,10 +3769,19 @@ class MetricStore:
                         if not self._unit_failed(name, group, "fetch"):
                             raise
                         continue
-                lane.submit(name, emit, res)
+                # the flusher's waits on the lane: for room in its
+                # queue, then for its last group
+                with obs_rec.maybe_stage("lane_wait"):
+                    lane.submit(name, emit, res)
         finally:
             # joins the serializer; re-raises the first emit error
-            lane.close()
+            with obs_rec.maybe_stage("lane_wait"):
+                lane.close()
+        # the plan's closures and what they fetched, freed here and not
+        # unseen in the return
+        with obs_rec.maybe_stage("release", scope=True):
+            del plan
+            res = fin = emit = None
 
     def _unit_failed(self, name: str, group, phase: str) -> bool:
         """The flush plan's shared failure edge (call from an except
@@ -3764,10 +3813,13 @@ class MetricStore:
         if col is not None and len(interner):
             from veneur_tpu.core import columnar as cb
 
-            names = cb.build_arenas(interner.names)
-            tags = cb.build_arenas(interner.joined)
+            with obs_rec.maybe_stage("arenas", scope=True):
+                names = cb.build_arenas(interner.names)
+                tags = cb.build_arenas(interner.joined)
             if not cb.has_sink_routing(tags[0]):
-                block = cb.digest_block(names, tags, r, agg, percentiles)
+                with obs_rec.maybe_stage("block", scope=True):
+                    block = cb.digest_block(names, tags, r, agg,
+                                            percentiles)
                 col.add_block(block)
                 if fwd_state is not None:
                     if packed:

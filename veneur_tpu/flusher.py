@@ -96,58 +96,66 @@ def _publish_interval(server, span, rec, timeline):
     from veneur_tpu.obs import tracectx
     from veneur_tpu.trace import samples as ssf_samples
 
-    hop_log = getattr(server, "obs_hops", None)
-    hops = hop_log.drain() if hop_log is not None else []
-    for h in hops:
-        # the true wall times ride as attrs: a hop that landed BEFORE
-        # this interval started gets its start clamped to 0 in the
-        # recorder's relative frame, and the /debug/trace stitcher
-        # needs the real ordering
-        attrs = {k: v for k, v in h.items()
-                 if k not in ("hop", "duration_ns")}
-        rec.record_abs(h["hop"],
-                       tracectx.wall_to_mono_ns(rec, h["wall_start"]),
-                       tracectx.wall_to_mono_ns(rec, h["wall_end"]),
-                       off_path=True, **attrs)
-    ingest_stages = _drain_ingest_stages(server)
-    if ingest_stages:
-        # the ingest-path stage tree: cumulative lane-time since the
-        # last interval (recv includes socket wait), anchored at the
-        # interval start and off-path — ingest overlaps the whole
-        # interval, so it must not count against flush coverage
-        total = sum(ingest_stages[s]
-                    for s in ("recv", "decode", "stage", "seal"))
-        rec.record_abs("ingest", rec.t0_ns, rec.t0_ns + total,
-                       off_path=True, lanes=ingest_stages["lanes"],
-                       iters=ingest_stages["iters"])
-        for stage in ("recv", "decode", "stage", "seal"):
-            rec.record_abs(f"ingest.{stage}", rec.t0_ns,
-                           rec.t0_ns + ingest_stages[stage],
-                           off_path=True)
-        # the merger thread's own busy time over the same stretch, and
-        # inside it the lock wait, the remap + interning and the
-        # staging calls (core/store.py import_lane_chunk)
-        merger = ingest_stages["merger"]
-        rec.record_abs("ingest.merge", rec.t0_ns,
-                       rec.t0_ns + merger["merge"], off_path=True,
-                       chunks=merger["chunks"],
-                       rows_interned=merger["rows_interned"])
-        stages = ["lock_wait", "remap", "stage"]
-        if getattr(getattr(server, "store", None), "mesh", None) is not None:
-            # of the remap: a mesh's placing of the first-sight rows
-            stages.append("route")
-        for stage in stages:
-            rec.record_abs(f"ingest.merge.{stage}", rec.t0_ns,
-                           rec.t0_ns + merger[stage], off_path=True)
-    imports = _take_import_stages(server)
-    if imports:
-        # a global's import path over the same stretch, clocked a
-        # message at a time by its gRPC workers (core/store.py
-        # import_columnar): cumulative and off-path like the merger's
-        messages = imports.pop("messages")
-        for stage, ns in imports.items():
-            rec.record_abs(f"import.{stage}", rec.t0_ns, rec.t0_ns + ns,
-                           off_path=True)
+    # the publication runs on the flusher's thread inside the
+    # interval's wall (which ends at finish()), so it has its leaves:
+    # the hop log and the lanes' cumulative trees read, then the wait
+    # for the store lock that the import workers' clock sits under
+    with rec.stage("publish"):
+        with rec.stage("drain", scope=True):
+            hop_log = getattr(server, "obs_hops", None)
+            hops = hop_log.drain() if hop_log is not None else []
+            for h in hops:
+                # the true wall times ride as attrs: a hop that landed BEFORE
+                # this interval started gets its start clamped to 0 in the
+                # recorder's relative frame, and the /debug/trace stitcher
+                # needs the real ordering
+                attrs = {k: v for k, v in h.items()
+                         if k not in ("hop", "duration_ns")}
+                rec.record_abs(h["hop"],
+                               tracectx.wall_to_mono_ns(rec, h["wall_start"]),
+                               tracectx.wall_to_mono_ns(rec, h["wall_end"]),
+                               off_path=True, **attrs)
+            ingest_stages = _drain_ingest_stages(server)
+            if ingest_stages:
+                # the ingest-path stage tree: cumulative lane-time since the
+                # last interval (recv includes socket wait), anchored at the
+                # interval start and off-path — ingest overlaps the whole
+                # interval, so it must not count against flush coverage
+                total = sum(ingest_stages[s]
+                            for s in ("recv", "decode", "stage", "seal"))
+                rec.record_abs("ingest", rec.t0_ns, rec.t0_ns + total,
+                               off_path=True, lanes=ingest_stages["lanes"],
+                               iters=ingest_stages["iters"])
+                for stage in ("recv", "decode", "stage", "seal"):
+                    rec.record_abs(f"ingest.{stage}", rec.t0_ns,
+                                   rec.t0_ns + ingest_stages[stage],
+                                   off_path=True)
+                # the merger thread's own busy time over the same stretch, and
+                # inside it the lock wait, the remap + interning and the
+                # staging calls (core/store.py import_lane_chunk)
+                merger = ingest_stages["merger"]
+                rec.record_abs("ingest.merge", rec.t0_ns,
+                               rec.t0_ns + merger["merge"], off_path=True,
+                               chunks=merger["chunks"],
+                               rows_interned=merger["rows_interned"])
+                stages = ["lock_wait", "remap", "stage"]
+                if getattr(getattr(server, "store", None), "mesh",
+                           None) is not None:
+                    # of the remap: a mesh's placing of the first-sight rows
+                    stages.append("route")
+                for stage in stages:
+                    rec.record_abs(f"ingest.merge.{stage}", rec.t0_ns,
+                                   rec.t0_ns + merger[stage], off_path=True)
+        with rec.stage("lock_wait"):
+            imports = _take_import_stages(server)
+        if imports:
+            # a global's import path over the same stretch, clocked a
+            # message at a time by its gRPC workers (core/store.py
+            # import_columnar): cumulative and off-path like the merger's
+            messages = imports.pop("messages")
+            for stage, ns in imports.items():
+                rec.record_abs(f"import.{stage}", rec.t0_ns, rec.t0_ns + ns,
+                               off_path=True)
     entry = rec.finish()
     if imports:
         entry["import"] = {"messages": messages}
@@ -218,12 +226,6 @@ def _publish_interval(server, span, rec, timeline):
             # ingest stamp -> global sink 2xx, the true e2e freshness
             e2e_ns = age_ns
             entry["e2e_age_ns"] = e2e_ns
-    # the egress-pipeline overlap measures (obs/timeline.py): lanes,
-    # egress_wall_ns, overlap_ratio, sum_vs_max_gap_ns — what the
-    # `6_egress_1m` bench gate reads straight off this endpoint
-    from veneur_tpu.obs.timeline import annotate_overlap
-
-    annotate_overlap(entry)
     timeline.publish(entry)
     _record_stage_spans(server, span, entry)
     store = getattr(server, "store", None)
@@ -251,11 +253,6 @@ def _publish_interval(server, span, rec, timeline):
     # live device observability: coverage of the interval's stages plus
     # compile/dispatch deltas per kernel scope (what the recompile lint
     # pass proves statically, observed at runtime)
-    if entry.get("overlap_ratio") is not None:
-        # the egress pipeline's sum-vs-max health in one gauge: ~1.0 =
-        # sequential, max(lane)/Σlanes = perfectly overlapped
-        span.add(ssf_samples.gauge("veneur.obs.overlap_ratio",
-                                   float(entry["overlap_ratio"]), None))
     span.add(
         ssf_samples.gauge("veneur.obs.stage_coverage_ratio",
                           float(entry["coverage_ratio"]), None),
@@ -426,7 +423,9 @@ def _flush_once(server: "Server", span, rec=None):
         span_flusher = threading.Thread(
             target=_flush_spans, args=(server,), daemon=True)
         server._span_flush_thread = span_flusher
-        span_flusher.start()
+        # a wait: a thread's start returns once the thread runs
+        with obs.maybe_stage("span_start"):
+            span_flusher.start()
     else:
         # degradation must be observable, not just logged: counted here,
         # emitted below as veneur.flush.span_flush_skipped_total
@@ -511,8 +510,10 @@ def _flush_once(server: "Server", span, rec=None):
     # fetch, and (when the forwarder takes chunks) forwardable digest
     # shards ship upstream the same way — behind the same retry/
     # breaker/deadline ladder, with per-chunk requeue accounting
-    stream, stream_sinks = _build_stream(server, now, deadline, rec,
-                                         use_columnar, forwarding, span)
+    with obs.maybe_stage("stream_open"):   # a wait: its workers' start
+        stream, stream_sinks = _build_stream(server, now, deadline, rec,
+                                             use_columnar, forwarding,
+                                             span)
     # flush_once's finally closes this on every unwind path; the happy
     # path's post barrier below closes it first (close is idempotent)
     server._active_stream = stream
@@ -551,45 +552,49 @@ def _flush_once(server: "Server", span, rec=None):
     # Non-blocking: a checkpoint write in flight holds the IO lock for
     # its full write+fsync, and the writer's own post-commit epoch
     # check removes the stale file instead
-    ckpt = getattr(server, "checkpointer", None)
-    if ckpt is not None:
-        ckpt.truncate(blocking=False)
-    if ha_snapshot is not None:
-        # the flush landed: the captured (now-retired) epoch may stream
-        # to the standbys off the flush path (depth-1 drop-oldest)
-        groups, flush_epoch = ha_snapshot
-        sby.capture(groups, flush_epoch)
+    with obs.maybe_stage("epoch_handoff", scope=True):
+        ckpt = getattr(server, "checkpointer", None)
+        if ckpt is not None:
+            ckpt.truncate(blocking=False)
+        if ha_snapshot is not None:
+            # the flush landed: the captured (now-retired) epoch may
+            # stream to the standbys off the flush path (depth-1
+            # drop-oldest)
+            groups, flush_epoch = ha_snapshot
+            sby.capture(groups, flush_epoch)
     # the canonical self-metric set (README.md:248-277) rides on the
     # flush span and re-enters the pipeline through the extraction sink
-    span.add(
-        ssf_samples.timing("veneur.flush.total_duration_ns", flush_elapsed,
-                           {"part": "store"}),
-        ssf_samples.count("veneur.flush.post_metrics_total",
-                          float(len(final_metrics)), None),
-        ssf_samples.count(
-            "veneur.flush.span_flush_skipped_total",
-            float(_delta_since(server, "_last_span_flush_skipped",
-                               getattr(server, "_span_flush_skipped", 0))),
-            None),
-        ssf_samples.gauge("veneur.flush.age_seconds",
-                          server.flush_age_seconds()
-                          if hasattr(server, "flush_age_seconds")
-                          else 0.0, None),
-        ssf_samples.count(
-            "veneur.flush.overrun_total",
-            float(_delta_since(server, "_last_flush_overruns",
-                               getattr(server, "flush_overruns", 0))),
-            None),
-        *_worker_samples(server, ms),
-        *_overload_samples(server, ms),
-        *_fleet_samples(server),
-        *_handoff_samples(server),
-        *_ha_samples(server),
-        *_forward_samples(server),
-        *_import_samples(server),
-        *_checkpoint_samples(server),
-        *_trace_client_samples(server),
-        *_runtime_samples())
+    with obs.maybe_stage("self_metrics", scope=True):
+        span.add(
+            ssf_samples.timing("veneur.flush.total_duration_ns",
+                               flush_elapsed, {"part": "store"}),
+            ssf_samples.count("veneur.flush.post_metrics_total",
+                              float(len(final_metrics)), None),
+            ssf_samples.count(
+                "veneur.flush.span_flush_skipped_total",
+                float(_delta_since(
+                    server, "_last_span_flush_skipped",
+                    getattr(server, "_span_flush_skipped", 0))),
+                None),
+            ssf_samples.gauge("veneur.flush.age_seconds",
+                              server.flush_age_seconds()
+                              if hasattr(server, "flush_age_seconds")
+                              else 0.0, None),
+            ssf_samples.count(
+                "veneur.flush.overrun_total",
+                float(_delta_since(server, "_last_flush_overruns",
+                                   getattr(server, "flush_overruns", 0))),
+                None),
+            *_worker_samples(server, ms),
+            *_overload_samples(server, ms),
+            *_fleet_samples(server),
+            *_handoff_samples(server),
+            *_ha_samples(server),
+            *_forward_samples(server),
+            *_import_samples(server),
+            *_checkpoint_samples(server),
+            *_trace_client_samples(server),
+            *_runtime_samples())
 
     # local → global forwarding happens off the flush path
     # (flusher.go:66-75); the flush span rides along so the global's
@@ -639,70 +644,74 @@ def _flush_once(server: "Server", span, rec=None):
             span_flusher.join(timeout=10.0)
         return
 
-    # one thread per metric sink (flusher.go:82-93). post_t0 starts
-    # BEFORE the stream barrier so the ``post`` stage covers the
-    # streamed chunks' tail as well as the batch fan-out; by the time
-    # the overrun check runs every chunk is acked or requeued.
+    # one thread per metric sink (flusher.go:82-93). ``post`` starts
+    # BEFORE the stream barrier so it covers the streamed chunks' tail
+    # as well as the batch fan-out; by the time the overrun check runs
+    # every chunk is acked or requeued. The flusher's waits here are
+    # its leaves (``post.stream_wait``, and ``post.sinks_wait`` for
+    # each sink thread's start and for their join); each sink's thread
+    # opens ``post.<sink>`` on its own stage stack, so the sink's
+    # ``marshal`` and ``send`` nest under it.
     t0 = time.perf_counter()
-    post_t0 = time.monotonic_ns()
-    if stream is not None:
-        stream.close()
-    threads = []
-    sink_elapsed: dict = {}
+    with obs.maybe_stage("post", sinks=len(server.metric_sinks)):
+        if stream is not None:
+            with obs.maybe_stage("stream_wait"):
+                stream.close()
+        threads = []
+        sink_elapsed: dict = {}
 
-    def timed(fn, sink, arg):
-        def run():
-            ts = time.perf_counter()
-            ts_ns = time.monotonic_ns()
-            try:
-                fn(sink, arg)
-            finally:
-                sink_elapsed[sink.name] = time.perf_counter() - ts
-                if rec is not None:
-                    # sink threads are outside the flusher's stage
-                    # stack: absolute path, nested under "post"
-                    rec.record_abs(f"post.{sink.name}", ts_ns,
-                                   time.monotonic_ns())
-        return run
+        def timed(fn, sink, arg):
+            def run():
+                ts = time.perf_counter()
+                try:
+                    with obs.activate(rec), \
+                            obs.maybe_stage(f"post.{sink.name}"):
+                        fn(sink, arg)
+                finally:
+                    sink_elapsed[sink.name] = time.perf_counter() - ts
+            return run
 
-    for sink in server.metric_sinks:
-        # the interval's shared egress budget, read by each sink's retry
-        # loop (set before the thread starts; sinks only read it)
-        if hasattr(sink, "set_flush_deadline"):
-            sink.set_flush_deadline(deadline)
-        if sink in stream_sinks:
-            # the emission blocks already streamed out chunk by chunk;
-            # only the extras (status checks, routed rows, per-row
-            # fallbacks) remain for this sink
-            t = threading.Thread(
-                target=timed(_flush_sink, sink,
-                             list(final_metrics.extras)),
-                daemon=True)
-        elif use_columnar and hasattr(sink, "flush_columnar"):
-            t = threading.Thread(
-                target=timed(_flush_sink_columnar, sink, final_metrics),
-                daemon=True)
-        else:
-            metrics = (final_metrics.to_intermetrics() if use_columnar
-                       else final_metrics)
-            t = threading.Thread(target=timed(_flush_sink, sink, metrics),
-                                 daemon=True)
-        t.start()
-        threads.append(t)
-    for t in threads:
-        t.join(timeout=30.0)
-    if rec is not None:
-        # the sink fan-out's wall-clock (its per-sink children recorded
-        # from their own threads above)
-        rec.record_abs("post", post_t0, time.monotonic_ns(),
-                       sinks=len(threads))
-    _check_flush_overrun(server, deadline, budget, sink_elapsed)
-    # total time across the parallel sink POSTs (README.md:264), plus
-    # the per-sink breakdown and each sink's errors/marshal/post parts
-    span.add(ssf_samples.timing("veneur.flush.total_duration_ns",
-                                time.perf_counter() - t0,
-                                {"part": "post"}))
-    span.add(*_sink_samples(server, sink_elapsed))
+        for sink in server.metric_sinks:
+            # the interval's shared egress budget, read by each sink's retry
+            # loop (set before the thread starts; sinks only read it)
+            if hasattr(sink, "set_flush_deadline"):
+                sink.set_flush_deadline(deadline)
+            if sink in stream_sinks:
+                # the emission blocks already streamed out chunk by chunk;
+                # only the extras (status checks, routed rows, per-row
+                # fallbacks) remain for this sink
+                t = threading.Thread(
+                    target=timed(_flush_sink, sink,
+                                 list(final_metrics.extras)),
+                    daemon=True)
+            elif use_columnar and hasattr(sink, "flush_columnar"):
+                t = threading.Thread(
+                    target=timed(_flush_sink_columnar, sink, final_metrics),
+                    daemon=True)
+            else:
+                metrics = final_metrics
+                if use_columnar:
+                    with obs.maybe_stage("materialize", scope=True):
+                        metrics = final_metrics.to_intermetrics()
+                t = threading.Thread(target=timed(_flush_sink, sink, metrics),
+                                     daemon=True)
+            # a thread's start waits for it to run, which under a busy GIL
+            # (the merger, the import workers) is a wait of its own
+            with obs.maybe_stage("sinks_wait"):
+                t.start()
+            threads.append(t)
+        with obs.maybe_stage("sinks_wait"):
+            for t in threads:
+                t.join(timeout=30.0)
+    with obs.maybe_stage("sink_metrics", scope=True):
+        _check_flush_overrun(server, deadline, budget, sink_elapsed)
+        # total time across the parallel sink POSTs (README.md:264),
+        # plus the per-sink breakdown and each sink's
+        # errors/marshal/post parts
+        span.add(ssf_samples.timing("veneur.flush.total_duration_ns",
+                                    time.perf_counter() - t0,
+                                    {"part": "post"}))
+        span.add(*_sink_samples(server, sink_elapsed))
 
     # plugins run after the sinks (flusher.go:95-109)
     with obs.maybe_stage("plugins"):
@@ -718,6 +727,10 @@ def _flush_once(server: "Server", span, rec=None):
 
     with obs.maybe_stage("span_join"):
         span_flusher.join(timeout=10.0)
+    # what the interval emitted (the columnar blocks, the extras' rows
+    # as Python objects), freed here and not unseen in the return
+    with obs.maybe_stage("release", scope=True):
+        del final_metrics
 
 
 def _build_stream(server, now, deadline, rec, use_columnar, forwarding,
@@ -1293,40 +1306,22 @@ def _sink_samples(server, sink_elapsed: dict):
                 "veneur.breaker.state", breaker.state_gauge(),
                 {"destination": breaker.name or name, "sink": name}))
         if hasattr(sink, "drain_flush_telemetry"):
-            from veneur_tpu import obs
-
-            rec = obs.current()
+            # the batch fan-out's and the streamed chunks' parts alike
+            # (their stages are post.<sink>.marshal / .send and
+            # post.<sink>.serialize / .post)
             for kind, value in sink.drain_flush_telemetry():
-                if kind == "marshal_s":
+                if kind in ("marshal_s", "chunk_marshal_s"):
                     out.append(ssf_samples.timing(
                         "veneur.flush.duration_ns", value,
                         {"sink": name, "part": "marshal"}))
-                    if rec is not None:
-                        rec.amend(f"post.{name}",
-                                  serialize_ns=int(value * 1e9))
-                elif kind == "post_s":
+                elif kind in ("post_s", "chunk_post_s"):
                     out.append(ssf_samples.timing(
                         "veneur.flush.duration_ns", value,
                         {"sink": name, "part": "post"}))
-                    if rec is not None:
-                        rec.amend(f"post.{name}",
-                                  post_ns=int(value * 1e9))
-                elif kind in ("chunk_marshal_s", "chunk_post_s"):
-                    # streamed chunks: same part-tagged self-metric, but
-                    # no stage amend — the chunk's own
-                    # post.<sink>.serialize/.post stages already carry
-                    # the timeline lanes (obs/timeline.py)
-                    out.append(ssf_samples.timing(
-                        "veneur.flush.duration_ns", value,
-                        {"sink": name,
-                         "part": "marshal" if kind == "chunk_marshal_s"
-                         else "post"}))
                 elif kind == "content_length_bytes":
                     out.append(ssf_samples.histogram(
                         "veneur.flush.content_length_bytes", float(value),
                         {"sink": name}))
-                    if rec is not None:
-                        rec.amend(f"post.{name}", bytes=int(value))
     return out
 
 

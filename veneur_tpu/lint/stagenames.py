@@ -10,7 +10,8 @@ traced route ships an undocumented contract — so this pass walks the
 package for:
 
 - every **stage string literal** passed to the StageRecorder surface
-  (``stage`` / ``maybe_stage`` / ``record_abs`` / ``record_late``) and
+  (``stage`` / ``maybe_stage`` / ``record_abs`` / ``record_late`` /
+  ``record_child``) and
   to ``sample_self_timing`` (the self-telemetry stage vocabulary).
   F-string holes normalize to ``<hole>`` and match any documented
   ``<...>`` placeholder (``f"post.{sink.name}"`` ↔ ``post.<sink>``).
@@ -36,7 +37,7 @@ from typing import List, Optional
 from veneur_tpu.lint.framework import Finding, Project, dotted, register
 
 _STAGE_FNS = ("stage", "maybe_stage", "record_abs", "record_late",
-              "sample_self_timing")
+              "record_child", "sample_self_timing")
 _TRACECTX_FILE = "veneur_tpu/obs/tracectx.py"
 _DOCS_FILE = "docs/observability.md"
 

@@ -14,6 +14,24 @@ stack (``threading.local``): ``stage("fetch")`` entered while
 call tree (sink POST threads, the off-path forward) record absolute
 paths with :meth:`StageRecorder.record_abs`.
 
+Every event carries the thread that recorded it, and that is what the
+interval's one rule for unnamed time reads (:func:`unstaged_ns`): of
+the recorder owner's (the flusher's) on-path stages, those with no
+child stage on the same thread are its *leaves*, and what of the
+interval's wall the leaves' union leaves out is ``unstaged_ns``. A
+stage another thread recorded never covers the flusher's time: the
+flusher's wait for that thread is a leaf of its own, which says what
+it waits for.
+
+Two clocks meet here. Stages are stamped with ``time.monotonic_ns``;
+the profiler's host scopes (``TraceAnnotation``, the ``veneur.*``
+scopes of a ``/debug/xprof`` capture) with the wall clock,
+``time.time_ns`` (CLOCK_REALTIME, which TraceMe reads on Linux). The
+recorder reads the two back to back at its start, and every entry
+publishes the pair's wall half as ``wall_start_ns``: a stage lies on a
+capture at ``wall_start_ns + start_ns``. ``stage(..., scope=True)``
+opens the stage's host scope, named ``veneur.<stage path>``, with it.
+
 The flusher parks the interval's recorder in a thread-local slot
 (:func:`activate`) so deep call sites — the store's generation swap,
 each digest group's compute/fetch, the breaker ladder's rung choice —
@@ -27,12 +45,13 @@ from __future__ import annotations
 import collections
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
 
 _NS = 1_000_000_000
 
 _tls = threading.local()
+_NO_STAGE = nullcontext()
 
 
 def current() -> Optional["StageRecorder"]:
@@ -52,17 +71,25 @@ def activate(rec: Optional["StageRecorder"]):
         _tls.recorder = prev
 
 
-@contextmanager
-def maybe_stage(name: str, **attrs):
+def maybe_stage(name: str, scope: bool = False, **attrs):
     """``rec.stage(name)`` against the current recorder, or a no-op
     when observability is off — the one-line hook for deep call
-    sites."""
+    sites. ``scope=True`` opens the stage's host scope with it (a
+    *work* leaf: what the host does there; a *wait* leaf opens none)."""
     rec = current()
     if rec is None:
-        yield None
-        return
-    with rec.stage(name, **attrs) as frame:
-        yield frame
+        return _NO_STAGE
+    return _Stage(rec, name, scope, attrs)
+
+
+def record_child(name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+    """A stretch already clocked by the caller, recorded as a child of
+    the innermost open stage of the current recorder (a lock's wait,
+    clocked around a ``with lock:`` that a stage cannot split); no-op
+    without one."""
+    rec = current()
+    if rec is not None:
+        rec.record_child(name, t0_ns, t1_ns, **attrs)
 
 
 def note(**attrs) -> None:
@@ -83,17 +110,63 @@ class _Frame:
         self.attrs = attrs
 
 
+class _Stage:
+    """One stage's ``with``. Its two clock reads are the first and the
+    last thing it does, so what the recording costs (the stack, the
+    host scope, the event) lies inside the stage and never between two
+    leaves, where it would read as unnamed time."""
+
+    __slots__ = ("_rec", "_name", "_scope", "_attrs", "_t0", "_frame",
+                 "_annotation")
+
+    def __init__(self, rec: "StageRecorder", name: str, scope: bool,
+                 attrs: dict):
+        self._rec = rec
+        self._name = name
+        self._scope = scope
+        self._attrs = attrs
+        self._annotation = None
+
+    def __enter__(self) -> _Frame:
+        rec = self._rec
+        self._t0 = rec._clock()
+        stack = rec._stack()
+        path = stack[-1].path + "." + self._name if stack else self._name
+        self._frame = frame = _Frame(self._name, path, self._attrs)
+        stack.append(frame)
+        if self._scope:
+            from veneur_tpu.obs.kernels import host_scope
+
+            self._annotation = host_scope(path)
+            self._annotation.__enter__()
+        return frame
+
+    def __exit__(self, *exc) -> None:
+        rec, frame = self._rec, self._frame
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        rec._stack().pop()
+        thread = threading.current_thread()
+        rec._events.append((frame.path, self._t0, rec._clock(),
+                            frame.attrs, thread.ident, thread.name))
+
+
 class StageRecorder:
     """Begin/end stage tracer for ONE flush interval."""
 
     def __init__(self, clock_ns=time.monotonic_ns):
         self._clock = clock_ns
-        # (path, t0_ns, t1_ns, attrs) — append is GIL-atomic
+        # (path, t0_ns, t1_ns, attrs, thread ident, thread name) —
+        # append is GIL-atomic
         self._events: "collections.deque" = collections.deque()
-        self._amends: "collections.deque" = collections.deque()
         self._stacks = threading.local()
+        # the thread whose time the interval accounts (the flusher's)
+        self._owner = threading.current_thread()
+        # the anchor pair, back to back: the stages' clock and the
+        # profiler's (module docstring)
         self.t0_ns = clock_ns()
-        self.wall_start = time.time()
+        self.wall_start_ns = time.time_ns()
+        self.wall_start = self.wall_start_ns / _NS
         self.entry: Optional[dict] = None  # set by finish()
         # fleet trace plane (obs/tracectx.py): the distributed-trace
         # identity this interval's stage tree publishes under. Zero =
@@ -126,36 +199,34 @@ class StageRecorder:
             st = self._stacks.stack = []
         return st
 
-    @contextmanager
-    def stage(self, name: str, **attrs):
-        """Record one nested stage around the with-body."""
-        stack = self._stack()
-        path = stack[-1].path + "." + name if stack else name
-        frame = _Frame(name, path, attrs)
-        stack.append(frame)
-        t0 = self._clock()
-        try:
-            yield frame
-        finally:
-            t1 = self._clock()
-            stack.pop()
-            self._events.append((path, t0, t1, frame.attrs))
+    def stage(self, name: str, scope: bool = False, **attrs) -> _Stage:
+        """Record one nested stage around the with-body; ``scope=True``
+        also opens its host scope (``veneur.<path>``) inside it."""
+        return _Stage(self, name, scope, attrs)
+
+    def _append(self, path: str, t0: int, t1: int, attrs: dict) -> None:
+        thread = threading.current_thread()
+        self._events.append((path, t0, t1, attrs, thread.ident,
+                             thread.name))
 
     def note(self, **attrs) -> None:
         stack = self._stack()
         if stack:
             stack[-1].attrs.update(attrs)
 
+    def record_child(self, name: str, t0_ns: int, t1_ns: int,
+                     **attrs) -> None:
+        """Record a stretch the caller clocked as a child of this
+        thread's innermost open stage (at the root without one)."""
+        stack = self._stack()
+        self._append(stack[-1].path + "." + name if stack else name,
+                     t0_ns, t1_ns, attrs)
+
     def record_abs(self, path: str, t0_ns: int, t1_ns: int,
                    **attrs) -> None:
         """Record a stage at an absolute dotted path — for threads
         outside the flusher's stage stack (per-sink POSTs)."""
-        self._events.append((path, t0_ns, t1_ns, attrs))
-
-    def amend(self, path: str, **attrs) -> None:
-        """Merge attrs into an already-recorded stage at finish time
-        (sink telemetry drains after the POST threads joined)."""
-        self._amends.append((path, attrs))
+        self._append(path, t0_ns, t1_ns, attrs)
 
     def record_late(self, path: str, t0_ns: int, t1_ns: int,
                     **attrs) -> None:
@@ -167,14 +238,14 @@ class StageRecorder:
             # finish() has not run yet (a fast forward): land in the
             # normal event stream, keeping the off-path marker so
             # coverage accounting excludes it either way
-            attrs = dict(attrs, off_path=True)
-            self._events.append((path, t0_ns, t1_ns, attrs))
+            self._append(path, t0_ns, t1_ns, dict(attrs, off_path=True))
             return
         stage = dict(attrs)
         stage["name"] = path
         stage["start_ns"] = max(0, t0_ns - self.t0_ns)
         stage["duration_ns"] = max(0, t1_ns - t0_ns)
         stage["off_path"] = True
+        stage.setdefault("thread", threading.current_thread().name)
         entry["stages"].append(stage)
         entry["tree"].append(dict(stage, children=[]))
 
@@ -182,41 +253,45 @@ class StageRecorder:
 
     def finish(self, total_ns: Optional[int] = None) -> dict:
         """Merge the recorded events into the interval record: a flat
-        ``stages`` list plus a nested ``tree``, both ordered by start.
-        ``coverage_ratio`` is the fraction of ``total_duration_ns``
-        accounted for by top-level stages (off-path stages like the
-        forward are excluded from both sides)."""
+        ``stages`` list plus a nested ``tree``, both ordered by start,
+        each stage with the name of the ``thread`` that recorded it.
+        ``unstaged_ns`` is what of ``total_duration_ns`` the owner's
+        leaves leave out (:func:`unstaged_ns`), and ``coverage_ratio``
+        is ``1 - unstaged_ns / total_duration_ns``; off-path stages
+        (the forward, the cumulative ingest and import sums) are in
+        neither."""
         end_ns = self._clock()
         if total_ns is None:
             total_ns = end_ns - self.t0_ns
-        amends: Dict[str, dict] = {}
-        # drain both deques destructively: a late sink/forward thread
-        # may still be appending while this merge runs (deque ops are
-        # GIL-atomic; iterating a mutating deque raises) — anything
-        # appended after this drain is swept up by the straggler pass
-        # below once ``self.entry`` is published
-        events = _drain(self._events)
-        for path, attrs in _drain(self._amends):
-            amends.setdefault(path, {}).update(attrs)
+        # drain destructively: a late sink/forward thread may still be
+        # appending while this merge runs (deque ops are GIL-atomic;
+        # iterating a mutating deque raises) — anything appended after
+        # this drain is swept up by the straggler pass below once
+        # ``self.entry`` is published
         stages: List[dict] = []
-        for path, t0, t1, attrs in events:
+        owned: List[tuple] = []
+        for path, t0, t1, attrs, ident, thread in _drain(self._events):
             stage = dict(attrs)
             stage["name"] = path
             stage["start_ns"] = max(0, t0 - self.t0_ns)
             stage["duration_ns"] = max(0, t1 - t0)
-            extra = amends.pop(path, None)
-            if extra:
-                stage.update(extra)
+            stage["thread"] = thread
             stages.append(stage)
+            if ident == self._owner.ident and not stage.get("off_path"):
+                owned.append((stage["start_ns"],
+                              stage["start_ns"] + stage["duration_ns"],
+                              path))
         stages.sort(key=lambda s: (s["start_ns"], s["name"]))
-        top_ns = sum(s["duration_ns"] for s in stages
-                     if "." not in s["name"] and not s.get("off_path"))
+        unstaged = unstaged_ns(owned, int(total_ns))
         entry = {
             "wall_start": self.wall_start,
+            "wall_start_ns": self.wall_start_ns,
             "wall_end": self.wall_start + (end_ns - self.t0_ns) / _NS,
             "total_duration_ns": int(total_ns),
-            "coverage_ratio": round(top_ns / total_ns, 4)
+            "unstaged_ns": unstaged,
+            "coverage_ratio": round(1 - unstaged / total_ns, 4)
             if total_ns else 0.0,
+            "thread": self._owner.name,
             "stages": stages,
             "tree": _build_tree(stages),
         }
@@ -230,9 +305,32 @@ class StageRecorder:
         # the entry publication (record_late saw entry None and fell
         # back to the stream) land in the published entry after all —
         # nothing recorded is ever silently lost
-        for path, t0, t1, attrs in _drain(self._events):
-            self.record_late(path, t0, t1, **attrs)
+        for path, t0, t1, attrs, _ident, thread in _drain(self._events):
+            self.record_late(path, t0, t1, **dict(attrs, thread=thread))
         return entry
+
+
+def unstaged_ns(owned: List[tuple], total_ns: int) -> int:
+    """The interval's one rule for unnamed time. ``owned``: the
+    ``(start_ns, end_ns, path)`` of the owner thread's on-path stages.
+    Its *leaves* are those with no child stage (a longer dotted path
+    under it, inside its span) among them; the result is
+    ``total_ns`` less the length of the leaves' union within
+    ``[0, total_ns]``. So a wrapper covers nothing of its own, and a
+    parent's time outside its children counts as unstaged."""
+    leaves = []
+    for start, end, path in owned:
+        prefix = path + "."
+        if not any(s >= start and e <= end and p.startswith(prefix)
+                   for s, e, p in owned):
+            leaves.append((max(0, start), min(end, total_ns)))
+    covered, edge = 0, 0
+    for start, end in sorted(leaves):
+        start = max(start, edge)
+        if end > start:
+            covered += end - start
+            edge = end
+    return max(0, total_ns - covered)
 
 
 def _drain(dq: "collections.deque") -> list:

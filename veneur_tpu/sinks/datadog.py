@@ -29,6 +29,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from veneur_tpu.forward.http_forward import post_helper
+from veneur_tpu.obs import recorder as obs_rec
 from veneur_tpu.protocol import constants as dogstatsd
 from veneur_tpu.protocol import wire
 from veneur_tpu.resilience import RetryPolicy, post_with_retry
@@ -179,20 +180,23 @@ class DatadogMetricSink(MetricSink):
         bodies: List[bytes] = []
         n_metrics = 0
         t_marshal = time.perf_counter()
-        for blk in batch.blocks:
-            with self._serialize_block(blk, batch.timestamp) as stream:
-                bodies.extend(body for body, _ready_ns in stream)
-            n_metrics += len(blk)
+        with obs_rec.maybe_stage("marshal", scope=True):
+            for blk in batch.blocks:
+                with self._serialize_block(blk, batch.timestamp) as stream:
+                    bodies.extend(body for body, _ready_ns in stream)
+                n_metrics += len(blk)
         t_marshal = time.perf_counter() - t_marshal
         threads = []
         t_post = time.perf_counter()
-        for body in bodies:
-            t = threading.Thread(target=self._flush_body, args=(body,),
-                                 daemon=True)
-            t.start()
-            threads.append(t)
-        for t in threads:
-            t.join()
+        with obs_rec.maybe_stage("send", scope=True,
+                                 bytes=sum(len(b) for b in bodies)):
+            for body in bodies:
+                t = threading.Thread(target=self._flush_body, args=(body,),
+                                     daemon=True)
+                t.start()
+                threads.append(t)
+            for t in threads:
+                t.join()
         t_post = time.perf_counter() - t_post
         with self._err_lock:
             self._telemetry.append(("marshal_s", t_marshal))
@@ -222,7 +226,6 @@ class DatadogMetricSink(MetricSink):
         budget the OLDEST parked bodies drop counted
         (``chunk_rows_dropped``), so memory stays bounded and a long
         outage degrades by counted drop."""
-        from veneur_tpu import obs
         from veneur_tpu.obs.kernels import host_scope
 
         # normally a no-op: the stream worker already reposted for this
@@ -232,9 +235,9 @@ class DatadogMetricSink(MetricSink):
         # driven intervals (hand-built test chunks carry cycle 0 and
         # fall back to it)
         self.repost_requeued(getattr(chunk, "cycle", 0) or chunk.timestamp)
-        rec = obs.current()
+        rec = obs_rec.current()
         serialize = f"post.{self.name}.serialize"
-        post = f"post.{self.name}.post"
+        wire = f"post.{self.name}.post.wire"
         t0_ns = time.monotonic_ns()
         # where the native serializer's wall went (encoding JSON, in
         # deflate), the same summed over its workers, the bodies it made:
@@ -244,19 +247,24 @@ class DatadogMetricSink(MetricSink):
             ("encode_ns", "deflate_ns", "encode_cpu_ns", "deflate_cpu_ns",
              "bodies", "workers"), 0)
         made_ns = t0_ns     # when the chunk's last body was made
+        first_ns = 0        # the blocks' native calls to their first body
         posts = []          # each body's POST: (start ns, return ns)
         sizes = []
         for blk in chunk.blocks:
+            b0 = time.monotonic_ns()
             with host_scope(serialize):
                 stream = self._serialize_block(blk, chunk.timestamp,
                                                native_ns)
             with stream:
-                for nrows in _body_rows(len(blk), self.flush_max_per_body):
+                for i, nrows in enumerate(
+                        _body_rows(len(blk), self.flush_max_per_body)):
                     with host_scope(serialize):
                         body, ready_ns = next(stream)
+                    if i == 0:
+                        first_ns += ready_ns - b0
                     made_ns = max(made_ns, ready_ns)
                     p0 = time.monotonic_ns()
-                    with host_scope(post):
+                    with host_scope(wire):
                         self._post_chunk_body(body, nrows)
                     posts.append((p0, time.monotonic_ns()))
                     sizes.append(len(body))
@@ -272,21 +280,26 @@ class DatadogMetricSink(MetricSink):
                 rec.record_abs(f"post.{self.name}.serialize.{part}", t0_ns,
                                t0_ns + native_ns[part + "_ns"],
                                chunk=chunk.seq)
+            # the stream worker's waits for the workers' first wave,
+            # summed over the blocks like the two parts above
+            rec.record_abs(f"post.{self.name}.serialize.first_body", t0_ns,
+                           t0_ns + first_ns, chunk=chunk.seq)
             # the POSTs overlap the serializer: `post` spans the first
             # POST's start to the last one's return, its `tail` what
-            # the serializer did not hide (from the last body made)
+            # the serializer did not hide (from the last body made),
+            # its `wire` the POSTs' own time, summed
             rec.record_abs(f"post.{self.name}.post", post_t0, post_t1,
                            chunk=chunk.seq, rows=chunk.rows,
                            bytes=sum(sizes), bodies_posted_early=sum(
                                1 for _p0, p1 in posts if p1 < made_ns))
             rec.record_abs(f"post.{self.name}.post.tail",
                            max(made_ns, post_t0), post_t1, chunk=chunk.seq)
+            rec.record_abs(wire, post_t0, post_t0 + sum(
+                p1 - p0 for p0, p1 in posts), chunk=chunk.seq,
+                posts=len(posts))
         with self._err_lock:
-            # chunk_* kinds: same part-tagged duration self-metrics as
-            # the batch path, but NOT amended onto the post.<sink>
-            # stage — the chunk's own post.<sink>.serialize/.post
-            # stages already carry the lanes, and an amend on top
-            # would double-bill annotate_overlap
+            # chunk_* kinds: the same part-tagged duration self-metrics
+            # as the batch path (veneur.flush.duration_ns)
             self._telemetry.append(("chunk_marshal_s",
                                     (made_ns - t0_ns) / 1e9))
             self._telemetry.append(("chunk_post_s",
@@ -428,44 +441,50 @@ class DatadogMetricSink(MetricSink):
 
     def flush(self, metrics: List[InterMetric]) -> None:
         t_marshal = time.perf_counter()
-        dd_metrics, checks = self.finalize_metrics(metrics)
+        with obs_rec.maybe_stage("marshal", scope=True):
+            dd_metrics, checks = self.finalize_metrics(metrics)
         t_marshal = time.perf_counter() - t_marshal
-        if checks:
-            # check_run takes an array but not deflate (datadog.go:113-116)
-            try:
-                status = self._resilient_post(lambda: self.post(
-                    f"{self.dd_hostname}/api/v1/check_run"
-                    f"?api_key={self.api_key}", checks, compress=False))
-                if not _ok(status):
-                    log.warning("Datadog check_run returned HTTP %d", status)
+        with obs_rec.maybe_stage("send", scope=True):
+            if checks:
+                # check_run takes an array but not deflate
+                # (datadog.go:113-116)
+                try:
+                    status = self._resilient_post(lambda: self.post(
+                        f"{self.dd_hostname}/api/v1/check_run"
+                        f"?api_key={self.api_key}", checks,
+                        compress=False))
+                    if not _ok(status):
+                        log.warning("Datadog check_run returned HTTP %d",
+                                    status)
+                        self._count_error()
+                except OSError:
+                    log.warning("error flushing checks to Datadog",
+                                exc_info=True)
                     self._count_error()
-            except OSError:
-                log.warning("error flushing checks to Datadog", exc_info=True)
-                self._count_error()
-        if not dd_metrics:
-            return
-        # equal-size chunks under flush_max_per_body, rounding-up division
-        # (datadog.go:127-146)
-        workers = ((len(dd_metrics) - 1) // self.flush_max_per_body) + 1
-        chunk_size = ((len(dd_metrics) - 1) // workers) + 1
-        threads = []
-        t_post = time.perf_counter()
-        for i in range(workers):
-            chunk = dd_metrics[i * chunk_size:(i + 1) * chunk_size]
-            t = threading.Thread(target=self._flush_part, args=(chunk,),
-                                 daemon=True)
-            t.start()
-            threads.append(t)
-        for t in threads:
-            t.join()
-        t_post = time.perf_counter() - t_post
-        # same part-tagged telemetry the columnar path records, so the
-        # documented veneur.flush.* set does not depend on which flush
-        # path a deployment runs
-        with self._err_lock:
-            self._telemetry.append(("marshal_s", t_marshal))
-            self._telemetry.append(("post_s", t_post))
-        self.metrics_flushed += len(dd_metrics)
+            if not dd_metrics:
+                return
+            # equal-size chunks under flush_max_per_body, rounding-up
+            # division (datadog.go:127-146)
+            workers = ((len(dd_metrics) - 1) // self.flush_max_per_body) + 1
+            chunk_size = ((len(dd_metrics) - 1) // workers) + 1
+            threads = []
+            t_post = time.perf_counter()
+            for i in range(workers):
+                chunk = dd_metrics[i * chunk_size:(i + 1) * chunk_size]
+                t = threading.Thread(target=self._flush_part,
+                                     args=(chunk,), daemon=True)
+                t.start()
+                threads.append(t)
+            for t in threads:
+                t.join()
+            t_post = time.perf_counter() - t_post
+            # same part-tagged telemetry the columnar path records, so
+            # the documented veneur.flush.* set does not depend on which
+            # flush path a deployment runs
+            with self._err_lock:
+                self._telemetry.append(("marshal_s", t_marshal))
+                self._telemetry.append(("post_s", t_post))
+            self.metrics_flushed += len(dd_metrics)
 
     def _flush_part(self, chunk: List[dict]) -> None:
         info = {}
