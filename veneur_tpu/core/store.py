@@ -439,6 +439,8 @@ class ScalarGroup(OverloadLimited):
     "status" (gauge + message/hostname, samplers.go:307-313).
     """
 
+    _retired = False  # see DigestGroup._retired
+
     def __init__(self, kind: str, capacity: int = DEFAULT_INITIAL_CAPACITY):
         self.kind = kind
         self.interner = Interner()
@@ -547,7 +549,11 @@ class ScalarGroup(OverloadLimited):
         n = len(self.interner)
         interner, self.interner = self.interner, Interner()
         values = self.values[:n].copy()
-        self.values[:] = 0
+        if not self._retired:
+            # a retired group is never written again and goes with its
+            # generation: zeroing it would fault in every reserved page
+            # of an array about to be freed (32 MiB at 2^22 rows)
+            self.values[:] = 0
         messages = hostnames = None
         if self.messages is not None:
             messages, self.messages = self.messages, []
