@@ -499,6 +499,65 @@ def test_sample_ingest_drains_held_rows_behind_a_branch(one_chip,
     assert compiled.memory_analysis().alias_size_in_bytes >= 4 * rows * K * 4
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_slab_programs_at_the_cell_s_slab(one_chip, kernel_admitted, dtype):
+    """``standalone-slab10m``'s slab of 262,144 rows in its flat storage
+    planes, both storage dtypes, for one described chip. The sample
+    ingest is the dense store's: its row drain is a loop that holds the
+    kernel, inside the entry's one conditional, whose other branch makes
+    no plane. The flush is one loop over the live rows' kernel slabs
+    that widens a window of the planes, never a whole one, and writes
+    it back in place."""
+    import re
+
+    from veneur_tpu.core.slab import (_flush_slab, _ingest_slab,
+                                      _init_digest_slab)
+    from veneur_tpu.ops import tdigest as td
+
+    rows = 1 << 18
+    digest = _on(jax.eval_shape(lambda: _init_digest_slab(rows, K, dtype)),
+                 one_chip)
+    temp = _on(jax.eval_shape(lambda: td.init_temp(rows, K)), one_chip)
+    ingest = _ingest_slab.lower(
+        temp, digest, _i32((CHUNK,), one_chip), _f32((CHUNK,), one_chip),
+        _f32((CHUNK,), one_chip), _i32((), one_chip), _i32((), one_chip),
+        COMPRESSION, True).compile()
+    text = ingest.as_text()
+    comps = _computations(text)
+    entry = re.search(r"ENTRY %?([\w.\-]+)", text).group(1)
+    outer = [ln for ln in comps[entry] if " conditional(" in ln]
+    assert len(outer) == 1
+    drains = [ln for lines in comps.values() for ln in lines
+              if " while(" in ln and any(
+                  "tpu_custom_call" in x for c in _called(ln)
+                  for x in comps.get(c, []))]
+    assert drains and not set(drains) & set(comps[entry])
+    idle = [n for n in _called(outer[0])
+            if not any(d in comps[c] for d in drains
+                       for c in _reach(comps, [n]))]
+    assert len(idle) == 1
+    made = [ln for ln in comps[idle[0]]
+            if rows * K in _result_elements(ln)[0]
+            and _result_elements(ln)[1] not in ("parameter", "tuple",
+                                                "get-tuple-element")]
+    assert not made, made
+    storage = jnp.dtype(dtype).itemsize
+    assert ingest.memory_analysis().alias_size_in_bytes >= \
+        2 * rows * K * (storage + 4)
+    flush = _flush_slab.lower(
+        digest, temp, _f32((4,), one_chip), _i32((), one_chip), COMPRESSION,
+        False, True).compile()
+    flat = f"[{rows * K}]"
+    body = _flush_loop_body(flush.as_text(), [f"f32{flat}",
+                                              f"f32[{rows},{K}]"])
+    mem = flush.memory_analysis()
+    # the retired generation's flush: no fresh slab, the drained planes
+    # written in place of the donated ones
+    assert mem.alias_size_in_bytes >= 2 * rows * K * storage
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 2 * _nbytes((digest, temp))
+
+
 def _reach(comps, names):
     """The computations ``names`` call, themselves included."""
     seen, todo = set(), list(names)

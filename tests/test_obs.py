@@ -332,6 +332,52 @@ class TestServerTimeline:
             "live": sum(s["rows_live"] for s in compute.values()),
             "run": sum(s["rows_run"] for s in compute.values())}
 
+    def test_slab_flush_counts_and_leaves(self):
+        """A slab group's flush says what its sample path drained, what
+        its programs ran against what was live and the slabs it grew
+        (timeline ``slab``: what the benchmark's ``slab_*`` metrics
+        read), under the leaves a dense group's has; ``/debug/vars``
+        names its planes."""
+        from veneur_tpu.config import Config
+        from veneur_tpu.core.slab import SlabDigestGroup
+        from veneur_tpu.debug import device_section
+        from veneur_tpu.ops import tdigest as td_ops
+        from veneur_tpu.server import Server
+        from veneur_tpu.sinks import ChannelMetricSink
+
+        cfg = Config(statsd_listen_addresses=[], interval="86400s",
+                     percentiles=[0.5], obs_timeline_intervals=4,
+                     store_chunk=64, digest_storage="slab", slab_rows=4096)
+        sink = ChannelMetricSink()
+        srv = Server(cfg, metric_sinks=[sink])
+        srv.start()
+        try:
+            assert isinstance(srv.store.histograms, SlabDigestGroup)
+            # 5,000 rows over two slabs, a row's second sample in a
+            # later dispatch than its first
+            for rep in range(2):
+                for i in range(5000):
+                    srv.handle_metric_packet(b"slab.h%d:%d.5|h" % (i, rep))
+            planes = device_section(srv.store)["digest_planes"]
+            srv.flush()
+            sink.get_flush()
+            e = srv.obs_timeline.entries()[-1]
+        finally:
+            srv.shutdown()
+        assert planes["shape"] == [4096 * td_ops.size_bound(100.0)]
+        slab = e["slab"]
+        assert slab["rows_live"] == 5000 and slab["grows"] == 2
+        assert slab["rows_run"] == 4096 + td_ops.flush_rows_run(4096, 904)
+        assert slab["dispatches"] >= 2 * 5000 // 64
+        assert slab["rows_drained"] == 5000 and slab["drain_trips"] >= 1
+        names = {s["name"] for s in e["stages"]}
+        for leaf in ("store.dispatch.histograms.drain",
+                     "store.dispatch.histograms.compute",
+                     "store.histograms.fetch.wait",
+                     "store.histograms.fetch.copy",
+                     "store.histograms.commit"):
+            assert leaf in names, leaf
+
     def test_flush_timeline_endpoint_schema_and_bound(self, obs_server):
         srv, sink = obs_server
         for _ in range(6):  # ring holds 4 (obs_timeline_intervals)

@@ -137,7 +137,8 @@ class Config:
     # a deployment that knows its cardinality fixes it here). Digest
     # groups place their device state on first write; set and
     # heavy-hitter groups start at no more than 4096 rows whatever this
-    # says (a set row is 16 KiB of registers)
+    # says (a set row is 16 KiB of registers); a slab store's digest
+    # groups ignore it and grow a slab_rows slab at a time
     store_initial_capacity: int = 4096
     # histogram/timer digest backing store: "dense" (one [S,K] plane per
     # group, default), "slab" (flat per-slab planes, the multi-million-
@@ -168,12 +169,16 @@ class Config:
     # boundary (0 = default 3) — demotion-side hysteresis against dense
     # slot ping-ponging
     tier_demote_intervals: int = 0
-    # resident digest dtype for the slab store: "float32" or "bfloat16"
-    # (bf16 halves HBM — the 10M-series-per-chip plan; kernel math and
-    # counts stay f32, quantile storage rounding <= 2^-8 relative)
+    # resident digest dtype for the slab store: "float32" or "packed16"
+    # (16-bit planes, the wire's packed format: bfloat16 weights and each
+    # mean a uint16 code against its row's [min, max]; the 10M-series
+    # plan; kernel math, counts, minima and maxima stay f32, and sparse
+    # rows keep the rank bound, docs/tdigest_accuracy.md)
     digest_dtype: str = "float32"
     # rows per slab for the slab store (clamped to 1M by Mosaic's 2 GiB
-    # operand bound; smaller slabs bound flush transients tighter)
+    # operand bound; smaller slabs bound flush transients tighter). A
+    # 10M-series deployment sets 262144 (40 slabs) with max_series
+    # 16777216, the 0.7-occupancy freeze clear of 10M names
     slab_rows: int = 1 << 20
     # drain plain-IPv4 UDP statsd listeners with the C++ recvmmsg reader
     # pool + batch parser when the native library is available
@@ -434,13 +439,13 @@ class Config:
                 raise ValueError(
                     f"{knob} must be >= 0 (0 = use the default), "
                     f"got {getattr(self, knob)}")
-        if self.digest_dtype not in ("float32", "bfloat16"):
+        if self.digest_dtype not in ("float32", "packed16"):
             raise ValueError(
-                f"digest_dtype must be 'float32' or 'bfloat16', got "
+                f"digest_dtype must be 'float32' or 'packed16', got "
                 f"{self.digest_dtype!r}")
-        if self.digest_dtype == "bfloat16" and self.digest_storage != "slab":
+        if self.digest_dtype == "packed16" and self.digest_storage != "slab":
             raise ValueError(
-                "digest_dtype: bfloat16 requires digest_storage: slab "
+                "digest_dtype: packed16 requires digest_storage: slab "
                 "(the dense store is f32-only)")
         if self.slab_rows <= 0:
             raise ValueError(f"slab_rows must be positive, got "

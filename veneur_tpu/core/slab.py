@@ -13,33 +13,41 @@ This bank re-plans the capacity:
 
   * state lives in **flat 1-D planes** per slab (``[slab*K]``), which tile
     without lane padding — resident bytes == logical bytes;
-  * the digest planes can be stored **bfloat16** (``digest_dtype``): all
-    kernel math stays f32 (upcast per slab), only storage is rounded.
-    Weight rounding perturbs quantile positions by <= 2^-8 relative — far
-    inside the t-digest error envelope (eps=.02, histo_test.go:11-25) —
-    and exact counts ride the separate f32 scalar stats, so nothing the
-    flusher emits as a counter is ever rounded;
+  * the digest planes can be held in 16 bits (``digest_dtype:
+    packed16``): bfloat16 weights, and each centroid's mean a uint16
+    code against its row's [min, max] frame (the wire's packed format,
+    ops/tdigest.py ``code_means``). All kernel math stays f32 (widened
+    at the rows a program works on), exact counts, minima and maxima
+    ride the separate f32 stats, so nothing the flusher emits as a
+    counter is ever rounded. A mean is coded, not rounded to bfloat16:
+    a row drained mid-interval keeps its later samples exact in the
+    f32 bins, and an 8-bit mean crossed them (0.5 by rank in the 2-15
+    band); a code is 1/65,535 of the row's span, and sparse rows keep
+    the rank bound (docs/tdigest_accuracy.md);
   * every device program touches ONE slab (<= 1M rows): peak transient
     memory is slab-sized, and each Pallas operand stays under Mosaic's
     2 GiB (32-bit byte offset) limit.
 
-Capacity plan this buys on one 16 GB v5e-1 (K=104; resident figures
-include the round-5 anchor-summary planes, 64 B/row in local mode):
+Bytes of one slab of 262,144 rows, K=104 (``hbm_bytes``, from the
+shapes: a local slab is the digest, five f32 planes a row (the means'
+frames, the imported extrema, the count), the interval's bins, the
+8-anchor summary and five stats; a merge slab the digest alone):
 
-  | series | digest dtype | resident | role |
-  |--------|--------------|----------|------|
-  |  4M    | f32          |  7.0 GB  | local (samples -> temp -> drain) |
-  | 10M    | bf16         | 13.2 GB  | local, the north-star config     |
-  | 10M    | bf16, merge  |  4.3 GB  | global (imported digest merges)  |
+  | digest dtype | role  | one slab | 10M rows (39 slabs) |
+  |--------------|-------|----------|---------------------|
+  | f32          | local | 0.463 GB | 18.1 GB             |
+  | packed16     | local | 0.354 GB | 13.8 GB             |
+  | packed16     | merge | 0.114 GB |  4.5 GB             |
 
-The 10M local config uses 256k-row slabs: per-slab flush transients
-scale with slab rows, and the ~2.3 GB the resident planes leave free
-no longer fits 512k-row transients.
-
-The 10M f32 local config needs ~16.7 GB resident and therefore two chips
-(or DP sharding via the mesh store, core/mesh_store.py) — that is the
-stated path beyond 10M as well: the series axis is embarrassingly
-shardable, so N chips multiply every row in this table by N.
+So 10M live rows on one 16 GB v5e-1 are the packed16 local plan, or two
+chips (or the mesh store, core/mesh_store.py): the series axis is
+embarrassingly shardable, so N chips multiply every row in this table
+by N. What has run through ``Server`` on the chip (one v5e) is one slab
+a generation under ``standalone-slab10m.steady``'s some 100,000 live
+rows an interval: ``memory_peak_bytes`` read 1,041,818,624 at packed16
+with two slabs resident (the retired and the fresh generation's at the
+swap) and 1,235,260,928 at f32 (PERF.md section 4); the 39-slab
+figures are reckoned from the shapes, not run.
 
 Reference behavior re-expressed here: Worker.Flush + Histo.Flush
 (flusher.go:134-254, samplers/samplers.go:511-636) for the local role,
@@ -50,6 +58,7 @@ from __future__ import annotations
 
 import logging
 import math
+import time
 from functools import partial
 from typing import List, NamedTuple, Optional, Sequence
 
@@ -68,9 +77,27 @@ log = logging.getLogger("veneur.slab")
 
 SLAB_ROWS_DEFAULT = 1 << 20
 
+# ``digest_dtype`` as configured (config.py) -> the weight plane's dtype:
+# "packed16" is the wire's packed format held resident, bfloat16 weights
+# beside uint16 coded means (``DigestSlab``); a numpy dtype passes as is
+STORAGE_DTYPES = {"float32": jnp.float32, "packed16": jnp.bfloat16}
+
+
+def storage_dtype(digest_dtype) -> jnp.dtype:
+    return jnp.dtype(STORAGE_DTYPES.get(digest_dtype, digest_dtype))
+
 
 class DigestSlab(NamedTuple):
     """Resident state for one slab of series rows (flat planes).
+
+    The digest is float32, or, held ``bfloat16``, 16 bits a plane:
+    bfloat16 weights and each mean a uint16 code against its row's
+    frame ``[fmin, fmax]`` (ops/tdigest.py ``coded``; a bfloat16 mean
+    written by a mid-interval drain crossed the row's later samples).
+    fmin/fmax bound every live mean of the row in either dtype: the
+    shared ops take them as the digest's min/max. dmin/dmax are the
+    imported digests' extrema, which only bound the final digest (as
+    ``DigestGroup.dmin``/``dmax``).
 
     count is an EXACT f32 per-series total maintained alongside the
     (possibly bf16) centroid weights: merge-mode flushes report it
@@ -79,199 +106,125 @@ class DigestSlab(NamedTuple):
     imported batch's contribution. (Local mode reports temp.count, which
     is f32 already; there this plane just rides along.)"""
 
-    mean: jax.Array      # [slab*K] storage dtype; +inf = empty slot
-    weight: jax.Array    # [slab*K] storage dtype; 0 = empty slot
-    dmin: jax.Array      # [slab] f32 observed minima (+inf when empty)
-    dmax: jax.Array      # [slab] f32 observed maxima (-inf when empty)
+    mean: jax.Array      # [slab*K] f32 (+inf = empty slot) or u16 codes
+    weight: jax.Array    # [slab*K] f32 or bf16; 0 = empty slot
+    fmin: jax.Array      # [slab] f32 least live mean's bound (+inf empty)
+    fmax: jax.Array      # [slab] f32 greatest live mean's bound
+    dmin: jax.Array      # [slab] f32 imported minima (+inf when empty)
+    dmax: jax.Array      # [slab] f32 imported maxima (-inf when empty)
     count: jax.Array     # [slab] f32 exact total weight
 
 
-class TempSlab(NamedTuple):
-    """Interval accumulators for one slab (local role only), flat planes.
-    seg_w/seg_wm: the incremental anchor summary (ops/tdigest.py
-    TempCentroids.seg_*), flat [slab*A]."""
-
-    sum_w: jax.Array     # [slab*K] f32
-    sum_wm: jax.Array    # [slab*K] f32
-    seg_w: jax.Array     # [slab*A] f32
-    seg_wm: jax.Array    # [slab*A] f32
-    count: jax.Array     # [slab] f32
-    vsum: jax.Array      # [slab] f32
-    vmin: jax.Array      # [slab] f32
-    vmax: jax.Array      # [slab] f32
-    recip: jax.Array     # [slab] f32
-
-
 def _init_digest_slab(slab: int, k: int, dtype) -> DigestSlab:
+    dtype = jnp.dtype(dtype)
+    edge = lambda v: jnp.full((slab,), v, jnp.float32)  # noqa: E731
     return DigestSlab(
-        mean=jnp.full((slab * k,), jnp.inf, dtype),
+        mean=(jnp.zeros((slab * k,), jnp.uint16) if dtype == jnp.bfloat16
+              else jnp.full((slab * k,), jnp.inf, dtype)),
         weight=jnp.zeros((slab * k,), dtype),
-        dmin=jnp.full((slab,), jnp.inf, jnp.float32),
-        dmax=jnp.full((slab,), -jnp.inf, jnp.float32),
-        count=jnp.zeros((slab,), jnp.float32),
+        fmin=edge(jnp.inf), fmax=edge(-jnp.inf), dmin=edge(jnp.inf),
+        dmax=edge(-jnp.inf), count=jnp.zeros((slab,), jnp.float32),
     )
 
 
-def _init_temp_slab(slab: int, k: int) -> TempSlab:
-    a = td_ops.BELOW_MASS_ANCHORS
-    return TempSlab(
-        sum_w=jnp.zeros((slab * k,), jnp.float32),
-        sum_wm=jnp.zeros((slab * k,), jnp.float32),
-        seg_w=jnp.zeros((slab * a,), jnp.float32),
-        seg_wm=jnp.zeros((slab * a,), jnp.float32),
-        count=jnp.zeros((slab,), jnp.float32),
-        vsum=jnp.zeros((slab,), jnp.float32),
-        vmin=jnp.full((slab,), jnp.inf, jnp.float32),
-        vmax=jnp.full((slab,), -jnp.inf, jnp.float32),
-        recip=jnp.zeros((slab,), jnp.float32),
-    )
+def _as_digest(digest: DigestSlab) -> td_ops.TDigest:
+    """A slab's digest as the shared ops take it: its flat storage
+    planes and their frames (ops/tdigest.py reads the layout from the
+    planes)."""
+    return td_ops.TDigest(mean=digest.mean, weight=digest.weight,
+                          min=digest.fmin, max=digest.fmax)
 
 
-def _guard_drain_slab(temp: TempSlab, digest: DigestSlab, rows, values,
-                      weights, slab: int, compression: float,
-                      use_pallas: bool = True):
-    """The slab form of ops/tdigest.py's shift guard: when the chunk's
-    per-row value ranges are disjoint from what the accumulated bins
-    cover for enough chunk mass, drain the bins into the (storage-dtype)
-    digest planes first so the fresh bins re-anchor — a lax.cond, so
-    stationary traffic pays one cheap reduction, never the drain. Temp
-    scalar stats survive (interval aggregates; only the bins move)."""
-    k = temp.sum_w.shape[0] // slab
-    pred = td_ops.shift_pred(temp.seg_w, temp.seg_wm, rows, values,
-                             weights, slab)
-
-    def do_drain(args):
-        t, d = args
-        dt = d.mean.dtype
-        d32 = td_ops.TDigest(
-            mean=d.mean.reshape(slab, k).astype(jnp.float32),
-            weight=d.weight.reshape(slab, k).astype(jnp.float32),
-            min=d.dmin, max=d.dmax)
-        # the drain reads the flat bin planes and the scalar stats; the
-        # anchors (in the slab's row-major order) ride along unread
-        drained = td_ops.drain_temp(d32, td_ops.TempCentroids(*t),
-                                    compression, use_pallas=use_pallas)
-        d2 = DigestSlab(
-            mean=drained.mean.astype(dt).reshape(-1),
-            weight=drained.weight.astype(dt).reshape(-1),
-            dmin=drained.min, dmax=drained.max, count=d.count)
-        t2 = t._replace(sum_w=jnp.zeros_like(t.sum_w),
-                        sum_wm=jnp.zeros_like(t.sum_wm),
-                        seg_w=jnp.zeros_like(t.seg_w),
-                        seg_wm=jnp.zeros_like(t.seg_wm))
-        return t2, d2
-
-    return lax.cond(pred, do_drain, lambda a: a, (temp, digest))
+def _with_digest(digest: DigestSlab, d: td_ops.TDigest) -> DigestSlab:
+    return digest._replace(mean=d.mean, weight=d.weight, fmin=d.min,
+                           fmax=d.max)
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(5, 6, 7))
-def _ingest_slab(temp: TempSlab, digest: DigestSlab, rows, values, weights,
-                 slab: int, compression: float, use_pallas: bool = True):
-    """Scatter one flat sample chunk into a slab's flat accumulators,
-    with the shift guard (returns (temp, digest)).
-
-    rows: [N] LOCAL row ids; anything >= slab is padding / out-of-slab and
-    must scatter nowhere (flat index >= slab*K with mode='drop')."""
-    k = temp.sum_w.shape[0] // slab
-    oor = rows >= slab
-    rows = jnp.where(oor, slab, rows)
-    weights = jnp.where(oor, 0.0, weights)
-    temp, digest = _guard_drain_slab(temp, digest, rows, values, weights,
-                                     slab, compression,
-                                     use_pallas=use_pallas)
-    r, v, w, b = td_ops.bin_flat_samples(
-        rows, values, weights, slab, k, compression,
-        acc_seg_w=temp.seg_w, acc_seg_wm=temp.seg_wm)
-    live = w > 0
-    vz = jnp.where(live, v, 0.0)
-    a = td_ops.BELOW_MASS_ANCHORS
-    flat = jnp.where(r >= slab, slab * k, r * k + b)
-    flat_seg = jnp.where(r >= slab, slab * a,
-                         r * a + td_ops.seg_of_bins(b, k))
-    return TempSlab(
-        sum_w=temp.sum_w.at[flat].add(w, mode="drop"),
-        sum_wm=temp.sum_wm.at[flat].add(w * vz, mode="drop"),
-        seg_w=temp.seg_w.at[flat_seg].add(w, mode="drop"),
-        seg_wm=temp.seg_wm.at[flat_seg].add(w * vz, mode="drop"),
-        count=temp.count.at[r].add(w, mode="drop"),
-        vsum=temp.vsum.at[r].add(w * vz, mode="drop"),
-        vmin=temp.vmin.at[r].min(jnp.where(live, v, jnp.inf), mode="drop"),
-        vmax=temp.vmax.at[r].max(jnp.where(live, v, -jnp.inf), mode="drop"),
-        recip=temp.recip.at[r].add(jnp.where(live, w / v, 0.0), mode="drop"),
-    ), digest
+def _host_rows(mean: np.ndarray, weight: np.ndarray, lo: np.ndarray,
+               hi: np.ndarray):
+    """Fetched ``[n, K]`` rows of a slab's storage planes (``lo``/``hi``
+    their frames) as float32 (mean, weight) on the host."""
+    weight = np.asarray(weight, np.float32)
+    if mean.dtype != np.uint16:
+        return np.asarray(mean, np.float32), weight
+    lo, hi = np.asarray(lo, np.float32), np.asarray(hi, np.float32)
+    with np.errstate(invalid="ignore"):
+        span = np.where(np.isfinite(hi - lo), hi - lo, np.float32(0))
+    base = np.where(np.isfinite(lo), lo, np.float32(0))
+    means = base[:, None] + mean.astype(np.float32) * (
+        span / np.float32(65535))[:, None]
+    return np.where(weight > 0, means, np.float32(np.inf)), weight
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(8, 9, 10))
-def _import_slab(temp: TempSlab, digest: DigestSlab, rows, means, weights,
-                 stat_rows, stat_mins, stat_maxs, slab: int,
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(7, 8))
+def _ingest_slab(temp: td_ops.TempCentroids, digest: DigestSlab, rows,
+                 values, weights, drained, trips, compression: float,
+                 use_pallas: bool = True):
+    """The sample path's ingest into one slab: the dense store's op
+    (ops/tdigest.py ``ingest_chunk_rowdrained``) on the slab's flat
+    storage planes. A held row of at most ``ROW_DRAIN_MAX_ARRIVALS``
+    arrivals is drained into its digest before more is binned into it,
+    behind that the shift guard, both in one ``lax.cond``. ``drained``
+    and ``trips`` (int32 scalars) count the rows drained and the drain
+    loop's trips. Returns (temp, digest, drained, trips).
+
+    rows: [N] LOCAL row ids; the slab's row count (or more) is padding,
+    which scatters nowhere."""
+    d, temp, n = td_ops.ingest_chunk_rowdrained(
+        _as_digest(digest), temp, rows, values, weights, compression,
+        use_pallas=use_pallas)
+    return (temp, _with_digest(digest, d), drained + n,
+            trips + td_ops.row_drain_trips(n, rows.shape[0]))
+
+
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(8, 9))
+def _import_slab(temp: td_ops.TempCentroids, digest: DigestSlab, rows,
+                 means, weights, stat_rows, stat_mins, stat_maxs,
                  compression: float, use_pallas: bool = True):
     """Fold imported digest CENTROIDS into a slab's accumulators without
-    touching the local scalar stats (samplers.go:473-480); imported
-    per-digest extrema land on the digest's dmin/dmax planes and only
-    bound the final digest."""
-    k = temp.sum_w.shape[0] // slab
-    oor = rows >= slab
-    rows = jnp.where(oor, slab, rows)
-    weights = jnp.where(oor, 0.0, weights)
-    temp, digest = _guard_drain_slab(temp, digest, rows, means, weights,
-                                     slab, compression,
-                                     use_pallas=use_pallas)
-    r, v, w, b = td_ops.bin_flat_samples(
-        rows, means, weights, slab, k, compression,
-        acc_seg_w=temp.seg_w, acc_seg_wm=temp.seg_wm)
-    live = w > 0
-    vz = jnp.where(live, v, 0.0)
-    a = td_ops.BELOW_MASS_ANCHORS
-    flat = jnp.where(r >= slab, slab * k, r * k + b)
-    flat_seg = jnp.where(r >= slab, slab * a,
-                         r * a + td_ops.seg_of_bins(b, k))
-    temp = temp._replace(
-        sum_w=temp.sum_w.at[flat].add(w, mode="drop"),
-        sum_wm=temp.sum_wm.at[flat].add(w * vz, mode="drop"),
-        seg_w=temp.seg_w.at[flat_seg].add(w, mode="drop"),
-        seg_wm=temp.seg_wm.at[flat_seg].add(w * vz, mode="drop"))
-    digest = digest._replace(
+    touching the local scalar stats (samplers.go:473-480): the dense
+    store's op (``ingest_centroids_rowdrained``: a row that holds bin
+    mass is drained before its run is binned). Imported per-digest
+    extrema land on the digest's dmin/dmax planes and only bound the
+    final digest."""
+    d, temp, _ = td_ops.ingest_centroids_rowdrained(
+        _as_digest(digest), temp, rows, means, weights, compression,
+        use_pallas=use_pallas)
+    digest = _with_digest(digest, d)
+    return temp, digest._replace(
         dmin=digest.dmin.at[stat_rows].min(stat_mins, mode="drop"),
         dmax=digest.dmax.at[stat_rows].max(stat_maxs, mode="drop"))
-    return temp, digest
 
 
-@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(3, 4, 5, 6, 7))
-def _flush_slab(digest: DigestSlab, temp: TempSlab, qs, slab: int,
-                compression: float, want_digest: bool = True,
-                want_fresh: bool = True, use_pallas: bool = True):
-    """Drain one slab's temp into its digests and emit percentiles.
+@partial(jax.jit, donate_argnums=(0, 1), static_argnums=(4, 5, 6))
+def _flush_slab(digest: DigestSlab, temp: td_ops.TempCentroids, qs, n,
+                compression: float, want_fresh: bool = True,
+                use_pallas: bool = True):
+    """Drain one slab's temp into its digests and emit percentiles: the
+    dense store's flush op (``drain_and_quantile``) on the flat storage
+    planes. ``n`` (an int32 scalar, or None for every row) is the
+    slab's live rows, which the interner hands out as a prefix: the
+    program works on the kernel slabs that hold them, one compiled
+    variant whatever ``n``.
 
     Returns (fresh empty digest+temp for the next interval — or None/None
     when want_fresh=False: a RETIRED generation's slabs are never reused,
     so skipping the zero-fill lets the donated planes free outright —
-    drained digest planes in storage dtype — or None/None when
-    want_digest=False, which saves a full-plane cast+write per flush —
-    percentiles [slab, P], scalar stats)."""
-    k = digest.mean.shape[0] // slab
-    dt = digest.mean.dtype
-    d = td_ops.TDigest(
-        mean=digest.mean.reshape(slab, k).astype(jnp.float32),
-        weight=digest.weight.reshape(slab, k).astype(jnp.float32),
-        min=digest.dmin, max=digest.dmax)
-    # as in _guard_drain_slab: the anchors ride along unread
-    t = td_ops.TempCentroids(*temp)
-    inf = jnp.full((slab,), jnp.inf, jnp.float32)
-    drained, pcts = td_ops.drain_and_quantile(d, t, inf, -inf, qs,
-                                              compression,
-                                              use_pallas=use_pallas)
-    if want_digest:
-        out_mean = drained.mean.astype(dt).reshape(-1)
-        out_weight = drained.weight.astype(dt).reshape(-1)
-    else:
-        out_mean = out_weight = None
+    the drained digest planes in their storage dtypes, written in place
+    of the donated ones and coded against the drained extrema that come
+    beside them, percentiles [slab, P], scalar stats)."""
+    slab, k = temp.num_series, temp.capacity
+    drained, pcts = td_ops.drain_and_quantile(
+        _as_digest(digest), temp, digest.dmin, digest.dmax, qs, compression,
+        use_pallas=use_pallas, n=n)
     if want_fresh:
-        fresh_d = _init_digest_slab(slab, k, dt)
-        fresh_t = _init_temp_slab(slab, k)
+        fresh_d = _init_digest_slab(slab, k, digest.weight.dtype)
+        fresh_t = td_ops.init_temp(slab, k)
     else:
         fresh_d = fresh_t = None
-    return (fresh_d, fresh_t, out_mean, out_weight, drained.min, drained.max,
-            pcts, temp.count, temp.vsum, temp.vmin, temp.vmax, temp.recip)
+    return (fresh_d, fresh_t, drained.mean, drained.weight, drained.min,
+            drained.max, pcts, temp.count, temp.vsum, temp.vmin, temp.vmax,
+            temp.recip)
 
 
 @partial(jax.jit, donate_argnums=(0, 1), static_argnums=(4, 5))
@@ -294,9 +247,10 @@ def _pack_slab(mean_flat, weight_flat, dmin, dmax, slab: int, k: int):
     Returns (counts uint16 [slab], q_pref uint16 [slab, k],
     wb_pref uint16 [slab, k]) — row r's live centroids are
     ``q_pref[r, :counts[r]]``; the caller (:func:`_fetch_packed`)
-    fetches counts first and then only live bytes."""
-    m = mean_flat.reshape(slab, k).astype(jnp.float32)
-    w = weight_flat.reshape(slab, k).astype(jnp.float32)
+    fetches counts first and then only live bytes. Coded storage planes
+    (``DigestSlab``) are coded against ``[dmin, dmax]``."""
+    m, w = td_ops.rows_f32(mean_flat.reshape(slab, k),
+                           weight_flat.reshape(slab, k), dmin, dmax)
     live = w > 0
     counts = jnp.sum(live, axis=1, dtype=jnp.int32)          # [slab]
     span = dmax - dmin
@@ -374,13 +328,17 @@ def _fetch_packed(counts_dev, q_pref, wb_pref, need: int):
     * skewed rows (one heavy row would widen the slice): a device-side
       flat compaction (:func:`_gather_pack`) sized pow2(total).
 
-    pow2 padding bounds the compiled variant count at ~log2 each."""
-    counts = np.asarray(jax.device_get(counts_dev[:need]))
+    pow2 padding bounds the compiled variant count at ~log2 each: the
+    counts come off the device as their pow2 bucket of rows and the
+    compaction at its pow2 length, each cut to size on the host."""
+    slab, k = q_pref.shape
+    (counts,) = cut_rows(jax.device_get(
+        _prefix_rows((counts_dev,), live_bucket(need, slab))), need)
+    counts = np.asarray(counts)
     total = int(counts.astype(np.int64).sum())
     if total == 0:
         empty = np.empty(0, np.uint16)
         return counts, empty, empty
-    slab, k = q_pref.shape
     maxc = int(counts.max())
     width = min(_next_pow2(maxc), k)
     rows = min(_next_pow2(need), slab)
@@ -393,7 +351,7 @@ def _fetch_packed(counts_dev, q_pref, wb_pref, need: int):
             counts[:, None].astype(np.int32)
         return counts, qs[mask], wbs[mask]
     packed = np.asarray(jax.device_get(
-        _gather_pack(counts_dev, q_pref, wb_pref, P)[:total]))
+        _gather_pack(counts_dev, q_pref, wb_pref, P)))[:total]
     return counts, (packed >> 16).astype(np.uint16), \
         (packed & 0xFFFF).astype(np.uint16)
 
@@ -405,22 +363,24 @@ def _merge_slab(digest: DigestSlab, in_mean, in_weight, in_min, in_max,
     global-aggregator path: tdigest.Merge, worker.go:354-398).
 
     in_mean/in_weight: [slab, M] f32, weight==0 padding; rows need not be
-    sorted. in_min/in_max: [slab] f32."""
+    sorted. in_min/in_max: [slab] f32. The merged extrema are the
+    frame the stored means are coded against."""
     k = digest.mean.shape[0] // slab
-    dt = digest.mean.dtype
-    own_m = digest.mean.reshape(slab, k).astype(jnp.float32)
-    own_w = digest.weight.reshape(slab, k).astype(jnp.float32)
+    d = _as_digest(digest)
+    own_m, own_w = td_ops.rows_f32(d.mean.reshape(slab, k),
+                                   d.weight.reshape(slab, k), d.min, d.max)
     live = in_weight > 0
     key = jnp.where(live, in_mean, jnp.inf)
     key, w_in = lax.sort((key, in_weight), dimension=-1, num_keys=1,
                          is_stable=False)
     new_m, new_w = td_ops._dispatch_compress_presorted(
         own_m, own_w, key, w_in, compression, k)
+    lo = jnp.minimum(digest.dmin, in_min)
+    hi = jnp.maximum(digest.dmax, in_max)
+    new_m, new_w = td_ops.rows_stored(d, new_m, new_w, lo, hi)
     return DigestSlab(
-        mean=new_m.astype(dt).reshape(-1),
-        weight=new_w.astype(dt).reshape(-1),
-        dmin=jnp.minimum(digest.dmin, in_min),
-        dmax=jnp.maximum(digest.dmax, in_max),
+        mean=new_m.reshape(-1), weight=new_w.reshape(-1),
+        fmin=lo, fmax=hi, dmin=lo, dmax=hi,
         # exact f32 running total, immune to bf16 weight rounding
         count=digest.count + jnp.sum(jnp.where(live, in_weight, 0.0),
                                      axis=-1),
@@ -432,14 +392,11 @@ def _quantile_slab(digest: DigestSlab, qs, slab: int, compression: float):
     """Flush a merge-mode slab: percentiles + counts from the resident
     digests alone, then reset (the global role has no temp accumulators)."""
     k = digest.mean.shape[0] // slab
-    dt = digest.mean.dtype
-    d = td_ops.TDigest(
-        mean=digest.mean.reshape(slab, k).astype(jnp.float32),
-        weight=digest.weight.reshape(slab, k).astype(jnp.float32),
+    d = td_ops.digest_as_rows(_as_digest(digest), k)._replace(
         min=digest.dmin, max=digest.dmax)
     pcts = td_ops.quantile(d, qs)
-    return (_init_digest_slab(slab, k, dt), pcts, digest.count, d.min,
-            d.max)
+    return (_init_digest_slab(slab, k, digest.weight.dtype), pcts,
+            digest.count, d.min, d.max)
 
 
 class SlabDigestBank:
@@ -476,13 +433,13 @@ class SlabDigestBank:
         self.slab_rows = min(slab_rows, 1 << 20,
                              max(-(-num_series // 128) * 128, 8))
         self.num_slabs = -(-num_series // self.slab_rows)
-        self.digest_dtype = jnp.dtype(digest_dtype)
+        self.digest_dtype = storage_dtype(digest_dtype)
         self.mode = mode
         self.digests: List[DigestSlab] = [
             _init_digest_slab(self.slab_rows, self.k, self.digest_dtype)
             for _ in range(self.num_slabs)]
-        self.temps: List[Optional[TempSlab]] = [
-            _init_temp_slab(self.slab_rows, self.k) if mode == "local"
+        self.temps: List[Optional[td_ops.TempCentroids]] = [
+            td_ops.init_temp(self.slab_rows, self.k) if mode == "local"
             else None
             for _ in range(self.num_slabs)]
 
@@ -492,7 +449,7 @@ class SlabDigestBank:
         """Resident-plane byte accounting (flat planes tile unpadded)."""
         dsz = self.digest_dtype.itemsize
         per_slab_digest = self.slab_rows * self.k * dsz * 2 \
-            + self.slab_rows * 4 * 2
+            + self.slab_rows * 4 * 5
         per_slab_temp = (self.slab_rows * self.k * 4 * 2
                          + self.slab_rows * 4
                          * (5 + 2 * td_ops.BELOW_MASS_ANCHORS)) \
@@ -513,10 +470,11 @@ class SlabDigestBank:
         """Fold a flat chunk of samples whose rows are LOCAL to one slab."""
         assert self.mode == "local"
         with obs_kernels.scope("drain.digest.slab"):
-            self.temps[slab_idx], self.digests[slab_idx] = _ingest_slab(
-                self.temps[slab_idx], self.digests[slab_idx],
-                jnp.asarray(rows), jnp.asarray(values),
-                jnp.asarray(weights), self.slab_rows, self.compression)
+            self.temps[slab_idx], self.digests[slab_idx], _, _ = \
+                _ingest_slab(self.temps[slab_idx], self.digests[slab_idx],
+                             jnp.asarray(rows), jnp.asarray(values),
+                             jnp.asarray(weights), np.int32(0), np.int32(0),
+                             self.compression)
 
     def ingest(self, rows, values, weights):
         """Fold a flat chunk with GLOBAL row ids: each slab scatters the
@@ -533,9 +491,9 @@ class SlabDigestBank:
                 local = jnp.where((rows >= base)
                                   & (rows < base + self.slab_rows),
                                   rows - base, self.slab_rows)
-                self.temps[i], self.digests[i] = _ingest_slab(
+                self.temps[i], self.digests[i], _, _ = _ingest_slab(
                     self.temps[i], self.digests[i], local, values, weights,
-                    self.slab_rows, self.compression)
+                    np.int32(0), np.int32(0), self.compression)
 
     # -- global role: digest import --------------------------------------
 
@@ -569,14 +527,17 @@ class SlabDigestBank:
                     (self.digests[i], self.temps[i], mean, weight, dmin,
                      dmax, pcts, count, vsum, vmin, vmax,
                      recip) = _flush_slab(
-                        self.digests[i], self.temps[i], qs, self.slab_rows,
-                        self.compression, want_digest)
+                        self.digests[i], self.temps[i], qs, None,
+                        self.compression)
                     out = {"percentiles": pcts, "count": count,
                            "sum": vsum, "min": vmin, "max": vmax,
                            "recip": recip}
                     if want_digest:
-                        out["digest_mean"] = mean
-                        out["digest_weight"] = weight
+                        out["digest_mean"], out["digest_weight"] = \
+                            td_ops.rows_f32(
+                                mean.reshape(self.slab_rows, self.k),
+                                weight.reshape(self.slab_rows, self.k),
+                                dmin, dmax)
                     outs.append(out)
                 else:
                     (self.digests[i], pcts, counts, dmin,
@@ -607,7 +568,8 @@ class SlabDigestBank:
 
 
 # cycle-safe: store imports nothing from slab at module top level
-from veneur_tpu.core.store import OverloadLimited, fetch_stage  # noqa: E402
+from veneur_tpu.core.store import (  # noqa: E402
+    OverloadLimited, _prefix_rows, cut_rows, fetch_stage, live_bucket)
 from veneur_tpu.overload import F32_ABS_MAX, MIN_SAMPLE_RATE  # noqa: E402
 
 
@@ -624,9 +586,13 @@ class SlabDigestGroup(OverloadLimited):
     results right after its device program so peak extra memory stays
     slab-sized.
 
-    Staged chunks are partitioned by slab on the host and padded to
-    power-of-two lengths, so each (slab width, chunk pow2) pair compiles
-    once — at most ~log2(chunk) program variants per group.
+    Its sample, import and flush programs are the dense store's ops on
+    the flat planes (ops/tdigest.py ``ingest_chunk_rowdrained``,
+    ``ingest_centroids_rowdrained``, ``drain_and_quantile``): a held row
+    is drained before more is binned into it, and the flush works on
+    each slab's live rows. Staged chunks are partitioned by slab on the
+    host, each part padded to the chunk's length, so each program
+    compiles once a group.
     """
 
     _retired = False  # see core.store.DigestGroup._retired
@@ -634,7 +600,7 @@ class SlabDigestGroup(OverloadLimited):
     def __init__(self, slab_rows: int = SLAB_ROWS_DEFAULT,
                  chunk: int = 1 << 16,
                  compression: float = td_ops.DEFAULT_COMPRESSION,
-                 digest_dtype=jnp.float32):
+                 digest_dtype=jnp.float32, slabs: int = 0):
         from veneur_tpu.core.store import Interner
 
         self._interner_cls = Interner
@@ -645,12 +611,27 @@ class SlabDigestGroup(OverloadLimited):
         if slab_rows <= 0:
             raise ValueError(f"slab_rows must be positive, got {slab_rows}")
         self.slab_rows = min(slab_rows, 1 << 20)
-        self.digest_dtype = jnp.dtype(digest_dtype)
+        self.digest_dtype = storage_dtype(digest_dtype)
+        # ``slabs`` placed now (a fresh generation gets its retired
+        # one's count, ``fresh``), any more as rows intern: a
+        # scope-class this deployment never writes holds no device
+        # memory (a slab placed for each of four such groups made the
+        # swap 48-238 ms a flush on the chip, PERF.md section 6)
         self.digests: List[DigestSlab] = [
-            _init_digest_slab(self.slab_rows, self.k, self.digest_dtype)]
-        self.temps: List[TempSlab] = [
-            _init_temp_slab(self.slab_rows, self.k)]
+            _init_digest_slab(self.slab_rows, self.k, self.digest_dtype)
+            for _ in range(slabs)]
+        self.temps: List[td_ops.TempCentroids] = [
+            td_ops.init_temp(self.slab_rows, self.k) for _ in range(slabs)]
         self._device_dirty = False
+        # this generation's counts, read at its flush (timeline ``slab``):
+        # the sample path's program dispatches, the device's own counts
+        # of the rows they drained before binning into them and of the
+        # drain loop's trips, and the slabs placed with the host's
+        # nanoseconds in placing them
+        self.smp_dispatches = 0
+        self._row_drains = None
+        self.grows = 0
+        self.grow_ns = 0
         self._new_sample_buffers()
         self._new_import_buffers()
 
@@ -664,26 +645,34 @@ class SlabDigestGroup(OverloadLimited):
         return len(self.interner)
 
     def fresh(self) -> "SlabDigestGroup":
-        """Empty same-config twin (swap-on-flush generation swap).
-        Starts with ONE slab and re-grows slab-at-a-time as rows intern:
-        fresh slabs are zero-fill appends (no copies), and lazy growth
-        keeps the flush window's HBM peak at resident + touched-slabs
-        instead of a full 2x (the retired generation's slabs free one by
-        one as the off-lock flush donates them into its programs)."""
+        """Empty same-config twin (swap-on-flush generation swap),
+        holding as many slabs as this generation placed, so an interval
+        that interns no more rows than the last grows nothing on the
+        merger's thread; past that it grows slab-at-a-time as rows
+        intern (zero-fill appends, no copies; the retired generation's
+        slabs free one by one as the off-lock flush donates them into
+        its programs). The grows are counted and timed (``grows``,
+        ``grow_ns``: timeline ``slab``)."""
         return SlabDigestGroup(self.slab_rows, self.chunk,
-                               self.compression, self.digest_dtype)
+                               self.compression, self.digest_dtype,
+                               slabs=len(self.digests))
 
     @requires_lock("store")
     def ensure_capacity(self, max_row: int):
+        if max_row < self.capacity:
+            return
+        t0 = time.monotonic_ns()
         while max_row >= self.capacity:
+            self.grows += 1
             self.digests.append(
                 _init_digest_slab(self.slab_rows, self.k, self.digest_dtype))
-            self.temps.append(_init_temp_slab(self.slab_rows, self.k))
+            self.temps.append(td_ops.init_temp(self.slab_rows, self.k))
             # stale sentinels from before the grow are harmless (their
             # weights are 0) but re-point them anyway, like DigestGroup
             self._rows[self._fill:] = self.capacity
             self._imp_rows[self._imp_fill:] = self.capacity
             self._imp_stat_rows[self._imp_stat_fill:] = self.capacity
+        self.grow_ns += time.monotonic_ns() - t0
 
     @requires_lock("store")
     def _row(self, key, tags) -> int:
@@ -802,14 +791,17 @@ class SlabDigestGroup(OverloadLimited):
 
     def _per_slab(self, rows, *arrays):
         """Partition staged entries by slab; yields (slab_idx, local_rows,
-        arrays...) padded to power-of-two lengths (bounded jit variants)."""
+        arrays...), each slab's part padded to the chunk's length: one
+        compiled program a group however a chunk splits over slabs and
+        however full the flush's last chunk is (a length a part made
+        every new count compile inside the window)."""
         slabs = rows // self.slab_rows
         for i in np.unique(slabs):
             if i < 0 or i >= len(self.digests):
                 continue  # sentinel padding rows
             sel = slabs == i
             m = int(sel.sum())
-            pad = _next_pow2(m)
+            pad = self.chunk
             local = np.full(pad, self.slab_rows, np.int32)
             local[:m] = rows[sel] - i * self.slab_rows
             padded = []
@@ -825,12 +817,16 @@ class SlabDigestGroup(OverloadLimited):
         self._device_dirty = True
         rows, vals, wts = self._rows, self._vals, self._wts
         self._new_sample_buffers()
+        drained, trips = self._row_drains or (np.int32(0), np.int32(0))
         with obs_kernels.scope("drain.digest.slab"):
             for i, local, (v, w) in self._per_slab(rows, vals, wts):
-                self.temps[i], self.digests[i] = _ingest_slab(
-                    self.temps[i], self.digests[i], jnp.asarray(local),
-                    jnp.asarray(v), jnp.asarray(w), self.slab_rows,
-                    self.compression, self._pallas_allowed())
+                self.smp_dispatches += 1
+                self.temps[i], self.digests[i], drained, trips = \
+                    _ingest_slab(self.temps[i], self.digests[i],
+                                 jnp.asarray(local), jnp.asarray(v),
+                                 jnp.asarray(w), drained, trips,
+                                 self.compression, self._pallas_allowed())
+        self._row_drains = (drained, trips)
 
     def _drain_imports(self):
         if self._imp_fill == 0 and self._imp_stat_fill == 0:
@@ -849,22 +845,22 @@ class SlabDigestGroup(OverloadLimited):
         stats = {i: (local, padded) for i, local, padded in
                  self._per_slab(stat_rows, stat_mins, stat_maxs)} \
             if len(stat_rows) else {}
-        empty_f = np.zeros(2, np.float32)
-        empty_r = np.full(2, self.slab_rows, np.int32)
+        empty_f = np.zeros(self.chunk, np.float32)
+        empty_r = np.full(self.chunk, self.slab_rows, np.int32)
         with obs_kernels.scope("drain.digest.slab"):
             for i in sorted(set(by_slab) | set(stats)):
                 c_local, c_pad = by_slab.get(
                     i, (empty_r, [empty_f, empty_f]))
                 s_local, s_pad = stats.get(
-                    i, (empty_r, [np.full(2, np.inf, np.float32),
-                                  np.full(2, -np.inf, np.float32)]))
+                    i, (empty_r, [np.full(self.chunk, np.inf, np.float32),
+                                  np.full(self.chunk, -np.inf,
+                                          np.float32)]))
                 self.temps[i], self.digests[i] = _import_slab(
                     self.temps[i], self.digests[i],
                     jnp.asarray(c_local), jnp.asarray(c_pad[0]),
                     jnp.asarray(c_pad[1]), jnp.asarray(s_local),
                     jnp.asarray(s_pad[0]), jnp.asarray(s_pad[1]),
-                    self.slab_rows, self.compression,
-                    self._pallas_allowed())
+                    self.compression, self._pallas_allowed())
 
     def _drain_staging(self):
         self._drain_samples()
@@ -883,7 +879,7 @@ class SlabDigestGroup(OverloadLimited):
         self.digests = [
             _init_digest_slab(self.slab_rows, self.k, self.digest_dtype)
             for _ in range(nslabs)]
-        self.temps = [_init_temp_slab(self.slab_rows, self.k)
+        self.temps = [td_ops.init_temp(self.slab_rows, self.k)
                       for _ in range(nslabs)]
         self._device_dirty = False
 
@@ -954,6 +950,7 @@ class SlabDigestGroup(OverloadLimited):
         inside ``finish`` (:func:`begin_compute_ladder` semantics)."""
         with obs_rec.maybe_stage("drain"):
             self._drain_staging()
+            self._note_drains()
         n = len(self.interner)
         if n == 0:
             res = self._flush_empty()
@@ -969,34 +966,45 @@ class SlabDigestGroup(OverloadLimited):
             self._kernel_plane())
         return lambda: self._flush_commit(fin())
 
+    def _note_drains(self) -> None:
+        """What this generation's sample path and growth counted, on the
+        flush's open ``drain`` stage (the flusher sums the notes into the
+        timeline entry's ``slab``)."""
+        if self.smp_dispatches or self.grows:
+            obs_rec.note(slab_ingest_dispatches=self.smp_dispatches,
+                         slab_grows=self.grows, slab_grow_ns=self.grow_ns)
+
     def _flush_empty(self):
-        interner, self.interner = self.interner, self._interner_cls()
-        if self._retired:
-            # release order: device planes first, then host staging;
-            # a dead twin must not allocate fresh buffers
-            self.digests = []
-            self.temps = []
-            self._device_dirty = False
-            self._drop_staging()
-            return interner, {}
-        if self._device_dirty:
-            self._reset_device()
-        self._new_sample_buffers()
-        self._new_import_buffers()
+        with obs_rec.maybe_stage("commit", scope=True):
+            interner, self.interner = self.interner, self._interner_cls()
+            if self._retired:
+                # release order: device planes first, then host staging;
+                # a dead twin must not allocate fresh buffers
+                self.digests = []
+                self.temps = []
+                self._device_dirty = False
+                self._drop_staging()
+                return interner, {}
+            if self._device_dirty:
+                self._reset_device()
+            self._new_sample_buffers()
+            self._new_import_buffers()
         return interner, {}
 
     def _flush_commit(self, out: dict):
-        interner, self.interner = self.interner, self._interner_cls()
-        self._device_dirty = False
-        if self._retired:
-            # release order: drained device planes first (their donated
-            # buffers already freed slab by slab), host staging second
-            self.digests = []
-            self.temps = []
-            self._drop_staging()
-        else:
-            self._new_sample_buffers()
-            self._new_import_buffers()
+        with obs_rec.maybe_stage("commit", scope=True):
+            interner, self.interner = self.interner, self._interner_cls()
+            self._device_dirty = False
+            if self._retired:
+                # release order: drained device planes first (their
+                # donated buffers already freed slab by slab), host
+                # staging second
+                self.digests = []
+                self.temps = []
+                self._drop_staging()
+            else:
+                self._new_sample_buffers()
+                self._new_import_buffers()
         return interner, out
 
     def _flush_fetch(self, n: int, percentiles, want_digests, want_stats,
@@ -1012,6 +1020,10 @@ class SlabDigestGroup(OverloadLimited):
         st = self._flush_dispatch(n, percentiles, want_digests,
                                   want_stats, use_pallas)
         return self._flush_collect(st, n, percentiles, want_digests)
+
+    def _slab_live(self, n: int, i: int) -> int:
+        """Rows of slab ``i`` among the ``n`` interned (a prefix)."""
+        return max(0, min(n - i * self.slab_rows, self.slab_rows))
 
     def _flush_dispatch(self, n: int, percentiles, want_digests,
                         want_stats, use_pallas: bool) -> dict:
@@ -1034,89 +1046,104 @@ class SlabDigestGroup(OverloadLimited):
               "refs": [],
               "next": 0}
         window = max(1, getattr(self, "_pipeline_window", 1))
-        for _ in range(min(window, st["nslabs"])):
-            self._dispatch_slab(st)
+        with obs_rec.maybe_stage("compute"):
+            obs_rec.note(slab_rows_live=n, slab_rows_run=sum(
+                td_ops.flush_rows_run(self.slab_rows, self._slab_live(n, i))
+                for i in range(st["nslabs"])))
+            for _ in range(min(window, st["nslabs"])):
+                self._dispatch_slab(st)
         return st
 
     def _dispatch_slab(self, st: dict) -> None:
-        """Dispatch one slab's flush program (async) and record its
-        fetchable refs in dispatch order."""
+        """Dispatch one slab's flush program (async) over its live rows
+        and record its fetchable refs, each the live count's pow2 bucket
+        of rows (``_flush_collect`` cuts them to the count on the host),
+        in dispatch order."""
         i = st["next"]
         st["next"] = i + 1
-        need = min(st["n"] - i * self.slab_rows, self.slab_rows)
-        # want_digest=False also skips the device-side cast+write of
-        # the drained planes, not just the host fetch; a retired
-        # generation additionally skips allocating fresh slabs (its
+        need = self._slab_live(st["n"], i)
+        # a retired generation skips allocating fresh slabs (its
         # donated planes free outright, slab by slab)
         with obs_kernels.scope("flush.digest.slab"):
             (st["new_digests"][i], st["new_temps"][i], mean, weight,
              dmin, dmax, pcts, count, vsum, vmin, vmax, recip) = \
                 _flush_slab(
                     self.digests[i], self.temps[i], st["qs"],
-                    self.slab_rows, self.compression,
-                    bool(st["want_digests"]), not self._retired,
+                    np.int32(need), self.compression, not self._retired,
                     st["use_pallas"])
-            if need <= 0:
+            if need == 0:
                 st["refs"].append(None)
                 return
-            k = self.k
+            b = live_bucket(need, self.slab_rows)
             planes = ()
-            pk_refs = None
+            pk_refs = digest_refs = None
             if st["packed"]:
                 pk_refs = _pack_slab(mean, weight, dmin, dmax,
-                                     self.slab_rows, k)
-                planes = (dmin[:need], dmax[:need])
+                                     self.slab_rows, self.k)
+                planes = (dmin, dmax)
             elif st["want_digests"]:
-                planes = (
-                    mean.reshape(self.slab_rows, k)[:need]
-                        .astype(jnp.float32),
-                    weight.reshape(self.slab_rows, k)[:need]
-                          .astype(jnp.float32),
-                    dmin[:need], dmax[:need])
+                digest_refs = _prefix_rows((mean, weight), b * self.k)
+                planes = (dmin, dmax)
             stats = {"pcts": pcts, "count": count, "sum": vsum,
                      "min": vmin, "max": vmax, "recip": recip}
-            st["refs"].append(
-                (need, pk_refs,
-                 planes + tuple(stats[nm][:need] for nm in st["sel"])))
+            st["refs"].append((need, pk_refs, digest_refs, _prefix_rows(
+                planes + tuple(stats[nm] for nm in st["sel"]), b)))
 
     def _flush_collect(self, st: dict, n: int, percentiles,
                        want_digests) -> dict:
         """Blocking half: fetch each dispatched slab's interned prefix
         in order, dispatching slab j+window while slab j's fetch
         blocks — device execution overlaps the host transfer instead
-        of idling behind it."""
+        of idling behind it. The device's own counts of the sample
+        path's drains come with the first fetch."""
         window = max(1, getattr(self, "_pipeline_window", 1))
-        parts = []
+        parts, digests = [], []
         pk_counts, pk_means, pk_wts = [], [], []
+        counters = self._row_drains
         for j in range(st["nslabs"]):
             while st["next"] < st["nslabs"] and st["next"] - j < window:
-                self._dispatch_slab(st)
+                with obs_rec.maybe_stage("compute"):
+                    self._dispatch_slab(st)
             ref = st["refs"][j]
             if ref is None:
                 continue
-            need, pk_refs, refs = ref
+            need, pk_refs, digest_refs, refs = ref
             st["refs"][j] = None  # drop the fetched slab's refs promptly
-            with fetch_stage((pk_refs, refs)):
+            with fetch_stage((pk_refs, digest_refs, refs)):
                 if st["packed"]:
                     c_h, pm_h, pw_h = _fetch_packed(*pk_refs, need)
                     pk_counts.append(c_h)
                     pk_means.append(pm_h)
                     pk_wts.append(pw_h)
-                parts.append(jax.device_get(refs))
+                fetched, planes, drains = jax.device_get(
+                    (refs, digest_refs, counters))
+                part = cut_rows(fetched, need)
+                parts.append(part)
+                if planes is not None:
+                    # the planes' frames are the drained extrema, the
+                    # part's first two columns
+                    digests.append(_host_rows(
+                        *(p.reshape(-1, self.k) for p in cut_rows(
+                            planes, need * self.k)), *part[:2]))
+                if counters is not None:
+                    rows, trips = drains
+                    obs_rec.note(slab_ingest_rows_drained=int(rows),
+                                 slab_ingest_drain_trips=int(trips))
+                    counters = None
         cols = [np.concatenate(c, axis=0) for c in zip(*parts)]
         # every slab's program + fetch succeeded: commit the fresh planes
         self.digests, self.temps = st["new_digests"], st["new_temps"]
         out = {}
-        if st["packed"]:
+        if st["packed"] or want_digests:
             out["digest_min"], out["digest_max"] = cols[:2]
             cols = cols[2:]
+        if st["packed"]:
             out["packed_counts"] = np.concatenate(pk_counts)
             out["packed_means"] = np.concatenate(pk_means)
             out["packed_weights"] = np.concatenate(pk_wts)
         elif want_digests:
-            (out["digest_mean"], out["digest_weight"], out["digest_min"],
-             out["digest_max"]) = cols[:4]
-            cols = cols[4:]
+            out["digest_mean"], out["digest_weight"] = (
+                np.concatenate(c, axis=0) for c in zip(*digests))
         return _fill_stat_results(st["sel"], cols, n, percentiles, out)
 
     # -- checkpoint snapshot / restore (veneur_tpu/persist/) --------------
@@ -1137,41 +1164,39 @@ class SlabDigestGroup(OverloadLimited):
         k = self.k
         slab_refs = []
         for i, d in enumerate(self.digests):
-            need = min(n - i * self.slab_rows, self.slab_rows)
-            if need <= 0:
+            need = self._slab_live(n, i)
+            if need == 0:
                 break
+            # the count's pow2 bucket of rows (a checkpoint compiles
+            # nothing new while the count wanders), cut on the host
             t = self.temps[i]
-            slab_refs.append((i, (
-                d.mean.reshape(self.slab_rows, k)[:need],
-                d.weight.reshape(self.slab_rows, k)[:need],
-                t.sum_w.reshape(self.slab_rows, k)[:need],
-                t.sum_wm.reshape(self.slab_rows, k)[:need],
-                d.dmin[:need], d.dmax[:need], t.count[:need],
-                t.vsum[:need], t.vmin[:need], t.vmax[:need],
-                t.recip[:need])))
+            b = live_bucket(need, self.slab_rows)
+            slab_refs.append((i, need, _prefix_rows(
+                (d.mean, d.weight, t.sum_w, t.sum_wm), b * k), _prefix_rows(
+                (d.fmin, d.fmax, d.dmin, d.dmax, t.count, t.vsum, t.vmin,
+                 t.vmax, t.recip), b)))
 
         def finish():
             from veneur_tpu.core.store import flatten_digest_state
 
             rows_p, means_p, weights_p, scalars_p = [], [], [], []
-            for i, refs in slab_refs:
-                (mean, weight, bin_w, bin_wm, dmn, dmx, cnt, vsum, vmin,
-                 vmax, recip) = jax.device_get(refs)
+            for i, need, plane_refs, row_refs in slab_refs:
+                planes, scalars = jax.device_get((plane_refs, row_refs))
+                mean, weight, bin_w, bin_wm = (
+                    p.reshape(need, k) for p in cut_rows(planes,
+                                                         need * k))
+                fmin, fmax, dmin, dmax, *stats = cut_rows(scalars, need)
                 flat = flatten_digest_state(
-                    np.asarray(mean, np.float32),
-                    np.asarray(weight, np.float32),
+                    *_host_rows(mean, weight, fmin, fmax),
                     np.asarray(bin_w, np.float32),
                     np.asarray(bin_wm, np.float32))
                 rows_p.append(flat["rows"] + np.int32(i * self.slab_rows))
                 means_p.append(flat["means"])
                 weights_p.append(flat["weights"])
-                scalars_p.append((np.asarray(dmn, np.float32),
-                                  np.asarray(dmx, np.float32),
-                                  np.asarray(cnt, np.float32),
-                                  np.asarray(vsum, np.float32),
-                                  np.asarray(vmin, np.float32),
-                                  np.asarray(vmax, np.float32),
-                                  np.asarray(recip, np.float32)))
+                # digest-bound extrema, as DigestGroup.snapshot_begin's
+                scalars_p.append(tuple(np.asarray(x, np.float32) for x in (
+                    np.minimum(fmin, dmin), np.maximum(fmax, dmax),
+                    *stats)))
             snap["rows"] = np.concatenate(rows_p)
             snap["means"] = np.concatenate(means_p)
             snap["weights"] = np.concatenate(weights_p)

@@ -2274,7 +2274,7 @@ class MetricStore:
 
         def _slab_group():
             # the multi-million-series capacity plan (core/slab.py): flat
-            # per-slab planes, optional bf16 residency, slab-wise growth
+            # per-slab planes, optional 16-bit residency, slab-wise growth
             from veneur_tpu.core.slab import SlabDigestGroup
 
             return SlabDigestGroup(slab_rows=slab_rows, chunk=chunk,

@@ -147,7 +147,10 @@ def device_section(store=None) -> dict:
     # read through __dict__: a debug read must not be the first touch
     # that allocates a lazily-placed group's device state
     group = getattr(store, "histograms", None)
-    digest = getattr(group, "__dict__", {}).get("digest")
+    held = getattr(group, "__dict__", {})
+    # a dense group's one digest, or a slab group's first slab
+    digest = held.get("digest") or next(iter(held.get("digests") or ()),
+                                        None)
     if digest is not None:
         held = sorted(digest.mean.devices(), key=lambda d: d.id)
         out["digest_planes"] = {

@@ -190,6 +190,24 @@ def _publish_interval(server, span, rec, timeline):
                                 for s in entry["stages"]),
             "drain_trips": sum(s.get("ingest_samples_drain_trips", 0)
                                for s in entry["stages"])}
+    # the slab store's (core/slab.py SlabDigestGroup notes them on its
+    # drain, compute and fetch stages): the sample path's dispatches,
+    # the rows they drained and the drain loop's trips, the slabs a
+    # generation placed and the host's seconds in it, and what
+    # the flush programs worked on against what was live
+    slabbed = [s for s in entry["stages"] if "slab_grows" in s]
+    if slabbed:
+        stages = entry["stages"]
+        entry["slab"] = {
+            "dispatches": sum(s["slab_ingest_dispatches"] for s in slabbed),
+            "rows_drained": sum(s.get("slab_ingest_rows_drained", 0)
+                                for s in stages),
+            "drain_trips": sum(s.get("slab_ingest_drain_trips", 0)
+                               for s in stages),
+            "grows": sum(s["slab_grows"] for s in slabbed),
+            "grow_s": sum(s["slab_grow_ns"] for s in slabbed) / 1e9,
+            "rows_live": sum(s.get("slab_rows_live", 0) for s in stages),
+            "rows_run": sum(s.get("slab_rows_run", 0) for s in stages)}
     # the heavy-hitter group's count-min updates (HeavyHitterGroup
     # notes them on its drain stage)
     topk = [s["topk_dispatches"] for s in entry["stages"]

@@ -769,6 +769,151 @@ def take_bin_rows(sum_w: jax.Array, sum_wm: jax.Array, rows: jax.Array,
     return lax.fori_loop(0, n, one, (sum_w, sum_wm, blank, blank))
 
 
+# A digest is held in one of three storage layouts, and the shared ops
+# below read which from its planes: ``[S, K]`` float32 (the dense and
+# mesh stores), or the slab bank's flat ``[S*K]`` planes (core/slab.py),
+# either float32 or coded in 16 bits: bfloat16 weights, and each mean a
+# uint16 code against its row's frame, the digest's ``min``/``max``
+# (the packed format of the wire, ``quantize_centroids``). The kernels
+# take float32 ``[R, K]`` rows: a flat digest is read and written a
+# window of rows at a time, widened on the way in and stored on the way
+# out, and never relaid whole. Whatever writes a coded row writes its
+# frame, and the frame holds every live mean of the row.
+#
+# A mean is coded, not rounded to bfloat16: a drain writes a row
+# mid-interval while the row's later samples stay exact in the float32
+# bins, and a mean kept in 8 bits (1,024 apart at 300,000) lands past a
+# later sample of its row, a sparse row's percentile past both (0.5 by
+# rank in the 2-15 band on the chip, PERF.md section 2). A code is
+# 1/65,535 of the row's own span, and the span's low end is exact.
+
+
+def coded(digest: TDigest) -> bool:
+    """Whether ``digest`` holds its means as codes against its frame."""
+    return digest.mean.dtype == jnp.uint16
+
+
+def code_means(mean: jax.Array, weight: jax.Array, lo: jax.Array,
+               hi: jax.Array) -> jax.Array:
+    """float32 ``[..., K]`` means as uint16 codes against the frame
+    ``[lo, hi]`` (``[...]``, holding every live mean): 0 at an empty
+    slot (``weight`` 0)."""
+    live = weight > 0
+    span = hi - lo
+    scale = jnp.where(span > 0, 65535.0 / span, 0.0)
+    off = jnp.where(live & jnp.isfinite(lo)[..., None],
+                    mean - lo[..., None], 0.0)
+    return jnp.clip(jnp.round(off * scale[..., None]), 0.0,
+                    65535.0).astype(jnp.uint16)
+
+
+def rows_f32(mean: jax.Array, weight: jax.Array, lo: jax.Array,
+             hi: jax.Array):
+    """``[R, K]`` rows of a digest's storage planes (``lo``/``hi`` their
+    ``[R]`` frame) as float32 (mean, weight), ascending along a row as
+    the Pallas kernels write and read it: an empty slot carries the mean
+    before it, -inf ahead of the first (+inf there took a sparse row's
+    centroids out of order: 0.5 by rank in every band, PERF.md section
+    6)."""
+    if mean.dtype != jnp.uint16:
+        return mean.astype(jnp.float32), weight.astype(jnp.float32)
+    mean, weight = dequantize_centroids(
+        mean, lax.bitcast_convert_type(weight, jnp.uint16), lo, hi)
+    return _cummax(jnp.where(weight > 0, mean, -jnp.inf)), weight
+
+
+def rows_stored(stored: TDigest, mean: jax.Array, weight: jax.Array,
+                lo: jax.Array, hi: jax.Array):
+    """float32 ``[R, K]`` rows as ``stored``'s planes hold them: in its
+    dtypes, each mean coded against ``[lo, hi]`` where it is coded."""
+    weight_s = weight.astype(stored.weight.dtype)
+    if coded(stored):
+        return code_means(mean, weight, lo, hi), weight_s
+    return mean.astype(stored.mean.dtype), weight_s
+
+
+def digest_as_rows(digest: TDigest, width: int) -> TDigest:
+    """``digest`` as the float32 ``[S, width]`` batch the kernels take:
+    a flat one widened whole (a program that works on every row), an
+    ``[S, K]`` one as it is."""
+    if digest.mean.ndim == 2:
+        return digest
+    mean, weight = rows_f32(digest.mean.reshape(-1, width),
+                            digest.weight.reshape(-1, width), digest.min,
+                            digest.max)
+    return digest._replace(mean=mean, weight=weight)
+
+
+def digest_like(stored: TDigest, digest: TDigest) -> TDigest:
+    """``digest`` (``[S, K]`` float32, its min/max holding every live
+    mean) back in ``stored``'s layout and dtypes."""
+    if stored.mean.ndim == 2:
+        return digest
+    mean, weight = rows_stored(stored, digest.mean, digest.weight,
+                               digest.min, digest.max)
+    return digest._replace(mean=mean.reshape(-1), weight=weight.reshape(-1))
+
+
+def take_digest_rows(digest: TDigest, rows: jax.Array, n, width: int):
+    """Rows ``rows[:n]`` ([R] int32, in range; ``n`` traced) of a
+    digest as float32 ``[R, width]`` (mean, weight): a gather of rows,
+    or, of a flat digest, a loop of ``n`` trips that reads a window a
+    row (as ``take_bin_rows`` reads the bins), empty past ``n``. One
+    gather and one scatter over the rows' entries instead read 0.033 s
+    a dispatch on the chip, against 0.027 for these loops (PERF.md
+    section 7)."""
+    if digest.mean.ndim == 2:
+        return digest.mean[rows], digest.weight[rows]
+
+    def one(i, carry):
+        m, w = carry
+        at = rows[i] * width
+        return (lax.dynamic_update_slice_in_dim(
+                    m, lax.dynamic_slice_in_dim(digest.mean, at, width)[None],
+                    i, 0),
+                lax.dynamic_update_slice_in_dim(
+                    w, lax.dynamic_slice_in_dim(digest.weight, at,
+                                                width)[None], i, 0))
+
+    empty = 0 if coded(digest) else jnp.inf
+    m, w = lax.fori_loop(0, n, one, (
+        jnp.full((rows.shape[0], width), empty, digest.mean.dtype),
+        jnp.zeros((rows.shape[0], width), digest.weight.dtype)))
+    return rows_f32(m, w, digest.min[rows], digest.max[rows])
+
+
+def put_digest_rows(digest: TDigest, rows: jax.Array, n, mean: jax.Array,
+                    weight: jax.Array) -> TDigest:
+    """``digest`` with rows ``rows`` set to the float32 ``[R, width]``
+    rows given: a scatter of rows (an id past the digest's rows is
+    dropped), or, on a flat digest, the first ``n`` (in range) written
+    a window a row in a loop of ``n`` trips, in place. A coded row takes
+    the live span of its means as its frame."""
+    if digest.mean.ndim == 2:
+        return digest._replace(mean=digest.mean.at[rows].set(mean,
+                                                             mode="drop"),
+                               weight=digest.weight.at[rows].set(
+                                   weight, mode="drop"))
+    lo, hi = digest.min, digest.max
+    if coded(digest):
+        mean, wb, lo_r, hi_r = quantize_centroids(mean, weight)
+        weight = lax.bitcast_convert_type(wb, jnp.bfloat16)
+        lo = lo.at[rows].set(lo_r, mode="drop")
+        hi = hi.at[rows].set(hi_r, mode="drop")
+    mean = mean.astype(digest.mean.dtype)
+    weight = weight.astype(digest.weight.dtype)
+    width = mean.shape[1]
+
+    def one(i, carry):
+        pm, pw = carry
+        at = rows[i] * width
+        return (lax.dynamic_update_slice_in_dim(pm, mean[i], at, 0),
+                lax.dynamic_update_slice_in_dim(pw, weight[i], at, 0))
+
+    pm, pw = lax.fori_loop(0, n, one, (digest.mean, digest.weight))
+    return digest._replace(mean=pm, weight=pw, min=lo, max=hi)
+
+
 def grow_temp(temp: TempCentroids, pad: int) -> TempCentroids:
     """``temp`` with ``pad`` empty rows appended (the one place outside
     the ingest that knows the planes' orders)."""
@@ -947,8 +1092,12 @@ def drain_every_bin(digest: TDigest, temp: TempCentroids,
     ``lax.cond``, then the chunk is binned against fresh anchors):
     every row's bins into its digest, bins and anchors emptied. The
     temp's scalar stats (count/vsum/vmin/vmax/recip) survive: they are
-    interval aggregates, only the BINS move. Returns (digest, temp)."""
-    digest = drain_temp(digest, temp, compression, use_pallas=use_pallas)
+    interval aggregates, only the BINS move. A flat digest (the slab
+    bank's) is widened whole for it and stored back. Returns (digest,
+    temp)."""
+    digest = digest_like(digest, drain_temp(
+        digest_as_rows(digest, temp.capacity), temp, compression,
+        use_pallas=use_pallas))
     return digest, temp._replace(sum_w=jnp.zeros_like(temp.sum_w),
                                  sum_wm=jnp.zeros_like(temp.sum_wm),
                                  seg_w=jnp.zeros_like(temp.seg_w),
@@ -1023,9 +1172,13 @@ def drain_rows(digest: TDigest, temp: TempCentroids, touched: jax.Array,
     for every count, no trip where nothing is held, its cost bounded by
     the chunk (at most one row a staged entry) and never by the rows
     reserved. Nothing is decided across rows, so a shard of a mesh
-    takes it alone and agrees with the dense store. Returns (digest,
+    takes it alone and agrees with the dense store, and a flat digest
+    (the slab bank's) has its rows read and written a window at a time
+    (``take_digest_rows`` / ``put_digest_rows``). Returns (digest,
     temp)."""
     num_series, k = temp.num_series, temp.capacity
+    # the planes the loop writes: a coded digest's frames with its rows
+    written = TDigest._fields[:4 if coded(digest) else 2]
     chunk_len = touched.shape[0]
     slab = min(ROW_DRAIN_SLAB_ROWS, chunk_len)
     touched = jnp.concatenate([touched, jnp.full(
@@ -1033,25 +1186,26 @@ def drain_rows(digest: TDigest, temp: TempCentroids, touched: jax.Array,
     lanes = jnp.arange(BELOW_MASS_ANCHORS, dtype=jnp.int32) * num_series
 
     def drain_slab(i, planes):
-        mean, weight, sum_w, sum_wm, seg_w, seg_wm = planes
+        kept, sum_w, sum_wm, seg_w, seg_wm = planes
+        d = digest._replace(**dict(zip(written, kept)))
         to = lax.dynamic_slice_in_dim(touched, i * slab, slab)
         at = jnp.minimum(to, num_series - 1)
-        sum_w, sum_wm, t_w, t_wm = take_bin_rows(
-            sum_w, sum_wm, at, jnp.minimum(count - i * slab, slab), k)
-        m, w = _merge_bins(mean[at], weight[at], t_w, t_wm, compression,
-                           digest.capacity, use_pallas)
+        held = jnp.minimum(count - i * slab, slab)
+        sum_w, sum_wm, t_w, t_wm = take_bin_rows(sum_w, sum_wm, at, held, k)
+        m, w = _merge_bins(*take_digest_rows(d, at, held, k), t_w, t_wm,
+                           compression, k, use_pallas)
         to_a = jnp.where(to[:, None] < num_series, lanes + to[:, None],
                          seg_w.shape[0])
-        return (mean.at[to].set(m, mode="drop"),
-                weight.at[to].set(w, mode="drop"), sum_w, sum_wm,
+        d = put_digest_rows(d, to, held, m, w)
+        return (tuple(getattr(d, f) for f in written), sum_w, sum_wm,
                 seg_w.at[to_a].set(0.0, mode="drop"),
                 seg_wm.at[to_a].set(0.0, mode="drop"))
 
-    mean, weight, sum_w, sum_wm, seg_w, seg_wm = lax.fori_loop(
+    kept, sum_w, sum_wm, seg_w, seg_wm = lax.fori_loop(
         0, row_drain_trips(count, chunk_len), drain_slab,
-        (digest.mean, digest.weight, temp.sum_w, temp.sum_wm, temp.seg_w,
-         temp.seg_wm))
-    return (digest._replace(mean=mean, weight=weight),
+        (tuple(getattr(digest, f) for f in written), temp.sum_w,
+         temp.sum_wm, temp.seg_w, temp.seg_wm))
+    return (digest._replace(**dict(zip(written, kept))),
             temp._replace(sum_w=sum_w, sum_wm=sum_wm, seg_w=seg_w,
                           seg_wm=seg_wm))
 
@@ -1205,17 +1359,21 @@ def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
     its work bounded by the interval's series and not by the rows
     reserved. Rows past the last slab run keep their input values
     (their percentiles read 0); nothing reads them. A batch of at most
-    one slab, or no ``n`` (the slab bank, whose rows are no prefix), is
-    the straight-line program."""
+    one slab, or no ``n``, is the straight-line program. A flat digest
+    (the slab bank's storage layout) comes back in its layout and
+    dtypes, a coded one against its drained min/max; the loop widens
+    and stores a slab's window of it, never the whole plane."""
     from veneur_tpu.ops import tdigest_pallas
 
     slab = tdigest_pallas._FLUSH_SLAB_ROWS
-    qs = jnp.asarray(qs, state.mean.dtype)
-    rows, k = state.mean.shape
+    flat = state.mean.ndim == 1
+    qs = jnp.asarray(qs, jnp.float32 if flat else state.mean.dtype)
+    rows, k = state.min.shape[0], temp.capacity
     if n is None or rows <= slab:
-        return _drain_and_quantile_rows(
-            state, *temp.bins(), temp.vmin, temp.vmax, dmin, dmax, qs,
-            compression, use_pallas)
+        drained, pcts = _drain_and_quantile_rows(
+            digest_as_rows(state, k), *temp.bins(), temp.vmin, temp.vmax,
+            dmin, dmax, qs, compression, use_pallas)
+        return digest_like(state, drained), pcts
     # the slab's inputs: temp's anchors and scalar stats are not read
     planes = (state.mean, state.weight, state.min, state.max)
     reads = (temp.vmin, temp.vmax, dmin, dmax)
@@ -1226,25 +1384,35 @@ def drain_and_quantile(state: TDigest, temp: TempCentroids, dmin, dmax,
         # those keep what they have (``fresh`` is all true otherwise)
         start = jnp.minimum(i * slab, rows - slab)
         cut = lambda x: lax.dynamic_slice_in_dim(x, start, slab, 0)
-        mean, weight, mn, mx = (cut(x) for x in carry[:4])
+        put = lambda old, x: lax.dynamic_update_slice_in_dim(old, x, start,
+                                                             0)
+        if flat:
+            window = lambda x: bin_rows(x, start, slab, k)
+            put_window = lambda old, x: lax.dynamic_update_slice_in_dim(
+                old, x.reshape(-1), start * k, 0)
+        else:
+            window, put_window = cut, put
+        cuts = (window, window, cut, cut, cut)
+        puts = (put_window, put_window, put, put, put)
+        mean, weight, mn, mx = (c(x) for c, x in zip(cuts, carry[:4]))
         drained, pcts = _drain_and_quantile_rows(
-            TDigest(mean, weight, mn, mx),
+            TDigest(*rows_f32(mean, weight, mn, mx), mn, mx),
             bin_rows(temp.sum_w, start, slab, k),
             bin_rows(temp.sum_wm, start, slab, k),
             *(cut(x) for x in reads), qs, compression, use_pallas)
-        new = tuple(drained) + (pcts,)
+        new = rows_stored(state, drained.mean, drained.weight, drained.min,
+                          drained.max) + (drained.min, drained.max, pcts)
         if rows % slab:
             fresh = start + jnp.arange(slab) >= i * slab
             new = tuple(
                 jnp.where(fresh.reshape((slab,) + (1,) * (x.ndim - 1)), x,
-                          cut(old)) for x, old in zip(new, carry))
-        return tuple(lax.dynamic_update_slice_in_dim(old, x, start, 0)
-                     for old, x in zip(carry, new))
+                          c(old)) for x, old, c in zip(new, carry, cuts))
+        return tuple(p(old, x) for old, x, p in zip(carry, new, puts))
 
     trips = (jnp.asarray(n, jnp.int32) + (slab - 1)) // slab
     out = lax.fori_loop(
         0, jnp.minimum(trips, -(-rows // slab)), one_slab,
-        planes + (jnp.zeros((rows, qs.shape[0]), state.mean.dtype),))
+        planes + (jnp.zeros((rows, qs.shape[0]), qs.dtype),))
     return TDigest(*out[:4]), out[4]
 
 
@@ -1321,12 +1489,7 @@ def quantize_centroids(mean: jax.Array, weight: jax.Array):
     live = weight > 0
     fmin = jnp.min(jnp.where(live, mean, jnp.inf), axis=-1)
     fmax = jnp.max(jnp.where(live, mean, -jnp.inf), axis=-1)
-    span = fmax - fmin
-    scale = jnp.where(span > 0, 65535.0 / span, 0.0)
-    mq = jnp.clip(jnp.round((jnp.where(live, mean, 0.0) - jnp.where(
-        jnp.isfinite(fmin), fmin, 0.0)[..., None]) * scale[..., None]),
-        0.0, 65535.0).astype(jnp.uint16)
-    mq = jnp.where(live, mq, 0)
+    mq = code_means(mean, weight, fmin, fmax)
     wb = lax.bitcast_convert_type(
         jnp.where(live, weight, 0.0).astype(jnp.bfloat16), jnp.uint16)
     return mq, wb, fmin, fmax
