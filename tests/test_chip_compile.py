@@ -359,9 +359,11 @@ def test_datagram_fed_mesh_at_deployment_size_fits_a_chip(mesh,
     on the two chips of its hosts pair. The hosts-sharded sample
     ingest and the flush compile for the described chips, each inside
     a chip's memory beside the fresh generation a swap holds; the
-    ingest carries the hosts-axis collectives, the flush none. A
-    dispatch puts every plane of a fresh shard-sized temp through
-    them: 2^21 rows x 229 float32 and the guard's two masses."""
+    ingest gathers the chunk over the hosts axis and psums the guard's
+    masses, the flush holds no collective. A dispatch puts a device's
+    slice of the chunk through them, 8,192 x 12 B, and the guard's two
+    masses; it bins into the planes in place, with chunk- and
+    anchor-sized scratch, not a fresh temp of the block."""
     from veneur_tpu.core.mesh_store import (MeshDigestGroup, _digest_specs,
                                             _mesh_flush_digests,
                                             _mesh_ingest_samples)
@@ -383,9 +385,10 @@ def test_datagram_fed_mesh_at_deployment_size_fits_a_chip(mesh,
         _f32((CHUNK,), h), _f32((CHUNK,), h), mesh, COMPRESSION,
         K).compile()
     text = ingest.as_text()
-    assert "all-reduce" in text
+    assert "all-gather" in text and "all-reduce" in text
     held = ingest.memory_analysis()
     assert held.argument_size_in_bytes <= half + (16 << 20)
+    assert held.temp_size_in_bytes < 1e9, held.temp_size_in_bytes
     # the program's own peak beside the generation it works on, and the
     # fresh twin a swap holds beside both: under the chip's 16 GB
     peak = (held.argument_size_in_bytes + held.temp_size_in_bytes
@@ -395,8 +398,8 @@ def test_datagram_fed_mesh_at_deployment_size_fits_a_chip(mesh,
     # as the timeline's mesh_ingest.collective_bytes counts it
     group = MeshDigestGroup.__new__(MeshDigestGroup)
     group.hosts, group.shards, group.capacity, group.k = 2, 2, rows, K
-    assert group.sample_collective_bytes() == 4 * ((1 << 21) * 229 + 2) \
-        == 1_920_991_240
+    group.chunk = CHUNK
+    assert group.sample_collective_bytes() == 4 * (3 * 8192 + 2) == 98_312
     group.hosts = 1  # series 4 x hosts 1: no hosts-axis collective
     assert group.sample_collective_bytes() == 0
 

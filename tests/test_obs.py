@@ -828,9 +828,10 @@ class TestMeshSampleStages:
         chan.get_flush()
         counted = srv.obs_timeline.entries()[-1]["mesh_ingest"]
         assert counted["dispatches"] == 3 and counted["samples"] == 150
-        # a device's 64 rows x (2 x 104 + 2 x 8 + 5) float32 and the
-        # guard's two masses, a dispatch
-        assert counted["collective_bytes"] == 3 * 4 * (64 * 229 + 2)
+        # a device's slice of the 64-sample chunk (row, value and
+        # weight: 3 x 32 four-byte words at hosts 2) into the gather
+        # and the guard's two masses, a dispatch
+        assert counted["collective_bytes"] == 3 * 4 * (3 * 64 // 2 + 2)
         assert counted["guard_drains"] == 0
         names = srv.obs_timeline.entries()[-1]["stages"]
         assert not [s for s in names if s["name"].startswith("import.")]

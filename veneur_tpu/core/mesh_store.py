@@ -18,10 +18,10 @@ Layout (cf. ``parallel/mesh.py``; shard placement in ``fleet/router.py``):
   from the first interval and agrees with any ring-routed upstream.
   The interner stays dense/sequential; flushes and snapshots gather the
   placement's permutation so every consumer still sees interner order.
-- **hosts axis** — sample chunks are *sharded* over this axis, so the
-  expensive chunk binning (sort + prefix sums in ``ops/tdigest.py``)
-  parallelizes across it; one ``psum``/``pmax`` per drain completes the
-  merge over ICI (``parallel/collectives.py``).
+- **hosts axis** — sample chunks come in *sharded* over this axis, each
+  device handed its slice; one ``all_gather`` over ICI gives every
+  device the whole chunk, which it bins against its own series block
+  in place. What crosses is the chunk (12 B a sample), never a plane.
 - **shard-routed import** — staged import chunks drain as ``[shards, b]``
   stacks sharded over the series axis: each device receives exactly its
   own rows' sub-chunk (whole centroid runs, order preserved) and bins
@@ -59,7 +59,6 @@ from veneur_tpu.obs import kernels as obs_kernels
 from veneur_tpu.obs import recorder as obs_rec
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.ops import tdigest as td_ops
-from veneur_tpu.parallel import collectives
 from veneur_tpu.parallel.mesh import HOSTS_AXIS, SERIES_AXIS
 
 
@@ -128,17 +127,6 @@ def _mesh_zero_registers(mesh: Mesh, capacity: int, m: int):
                      check_vma=False)()
 
 
-def _add_temp(a: td_ops.TempCentroids,
-              b: td_ops.TempCentroids) -> td_ops.TempCentroids:
-    """Elementwise accumulate: all TempCentroids fields are associative."""
-    return td_ops.TempCentroids(
-        sum_w=a.sum_w + b.sum_w, sum_wm=a.sum_wm + b.sum_wm,
-        seg_w=a.seg_w + b.seg_w, seg_wm=a.seg_wm + b.seg_wm,
-        count=a.count + b.count, vsum=a.vsum + b.vsum,
-        vmin=jnp.minimum(a.vmin, b.vmin), vmax=jnp.maximum(a.vmax, b.vmax),
-        recip=a.recip + b.recip)
-
-
 def _digest_specs():
     sk, s = P(SERIES_AXIS, None), P(SERIES_AXIS)
     # the temp's flat planes split into the shards' row blocks, each
@@ -150,66 +138,61 @@ def _digest_specs():
     return temp_spec, dig_spec, sk, s
 
 
-def _guarded_drain(temp, digest, rows_l, vals, wts, s_loc, axes,
-                   compression):
-    """The dense/slab stores' shift guard, mesh form: the drain is
-    row-local (no collective inside the cond), but the DECISION psums
-    the shift/total masses over ``axes`` so every shard takes the same
-    drain the dense store would on the same data. Returns the decision
-    too (the same on every device), for the dispatch's drain count."""
-    shifted, total = td_ops.shift_masses(
-        *temp.anchors(), rows_l, vals, wts, s_loc)
-    shifted = lax.psum(shifted, axes)
-    total = lax.psum(total, axes)
+def _guarded_ingest(temp, digest, rows_l, vals, wts, compression):
+    """The dense/slab stores' shift guard and binning, mesh form: the
+    DECISION psums the shift/total masses over the series axis, so every
+    shard takes the drain the dense store would on the same chunk; the
+    drain is row-local (no collective inside the cond) and works on a
+    slab of the block's rows at a time, in place. The chunk is then
+    binned against the anchors' ``[S, A]`` views the branch taken
+    returns: the emptied ones after a drain, else the ones the guard
+    read, which the chip relays from the flat anchor planes once a
+    dispatch, not twice. Returns the decision too (the same on every
+    device), for the dispatch's drain count."""
+    s_loc = temp.num_series
+    anchors = temp.anchors()
+    shifted, total = td_ops.shift_masses(*anchors, rows_l, vals, wts, s_loc)
+    shifted = lax.psum(shifted, SERIES_AXIS)
+    total = lax.psum(total, SERIES_AXIS)
     pred = shifted > td_ops.SHIFT_GUARD_FRAC * jnp.maximum(
         total, jnp.finfo(jnp.float32).tiny)
 
-    def do_drain(args):
-        t, d = args
-        d2 = td_ops.drain_temp(d, t, compression)
-        t2 = t._replace(sum_w=jnp.zeros_like(t.sum_w),
-                        sum_wm=jnp.zeros_like(t.sum_wm),
-                        seg_w=jnp.zeros_like(t.seg_w),
-                        seg_wm=jnp.zeros_like(t.seg_wm))
-        return t2, d2
+    def drain(state):
+        digest, temp = td_ops.drain_every_bin_by_slab(*state, compression)
+        return digest, temp, temp.anchors()
 
-    temp, digest = lax.cond(pred, do_drain, lambda a: a, (temp, digest))
+    digest, temp, anchors = lax.cond(
+        pred, drain, lambda state: state + (anchors,), (digest, temp))
+    temp = td_ops.ingest_chunk(temp, rows_l, vals, wts, compression,
+                               anchors=anchors)
     return temp, digest, pred
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2), static_argnums=(6, 7, 8))
 def _mesh_ingest_samples(temp, digest, drains, rows, vals, wts, mesh: Mesh,
                          compression: float, k: int):
-    """Hosts-sharded sample ingest: each device bins its hosts-axis
-    slice of the chunk against its series block, then ONE psum merges
-    the additive bin deltas over ICI (``collectives.merge_temp``).
-    ``drains`` (int32, the same on every device) counts the dispatches
-    whose shift guard drained the temp: the mesh's decision is one for
-    all shards (``_guarded_drain``)."""
+    """Hosts-sharded sample ingest: the chunk comes in split over the
+    hosts axis, ONE all-gather there hands every device the whole chunk
+    (its original order), and each device bins it against its series
+    block into the accumulated temp in place (``td_ops.ingest_chunk``,
+    the dense store's binning), so what crosses the hosts axis is the
+    chunk's 12 B a sample, not the block's planes. ``drains`` (int32,
+    the same on every device) counts the dispatches whose shift guard
+    drained the temp: the mesh's decision is one for all shards
+    (``_guarded_ingest``). ``k`` is the temp's bin count, which its
+    shape carries too."""
     hosts = mesh.shape.get(HOSTS_AXIS, 1)
     temp_spec, dig_spec, _, _ = _digest_specs()
     h = P(HOSTS_AXIS)
 
     def local_ingest(temp, digest, drains, rows, vals, wts):
-        s_loc = temp.num_series
-        rows_l = _relocal(rows, s_loc)
-        # hosts-sharded chunk: the guard masses psum over BOTH axes
-        # (each shard sees its sub-chunk x its rows)
-        axes = (SERIES_AXIS, HOSTS_AXIS) if hosts > 1 else SERIES_AXIS
-        temp, digest, drained = _guarded_drain(
-            temp, digest, rows_l, vals, wts, s_loc, axes, compression)
-        # bin into a FRESH temp (the delta rides the hosts-axis
-        # collective) but anchor bin ids on the ACCUMULATED bins so
-        # ordered arrival stays value-coherent across chunks (the
-        # tdigest_sweep ordered-arrival regression)
-        binned = td_ops.ingest_chunk(
-            td_ops.init_temp(s_loc, k, compression),
-            rows_l, vals, wts, compression,
-            acc_seg_w=temp.seg_w, acc_seg_wm=temp.seg_wm)
         if hosts > 1:
-            binned = collectives.merge_temp(binned, HOSTS_AXIS)
-        return (_add_temp(temp, binned), digest,
-                drains + drained.astype(jnp.int32))
+            rows, vals, wts = lax.all_gather((rows, vals, wts), HOSTS_AXIS,
+                                             tiled=True)
+        temp, digest, drained = _guarded_ingest(
+            temp, digest, _relocal(rows, temp.num_series), vals, wts,
+            compression)
+        return temp, digest, drains + drained.astype(jnp.int32)
 
     return shard_map(local_ingest, mesh=mesh,
                      in_specs=(temp_spec, dig_spec, P(), h, h, h),
@@ -538,16 +521,15 @@ class MeshDigestGroup(_PlacementMixin, DigestGroup):
         self.smp_dispatch_ns += time.monotonic_ns() - t0
 
     def sample_collective_bytes(self) -> int:
-        """Bytes one device puts through hosts-axis collectives in one
-        sample dispatch, from the shapes the program is called with:
-        every plane of the fresh shard-sized temp
-        (``collectives.merge_temp``: a psum, pmin or pmax each) and the
-        guard's two float32 masses. Nothing where the hosts axis is 1."""
+        """Bytes one device puts through collectives in one sample
+        dispatch, from the shapes the program is called with: its
+        hosts-axis slice of the chunk (row, value and weight, 4 B each,
+        into the ``all_gather``) and the guard's two float32 masses.
+        0 where the hosts axis is 1: the chunk needs no exchange there,
+        and the guard's 8 B alone are not counted."""
         if self.hosts == 1:
             return 0
-        s_loc = self.capacity // self.shards
-        per_row = 2 * self.k + 2 * td_ops.BELOW_MASS_ANCHORS + 5
-        return 4 * (s_loc * per_row + 2)
+        return 4 * (3 * self.chunk // self.hosts + 2)
 
     def _replicated_zero(self) -> jax.Array:
         """The sample path's drain counter at its start: one int32, the

@@ -12,11 +12,14 @@ the TPU re-expression of the reference's global-aggregator merge loop
     t-digest centroids  butterfly ppermute merge / all-gather + one compress
                         (MergingDigest.Merge, merging_digest.go:358-370)
 
-The t-digest temp-bin trick is the load-bearing design point: because ingest
-pre-clusters samples into k-scale bins whose (sum_w, sum_wm) accumulators are
-*additive*, the cross-host merge of in-progress digest state is a plain
-``psum`` — no sequential centroid walk crosses the wire, and ICI carries
-``[S_shard, K]`` float32 tensors.
+The t-digest temp-bin trick: because ingest pre-clusters samples into
+k-scale bins whose (sum_w, sum_wm) accumulators are *additive*, the
+cross-host merge of in-progress digest state is a plain ``psum`` — no
+sequential centroid walk crosses the wire, and ICI carries ``[S_shard, K]``
+float32 tensors. ``parallel/global_agg.py``'s step uses it. The serving
+mesh's sample path does not: a plane-sized psum costs the rows reserved
+whatever the chunk carried, so ``core/mesh_store.py`` gathers the chunk
+over the hosts axis instead and bins it on every device of a series shard.
 """
 
 from __future__ import annotations
