@@ -217,6 +217,44 @@ def test_hll_insert_and_estimate(one_chip):
     _estimate_all.lower(regs).compile()
 
 
+SET_ROWS, SET_WIDTH, SET_POOL = 1 << 20, 128, 4096
+
+
+def test_sparse_set_programs_at_the_cell_s_store(one_chip):
+    """``standalone-sets1m``'s sparse store (2^20 series of 128 entries,
+    a pool of 4,096 dense rows, p = 14) for one described chip: the
+    insert of a 16,384-hash chunk updates every plane in place, the
+    flush's estimate runs the live bucket of 65,536 rows (the cell's
+    36,864 live sets), and neither needs scratch of the store's size.
+    What each counts is printed for PERF.md: the runtime's peak does
+    not see a program's scratch."""
+    from veneur_tpu.core.sets import (_sparse_set_estimate,
+                                      _sparse_set_insert)
+    from veneur_tpu.ops import hll
+
+    m = hll.num_registers(14)
+    state = (_i32((SET_ROWS, SET_WIDTH), one_chip),
+             _i32((SET_ROWS,), one_chip),
+             jax.ShapeDtypeStruct((SET_POOL, m), jnp.int8,
+                                  sharding=one_chip),
+             _i32((hll.N_COUNTERS,), one_chip))
+    u32 = jax.ShapeDtypeStruct((CHUNK,), jnp.uint32, sharding=one_chip)
+    insert = _sparse_set_insert.lower(*state, _i32((CHUNK,), one_chip),
+                                      u32, u32).compile()
+    estimate = _sparse_set_estimate.lower(*state[:3], 1 << 16).compile()
+    held = _nbytes(state)
+    for name, program in (("insert", insert), ("estimate", estimate)):
+        mem = program.memory_analysis()
+        print(f"sparse set {name}: argument "
+              f"{mem.argument_size_in_bytes} B, scratch "
+              f"{mem.temp_size_in_bytes} B, output "
+              f"{mem.output_size_in_bytes} B")
+        assert mem.temp_size_in_bytes < held // 8
+    mem = insert.memory_analysis()
+    assert mem.alias_size_in_bytes >= held
+    assert mem.argument_size_in_bytes < 700 * 2**20
+
+
 def test_mesh_programs_shard_state_over_series(one_chip_flush, mesh,
                                                kernel_admitted):
     """The four-chip global: the flush and the routed import compile

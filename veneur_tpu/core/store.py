@@ -21,6 +21,10 @@ here every scope-class is ONE dense group —
     local_sets             device HLL registers [S, 2^p] (int8)
     =====================  =============================================
 
+(or, for the two set groups of a store reserved past 4096 rows without a
+mesh, each series' filled registers as ``[S, 128]`` entries until it
+fills them, core/sets.py)
+
 — so the per-interval flush (the hot path, ``flusher.go:26-132``) is a handful
 of jitted XLA programs over ``[S, ...]`` tensors instead of S sequential
 sketch walks. Counters/gauges stay host-side numpy: they are exact integer /
@@ -2306,6 +2310,20 @@ class MetricStore:
         # configuration's default (4096 rows, 64 MiB of registers) and
         # grow by doubling as before.
         wide_capacity = min(initial_capacity, 4096)
+
+        def _set_group():
+            # Past that cap, and off a mesh (whose set groups stay dense
+            # and sharded, and whose local groups hold only the
+            # instance's own telemetry), a set group keeps a series'
+            # registers sparse until it fills them (core/sets.py): 512 B
+            # a row, so the whole capacity is reserved and never grows.
+            if mesh is None and initial_capacity > wide_capacity:
+                from veneur_tpu.core.sets import SparseSetGroup
+
+                return SparseSetGroup(initial_capacity, chunk,
+                                      hll_precision)
+            return SetGroup(wide_capacity, chunk, hll_precision)
+
         if mesh is not None:
             # Fleet mode: every group (scalars included) places series
             # by the shared router, so one shard owns a series across
@@ -2366,15 +2384,15 @@ class MetricStore:
         elif digest_storage == "slab":
             self.histograms = self._slab_group()
             self.timers = self._slab_group()
-            self.sets = SetGroup(wide_capacity, chunk, hll_precision)
+            self.sets = _set_group()
         elif digest_storage == "tiered":
             self.histograms = _tiered_group()
             self.timers = _tiered_group()
-            self.sets = SetGroup(wide_capacity, chunk, hll_precision)
+            self.sets = _set_group()
         else:
             self.histograms = DigestGroup(initial_capacity, chunk, compression)
             self.timers = DigestGroup(initial_capacity, chunk, compression)
-            self.sets = SetGroup(wide_capacity, chunk, hll_precision)
+            self.sets = _set_group()
         if digest_storage == "slab":
             self.local_histograms = self._slab_group()
             self.local_timers = self._slab_group()
@@ -2386,7 +2404,7 @@ class MetricStore:
                                                 compression)
             self.local_timers = DigestGroup(initial_capacity, chunk,
                                             compression)
-        self.local_sets = SetGroup(wide_capacity, chunk, hll_precision)
+        self.local_sets = _set_group()
         # the dedicated self-telemetry group (veneur_tpu/obs/): the
         # server's own stage durations, always a small dense DigestGroup
         # regardless of digest_storage — bounded cardinality (one row

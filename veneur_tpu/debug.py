@@ -137,7 +137,10 @@ def device_section(store=None) -> dict:
     """The device in the program's own words: what JAX reports, and
     where the histogram digest planes actually live (``.devices()`` of
     the arrays, not ``jax.devices()`` alone — code that has only seen
-    one chip may leave everything on the first)."""
+    one chip may leave everything on the first). Where no histogram
+    digest is placed yet (its first chunk drains at the flush), the set
+    group's planes stand in: a sparse group's entries, a dense one's
+    registers."""
     import jax
 
     devs = jax.devices()
@@ -151,13 +154,18 @@ def device_section(store=None) -> dict:
     # a dense group's one digest, or a slab group's first slab
     digest = held.get("digest") or next(iter(held.get("digests") or ()),
                                         None)
-    if digest is not None:
-        held = sorted(digest.mean.devices(), key=lambda d: d.id)
+    plane, of = getattr(digest, "mean", None), "histograms"
+    if plane is None:
+        held = getattr(getattr(store, "sets", None), "__dict__", {})
+        plane, of = held.get("entries", held.get("registers")), "sets"
+    if plane is not None:
+        held = sorted(plane.devices(), key=lambda d: d.id)
         out["digest_planes"] = {
+            "group": of,
             "devices": [str(d) for d in held],
             "platform": held[0].platform,
-            "shape": list(digest.mean.shape),
-            "dtype": str(digest.mean.dtype)}
+            "shape": list(plane.shape),
+            "dtype": str(plane.dtype)}
     # the CPU backend reports no memory statistics
     peaks = [stats["peak_bytes_in_use"]
              for stats in (d.memory_stats() for d in devs)

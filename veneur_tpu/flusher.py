@@ -208,6 +208,18 @@ def _publish_interval(server, span, rec, timeline):
             "grow_s": sum(s["slab_grow_ns"] for s in slabbed) / 1e9,
             "rows_live": sum(s.get("slab_rows_live", 0) for s in stages),
             "rows_run": sum(s.get("slab_rows_run", 0) for s in stages)}
+    # the sparse set groups' (core/sets.py SparseSetGroup notes them on
+    # its drain, compute and fetch stages): the insert dispatches and
+    # the hashes they carried, the pool's growths, the device's own
+    # counts of promotions, of the entries the inserts touched and of
+    # the dense registers they wrote, and what the flush ran on
+    if any("sets_dispatches" in s for s in entry["stages"]):
+        entry["sets"] = {
+            field: sum(s.get("sets_" + field, 0) for s in entry["stages"])
+            for field in ("dispatches", "hashes", "pool_grows",
+                          "promotions", "rows_live", "rows_run",
+                          "rows_dense", "entries_live", "entries_touched",
+                          "dense_writes")}
     # the heavy-hitter group's count-min updates (HeavyHitterGroup
     # notes them on its drain stage)
     topk = [s["topk_dispatches"] for s in entry["stages"]

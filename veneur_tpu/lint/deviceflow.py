@@ -81,6 +81,9 @@ DONATION_PRONE_PLANES: Dict[str, Dict[str, Tuple[str, ...]]] = {
     "veneur_tpu/core/slab.py": {
         "SlabDigestGroup": ("digests", "temps"),
     },
+    "veneur_tpu/core/sets.py": {
+        "SparseSetGroup": ("entries", "slot", "pool", "counters"),
+    },
     "veneur_tpu/core/tiered.py": {
         "TieredDigestGroup": ("pools",),
     },

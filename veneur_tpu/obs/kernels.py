@@ -126,6 +126,12 @@ PROGRAM_SCOPES: Dict[str, Tuple[str, Optional[Tuple[str, str]]]] = {
         ("flush.digest.slab", ("veneur_tpu.core.slab", "_slice_pack")),
     "veneur_tpu/core/slab.py::_gather_pack":
         ("flush.digest.slab", ("veneur_tpu.core.slab", "_gather_pack")),
+    "veneur_tpu/core/sets.py::_sparse_set_estimate":
+        ("flush.set.sparse",
+         ("veneur_tpu.core.sets", "_sparse_set_estimate")),
+    "veneur_tpu/core/sets.py::_sparse_set_registers":
+        ("flush.set.sparse",
+         ("veneur_tpu.core.sets", "_sparse_set_registers")),
     "veneur_tpu/core/tiered.py::_pool_ingest":
         ("drain.digest.tiered", ("veneur_tpu.core.tiered", "_pool_ingest")),
     "veneur_tpu/core/tiered.py::_pool_import":
