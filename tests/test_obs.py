@@ -705,26 +705,35 @@ class TestMergerStages:
 
     def test_four_stages_off_path_and_parts_inside_the_whole(
             self, lane_server):
+        import socket
+
         srv, chan, _post = lane_server
         # two intervals: the interners restart at each flush, so every
-        # series is first-sight again in the second
-        for _ in range(2):
-            send_and_merge(srv, self.LINES)
-            srv.flush()
-            chan.get_flush()
-            st = stages_of(srv.obs_timeline.entries()[-1])
-            parts = ("ingest.merge.lock_wait", "ingest.merge.remap",
-                     "ingest.merge.stage")
-            for name in ("ingest.merge",) + parts:
-                assert st[name]["off_path"] is True, name
-                assert st[name]["start_ns"] == 0, name
-            merge = st["ingest.merge"]
-            assert merge["rows_interned"] == len(self.LINES)
-            assert merge["chunks"] >= 1
-            assert st["ingest.merge.remap"]["duration_ns"] > 0
-            assert st["ingest.merge.stage"]["duration_ns"] > 0
-            assert 0 < sum(st[p]["duration_ns"] for p in parts) \
-                <= merge["duration_ns"]
+        # series is first-sight again in the second, where the lane
+        # (one socket, so one lane) has every identity cached
+        with socket.socket(socket.AF_INET, socket.SOCK_DGRAM) as sock:
+            for interval in range(2):
+                send_and_merge(srv, self.LINES, sock)
+                srv.flush()
+                chan.get_flush()
+                entry = srv.obs_timeline.entries()[-1]
+                st = stages_of(entry)
+                parts = ("ingest.merge.lock_wait", "ingest.merge.remap",
+                         "ingest.merge.stage")
+                for name in ("ingest.merge",) + parts:
+                    assert st[name]["off_path"] is True, name
+                    assert st[name]["start_ns"] == 0, name
+                merge = st["ingest.merge"]
+                assert merge["rows_interned"] == len(self.LINES)
+                assert merge["rows_reinterned"] == interval * len(self.LINES)
+                assert entry["ingest_merge"] == {
+                    "rows_interned": merge["rows_interned"],
+                    "rows_reinterned": merge["rows_reinterned"]}
+                assert merge["chunks"] >= 1
+                assert st["ingest.merge.remap"]["duration_ns"] > 0
+                assert st["ingest.merge.stage"]["duration_ns"] > 0
+                assert 0 < sum(st[p]["duration_ns"] for p in parts) \
+                    <= merge["duration_ns"]
 
     def test_known_rows_are_not_interned_again(self, lane_server):
         import socket

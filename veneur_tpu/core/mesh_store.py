@@ -347,6 +347,7 @@ class _PlacementMixin:
 
     router: Optional[ShardRouter]
     placement: Optional[ShardPlacement]
+    _bulk_intern = False  # every new row is routed in _row
 
     def _route_new_row(self, row: int, key) -> None:
         """Assign a freshly interned logical row to its shard (the

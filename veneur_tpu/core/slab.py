@@ -596,6 +596,7 @@ class SlabDigestGroup(OverloadLimited):
     """
 
     _retired = False  # see core.store.DigestGroup._retired
+    _bulk_intern = True  # slabs are placed by ensure_capacity at staging
 
     def __init__(self, slab_rows: int = SLAB_ROWS_DEFAULT,
                  chunk: int = 1 << 16,

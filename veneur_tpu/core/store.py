@@ -239,6 +239,11 @@ class OverloadLimited:
     _overload = None        # overload.OverloadController
     _quarantine = None      # overload.Quarantine (shared ledger)
     _compute = None         # resilience.compute.ComputeBreaker
+    # True where the group's ``_row`` is ``_intern_row`` plus a capacity
+    # grow and nothing else, so ``intern_many`` may intern a batch
+    # without it; a group whose ``_row`` acts on each new row (a mesh's
+    # shard routing, a top-k sid, a tiered dense slot) leaves it False
+    _bulk_intern = False
 
     def _intern_row(self, key: MetricKey, tags: List[str]) -> int:
         """Interner hit -> its row; first-sight -> a fresh row, or the
@@ -257,6 +262,41 @@ class OverloadLimited:
                 and not freeze_exempt(key.name)):
             return self._spill_row()
         return interner.intern(key, tags)
+
+    @requires_lock("store")
+    def intern_many(self, keys: List[MetricKey],
+                    tags: List[List[str]]) -> List[int]:
+        """``_row`` of each key in order, in one pass where nothing can
+        spill: the group interns plainly (``_bulk_intern``), the cap
+        leaves room for every key being new, and the overload
+        controller is not freezing first-sight series (read once for
+        the batch). Otherwise each key goes through ``_row``, so spill,
+        freeze and a group's own per-row work are the same row for row.
+        The one pass grows no capacity: the caller grows it to the
+        largest row before staging, as the bulk paths do."""
+        interner = self.interner
+        ms = self.max_series
+        ctl = self._overload
+        if (not self._bulk_intern
+                or (ms and len(interner) + len(keys)
+                    > (ms if self._overflow_row >= 0 else ms - 1))
+                or (ctl is not None and ctl.freeze_new_series())):
+            row = self._row
+            return [row(k, t) for k, t in zip(keys, tags)]
+        rows = interner.rows
+        setdefault = rows.setdefault
+        names, row_tags, joined = (interner.names, interner.tags,
+                                   interner.joined)
+        out = []
+        for key, t in zip(keys, tags):
+            n = len(rows)
+            row = setdefault(key, n)
+            if row == n:
+                names.append(key.name)
+                row_tags.append(t)
+                joined.append(key.joined_tags)
+            out.append(row)
+        return out
 
     def _spill_row(self) -> int:
         if self._overflow_row < 0:
@@ -444,6 +484,7 @@ class ScalarGroup(OverloadLimited):
     """
 
     _retired = False  # see DigestGroup._retired
+    _bulk_intern = True
 
     def __init__(self, kind: str, capacity: int = DEFAULT_INITIAL_CAPACITY):
         self.kind = kind
@@ -455,6 +496,8 @@ class ScalarGroup(OverloadLimited):
             self.values = np.zeros(capacity, np.float64)
         self.messages: Optional[List[str]] = [] if kind == "status" else None
         self.hostnames: Optional[List[str]] = [] if kind == "status" else None
+        if kind == "status":
+            self._bulk_intern = False  # _row grows messages a new row
 
     def __len__(self):
         return len(self.interner)
@@ -779,6 +822,7 @@ class DigestGroup(OverloadLimited):
     # in-place-reset level instead of 3 planes (retired + fresh twin +
     # pointless post-flush reinit)
     _retired = False
+    _bulk_intern = True
 
     def __init__(self, capacity: int = DEFAULT_INITIAL_CAPACITY,
                  chunk: int = DEFAULT_CHUNK,
@@ -1404,6 +1448,7 @@ class SetGroup(OverloadLimited):
     """
 
     _retired = False  # see DigestGroup._retired
+    _bulk_intern = True
 
     def __init__(self, capacity: int = DEFAULT_INITIAL_CAPACITY,
                  chunk: int = DEFAULT_CHUNK,
@@ -2452,6 +2497,10 @@ class MetricStore:
         self._native_table = None
         self._mlist_table = None
         self._kind_groups = None
+        # the lanes' series identities a kind, keyed by a lane registry
+        # entry's (name, tags) bytes (_lane_remap): kept across flush
+        # epochs and lanes, so a returning series is decoded once
+        self._lane_identities: List[dict] = [{} for _ in range(11)]
         # import_columnar's clock, drained by take_import_stages
         self.import_ns = dict.fromkeys(
             ("messages", "decode", "lock_wait", "intern", "stage",
@@ -2694,15 +2743,24 @@ class MetricStore:
         return self._kind_groups[kind]
 
     @requires_lock("store")
+    def _native_identity(self, t: int, name_b: bytes,
+                         tags_b: bytes) -> Tuple[MetricKey, List[str], bool]:
+        """A native record's series identity: its ``MetricKey``, its tag
+        list and whether the tag-length cap cut the tags (counted here,
+        once; a caller that interns the identity again counts again)."""
+        raw = tags_b.decode("utf-8", "replace")
+        joined = self._truncate_tags(raw)
+        key = MetricKey(name=name_b.decode("utf-8", "replace"),
+                        type=_NATIVE_TYPE_NAMES[t], joined_tags=joined)
+        return (key, joined.split(",") if joined else [],
+                len(joined) < len(raw))
+
+    @requires_lock("store")
     def _intern_native(self, t: int, sc: int, name_b: bytes,
                        tags_b: bytes) -> Tuple[int, object, int]:
         """Slow path of the native-batch cache: decode strings, pick the
         scope-class group (worker.go:96-157), intern the row."""
-        name = name_b.decode("utf-8", "replace")
-        joined = self._truncate_tags(tags_b.decode("utf-8", "replace"))
-        tags = joined.split(",") if joined else []
-        key = MetricKey(name=name, type=_NATIVE_TYPE_NAMES[t],
-                        joined_tags=joined)
+        key, tags, _ = self._native_identity(t, name_b, tags_b)
         if t == 0:
             if sc == GLOBAL_ONLY:
                 kind, group = _K_GLOBAL_COUNTER, self.global_counters
@@ -3060,9 +3118,10 @@ class MetricStore:
         ``timing`` (the merger's ``IngestFleet.merge_ns``, or None with
         stage tracing off) gains the chunk's ns waiting for the lock
         (``lock_wait``), remapping every kind's lane rows (``remap``;
-        ``rows_interned`` counts the first-sight rows it interned; of
-        it ``route``, a mesh's placing of those rows on their shards,
-        read off the router's own clock) and
+        ``rows_interned`` counts the first-sight rows it interned and
+        ``rows_reinterned`` those of them interned from an identity the
+        store had cached; of it ``route``, a mesh's placing of those
+        rows on their shards, read off the router's own clock) and
         staging them (``stage``): four clock reads a chunk, which is
         why the kinds are remapped first and staged after.
 
@@ -3076,14 +3135,15 @@ class MetricStore:
                 resolver.epoch = self.flush_epoch
             for kind, new in chunk.new_entries.items():
                 resolver.entries[kind].extend(new)
-            interned = 0
+            interned = reinterned = 0
             staged = []
             router = self.shard_router if timing is not None else None
             placed = router.place_ns if router is not None else 0
             for kind, span in chunk.spans.items():
-                remap, first_sight = self._lane_remap(kind, resolver,
-                                                      span[0])
+                remap, first_sight, cached = self._lane_remap(
+                    kind, resolver, span[0])
                 interned += first_sight
+                reinterned += cached
                 staged.append((kind, span, remap[span[0]]))
             t2 = time.monotonic_ns() if timing is not None else 0
             for kind, span, grp_rows in staged:
@@ -3109,6 +3169,7 @@ class MetricStore:
                     timing["route"] += router.place_ns - placed
                 timing["stage"] += time.monotonic_ns() - t2
                 timing["rows_interned"] += interned
+                timing["rows_reinterned"] += reinterned
         return chunk.raws
 
     @requires_lock("store")
@@ -3119,11 +3180,22 @@ class MetricStore:
         an idle series the lane once saw is NOT resurrected into every
         fresh store generation (it would emit as zero forever), and
         the under-lock work is bounded by the chunk's live rows, not
-        the lane's lifetime registry. Interning goes through
-        _intern_native, so the tag-length cap and the overload
-        spill/freeze semantics apply to lane-merged series exactly as
-        to every other ingest path. Returns the array and how many
-        rows this call interned."""
+        the lane's lifetime registry.
+
+        A series is decoded once: ``_native_identity``'s key, tag list
+        and truncation flag are kept under the entry's (name, tags)
+        bytes in ``_lane_identities``, where every other lane that
+        carries the series finds them, and in place of the bytes in the
+        lane's registry, so every later flush epoch interns them again
+        as they are. The memo of a kind starts over when it holds as
+        many series as a lane's registry may (``max_series``, else
+        2^20); the registries keep what they hold.
+        The chunk's unresolved rows of the kind intern as one batch
+        (``intern_many``), so the tag-length cap (counted each time an
+        identity is interned) and the overload spill/freeze semantics
+        apply to lane-merged series exactly as to every other ingest
+        path. Returns the array, how many rows this call interned and
+        how many of them from a cached identity."""
         entries = resolver.entries[kind]
         remap = resolver.remap[kind]
         if remap is None or len(remap) < len(entries):
@@ -3133,12 +3205,32 @@ class MetricStore:
             remap = resolver.remap[kind] = grown
         needed = np.unique(rows)
         todo = needed[remap[needed] < 0]
-        if len(todo):
-            t, sc = self._KIND_NATIVE[kind]
-            for r in todo:
-                name_b, tags_b = entries[int(r)]
-                remap[r] = self._intern_native(t, sc, name_b, tags_b)[2]
-        return remap, len(todo)
+        if not len(todo):
+            return remap, 0, 0
+        t = self._KIND_NATIVE[kind][0]
+        memo = self._lane_identities[kind]
+        limit = self.max_series or (1 << 20)
+        keys, tags = [], []
+        decoded = cut = 0
+        for r in todo.tolist():
+            ident = entries[r]
+            if len(ident) == 2:  # the lane's bytes: its first resolve
+                raw, ident = ident, memo.get(ident)
+                if ident is None:
+                    if len(memo) >= limit:
+                        memo.clear()
+                    # a decode counts its own cut (_truncate_tags)
+                    ident = memo[raw] = self._native_identity(t, *raw)
+                    decoded += 1
+                    cut -= ident[2]
+                entries[r] = ident
+            cut += ident[2]
+            keys.append(ident[0])
+            tags.append(ident[1])
+        if cut:
+            self.quarantine.count("oversized_tags", cut)
+        remap[todo] = self._group_for_kind(kind).intern_many(keys, tags)
+        return remap, len(todo), len(todo) - decoded
 
     @acquires_lock("store")
     def import_topk(self, table: np.ndarray, series: List[tuple]):

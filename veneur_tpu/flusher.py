@@ -137,7 +137,8 @@ def _publish_interval(server, span, rec, timeline):
                 rec.record_abs("ingest.merge", rec.t0_ns,
                                rec.t0_ns + merger["merge"], off_path=True,
                                chunks=merger["chunks"],
-                               rows_interned=merger["rows_interned"])
+                               rows_interned=merger["rows_interned"],
+                               rows_reinterned=merger["rows_reinterned"])
                 stages = ["lock_wait", "remap", "stage"]
                 if getattr(getattr(server, "store", None), "mesh",
                            None) is not None:
@@ -179,6 +180,14 @@ def _publish_interval(server, span, rec, timeline):
                                     for s in sampled),
             "guard_drains": sum(s.get("mesh_ingest_guard_drains", 0)
                                 for s in entry["stages"])}
+    # the merger's interning (core/store.py import_lane_chunk counts
+    # it): the lane rows it interned, and of them those interned from
+    # an identity the lane's resolver had cached
+    merge = next((s for s in entry["stages"] if s["name"] == "ingest.merge"),
+                 None)
+    if merge is not None:
+        entry["ingest_merge"] = {field: merge[field] for field in
+                                 ("rows_interned", "rows_reinterned")}
     # and the dense sample path's: its dispatches, the rows they
     # drained before binning into them, the drain loop's trips
     sampled = [s for s in entry["stages"] if "ingest_samples_dispatches" in s]

@@ -169,7 +169,10 @@ class SealedChunk:
 class LaneResolver:
     """Merger-side lane-row -> store-row state for one lane intern
     generation. ``entries[kind]`` accumulates the lane's (name, tags)
-    registry in row order; ``remap[kind]`` is the resolved store-row
+    registry in row order, an entry's bytes replaced by the series'
+    decoded identity the first time a chunk references its row (so the
+    identity lives as long as the generation); ``remap[kind]`` is the
+    resolved store-row
     array, dropped whole when the store's flush epoch moves (fresh
     generation twins restart their interners) and rebuilt lazily under
     the store lock (``MetricStore._lane_remap``)."""
@@ -769,12 +772,14 @@ class IngestFleet:
         # _merge_chunk (``merge``) and, filled by import_lane_chunk,
         # the wait for the store lock, the lane-row remap with its
         # first-sight interning (of it ``route``, a mesh's placing of
-        # those rows), and the staging calls; plus chunks merged and
-        # rows interned. Written under _merge_lock only;
+        # those rows), and the staging calls; plus chunks merged, rows
+        # interned and, of those, rows interned from an identity the
+        # store had cached. Written under _merge_lock only;
         # None when stage tracing is off (no clock is read then).
         self.merge_ns: Optional[Dict[str, int]] = dict.fromkeys(
             ("merge", "lock_wait", "remap", "route", "stage", "chunks",
-             "rows_interned"), 0) if trace_stages else None
+             "rows_interned", "rows_reinterned"),
+            0) if trace_stages else None
         self.unrouted_raws: list = []  # only without a raw_handler (tests)
         intern_limit = (intern_limit
                         or getattr(store, "max_series", 0) or (1 << 20))
